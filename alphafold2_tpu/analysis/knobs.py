@@ -241,8 +241,6 @@ _GROUPS = [
     ("AF2TPU_KERNELS_BENCH_", "kernel microbench"),
     ("AF2TPU_KERNELS", "kernel backend selection"),
     ("AF2TPU_BENCH_", "bench harness"),
-    ("AF2TPU_SESSION_", "TPU session orchestration"),
-    ("AF2TPU_TRAIN_REAL_", "real-data training session"),
     ("AF2TPU_", "core / misc"),
 ]
 

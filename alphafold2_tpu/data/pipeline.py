@@ -486,26 +486,23 @@ def make_dataset(config: DataConfig, seed: int = 0):
     if config.source == "native":
         from alphafold2_tpu.data import native
 
-        if native.available():
-            # data_dir set -> real npz shards through the native prefetch
-            # ring; otherwise the native synthetic stream
-            if config.data_dir:
-                if shards_carry_msa(config):
-                    import warnings
-
-                    warnings.warn(MSA_FALLBACK_WARNING)
-                    return NpzShardDataset(config, seed=seed)
-                return native.NativeShardLoader(config, seed=seed)
-            return native.NativeSyntheticLoader(config, seed=seed)
-        import warnings
-
-        warnings.warn(
-            "native loader requested but libaf2data.so is not built "
-            "(make -C native); falling back to the numpy pipeline"
-        )
+        if not native.available():
+            # same error native.py's own entry points raise: a requested
+            # loader is never swapped for the numpy pipeline
+            raise RuntimeError(
+                "data.source='native' but libaf2data.so is not built "
+                "(make -C native)"
+            )
+        # data_dir set -> real npz shards through the native prefetch
+        # ring; otherwise the native synthetic stream
         if config.data_dir:
-            return NpzShardDataset(config, seed=seed)
-        return SyntheticDataset(config, seed=seed)
+            if shards_carry_msa(config):
+                import warnings
+
+                warnings.warn(MSA_FALLBACK_WARNING)
+                return NpzShardDataset(config, seed=seed)
+            return native.NativeShardLoader(config, seed=seed)
+        return native.NativeSyntheticLoader(config, seed=seed)
     if config.source == "npz":
         return NpzShardDataset(config, seed=seed)
     if config.source == "sidechainnet":

@@ -16,7 +16,7 @@ Flax defaults differ materially: Dense kernels are lecun-normal
 (std 1/sqrt(fan_in), vs torch's uniform with std 1/sqrt(3*fan_in)), biases
 are zeros (vs torch's uniform), and ``nn.Embed`` draws N(0, 1/features) —
 at dim 256 the reference's token embeddings are 16x larger in scale.
-VERDICT r3 named this distribution mismatch the prime suspect for the
+A review named this distribution mismatch the prime suspect for the
 flagship-width in-distribution quality gap; re-drawing an initialized tree
 under the torch rules isolates init alone while keeping data, optimizer,
 and architecture bit-identical.

@@ -1,9 +1,7 @@
-"""Unified observability subsystem: spans, histograms, telemetry, liveness.
+"""Unified observability subsystem: spans, histograms, telemetry.
 
-The reference's only observability is ``print`` (SURVEY.md S5.1/S5.5), and
-round 5 showed why that is fatal at scale: a whole bench deadline burned
-hung in ``backend_init`` with no structured signal. This package is the
-first-class answer:
+The reference's only observability is ``print`` (SURVEY.md S5.1/S5.5);
+this package is the first-class answer:
 
 - :mod:`tracing` — ``Tracer``/``Span``: nested span tracing emitted as
   Chrome-trace-event JSONL, loadable in Perfetto / ``chrome://tracing``,
@@ -15,10 +13,6 @@ first-class answer:
   thread-safe ``EventCounters`` (compile counts, cache hits, totals).
 - :mod:`memory` — ``MemorySampler`` over ``device.memory_stats()`` (HBM
   peaks; graceful no-op on backends that expose none).
-- :mod:`watchdog` — ``LivenessWatchdog``: a heartbeat thread with
-  per-stage deadlines backed by a cheap subprocess backend probe, so a
-  dead-at-start backend produces a structured ``liveness: dead`` failure
-  in seconds instead of eating a whole deadline.
 - :mod:`profiler` — ``Profiler``: jax.profiler XLA trace over a step
   window (TensorBoard/XProf), unchanged from the original train hook.
 - :mod:`numerics` — in-graph per-tensor telemetry: ``tag(name, x)``
@@ -38,8 +32,8 @@ first-class answer:
 - :mod:`slo` — declarative ``SLOSpec`` objectives with multi-window
   burn-rate alerting over the resolved-request stream.
 - :mod:`flightrec` — ``FlightRecorder``: bounded rings of recent
-  telemetry dumped as a scrubbed incident file on watchdog fire,
-  dispatch error, or SIGTERM.
+  telemetry dumped as a scrubbed incident file on dispatch error or
+  SIGTERM.
 - :mod:`workload` — ``WorkloadRecorder``: the request STREAM itself as
   a scrubbed, replayable JSONL artifact (fingerprints, not sequences,
   unless opted in), plus the replay builder and the seeded synthetic
@@ -67,7 +61,6 @@ from alphafold2_tpu.observe.tracectx import (
     use_trace,
 )
 from alphafold2_tpu.observe.tracing import Span, Tracer
-from alphafold2_tpu.observe.watchdog import LivenessWatchdog, probe_backend
 from alphafold2_tpu.observe.workload import (
     WorkloadRecorder,
     build_replay,
@@ -79,7 +72,6 @@ __all__ = [
     "EventCounters",
     "FlightRecorder",
     "Histogram",
-    "LivenessWatchdog",
     "MemorySampler",
     "MetricsLogger",
     "MetricsRegistry",
@@ -96,7 +88,6 @@ __all__ = [
     "load_workload",
     "numerics",
     "parse_slo_specs",
-    "probe_backend",
     "regress",
     "scrub_env",
     "synthetic_diurnal",

@@ -24,22 +24,13 @@ PEAK_FLOPS = {
     "TPU v6e": 918e12,
 }
 
-# no production chip sustains 2 PFLOP/s dense bf16 today (v6e peaks at
-# 918 TF); a measurement implying more is a broken clock on ANY device,
-# known or not — the unknown-device fallback for the implausibility guard
-SANITY_FLOPS_CEILING = 2e15
-
-
 def cost_analysis(compiled) -> dict:
     """Normalized XLA cost-analysis properties of a compiled executable.
 
-    Handles the older-jax list-of-per-device-dicts form; returns ``{}`` when
-    the backend exposes nothing (cost analysis is best-effort and must never
-    break a measurement)."""
+    Returns ``{}`` when the backend exposes nothing (cost analysis is
+    best-effort and must never break a measurement)."""
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         return dict(cost) if cost else {}
     except Exception:
         return {}
@@ -86,19 +77,23 @@ def executable_memory(compiled) -> dict:
 
 def device_peak_flops(device=None) -> Optional[float]:
     """Published peak dense bf16 FLOPs/s of ``device`` (default: the first
-    jax device); None for chips the table does not know (CPUs included)."""
-    try:
-        if device is None:
-            import jax
+    jax device). None on the host CPU, where no utilization is reported at
+    all; an accelerator whose ``device_kind`` is not in ``PEAK_FLOPS`` is an
+    error — a peak is looked up, never estimated."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = device.device_kind
-        return next(
-            (v for k, v in PEAK_FLOPS.items() if k.lower() in kind.lower()),
-            None,
-        )
-    except Exception:
+        device = jax.devices()[0]
+    if device.platform == "cpu":
         return None
+    kind = device.device_kind
+    for k, v in PEAK_FLOPS.items():
+        if k.lower() in kind.lower():
+            return v
+    raise ValueError(
+        f"unknown device_kind {kind!r}: add its published peak (with the "
+        "source) to observe.flops.PEAK_FLOPS before measuring on it"
+    )
 
 
 def mfu(
@@ -106,15 +101,16 @@ def mfu(
     seconds: float,
     device=None,
     peak: Optional[float] = None,
+    n_devices: int = 1,
 ) -> Optional[float]:
-    """Model FLOPs utilization: ``flops / seconds / peak``. None when the
-    flop count or the chip's peak is unknown."""
+    """Model FLOPs utilization: ``flops / seconds / (peak * n_devices)``.
+    None when the flop count is unknown or the run is on the host CPU."""
     if not flops or not seconds or seconds <= 0:
         return None
     peak = peak if peak is not None else device_peak_flops(device)
     if not peak:
         return None
-    return flops / seconds / peak
+    return flops / seconds / (peak * max(1, n_devices))
 
 
 def estimate_mfu(compiled, step_seconds: float) -> Optional[float]:
@@ -168,72 +164,3 @@ def attention_flops_attribution(
     if total_flops:
         out["other"] = max(0.0, float(total_flops) - sum(out.values()))
     return out
-
-
-# one measured-peak probe per process (keyed by device kind)
-_CALIBRATED: dict = {}
-
-
-def calibrated_peak_flops(device=None, n: int = 1024, iters: int = 8):
-    """MEASURED dense-matmul peak FLOPs/s for chips the published table
-    does not know (the CPU mesh above all): times a jitted f32 matmul of
-    known cost. This is what lets the serve bench report an honest MFU on
-    the 8-virtual-device CPU mesh — utilization against the host's own
-    measured matmul roofline, labeled as such (``mfu_basis``), never
-    against a made-up CPU "peak". Virtual devices share the physical
-    silicon, so the calibration is per HOST and callers must not multiply
-    it by the virtual device count. Cached per device kind."""
-    import time
-
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        device = device if device is not None else jax.devices()[0]
-        kind = device.device_kind
-        if kind in _CALIBRATED:
-            return _CALIBRATED[kind]
-        x = jax.device_put(jnp.ones((n, n), jnp.float32), device)
-        f = jax.jit(lambda a: a @ a)
-        jax.block_until_ready(f(x))  # compile + warm outside the timing
-        t0 = time.perf_counter()
-        y = x
-        for _ in range(iters):
-            y = f(y)
-        jax.block_until_ready(y)
-        peak = iters * 2 * n**3 / max(time.perf_counter() - t0, 1e-9)
-        _CALIBRATED[kind] = peak
-        return peak
-    except Exception:
-        return None
-
-
-def mesh_mfu(flops: Optional[float], seconds: float, mesh=None) -> dict:
-    """MFU of a (possibly sharded) program: ``{"mfu": ..., "mfu_basis":
-    "published-peak" | "calibrated-matmul"}`` (empty values -> {"mfu":
-    None}). On chips with a published peak the denominator is
-    peak * n_devices (the multi-chip MFU the ROADMAP wants from the
-    sharded serve path); on unknown chips (CPU mesh) it is the measured
-    host matmul roofline — virtual devices share silicon, so no
-    multiplier."""
-    if not flops or not seconds or seconds <= 0:
-        return {"mfu": None}
-    peak = device_peak_flops()
-    if peak is not None:
-        n_dev = 1
-        if mesh is not None:
-            try:
-                n_dev = int(mesh.devices.size)
-            except Exception:
-                n_dev = 1
-        return {
-            "mfu": flops / seconds / (peak * max(1, n_dev)),
-            "mfu_basis": "published-peak",
-        }
-    peak = calibrated_peak_flops()
-    if not peak:
-        return {"mfu": None}
-    return {
-        "mfu": flops / seconds / peak,
-        "mfu_basis": "calibrated-matmul",
-    }

@@ -193,7 +193,7 @@ class WorkloadRecorder:
     def observe(self, result, priority: int) -> None:
         """Resolution hook (``AsyncServeFrontend.add_observer``): one
         event per resolution, linked to its submit by trace id. Only the
-        structured taxonomy is recorded — error text can quote request
+        structured class is recorded — error text can quote request
         content, so it stays out of the log."""
         try:
             ev = {

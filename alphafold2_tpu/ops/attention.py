@@ -41,6 +41,23 @@ from flax import linen as nn
 MASK_VALUE = -1e9
 
 
+def _kernel_hook(kernel_fn, sharded: bool):
+    """An ``attn_fn`` hook around a Pallas kernel. ``sharded=True`` means the
+    hook already runs per device inside the grid shard_map; otherwise it is
+    called on global arrays, where an active mesh needs the kernel wrapped
+    (parallel.sharding.per_device)."""
+    if sharded:
+        return kernel_fn
+    from alphafold2_tpu.parallel.sharding import per_device
+
+    def attn_fn(q2, k2, v2, m2):
+        return per_device(kernel_fn, q2, k2, v2, m2)
+
+    if hasattr(kernel_fn, "accepts"):
+        attn_fn.accepts = kernel_fn.accepts
+    return attn_fn
+
+
 def grid_axial_project_attend(
     to_q, to_kv, to_out, heads, dim_head, x, mask, attend_axis, attn_fn,
     sharded,
@@ -168,16 +185,18 @@ class Attention(nn.Module):
             # by explicit KernelPolicy, never silently
             from alphafold2_tpu.ops.pallas.axial import axial_attn_fn
 
-            attn_fn = axial_attn_fn(dh**-0.5)
+            attn_fn = _kernel_hook(axial_attn_fn(dh**-0.5), sharded)
         elif impl == "dense":
             attn_fn = None  # debug escape: plain per-device dense attention
         elif self._use_flash():
             from alphafold2_tpu.ops.flash import flash_attention
 
-            def attn_fn(q2, k2, v2, m2):
+            def flash_fn(q2, k2, v2, m2):
                 return flash_attention(
                     q2, k2, v2, q_mask=m2, kv_mask=m2, sm_scale=dh**-0.5
                 )
+
+            attn_fn = _kernel_hook(flash_fn, sharded)
         else:
             # off-TPU long-chain path: exact streamed attention once the
             # per-device logits would cross the chunk threshold; declines
@@ -289,16 +308,16 @@ class Attention(nn.Module):
         # in VMEM instead of HBM.
         if self._use_flash() and fused_ok:
             from alphafold2_tpu.ops.flash import flash_attention
+            from alphafold2_tpu.parallel.sharding import per_device
 
-            out = flash_attention(
-                heads_first(q),
-                heads_first(k),
-                heads_first(v),
-                q_mask=mask,
-                kv_mask=kv_mask,
-                sm_scale=scale,
+            out = per_device(
+                lambda q, k, v, qm, km: flash_attention(
+                    q, k, v, q_mask=qm, kv_mask=km, sm_scale=scale
+                ),
+                heads_first(q), heads_first(k), heads_first(v),
+                mask, kv_mask,
             )
-            if out is not None:
+            if out is not None:  # declined: off-TPU, or under one block
                 return project_out(out)
 
         # exact streamed attention off-TPU once the dense logits would
@@ -382,10 +401,15 @@ class Attention(nn.Module):
                     tied_row_attention,
                 )
 
+                from alphafold2_tpu.parallel.sharding import per_device
+
                 km = context_mask if has_context else mask
-                out = tied_row_attention(
-                    q, k, v, q_mask=mask, kv_mask=km,
-                    sm_scale=scale, tie_scale=tie_scale,
+                out = per_device(
+                    lambda q, k, v, qm, km, ts: tied_row_attention(
+                        q, k, v, q_mask=qm, kv_mask=km, sm_scale=scale,
+                        tie_scale=ts,
+                    ),
+                    q, k, v, mask, km, tie_scale,
                 )  # (B, R, n, h, dh)
                 out = out.reshape(-1, *out.shape[2:])
                 out = out.reshape(*out.shape[:-2], inner)

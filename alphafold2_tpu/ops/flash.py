@@ -10,28 +10,18 @@ pad=0: cross-segment pairs are masked inside the kernel).
 
 Used automatically by :class:`ops.attention.Attention` on TPU backends for
 the un-tied paths, including KV-compressed cross-attention (the kernel
-sees the already-compressed k/v and the pooled mask); everything falls
-back to the jnp dense path off-TPU or if the kernel rejects the shape
-(trace-time validation is caught and logged once).
+sees the already-compressed k/v and the pooled mask). Off-TPU, and for
+sequences shorter than one 128 block on both axes, the caller takes the jnp
+dense path; a shape the kernel rejects on TPU is an error, never a silent
+dense run (65,536 x 4,096 x 8 logits at the flagship cross-attention).
 """
 
 from __future__ import annotations
 
-import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-_WARNED = set()
-
-
-def warn_once(key: str, message: str) -> None:
-    """De-duplicated warning — trace-time fallbacks fire per call site but
-    should reach the user once (shared by the flash and sparse modules)."""
-    if key not in _WARNED:
-        _WARNED.add(key)
-        warnings.warn(message)
 
 
 def flash_available() -> bool:
@@ -48,8 +38,9 @@ def flash_attention(
 ) -> Optional[jnp.ndarray]:
     """Fused attention via the stock Pallas TPU kernel.
 
-    Returns None when the kernel cannot take this call (wrong backend or
-    shape constraints) — the caller falls back to the dense jnp path.
+    Returns None off-TPU and for short sequences (both axes under one 128
+    block) — the caller takes the dense jnp path there by design. Whatever
+    the kernel itself refuses propagates.
     """
     if not flash_available():
         return None
@@ -99,13 +90,5 @@ def flash_attention(
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    try:
-        out = _fa(q, k, v, segment_ids=segment_ids, sm_scale=sm_scale)
-    except (ValueError, NotImplementedError) as e:
-        warn_once(
-            str(e)[:80],
-            f"flash attention unavailable for shape q={q.shape} "
-            f"k={k.shape}: {e}; using dense attention",
-        )
-        return None
+    out = _fa(q, k, v, segment_ids=segment_ids, sm_scale=sm_scale)
     return out[:, :, :nq] if pad_q else out

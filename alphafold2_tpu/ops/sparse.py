@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 from typing import Optional
 
 import jax
@@ -39,7 +40,7 @@ import numpy as np
 from flax import linen as nn
 
 from alphafold2_tpu.ops.attention import MASK_VALUE, grid_axial_project_attend
-from alphafold2_tpu.ops.flash import warn_once
+from alphafold2_tpu.parallel.sharding import per_device
 
 
 @dataclasses.dataclass(frozen=True)
@@ -233,7 +234,7 @@ def _block_layout_mask_cls():
         """Element-level view of a block-level layout, evaluated lazily:
         __getitem__ maps the requested element indices to layout blocks,
         touching only the requested chunk — nothing O(n^2) is ever
-        materialized, at any sequence length (ADVICE r2)."""
+        materialized, at any sequence length."""
 
         def __init__(self, layout: np.ndarray, block_size: int):
             self._layout = layout
@@ -252,7 +253,7 @@ def _block_layout_mask_cls():
             r = np.arange(self.shape[0])[idx[0]] // self._bs
             c = np.arange(self.shape[1])[idx[1]] // self._bs
             # dispatch on the ORIGINAL index types, not the resolved
-            # arrays (ADVICE r3): numpy gives slice-involved indexing
+            # arrays: numpy gives slice-involved indexing
             # outer-product semantics but array+array element-wise
             # *paired/broadcast* semantics, and a dense ndarray mask would
             # honor both — np.ix_ on a resolved integer-array pair would
@@ -317,18 +318,15 @@ def block_sparse_attention_splash(
 
     b, h, n, d = q.shape
     if n % 128 != 0:
-        # the splash kernel's q/kv block size is 128: shorter/unaligned
-        # sequences fall back to the gather oracle (same contract as
-        # ops/flash.py — warn once, never crash training)
-        warn_once(
-            f"splash_unaligned_{n}",
-            f"splash backend needs seq_len % 128 == 0, got {n}; "
-            "falling back to the jnp gather implementation",
+        # the splash kernel's q/kv block size is 128. A backend chosen by
+        # name is never swapped for the gather oracle behind the caller's
+        # back: on a chip that turns a shape bug into a slower run
+        raise ValueError(
+            f"splash backend needs seq_len % 128 == 0, got {n}; pad the "
+            "sequence or pick backend=\"pallas\"/\"jnp\""
         )
-        return block_sparse_attention(q, k, v, layout, block_size, mask=mask)
     if jax.default_backend() != "tpu":
-        warn_once(
-            "splash_interpret",
+        warnings.warn(  # python shows a warning once per call site
             "splash backend off-TPU runs the kernel in Pallas interpret "
             "mode (orders of magnitude slower) — fine for tests, wrong "
             "for real runs; use backend=\"auto\" or \"jnp\" off-TPU",
@@ -399,6 +397,20 @@ class SparseAttention(nn.Module):
 
         return impls[resolve_block_sparse()]
 
+    def _attend(self, q, k, v, mask, layout, wrap: bool):
+        """The selected backend on (B, H, N, D) arrays. A kernel called on
+        global arrays (``wrap``) runs per device under an active mesh
+        (parallel.sharding.per_device); the jnp oracle is left to GSPMD."""
+        impl = self._impl()
+        bs = self.config.block_size
+
+        def call(q, k, v, m):
+            return impl(q, k, v, layout, bs, mask=m)
+
+        if wrap and impl is not block_sparse_attention:
+            return per_device(call, q, k, v, mask)
+        return call(q, k, v, mask)
+
     def grid_axial(self, x, mask=None, attend_axis: int = 2,
                    sharded: bool = True):
         """Block-sparse self-attention along ONE axis of a (B, H, W, D) grid
@@ -420,10 +432,10 @@ class SparseAttention(nn.Module):
                 f"attended axis {n_att} exceeds max_seq_len {self.seq_len}"
             )
         layout = self.config.layout(n_att)
-        impl = self._impl()
 
         def attn_fn(q2, k2, v2, m2):
-            return impl(q2, k2, v2, layout, bs, mask=m2)
+            # inside the grid shard_map the call is per device already
+            return self._attend(q2, k2, v2, m2, layout, wrap=not sharded)
 
         return grid_axial_project_attend(
             self.to_q, self.to_kv, self.to_out, h, dh,
@@ -471,7 +483,7 @@ class SparseAttention(nn.Module):
 
         q, k, v = heads_first(q), heads_first(k), heads_first(v)
         layout = self.config.layout(padded_n)
-        out = self._impl()(q, k, v, layout, bs, mask=mask)
+        out = self._attend(q, k, v, mask, layout, wrap=True)
 
         out = jnp.moveaxis(out, 1, 2).reshape(b, padded_n, inner)
         out = self.to_out(out)

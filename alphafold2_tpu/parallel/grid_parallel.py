@@ -30,14 +30,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from alphafold2_tpu.ops.attention import MASK_VALUE
-from alphafold2_tpu.parallel.sharding import (
-    axis_size_compat,
-    shard_map_compat as shard_map,
-)
 
 DATA_AXIS_NAME = "dp"
 ROW_AXIS_NAME = "spr"  # shards grid axis 1 (rows / height)
@@ -52,7 +48,8 @@ def make_grid_mesh(
     Device order comes from ``mesh_utils.create_device_mesh`` so the spr/spc
     axes land on physically-adjacent chips (their per-layer all_to_all
     transposes then ride ICI, with dp crossing DCN — same placement policy
-    as distributed.pod_mesh); falls back to raw order off-TPU."""
+    as distributed.pod_mesh). Only the host CPU's virtual devices take raw
+    order; on an accelerator a layout the helper refuses is an error."""
     import numpy as np
 
     devices = devices if devices is not None else jax.devices()
@@ -61,14 +58,15 @@ def make_grid_mesh(
         raise ValueError(
             f"mesh {n_data}x{n_row}x{n_col} != {len(devices)} devices"
         )
-    try:
+    if devices[0].platform == "cpu":
+        # virtual host devices: any order works, nothing to optimize
+        arr = np.asarray(devices).reshape(n_data, n_row, n_col)
+    else:
         from jax.experimental import mesh_utils
 
         arr = mesh_utils.create_device_mesh(
             (n_data, n_row, n_col), devices=devices
         )
-    except Exception:  # non-TPU backends: any order works, nothing to optimize
-        arr = np.asarray(devices).reshape(n_data, n_row, n_col)
     return Mesh(arr, (DATA_AXIS_NAME, ROW_AXIS_NAME, COL_AXIS_NAME))
 
 
@@ -122,7 +120,7 @@ def _sharded_pass(q, k, v, mask, attend_axis: int, attn_fn=None):
         gather_name, split_axis = ROW_AXIS_NAME, 2
     else:
         raise ValueError(f"attend_axis must be 1 or 2, got {attend_axis}")
-    size = axis_size_compat(gather_name)
+    size = lax.axis_size(gather_name)
     if q.shape[split_axis] % size:
         raise ValueError(
             f"non-attended local axis {q.shape[split_axis]} must divide by "

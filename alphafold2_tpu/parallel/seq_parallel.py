@@ -34,14 +34,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from alphafold2_tpu.ops.attention import MASK_VALUE
-from alphafold2_tpu.parallel.sharding import (
-    axis_size_compat,
-    shard_map_compat as shard_map,
-)
 
 SEQ_AXIS_NAME = "sp"
 DATA_AXIS_NAME = "dp"
@@ -69,7 +65,7 @@ def ring_attention(
     into (running_max, running_sum, accumulator); rotate KV one hop with
     ppermute. After ``sp`` steps every query block has seen every key.
     """
-    sp = axis_size_compat(axis_name)
+    sp = lax.axis_size(axis_name)
     scale = q.shape[-1] ** -0.5
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -96,8 +92,12 @@ def ring_attention(
         b_nxt = lax.ppermute(bias_cur, axis_name, perm)
         return (m_new, l_new, acc_new, k_nxt, v_nxt, b_nxt), None
 
+    # checkpointed: the backward pass recomputes each visiting block's
+    # (n_local x n_local) probabilities from q and the rotated k instead of
+    # keeping sp of them — at the flagship cross-attention one is 4 GB
     (m, l, acc, _, _, _), _ = lax.scan(
-        body, (m0, l0, acc0, k, v, kmask_bias), None, length=sp
+        jax.checkpoint(body), (m0, l0, acc0, k, v, kmask_bias), None,
+        length=sp,
     )
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
@@ -111,7 +111,7 @@ def ulysses_attention(
 ) -> jnp.ndarray:
     """All-to-all sequence parallelism (Ulysses): re-shard seq -> heads,
     attend densely over the full sequence locally, re-shard back."""
-    sp = axis_size_compat(axis_name)
+    sp = lax.axis_size(axis_name)
     if q.shape[1] % sp != 0:
         raise ValueError(
             f"heads {q.shape[1]} must divide by sp={sp} for ulysses"

@@ -36,40 +36,6 @@ SEQ_AXIS = "sp"
 _active: dict = {"mesh": None}
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    The public ``jax.shard_map`` (with its ``check_vma`` kwarg) only exists
-    on newer jax; older versions ship it as ``jax.experimental.shard_map``
-    where the same knob is spelled ``check_rep``. Every shard_map in this
-    package goes through here so a version bump is a one-line change."""
-    try:
-        from jax import shard_map as _sm
-
-        return _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-
-def axis_size_compat(axis_name: str):
-    """``lax.axis_size`` across jax versions: absent on older jax, where
-    ``psum(1, axis)`` is the idiomatic (constant-folded) equivalent."""
-    from jax import lax
-
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:
-        return lax.psum(1, axis_name)
-
-
 def make_mesh(
     n_data: Optional[int] = None, n_seq: int = 1, devices=None
 ) -> Mesh:
@@ -101,6 +67,42 @@ def use_mesh(mesh: Mesh):
 
 def active_mesh() -> Optional[Mesh]:
     return _active["mesh"]
+
+
+def per_device(fn, *args):
+    """``fn(*args)`` where ``fn`` holds a Pallas kernel, which GSPMD cannot
+    partition ("Mosaic kernels cannot be automatically partitioned"): under
+    an active mesh the call runs inside a ``shard_map``, each device on its
+    own slice. Without a mesh it is the plain call.
+
+    Every array in ``args`` and the result carry the same leading axis, and
+    work along it is independent (batch, or batch x rows folded); None and
+    scalar entries pass through as they are. The axis is split over the
+    mesh axes that divide it, taken in mesh order — 512 folded rows on
+    (dp=2, sp=2) become P((dp, sp)), the pair stream's own layout; a batch
+    of 2 becomes P(dp). A mesh axis that does not divide what is left
+    repeats the work on its devices: correct and wasteful, never wrong."""
+    mesh = _active["mesh"]
+    if mesh is None:
+        return fn(*args)
+    present = [i for i, a in enumerate(args) if getattr(a, "ndim", 0)]
+    left = args[present[0]].shape[0]
+    names = []
+    for name, size in mesh.shape.items():
+        if left % size == 0:
+            names.append(name)
+            left //= size
+    spec = P(tuple(names)) if names else P()
+
+    def local(*arrays):
+        full = list(args)
+        for i, a in zip(present, arrays):
+            full[i] = a
+        return fn(*full)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
+    )(*(args[i] for i in present))
 
 
 def _constrain(x, spec: P):

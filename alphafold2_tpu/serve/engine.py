@@ -32,6 +32,7 @@ layer the ROADMAP north star needs instead:
 from __future__ import annotations
 
 import dataclasses
+import re
 import threading
 import time
 import warnings
@@ -115,8 +116,8 @@ class ServeRequest:
 
 @dataclasses.dataclass
 class ServeResult:
-    """One request's outcome. ``status`` is the structured failure
-    taxonomy: ``"ok"`` (arrays populated), ``"error"`` (dispatch raised —
+    """One request's outcome. ``status`` is one of the structured failure
+    classes: ``"ok"`` (arrays populated), ``"error"`` (dispatch raised —
     converted, never propagated, so a batch partner's poison pill cannot
     crash the caller), ``"rejected"`` (admission control turned the request
     away; ``retry_after_s`` hints when to come back), or
@@ -583,8 +584,9 @@ class ServeEngine:
             # would show up as donated_args without any unusable report
             # AND without aliasing, which tests/test_serve_pipeline.py pins
             **({"donated_args": len(donate)} if donate else {}),
-            **({"donation_unusable":
-                str(donation_notes[0].message).count("ShapedArray")}
+            # jax lists them as "int32[2,8], bool[2,8], ..."
+            **({"donation_unusable": len(re.findall(
+                r"\w+\[[^\]]*\]", str(donation_notes[0].message)))}
                if donate and donation_notes else {}),
             **({"mesh": self.mesh_desc} if self.mesh_desc else {}),
             # precision/kernel keys ride only when non-default so records

@@ -46,24 +46,12 @@ class CheckpointManager:
         latest = self._mgr.latest_step()
         if latest is None:
             raise FileNotFoundError(f"no checkpoint found under {self._dir!r}")
-        import inspect
-
-        if "partial_restore" in inspect.signature(
-            ocp.args.PyTreeRestore
-        ).parameters:
-            restored = self._mgr.restore(
-                latest,
-                args=ocp.args.PyTreeRestore(
-                    item={"params": params_template}, partial_restore=True
-                ),
-            )
-        else:
-            # older orbax has no partial_restore and requires item trees to
-            # match the saved structure; restore template-free (numpy, from
-            # saved metadata) and slice the params subtree out. Costs a
-            # transient opt_state read but keeps inference independent of
-            # the training run's optimizer tree shape.
-            restored = self._mgr.restore(latest, args=ocp.args.PyTreeRestore())
+        restored = self._mgr.restore(
+            latest,
+            args=ocp.args.PyTreeRestore(
+                item={"params": params_template}, partial_restore=True
+            ),
+        )
         return restored["params"], latest
 
     def latest_step(self) -> Optional[int]:

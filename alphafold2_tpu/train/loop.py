@@ -467,7 +467,6 @@ def device_put_batch(batch: dict, mesh: Optional[Mesh] = None) -> dict:
 def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=()):
     """Distogram pretraining driver (the runnable train_pre.py equivalent)."""
     import os
-    import sys
     import time
 
     from alphafold2_tpu.data.pipeline import make_dataset
@@ -546,6 +545,11 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
     start_step = 0
     if ckpt is not None:
         state, start_step = ckpt.maybe_restore(state)
+    if mesh is not None and jax.process_count() == 1:
+        # place the state where every later step will find it (replicated
+        # over the mesh): handed over on one device, step 0 compiles for
+        # that placement and step 1 compiles the same program again
+        state = jax.device_put(state, NamedSharding(mesh, P()))
 
     logger = MetricsLogger(cfg.train.checkpoint_dir)
     profiler = Profiler(cfg.train.profile_dir, cfg.train.profile_steps)
@@ -603,25 +607,19 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
     step_call = step_fn
     step_flops = None
     if mesh is None and jax.process_count() == 1:
-        try:
-            t_c = time.perf_counter()
-            with tracer.span("train.compile"):
-                compiled = step_fn.lower(state, batch, rng).compile()
-            compile_s = time.perf_counter() - t_c
-            costs = flops_mod.executable_costs(compiled)
-            step_flops = costs["flops"]
-            step_call = compiled
-            logger.log(start_step, {
-                "compile_s": round(compile_s, 3),
-                **({"step_flops": step_flops} if step_flops else {}),
-                **({"step_bytes_accessed": costs["bytes_accessed"]}
-                   if costs["bytes_accessed"] else {}),
-            })
-        except Exception as e:  # AOT is an optimization; never block training
-            print(
-                f"train-step AOT compile unavailable ({type(e).__name__}: "
-                f"{e}); falling back to jit", file=sys.stderr,
-            )
+        t_c = time.perf_counter()
+        with tracer.span("train.compile"):
+            compiled = step_fn.lower(state, batch, rng).compile()
+        compile_s = time.perf_counter() - t_c
+        costs = flops_mod.executable_costs(compiled)
+        step_flops = costs["flops"]
+        step_call = compiled
+        logger.log(start_step, {
+            "compile_s": round(compile_s, 3),
+            **({"step_flops": step_flops} if step_flops else {}),
+            **({"step_bytes_accessed": costs["bytes_accessed"]}
+               if costs["bytes_accessed"] else {}),
+        })
 
     # NaN triage (numerics_mode "triage"/"full"): when a step's non-finite-
     # grad skip fired, rerun it fully tagged and report the first bad

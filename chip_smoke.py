@@ -1,0 +1,633 @@
+#!/usr/bin/env python
+"""First light on the attached TPU: drive the main path once, check it, say so.
+
+    python chip_smoke.py                # one chip: train, serve, kernels
+    python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh only
+
+Needs a TPU. Where ``jax.devices()[0].platform`` is anything else the script
+says so and exits non-zero: there is no platform override and no CPU mode
+(the CPU rehearsal of the same phase functions is tests/test_chip_smoke.py).
+One process, the only one that touches JAX; nothing here starts another.
+
+Default phases, on one chip, at the width of the flagship (bench.py):
+
+1. train  — ``alphafold2_tpu.train.loop.train(cfg)``, what train_pre.py
+   calls, for a few optimizer steps on synthetic data from a seed.
+2. serve  — a ``ServeEngine`` behind ``AsyncServeFrontend`` with the
+   dispatch pipeline on, answering requests over three buckets, twice.
+3. kernels — the three in-repo Pallas kernels, forward and gradient,
+   against plain jnp references at float32 / highest matmul precision.
+
+Every phase prints one JSON object on a line of its own. The LAST line of
+stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
+carries nothing else; on any failure it is ``{"ok": false, ...}`` and the
+exit code is 1.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+# what the error of a kernel against its reference may be, relative to the
+# reference's largest entry; set from the dtype before the first chip run
+# (bfloat16 keeps 8 mantissa bits; float32 leaves room for a matmul that
+# the MXU takes in bfloat16 passes on one side of the comparison)
+KERNEL_TOL = {"bfloat16": 4e-2, "float32": 2e-2}
+# one-chip and dp2 x sp2 losses: the same bfloat16 step, summed in another
+# order across chips
+MESH_LOSS_TOL = 5e-2
+
+FLAGSHIP = dict(
+    dim=256, heads=8, dim_head=64, depth=2, crop=256, msa_depth=16,
+    msa_len=256, batch=1,
+)
+# about eight requests over three rungs of the default ladder
+# (64, 96, 128, 192, 256), the top one included
+SERVE_LENGTHS = (40, 64, 100, 128, 120, 200, 256, 230)
+
+# What 16 GB forced, found by compiling for a described v5e before any chip
+# run (tests/test_chip_compile.py keeps the train-step compiles). Widths are
+# never cut; every phase prints its entry under "cut".
+CUTS = {
+    "serve": {
+        "max_batch": "4 -> 2: the bucket-256 executable elongates to 768 "
+        "tokens, and at batch 4 its program needs 22.6 GB of the 15.75 GB "
+        "a v5e chip offers; at batch 2 it needs 11.95 GB",
+    },
+    "mesh": {
+        "one_device_remat": "off -> on, one-device twin only: at global "
+        "batch 2 its program needs 14.56 GB without remat and 7.94 GB with "
+        "it; the dp2 x sp2 program (6.64 GB per device) runs as the "
+        "flagship does, and remat changes no value",
+    },
+}
+SERVE_MAX_BATCH = 2
+
+
+def emit(record: dict) -> None:
+    print(json.dumps(record), flush=True)
+
+
+def device_record() -> dict:
+    import jax
+
+    d = jax.devices()
+    return {"platform": d[0].platform, "kind": d[0].device_kind,
+            "count": len(d)}
+
+
+def peak_bytes() -> list:
+    """``peak_bytes_in_use`` of every device (None where the backend keeps
+    no allocator statistics, as the host CPU does)."""
+    import jax
+
+    return [
+        (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.devices()
+    ]
+
+
+def program_bytes(compiled) -> int:
+    """Arguments + outputs + temporaries of a compiled program, per device."""
+    ma = compiled.memory_analysis()
+    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+               + ma.temp_size_in_bytes)
+
+
+# ------------------------------------------------------------------ train ---
+
+
+def train_config(sizes: dict, *, steps: int, seed: int = 0, dp: int = 1,
+                 sp: int = 1, bfloat16: bool = True):
+    """The flagship train config of bench.py at ``sizes``; ``dp``/``sp``
+    lay it over a (dp, sp) mesh with ring context parallelism."""
+    from alphafold2_tpu.config import (
+        Config, DataConfig, MeshConfig, ModelConfig, TrainConfig,
+    )
+
+    return Config(
+        model=ModelConfig(
+            dim=sizes["dim"], depth=sizes["depth"], heads=sizes["heads"],
+            dim_head=sizes["dim_head"], max_seq_len=sizes["crop"] * 2,
+            msa_tie_row_attn=True, bfloat16=bfloat16,
+            context_parallel="ring" if sp > 1 else None,
+        ),
+        mesh=MeshConfig(data_parallel=dp, seq_parallel=sp),
+        data=DataConfig(
+            crop_len=sizes["crop"], msa_depth=sizes["msa_depth"],
+            msa_len=sizes["msa_len"], batch_size=sizes["batch"],
+            min_len_filter=sizes["crop"],  # full-length crops
+        ),
+        train=TrainConfig(
+            gradient_accumulate_every=1, warmup_steps=2, num_steps=steps,
+            seed=seed,
+        ),
+    )
+
+
+def compile_train_step(cfg):
+    """Lower and compile the step ``train(cfg)`` builds — same model, state,
+    first batch, mesh and numerics mode — to time the compile and read the
+    program. ``train()`` then finds it in the compile cache. Returns
+    (compiled, seconds)."""
+    import jax
+
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.train.loop import (
+        apply_features, build_model, device_put_batch, make_train_step,
+        tiny_init_state,
+    )
+
+    dataset = make_dataset(cfg.data, seed=cfg.train.seed)
+    sample = next(apply_features(iter(dataset), cfg))
+    model = build_model(cfg)
+    state = tiny_init_state(cfg, model, sample)
+    mesh = None
+    if cfg.mesh.data_parallel * cfg.mesh.seq_parallel > 1:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from alphafold2_tpu.parallel.distributed import pod_mesh
+
+        mesh = pod_mesh(cfg.mesh.data_parallel, cfg.mesh.seq_parallel)
+        state = jax.device_put(state, NamedSharding(mesh, PartitionSpec()))
+    step = make_train_step(model, mesh, numerics_mode="norms")
+    t0 = time.perf_counter()
+    compiled = step.lower(
+        state, device_put_batch(sample, mesh),
+        jax.random.key(cfg.train.seed + 1),
+    ).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def run_train(cfg, steps: int) -> dict:
+    """``train(cfg)`` for ``steps`` steps; loss VALUES fetched each step, so
+    a step's seconds end when the device has finished it."""
+    from alphafold2_tpu.train.loop import train
+
+    losses, skipped, stamps = [], [], []
+
+    def on_step(i, state, metrics):
+        losses.append(float(metrics["loss"]))
+        skipped.append(int(metrics["skipped"]))
+        stamps.append(time.perf_counter())
+
+    t0 = time.perf_counter()
+    train(cfg, num_steps=steps, callbacks=[on_step])
+    return {
+        "losses": losses,
+        "skipped": skipped[-1] if skipped else None,
+        # set-up, the (cached) compile and step 0
+        "first_step_s": round(stamps[0] - t0, 3),
+        "step_s": [round(b - a, 4) for a, b in zip(stamps, stamps[1:])],
+    }
+
+
+def check_losses(losses: list, skipped, steps: int) -> None:
+    import math
+
+    if len(losses) != steps:
+        raise RuntimeError(f"{len(losses)} losses for {steps} steps")
+    if not all(math.isfinite(x) for x in losses):
+        raise RuntimeError(f"non-finite loss: {losses}")
+    if skipped:
+        raise RuntimeError(f"{skipped} step(s) skipped as non-finite")
+    if any(a == b for a, b in zip(losses, losses[1:])):
+        raise RuntimeError(f"loss did not change between steps: {losses}")
+
+
+def phase_train(sizes: dict = FLAGSHIP, steps: int = 4, seed: int = 0,
+                bfloat16: bool = True) -> dict:
+    cfg = train_config(sizes, steps=steps, seed=seed, bfloat16=bfloat16)
+    compiled, compile_s = compile_train_step(cfg)
+    has_kernel = "tpu_custom_call" in compiled.as_text()
+    step_bytes = program_bytes(compiled)
+    del compiled
+    run = run_train(cfg, steps)
+    check_losses(run["losses"], run["skipped"], steps)
+    return {
+        "phase": "train", "sizes": sizes, "steps": steps, "seed": seed,
+        "cut": None,
+        "compile_s": round(compile_s, 2),
+        "has_tpu_custom_call": has_kernel,
+        "program_bytes": step_bytes,
+        **run,
+        "peak_bytes_in_use": peak_bytes()[0],
+    }
+
+
+# ------------------------------------------------------------------ serve ---
+
+
+def serve_config(sizes: dict, buckets=None, seed: int = 0,
+                 bfloat16: bool = True):
+    """Model at the flagship width behind the default ServeConfig (ladder,
+    mds_iters, pipeline depth 2) with ``max_batch`` as CUTS says;
+    ``buckets`` only shrinks the ladder for the CPU rehearsal."""
+    from alphafold2_tpu.config import (
+        Config, DataConfig, ModelConfig, ServeConfig, TrainConfig,
+    )
+
+    serve = (
+        ServeConfig(max_batch=SERVE_MAX_BATCH) if buckets is None
+        else ServeConfig(buckets=tuple(buckets), mds_iters=10,
+                         max_batch=SERVE_MAX_BATCH)
+    )
+    return Config(
+        model=ModelConfig(
+            dim=sizes["dim"], depth=sizes["depth"], heads=sizes["heads"],
+            dim_head=sizes["dim_head"], max_seq_len=3 * max(serve.buckets),
+            bfloat16=bfloat16,
+        ),
+        data=DataConfig(msa_depth=sizes["serve_msa_depth"]),
+        train=TrainConfig(seed=seed),
+        serve=serve,
+    )
+
+
+def _sequences(lengths, seed: int) -> list:
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    alphabet = "ACDEFGHIKLMNPQRSTVWY"
+    return ["".join(rng.choice(list(alphabet), n)) for n in lengths]
+
+
+def phase_serve(sizes: dict = {**FLAGSHIP, "serve_msa_depth": 5},
+                lengths=SERVE_LENGTHS, buckets=None, seed: int = 0,
+                bfloat16: bool = True, timeout_s: float = 900.0) -> dict:
+    import numpy as np
+
+    from alphafold2_tpu.serve import (
+        AsyncServeFrontend, ServeEngine, ServeRequest,
+    )
+
+    cfg = serve_config(sizes, buckets, seed, bfloat16)
+    engine = ServeEngine(cfg)
+    if engine.pipeline is None:
+        raise RuntimeError("dispatch pipeline is off")
+    frontend = AsyncServeFrontend(engine)
+    passes = []
+    try:
+        for n_pass in range(2):
+            # other sequences and seeds on the second pass: same lengths,
+            # but nothing the result cache could answer
+            seqs = _sequences(lengths, seed + 1000 * n_pass)
+            handles = [
+                frontend.submit(ServeRequest(s, seed=seed + n_pass))
+                for s in seqs
+            ]
+            results = [h.result(timeout=timeout_s) for h in handles]
+            for s, r in zip(seqs, results):
+                if r.status != "ok" or r.retried or r.cache_hit:
+                    raise RuntimeError(
+                        f"request len {len(s)}: status={r.status} "
+                        f"retried={r.retried} cache_hit={r.cache_hit} "
+                        f"error={r.error}"
+                    )
+                if r.atom14.shape != (len(s), 14, 3):
+                    raise RuntimeError(
+                        f"request len {len(s)}: coords {r.atom14.shape}"
+                    )
+                if not np.all(np.isfinite(r.atom14)):
+                    raise RuntimeError(
+                        f"request len {len(s)}: non-finite coordinates"
+                    )
+            passes.append({
+                "latency_s": [round(r.latency_s, 3) for r in results],
+                "buckets": [r.bucket for r in results],
+                "compiles": len(engine.compile_records),
+            })
+    finally:
+        frontend.close()
+        engine.close()
+    if passes[1]["compiles"] != passes[0]["compiles"]:
+        raise RuntimeError(
+            f"second pass compiled: {passes[0]['compiles']} -> "
+            f"{passes[1]['compiles']} executables"
+        )
+    if len(set(passes[0]["buckets"])) < 3:
+        raise RuntimeError(f"fewer than 3 buckets: {passes[0]['buckets']}")
+    stats = engine.stats()
+    if stats.get("serve.dispatch_errors") or stats.get("sched.retries"):
+        raise RuntimeError(f"dispatch errors or retries: {stats}")
+    return {
+        "phase": "serve", "sizes": sizes, "lengths": list(lengths),
+        "ladder": list(engine.buckets), "max_batch": engine.max_batch,
+        "mds_iters": cfg.serve.mds_iters, "pipeline": engine.pipeline_desc,
+        "cut": CUTS["serve"],
+        "compile_s": {
+            str(r["bucket"]): r["seconds"] for r in engine.compile_records
+        },
+        "passes": passes,
+        "peak_bytes_in_use": peak_bytes()[0],
+    }
+
+
+# ---------------------------------------------------------------- kernels ---
+
+
+def _ref_attention(q, k, v, q_mask, kv_mask, scale):
+    """Plain softmax attention, (B, H, N, D) layout, float32."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    dots = jnp.einsum("bhid,bhjd->bhij", q, k) * scale
+    if kv_mask is not None:
+        dots = jnp.where(kv_mask[:, None, None, :], dots, -1e30)
+    out = jnp.einsum("bhij,bhjd->bhid", jax.nn.softmax(dots, axis=-1), v)
+    if q_mask is not None:  # the kernels zero masked queries
+        out = jnp.where(q_mask[:, None, :, None], out, 0)
+    return out
+
+
+def _ref_tied(q, k, v, q_mask, kv_mask, scale):
+    """Tied-row attention, (B, R, N, H, D) layout: one attention matrix per
+    (batch, head), logits summed over the R rows and scaled by R**-0.5."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    dots = jnp.einsum("brihd,brjhd->bhij", q, k) * scale * q.shape[1] ** -0.5
+    if kv_mask is not None:
+        dots = jnp.where(kv_mask[:, None, None, :], dots, -1e30)
+    out = jnp.einsum("bhij,brjhd->brihd", jax.nn.softmax(dots, axis=-1), v)
+    if q_mask is not None:
+        out = jnp.where(q_mask[:, None, :, None, None], out, 0)
+    return out
+
+
+def _tail_mask(b: int, n: int, pad: int):
+    import jax.numpy as jnp
+
+    return jnp.ones((b, n), bool).at[:, n - pad:].set(False) if pad else None
+
+
+def kernel_cases(small: bool = False) -> list:
+    """(name, kernel fn, reference fn, input shapes, dtype) for the three
+    in-repo kernels at the shapes the flagship reaches, plus one masked
+    odd-length case each. ``small`` is the CPU rehearsal's size."""
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.ops.pallas.axial import fused_attention
+    from alphafold2_tpu.ops.pallas.tied_row import tied_row_attention
+    from alphafold2_tpu.ops.sparse import (
+        BlockSparseConfig, block_sparse_attention,
+        block_sparse_attention_pallas,
+    )
+
+    cases = []
+
+    def axial(name, shape, dtype, pad):
+        b, h, n, d = shape
+        m = _tail_mask(b, n, pad)
+        cases.append((
+            name,
+            lambda q, k, v: fused_attention(
+                q, k, v, q_mask=m, kv_mask=m, sm_scale=d ** -0.5),
+            lambda q, k, v: _ref_attention(q, k, v, m, m, d ** -0.5),
+            (shape,) * 3, dtype,
+        ))
+
+    def tied(name, shape, dtype, pad):
+        b, r, n, h, d = shape
+        m = _tail_mask(b, n, pad)
+
+        def zeroed(t):  # padded positions abstain: the caller zeroes them
+            return t if m is None else jnp.where(
+                m[:, None, :, None, None], t, 0)
+
+        cases.append((
+            name,
+            lambda q, k, v: tied_row_attention(
+                zeroed(q), zeroed(k), zeroed(v), q_mask=m, kv_mask=m,
+                sm_scale=d ** -0.5),
+            lambda q, k, v: _ref_tied(
+                zeroed(q), zeroed(k), zeroed(v), m, m, d ** -0.5),
+            (shape,) * 3, dtype,
+        ))
+
+    def sparse(name, n, block, pad):
+        shape = (1, 4, n, 64 if not small else 16)
+        layout = BlockSparseConfig(
+            block_size=block, num_local_blocks=4, num_global_blocks=1,
+            num_random_blocks=None,
+        ).layout(n)
+        m = _tail_mask(1, n, pad)
+        cases.append((
+            name,
+            lambda q, k, v: block_sparse_attention_pallas(
+                q, k, v, layout, block, mask=m),
+            # the repo's gather-based jnp implementation of the same layout
+            lambda q, k, v: block_sparse_attention(
+                q, k, v, layout, block, mask=m),
+            (shape,) * 3, "float32",
+        ))
+
+    if small:
+        axial("fused_axial_f32", (2, 2, 32, 16), "float32", 0)
+        axial("fused_axial_masked_odd", (1, 2, 40, 16), "float32", 7)
+        tied("tied_row_f32", (1, 3, 32, 2, 16), "float32", 0)
+        tied("tied_row_masked_odd", (1, 3, 40, 2, 16), "float32", 5)
+        sparse("block_sparse_n64", 64, 16, 0)
+        sparse("block_sparse_masked", 64, 16, 5)
+        return cases
+    for dt in ("bfloat16", "float32"):
+        axial(f"fused_axial_{dt}", (256, 8, 256, 64), dt, 0)
+        tied(f"tied_row_{dt}", (1, 16, 256, 8, 64), dt, 0)
+    axial("fused_axial_masked_odd", (4, 8, 200, 64), "float32", 17)
+    tied("tied_row_masked_odd", (1, 5, 200, 8, 64), "float32", 9)
+    sparse("block_sparse_n512", 512, 128, 0)
+    sparse("block_sparse_n1024_masked", 1024, 128, 17)
+    return cases
+
+
+def phase_kernels(small: bool = False, seed: int = 0) -> dict:
+    """Forward and gradient of every case against its reference. The
+    reference runs in float32 at the highest matmul precision on the same
+    (dtype-rounded) inputs; the error is the largest absolute difference
+    over the reference's largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    def rel_err(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-6))
+
+    def fwd_and_grad(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    results, failed = [], []
+    for i, (name, kernel, ref, shapes, dtype) in enumerate(
+            kernel_cases(small)):
+        keys = jax.random.split(jax.random.key(seed + i), len(shapes))
+        args = [
+            jax.random.normal(kk, s, jnp.float32).astype(dtype)
+            for kk, s in zip(keys, shapes)
+        ]
+        (_, out), grads = fwd_and_grad(kernel)(*args)
+        with jax.default_matmul_precision("highest"):
+            (_, out_r), grads_r = fwd_and_grad(ref)(*args)
+        errs = {
+            "fwd": rel_err(out, out_r),
+            **{f"d{n}": rel_err(g, gr)
+               for n, g, gr in zip("qkv", grads, grads_r)},
+        }
+        tol = KERNEL_TOL[dtype]
+        ok = all(e == e and e <= tol for e in errs.values())  # e==e: no NaN
+        if not ok:
+            failed.append(name)
+        results.append({
+            "case": name, "dtype": dtype, "shape": list(shapes[0]),
+            "tol": tol, "ok": ok,
+            **{k: float(f"{v:.3g}") for k, v in errs.items()},
+        })
+    if failed:
+        raise RuntimeError(
+            f"kernels disagree with their references: {failed}: "
+            + json.dumps([r for r in results if not r["ok"]])
+        )
+    return {"phase": "kernels", "cases": results}
+
+
+# ------------------------------------------------------------- four chips ---
+
+
+def phase_mesh(sizes: dict = {**FLAGSHIP, "batch": 2}, steps: int = 3,
+               seed: int = 0, bfloat16: bool = True,
+               tol: float = MESH_LOSS_TOL) -> dict:
+    """The train step on a dp2 x sp2 mesh (ring context parallelism, global
+    batch 2) through ``train(cfg)``, against the same steps from the same
+    seed on one device."""
+    import jax
+
+    if len(jax.devices()) < 4:
+        raise RuntimeError(f"need 4 devices, have {len(jax.devices())}")
+    cfg_mesh = train_config(sizes, steps=steps, seed=seed, dp=2, sp=2,
+                            bfloat16=bfloat16)
+    cfg_one = train_config(sizes, steps=steps, seed=seed,
+                           bfloat16=bfloat16)
+    cfg_one.model.remat = True  # CUTS["mesh"]
+
+    compiled, compile_s = compile_train_step(cfg_mesh)
+    text = compiled.as_text()
+    collectives = {
+        name: text.count(f" {name}(") + text.count(f" {name}-start(")
+        for name in ("all-reduce", "collective-permute", "all-gather",
+                     "all-to-all")
+    }
+    per_device_program_bytes = program_bytes(compiled)
+    argument_bytes = int(compiled.memory_analysis().argument_size_in_bytes)
+    del compiled, text
+    for need in ("all-reduce", "collective-permute"):
+        if not collectives[need]:
+            raise RuntimeError(f"no {need} in the mesh program: {collectives}")
+
+    mesh_run = run_train(cfg_mesh, steps)
+    check_losses(mesh_run["losses"], mesh_run["skipped"], steps)
+    in_use = [
+        (d.memory_stats() or {}).get("bytes_in_use") for d in jax.devices()
+    ]
+    peaks = peak_bytes()
+    one_run = run_train(cfg_one, steps)
+    check_losses(one_run["losses"], one_run["skipped"], steps)
+
+    diffs = [abs(a - b) for a, b in zip(mesh_run["losses"],
+                                        one_run["losses"])]
+    record = {
+        "phase": "mesh", "sizes": sizes, "layout": "dp2 x sp2",
+        "context_parallel": "ring", "steps": steps, "seed": seed,
+        "cut": CUTS["mesh"],
+        "compile_s": round(compile_s, 2), "collectives": collectives,
+        "per_device_program_bytes": per_device_program_bytes,
+        "per_device_argument_bytes": argument_bytes,
+        "mesh_run": mesh_run, "one_device_run": one_run,
+        "loss_abs_diff": [float(f"{d:.3g}") for d in diffs], "tol": tol,
+        "bytes_in_use_after_mesh_run": in_use,
+        "peak_bytes_in_use_after_mesh_run": peaks,
+    }
+    if max(diffs) > tol:
+        raise RuntimeError(
+            f"mesh and one-device losses differ by {max(diffs):.3g} > "
+            f"{tol}: {json.dumps(record)}"
+        )
+    # the CPU keeps no allocator statistics: only an accelerator can show
+    # that every device held bytes, and at its peak at least the program's
+    # arguments (the replicated state and its slice of the batch)
+    if jax.devices()[0].platform != "cpu" and not all(
+        use and peak >= argument_bytes for use, peak in zip(in_use, peaks)
+    ):
+        raise RuntimeError(
+            f"a device held less than the program's {argument_bytes} "
+            f"argument bytes: in use {in_use}, peak {peaks}"
+        )
+    return record
+
+
+# ------------------------------------------------------------------- main ---
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument(
+        "--four-chips", action="store_true",
+        help="run only the (dp2, sp2) mesh phase and its one-chip twin",
+    )
+    args = ap.parse_args(argv)
+    try:
+        import jax
+
+        device = device_record()
+        if device["platform"] != "tpu":
+            raise RuntimeError(
+                f"chip_smoke needs a TPU; JAX found {device} — run it "
+                "through the chip tool, there is no CPU mode"
+            )
+        want = 4 if args.four_chips else 1
+        if device["count"] != want:
+            raise RuntimeError(
+                ("--four-chips needs 4 chips" if args.four_chips
+                 else "the default phases need exactly 1 chip")
+                + f", JAX found {device['count']}"
+            )
+        import alphafold2_tpu
+
+        alphafold2_tpu.enable_compile_cache()
+        emit({"phase": "start", "device": device,
+              "jax": jax.__version__,
+              "compile_cache": alphafold2_tpu.compile_cache_dir()})
+        t0 = time.perf_counter()
+        if args.four_chips:
+            emit(phase_mesh())
+        else:
+            train_rec = phase_train()
+            emit(train_rec)
+            if not train_rec["has_tpu_custom_call"]:
+                raise RuntimeError(
+                    "the compiled train step holds no tpu_custom_call: no "
+                    "Pallas kernel ran (the dense pair<->MSA cross-attention "
+                    "is 65,536 x 4,096 x 8 logits at this size)"
+                )
+            emit(phase_serve())
+            emit(phase_kernels())
+        emit({"phase": "done", "seconds": round(time.perf_counter() - t0, 1)})
+    except Exception as e:  # the boundary: report, then fail
+        import traceback
+
+        traceback.print_exc()
+        emit({"ok": False, "error": f"{type(e).__name__}: {e}"[:2000]})
+        return 1
+    emit({"ok": True, "device": device})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -27,9 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-import alphafold2_tpu
-
-alphafold2_tpu.setup_platform("cpu")  # matched-pair runs are host-side
+os.environ["JAX_PLATFORMS"] = "cpu"  # matched-pair runs are host-side
 
 
 def main() -> int:

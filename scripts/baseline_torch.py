@@ -33,9 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-import alphafold2_tpu
-
-alphafold2_tpu.setup_platform("cpu")  # jax side (labels/metrics) stays on host
+os.environ["JAX_PLATFORMS"] = "cpu"  # jax side (labels/metrics) stays on host
 
 
 def _install_stubs():

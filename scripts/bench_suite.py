@@ -24,7 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import alphafold2_tpu
 
-alphafold2_tpu.setup_platform()
+alphafold2_tpu.enable_compile_cache()
 
 import jax
 import jax.numpy as jnp

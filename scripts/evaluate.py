@@ -32,7 +32,7 @@ def main() -> int:
     ap.add_argument("overrides", nargs="*")
     args = ap.parse_args()
 
-    alphafold2_tpu.setup_platform()
+    alphafold2_tpu.enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np

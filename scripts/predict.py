@@ -31,7 +31,7 @@ def main() -> int:
     ap.add_argument("overrides", nargs="*", help="config overrides key=value")
     args = ap.parse_args()
 
-    alphafold2_tpu.setup_platform()
+    alphafold2_tpu.enable_compile_cache()
     from alphafold2_tpu.predict import predict
     from alphafold2_tpu.utils import pdb as pdbio
 

@@ -2,8 +2,8 @@
 the top ops by self time, aggregated from the trace's XLA-op events.
 
 Usage: python scripts/profile_step.py [overrides like AF2TPU_BENCH_* env]
-Writes the raw jax.profiler trace under ~/.cache/af2tpu/profile (inspect with
-tensorboard if available) and prints a text summary so no external viewer
+Writes the raw jax.profiler trace under <checkout>/chiprun_out/profile (the
+directory a chip run brings back; inspect with tensorboard if available) and prints a text summary so no external viewer
 is needed.
 """
 
@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import alphafold2_tpu
 
-alphafold2_tpu.setup_platform()
+alphafold2_tpu.enable_compile_cache()
 
 import jax
 import jax.numpy as jnp
@@ -122,13 +122,12 @@ def summarize(trace_dir: str, n_steps: int, top: int = 30):
 
 
 if __name__ == "__main__":
-    import alphafold2_tpu
-
-    # same default as tpu_session's stage_profile: per-user, not a fixed
-    # world-writable /tmp path (and standalone + session runs share traces)
     trace_dir = os.environ.get(
         "AF2TPU_TRACE_DIR",
-        os.path.join(alphafold2_tpu.user_cache_dir(), "profile"),
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "chiprun_out", "profile",
+        ),
     )
     n = int(os.environ.get("AF2TPU_PROFILE_STEPS", 3))
     run_profiled_steps(trace_dir, n)

@@ -65,7 +65,7 @@ def run_native_relax(pdb_in: str, pdb_out: str, iters: int = 200) -> str:
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     import alphafold2_tpu
 
-    alphafold2_tpu.setup_platform()  # AF2TPU_PLATFORM=cpu for host-side runs
+    alphafold2_tpu.enable_compile_cache()
     import jax
     import numpy as np
 

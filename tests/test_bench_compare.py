@@ -259,14 +259,16 @@ def test_cli_mesh_baseline_routing(bench_compare):
 
 def test_committed_mesh_baseline_is_valid_and_self_consistent():
     """The committed mesh-keyed baseline must be a usable measurement
-    (regress validity taxonomy) carrying the acceptance fields: mesh
+    (regress validity classes) carrying the acceptance fields: mesh
     shape, per-device memory, and MFU accounting."""
     with open(os.path.join(REPO, "bench_serve_mesh_baseline.json")) as f:
         base = json.load(f)
     assert regress.record_invalid_reason(base) is None
     assert base["mesh"] == "dp1.spr2.spc4" and base["mesh_devices"] == 8
     assert base["per_device_program_bytes"] > 0
-    assert base["mfu"] is not None and base["mfu_basis"]
+    # a CPU-mesh record carries no utilization: there is no published
+    # peak for a host CPU and none is estimated
+    assert "mfu" not in base and "mfu_basis" not in base
     assert any(
         c["bucket"] >= 512 and c.get("mesh") for c in base["compile_records"]
     )
@@ -353,7 +355,7 @@ def test_flops_single_parser_and_mfu():
     assert flops.mfu(1e12, 1.0, peak=2e12) == 0.5
     assert flops.mfu(None, 1.0, peak=2e12) is None
     assert flops.mfu(1e12, 0.0, peak=2e12) is None
-    assert flops.device_peak_flops() is None  # CPU is not in the peak table
+    assert flops.device_peak_flops() is None  # host CPU: nothing reported
     assert flops.estimate_mfu(compiled, 1.0) is None
 
     # bench.py sources flops/MFU from observe.flops (single parser in tree)
@@ -361,22 +363,22 @@ def test_flops_single_parser_and_mfu():
 
     assert bench._step_flops is flops.step_flops
     assert bench._estimate_mfu is flops.estimate_mfu
-    assert bench._PEAK_FLOPS is flops.PEAK_FLOPS
+    assert bench._device_peak_flops is flops.device_peak_flops
 
 
-def test_cost_analysis_list_form_and_failure():
+def test_cost_analysis_dict_form_and_failure():
     from alphafold2_tpu.observe import flops
 
-    class ListCompiled:  # older jax: one dict per device
+    class DictCompiled:
         def cost_analysis(self):
-            return [{"flops": 7.0, "bytes accessed": 3.0}]
+            return {"flops": 7.0, "bytes accessed": 3.0}
 
     class Broken:
         def cost_analysis(self):
             raise RuntimeError("no cost analysis on this backend")
 
-    assert flops.step_flops(ListCompiled()) == 7.0
-    assert flops.executable_costs(ListCompiled())["bytes_accessed"] == 3.0
+    assert flops.step_flops(DictCompiled()) == 7.0
+    assert flops.executable_costs(DictCompiled())["bytes_accessed"] == 3.0
     assert flops.step_flops(Broken()) is None
     assert flops.executable_costs(Broken()) == {
         "flops": None, "bytes_accessed": None

@@ -1,6 +1,5 @@
-"""bench_suite subset runs must MERGE into BENCH_SUITE.json (VERDICT r3 #6:
-a partial TPU session re-running one config must not clobber the other
-rows), but only when rows are comparable (same device, same smoke flag)."""
+"""bench_suite subset runs must MERGE into BENCH_SUITE.json (a partial
+run re-running one config must not clobber the other rows), but only when rows are comparable (same device, same smoke flag)."""
 
 import importlib
 import json

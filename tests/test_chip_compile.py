@@ -1,0 +1,280 @@
+"""Compile the main path's kernels for a described TPU v5e, nothing attached.
+
+Interpret mode (every other kernel test here) skips Mosaic, and the lowering
+gate (test_pallas_lowering.py) stops before the chip's compiler. These cases
+go all the way: ``jit(f).lower(shapes on a described v5e chip).compile()``
+raises what the chip's compiler would raise — a slice not aligned to the
+tiling, more fast memory than a kernel may use, a program that does not fit
+16 GB. Shapes are the flagship's (bench.py / chip_smoke.py): dim_head 64,
+8 heads, crop 256, MSA 16 x 256. A compile that passes here is not a chip
+run; chip_smoke.py is.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may load the TPU's library, every xdist worker imports this
+file, and a file that asked at import time would give the workers different
+tests to collect. Keep these cases in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off around the
+    compiles: an executable for a described chip is written to the cache but
+    cannot be read back without one, and the next run would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile ``fn`` for the described chip at ``shapes`` — (shape, dtype)
+    pairs — and return the compiled program's text."""
+    args = [
+        jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=sharding)
+        for s, d in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _grad_of(fn):
+    """Gradient w.r.t. q, k, v of a scalar of ``fn(q, k, v, *rest)``."""
+
+    def loss(q, k, v, *rest):
+        out = fn(q, k, v, *rest)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+# ------------------------------------------------------------ the kernels ---
+
+
+def _fused_axial(q, k, v, mask=None):
+    from alphafold2_tpu.ops.pallas.axial import fused_attention
+
+    return fused_attention(
+        q, k, v, q_mask=mask, kv_mask=mask, sm_scale=q.shape[-1] ** -0.5,
+        interpret=False,
+    )
+
+
+def _tied_row(q, k, v, mask=None):
+    from alphafold2_tpu.ops.pallas.tied_row import tied_row_attention
+
+    return tied_row_attention(
+        q, k, v, q_mask=mask, kv_mask=mask, sm_scale=q.shape[-1] ** -0.5,
+        interpret=False,
+    )
+
+
+def _stock_flash(q, k, v, kv_mask=None):
+    """The stock kernel through the repo's wrapper (padding to the 128
+    block and segment ids included), as Attention.__call__ reaches it."""
+    from alphafold2_tpu.ops.flash import flash_attention
+
+    return flash_attention(q, k, v, kv_mask=kv_mask, sm_scale=0.125)
+
+
+def _block_sparse(n, block=128):
+    from alphafold2_tpu.ops.sparse import (
+        BlockSparseConfig, block_sparse_attention_pallas,
+    )
+
+    layout = BlockSparseConfig(
+        block_size=block, num_local_blocks=4, num_global_blocks=1,
+        num_random_blocks=None,
+    ).layout(n)
+
+    def fn(q, k, v, mask=None):
+        return block_sparse_attention_pallas(
+            q, k, v, layout, block, mask=mask, interpret=False
+        )
+
+    return fn
+
+
+AXIAL = (256, 8, 256, 64)  # (B*N rows, heads, N, dim_head) at crop 256
+TIED = (1, 16, 256, 8, 64)  # (B, MSA rows, N, heads, dim_head)
+PAIR_Q = (1, 8, 256 * 256, 64)  # the flat pair stream as queries
+MSA_KV = (1, 8, 16 * 256, 64)  # the flat MSA stream as keys/values
+# cross_attn_compress_ratio=3 pools 4096 keys to 1366; the wrapper pads
+# them to 1408 with mask-excluded positions
+MSA_KV_COMPRESSED = (1, 8, -(-16 * 256 // 3), 64)
+
+CASES = {
+    "fused_axial_bf16": (_fused_axial, [(AXIAL, "bfloat16")] * 3),
+    "fused_axial_f32": (_fused_axial, [(AXIAL, "float32")] * 3),
+    "tied_row_bf16": (_tied_row, [(TIED, "bfloat16")] * 3),
+    "tied_row_f32": (_tied_row, [(TIED, "float32")] * 3),
+    "stock_flash_axial": (_stock_flash, [(AXIAL, "bfloat16")] * 3),
+    "stock_flash_cross": (
+        _stock_flash,
+        [(PAIR_Q, "bfloat16"), (MSA_KV, "bfloat16"), (MSA_KV, "bfloat16")],
+    ),
+    "stock_flash_compressed_cross": (
+        _stock_flash,
+        [(PAIR_Q, "bfloat16"), (MSA_KV_COMPRESSED, "bfloat16"),
+         (MSA_KV_COMPRESSED, "bfloat16"),
+         ((1, MSA_KV_COMPRESSED[2]), "bool")],  # (B, Nk) pooled key mask
+    ),
+    "block_sparse_n512": (
+        lambda *a: _block_sparse(512)(*a), [((1, 4, 512, 64), "float32")] * 3,
+    ),
+    "block_sparse_n1024": (
+        lambda *a: _block_sparse(1024)(*a),
+        [((1, 4, 1024, 64), "float32")] * 3,
+    ),
+}
+
+# one masked, odd-length case per in-repo kernel (block-sparse lengths are
+# block multiples by construction: its odd part is the masked tail)
+MASKED = {
+    "fused_axial_masked_odd": (
+        _fused_axial,
+        [((4, 8, 200, 64), "float32")] * 3 + [((4, 200), "bool")],
+    ),
+    "tied_row_masked_odd": (
+        _tied_row,
+        [((1, 5, 200, 8, 64), "float32")] * 3 + [((1, 200), "bool")],
+    ),
+    "block_sparse_masked": (
+        lambda *a: _block_sparse(512)(*a),
+        [((1, 4, 512, 64), "float32")] * 3 + [((1, 512), "bool")],
+    ),
+}
+
+
+@pytest.fixture
+def on_tpu_branch(monkeypatch):
+    """ops/flash.py asks ``jax.default_backend()``, which says ``cpu`` here;
+    the test steers it to the branch the chip takes."""
+    from alphafold2_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "flash_available", lambda: True)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, on_tpu_branch, name, direction):
+    fn, shapes = CASES[name]
+    text = _compile(fn if direction == "fwd" else _grad_of(fn), one_chip,
+                    *shapes)
+    assert "tpu_custom_call" in text  # the kernel is in the program
+
+
+@pytest.mark.parametrize("name", sorted(MASKED))
+def test_masked_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = MASKED[name]
+    text = _compile(_grad_of(fn), one_chip, *shapes)  # fwd + dq + dk/dv
+    assert "tpu_custom_call" in text
+
+
+def test_mis_tiled_kernel_is_refused(one_chip):
+    """The negative control of analysis/lowering.py, taken to the chip's
+    compiler: a (1, block) row block on a (rows, n) array — the bug class
+    that killed the first on-chip attempt — must not compile. If it does,
+    the cases above prove nothing."""
+    from jax.experimental import pallas as pl
+
+    from alphafold2_tpu.analysis.lowering import _is_mosaic_tiling_rejection
+
+    def kern(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    def f(x):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((4, 512), jnp.float32),
+            grid=(4,),
+            in_specs=[pl.BlockSpec((1, 512), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((1, 512), lambda i: (i, 0)),
+        )(x)
+
+    with pytest.raises(Exception) as err:
+        _compile(f, one_chip, ((4, 512), "float32"))
+    assert _is_mosaic_tiling_rejection(err.value), err.value
+
+
+# ------------------------------------------------------- the whole program ---
+
+
+@pytest.mark.parametrize("layout", ["one_chip", "dp2_sp2"])
+def test_flagship_train_step_compiles_for_v5e(topo, one_chip, monkeypatch,
+                                              layout):
+    """The whole jitted train step of chip_smoke.py at the flagship's sizes,
+    for one described chip and for the dp2 x sp2 mesh with ring context
+    parallelism (global batch 2): the TPU branch's kernels are in it, it fits
+    16 GB, and on the mesh the kernels sit inside a shard_map (GSPMD refuses
+    to partition a Mosaic kernel) beside the all-reduce and the ring's
+    collective-permute."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import chip_smoke
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.train.loop import (
+        build_model, make_train_step, tiny_init_state,
+    )
+
+    # the program asks jax.default_backend() which branch to take; it says
+    # "cpu" here, so the test steers it to what the chip would answer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    if layout == "one_chip":
+        cfg = chip_smoke.train_config(chip_smoke.FLAGSHIP, steps=4)
+        mesh, repl, data = None, one_chip, one_chip
+    else:
+        cfg = chip_smoke.train_config(
+            {**chip_smoke.FLAGSHIP, "batch": 2}, steps=4, dp=2, sp=2
+        )
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "sp"))
+        repl = NamedSharding(mesh, P())
+        data = NamedSharding(mesh, P("dp"))
+
+    def shapes(tree, sharding):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    sample = next(iter(make_dataset(cfg.data, seed=0)))
+    model = build_model(cfg)
+    state = jax.eval_shape(lambda: tiny_init_state(cfg, model, sample))
+    rng = jax.eval_shape(lambda: jax.random.key(1))
+    compiled = make_train_step(model, mesh, numerics_mode="norms").lower(
+        shapes(state, repl),
+        shapes({k: jnp.asarray(v) for k, v in sample.items()}, data),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=repl),
+    ).compile()
+
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert per_device < 15.75 * 2**30  # what the compiler leaves of 16 GB
+    if mesh is not None:
+        assert " all-reduce(" in text or " all-reduce-start(" in text
+        assert (" collective-permute(" in text
+                or " collective-permute-start(" in text)

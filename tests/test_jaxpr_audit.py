@@ -33,7 +33,7 @@ def test_clean_function_has_no_findings():
 
 
 def test_f64_widening_rejected():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
 
         def poisoned(x):
             return x.astype(jnp.float64) * 2.0

@@ -1,8 +1,8 @@
 """Unit tests for the observability subsystem (alphafold2_tpu/observe):
 tracer span emission in valid Chrome trace-event format, streaming
 histogram percentiles, thread-safe counters, MetricsLogger JSONL output
-and jax-free construction, memory sampler no-op behavior, Profiler
-step-window logic, and the liveness watchdog's dead/alive verdicts."""
+and jax-free construction, memory sampler no-op behavior, and Profiler
+step-window logic."""
 
 import json
 import threading
@@ -14,12 +14,10 @@ import pytest
 from alphafold2_tpu.observe import (
     EventCounters,
     Histogram,
-    LivenessWatchdog,
     MemorySampler,
     MetricsLogger,
     Profiler,
     Tracer,
-    probe_backend,
 )
 from alphafold2_tpu.observe.tracing import load_trace_events
 
@@ -378,110 +376,6 @@ def test_profiler_reentry_safety(monkeypatch, tmp_path):
     # a fresh window instance would start again at its own start step
     p.maybe_start(1)
     assert fake.calls[-1] == ("start", str(tmp_path))
-
-
-# ---------------------------------------------------------------- watchdog
-
-
-def _run_watchdog(stage, deadlines, probe, timeout=5.0):
-    fired = []
-    done = threading.Event()
-
-    def on_dead(rec):
-        fired.append(rec)
-        done.set()
-
-    wd = LivenessWatchdog(
-        stage_fn=lambda: stage["name"], deadlines=deadlines,
-        on_dead=on_dead, probe=probe, poll_s=0.05,
-    ).start()
-    done.wait(timeout)
-    wd.stop()
-    return fired
-
-
-def test_watchdog_fires_dead_on_hung_stage():
-    stage = {"name": "backend_init"}
-    t0 = time.monotonic()
-    fired = _run_watchdog(
-        stage, {"backend_init": 0.2},
-        probe=lambda: (False, "probe hung >1s (dead tunnel)"),
-    )
-    elapsed = time.monotonic() - t0
-    assert len(fired) == 1
-    rec = fired[0]
-    assert rec["liveness"] == "dead"
-    assert rec["stage"] == "backend_init"
-    assert rec["probe"] == "probe hung >1s (dead tunnel)"
-    assert rec["waited_s"] >= 0.2
-    assert elapsed < 5.0  # seconds, not a bench deadline
-
-
-def test_watchdog_suffix_matches_prefixed_stages():
-    stage = {"name": "serve:backend_init"}
-    fired = _run_watchdog(
-        stage, {"backend_init": 0.1}, probe=lambda: (False, "dead")
-    )
-    assert fired and fired[0]["stage"] == "serve:backend_init"
-
-
-def test_watchdog_alive_probe_extends_instead_of_firing():
-    stage = {"name": "backend_init"}
-    probes = []
-
-    def probe():
-        probes.append(time.monotonic())
-        return True, "probe ok"
-
-    fired = _run_watchdog(stage, {"backend_init": 0.15}, probe, timeout=0.7)
-    assert fired == []  # alive backend: never declared dead
-    assert len(probes) >= 2  # but it kept re-checking each deadline
-
-
-def test_watchdog_stage_progress_resets_clock():
-    stage = {"name": "backend_init"}
-    probes = []
-
-    def probe():
-        probes.append(1)
-        return False, "dead"
-
-    done = threading.Event()
-    wd = LivenessWatchdog(
-        stage_fn=lambda: stage["name"], deadlines={"backend_init": 0.3},
-        on_dead=lambda rec: done.set(), probe=probe, poll_s=0.05,
-    ).start()
-    # keep making progress: the deadline never accumulates 0.3s in one stage
-    for i in range(4):
-        time.sleep(0.15)
-        stage["name"] = f"phase_{i}:backend_init"
-    assert not done.is_set() and probes == []
-    wd.stop()
-
-
-def test_watchdog_unlisted_stage_is_unbounded():
-    stage = {"name": "timed_run"}
-    fired = _run_watchdog(
-        stage, {"backend_init": 0.05}, probe=lambda: (False, "dead"),
-        timeout=0.4,
-    )
-    assert fired == []
-
-
-def test_probe_backend_simulated_hang_times_out(monkeypatch):
-    monkeypatch.setenv(
-        "AF2TPU_LIVENESS_PROBE_CODE", "import time; time.sleep(60)"
-    )
-    t0 = time.monotonic()
-    alive, why = probe_backend(timeout=1)
-    assert alive is False
-    assert "hung" in why
-    assert time.monotonic() - t0 < 10
-
-
-def test_probe_backend_trivial_code_passes():
-    alive, why = probe_backend(timeout=60, code="pass")
-    assert alive, why
 
 
 # ------------------------------------------------------- train-loop wiring

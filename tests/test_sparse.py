@@ -285,28 +285,26 @@ def test_splash_backend_selected_by_config(monkeypatch):
     assert not called  # explicit use_pallas=False -> jnp oracle, not splash
 
 
-def test_splash_backend_unaligned_falls_back():
-    # seq lengths not divisible by the splash kernel's 128 block fall back
-    # to the jnp oracle (warn-once, never crash) — same contract as flash
+def test_splash_backend_unaligned_raises():
+    # seq lengths not divisible by the splash kernel's 128 block are an
+    # error: a named backend never quietly becomes the jnp gather oracle
     from alphafold2_tpu.ops.sparse import (
-        BlockSparseConfig, block_sparse_attention,
-        block_sparse_attention_splash,
+        BlockSparseConfig, block_sparse_attention_splash,
     )
 
     b, h, n, d, bs = 1, 2, 64, 16, 16
     layout = BlockSparseConfig(block_size=bs, num_random_blocks=0).layout(n)
     ks = jax.random.split(jax.random.key(40), 3)
     q, k, v = (jax.random.normal(kk, (b, h, n, d)) for kk in ks)
-    out = block_sparse_attention_splash(q, k, v, layout, bs)
-    ref = block_sparse_attention(q, k, v, layout, bs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+    with pytest.raises(ValueError, match="% 128"):
+        block_sparse_attention_splash(q, k, v, layout, bs)
 
 
 def test_block_layout_mask_indexing_matches_dense():
     """_BlockLayoutMask.__getitem__ must honor numpy's dense-ndarray
     indexing semantics for every index form splash (or a future jax) might
     use: slice+slice and slice+array are outer-product, array+array is
-    element-wise paired/broadcast (ADVICE r3: np.ix_ on a resolved integer
+    element-wise paired/broadcast (np.ix_ on a resolved integer
     pair silently returned an outer-product block of the wrong shape)."""
     from alphafold2_tpu.ops.sparse import (
         BlockSparseConfig, _block_layout_mask_cls,

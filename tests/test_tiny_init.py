@@ -87,7 +87,7 @@ def test_tiny_init_matches_full_init_templates():
     # bench_suite config_4 inits at tiny template shapes inline; this pins
     # the invariant that run relies on: the template embedder (with and
     # without the SE(3) sidechain colorer) has no input-shape-dependent
-    # params or rng draws, so tiny-shape init is bit-identical (ADVICE r2)
+    # params or rng draws, so tiny-shape init is bit-identical
     import jax.numpy as jnp
 
     from alphafold2_tpu.models import Alphafold2

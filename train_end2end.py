@@ -15,7 +15,7 @@ from alphafold2_tpu.config import Config, DataConfig, ModelConfig, parse_cli
 
 
 def main(argv):
-    alphafold2_tpu.setup_platform()  # AF2TPU_PLATFORM=cpu to force host
+    alphafold2_tpu.enable_compile_cache()
     from alphafold2_tpu.parallel.distributed import initialize
 
     initialize()  # multi-host process group (no-op single-process)
