@@ -1,28 +1,120 @@
-"""XLA trace capture over a configured train-step window.
+"""XLA trace capture over a configured train-step window, reduced in place.
 
 Complements the span tracing in :mod:`alphafold2_tpu.observe.tracing`:
-spans time host-side stages; this captures the device-side XLA trace
-(``train.profile_dir`` / ``train.profile_steps``) for TensorBoard/XProf.
+``Profiler`` starts and stops a ``jax.profiler`` trace
+(``train.profile_dir`` / ``train.profile_steps``) and, when it stops, reads
+the ``.xplane.pb`` it has just written into a **record** kept in memory:
+
+- per device plane the operations of the ``XLA Ops`` line as ``(instruction,
+  scope, start_ns, end_ns)``, the program executions of ``XLA Modules`` and
+  the ``Steps`` line, each as ``(name, start_ns, end_ns)``;
+- the host plane's annotation events whose name an enabled ``Tracer`` sent
+  there, as ``(name, start_ns, end_ns, thread, args)``, on the same clock.
+
+An operation's scope is the ``op_name`` of its HLO instruction
+(``jit(step)/jvp(Alphafold2)/trunk/layer_0/pair_from_msa/to_q/dot_general``:
+Flax names the modules, ``make_train_step`` the phases around the model). The
+TPU's trace does not carry it (an event is named by the instruction's text
+without metadata), so it is joined by instruction name from the compiled
+step's text (``name_operations``); an operation of another program (the
+loop's ``jax.random.split``) is given that program's name, ``jit(<name>)``.
+
+``summarize`` turns a record into the one line ``train()`` logs: device ms a
+step by block, forward and backward apart, the step program's device ms, the
+idle share, idle time by the innermost host span that covers it, and the
+collectives' own time by block. ``last_record()`` hands the newest record to
+whoever asks (the benchmark's readers), also after ``train()`` was left by an
+exception. Nothing here runs unless a trace directory is configured.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import glob
+import os
+import re
+import time
+from typing import Callable, Optional, Tuple
+
+OPS_LINE, MODULES_LINE, STEPS_LINE = "XLA Ops", "XLA Modules", "Steps"
+DEVICE_PREFIX = "/device:TPU:"
+HOST_PLANE = "/host:CPU"
+PHASES = ("loss", "grads_ok", "grad_clip", "optimizer", "rng", "metrics")
+COLLECTIVES = ("all-reduce", "collective-permute", "all-gather",
+               "reduce-scatter", "all-to-all", "async-collective")
+OUTSIDE_ANY_SPAN = "outside_any_span"
+# The profiler's own Python tracer is off: it adds some 10,000 events of
+# Python calls a traced step to the host plane, none of which is read.
+PYTHON_TRACER_LEVEL = 0
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WRAPPED = re.compile(r"^(?:jvp|transpose)\((.*)\)$")
+_LAYER = re.compile(r"^layer_\d+$")
+
+_LAST: Optional[dict] = None
+
+
+def last_record() -> Optional[dict]:
+    """The record of the newest trace this process has stopped (with the
+    tracer's own events under ``spans`` and the compile counter under
+    ``compile_counts`` once ``train()`` has handed them over); None if no
+    ``Profiler`` was given a directory."""
+    return _LAST
+
+
+def instruction_scopes(hlo_text: str) -> Tuple[str, dict]:
+    """(module name, {instruction name: op_name}) of a compiled program's
+    text (``compiled.as_text()``)."""
+    module = hlo_text.split(None, 2)[1].rstrip(",") if hlo_text.startswith(
+        "HloModule") else ""
+    scopes = {}
+    for line in hlo_text.splitlines():
+        named = _INSTRUCTION.match(line)
+        if named:
+            op_name = _OP_NAME.search(line)
+            if op_name:
+                scopes[named.group(1)] = op_name.group(1)
+    return module, scopes
 
 
 class Profiler:
-    """Start/stop a jax profiler trace across a [start, stop) step window."""
+    """Start/stop a jax profiler trace across a [start, stop) step window,
+    and reduce it when it stops.
 
-    def __init__(self, trace_dir: Optional[str], steps: Tuple[int, int] = (10, 13)):
+    ``span_names`` returns the names whose host events are kept (a
+    ``Tracer.annotated_names``), ``log(step, metrics)`` takes the one line.
+    """
+
+    def __init__(self, trace_dir: Optional[str],
+                 steps: Tuple[int, int] = (10, 13),
+                 span_names: Optional[Callable[[], set]] = None,
+                 log: Optional[Callable[[int, dict], None]] = None):
+        global _LAST
         self._dir = trace_dir
         self._start, self._stop = steps
         self._active = False
+        self._span_names = span_names or set
+        self._log = log
+        self._module, self._scopes = "", {}
+        if trace_dir:
+            _LAST = {"devices": {}, "host": [], "step_module": ""}
+
+    @property
+    def enabled(self) -> bool:
+        return bool(self._dir)
+
+    def name_operations(self, hlo_text: str) -> None:
+        """The compiled step's text: which program is the step, and the
+        scope of each of its instructions."""
+        self._module, self._scopes = instruction_scopes(hlo_text)
 
     def maybe_start(self, step: int) -> None:
         if self._dir and step == self._start and not self._active:
             import jax
 
-            jax.profiler.start_trace(self._dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = PYTHON_TRACER_LEVEL
+            jax.profiler.start_trace(self._dir, profiler_options=options)
             self._active = True
 
     def maybe_stop(self, step: int) -> None:
@@ -30,5 +122,261 @@ class Profiler:
             import jax
 
             jax.block_until_ready(jax.numpy.zeros(()))
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
             self._active = False
+            t1 = time.perf_counter()
+            self._reduce(step, t1 - t0)
+
+    def close(self) -> None:
+        """Stop a trace the window's end never came to (the loop was left
+        early); nothing is read from it."""
+        if self._active:
+            import jax
+
+            jax.profiler.stop_trace()
+            self._active = False
+
+    def _reduce(self, step: int, stop_s: float) -> None:
+        global _LAST
+        t0 = time.perf_counter()
+        found = sorted(glob.glob(os.path.join(
+            self._dir, "**", "*.xplane.pb"), recursive=True),
+            key=os.path.getmtime)
+        if not found:
+            return
+        record = read_record(found[-1], self._span_names(), self._module,
+                             self._scopes)
+        record["stop_trace_s"] = stop_s
+        record["read_s"] = time.perf_counter() - t0
+        _LAST = record
+        if self._log is not None:
+            self._log(step, {"event": "profile", **summarize(record)})
+
+
+def hand_over(spans: list, compile_counts: dict) -> None:
+    """Attach the tracer's own events and the compile counter to the record
+    (``train()`` calls this on its way out, whichever way that is)."""
+    if _LAST is not None:
+        _LAST["spans"] = spans
+        _LAST["compile_counts"] = compile_counts
+
+
+# ------------------------------------------------------ trace -> record ---
+
+
+def _instruction_name(event_name: str) -> str:
+    """``%fusion.16 = (u32[1]...) fusion(...)`` -> ``fusion.16``."""
+    return event_name.partition(" = ")[0].strip().lstrip("%")
+
+
+def _spans(line) -> list:
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events), key=lambda span: span[1])
+
+
+def _module_scope(module_event_name: str) -> str:
+    """``jit__threefry_split(1193...)`` -> ``jit(_threefry_split)``."""
+    name = module_event_name.partition("(")[0]
+    return f"jit({name[4:]})" if name.startswith("jit_") else name
+
+
+def _device_plane(plane, step_module: str, scopes: dict) -> Optional[dict]:
+    lines = {line.name: line for line in plane.lines}
+    if OPS_LINE not in lines:
+        return None
+    modules = _spans(lines[MODULES_LINE]) if MODULES_LINE in lines else []
+    steps = _spans(lines[STEPS_LINE]) if STEPS_LINE in lines else []
+    ops = []
+    at = 0  # modules run one after another: walk them with the operations
+    for e in sorted(lines[OPS_LINE].events, key=lambda e: e.start_ns):
+        while at < len(modules) and modules[at][2] <= e.start_ns:
+            at += 1
+        inside = at < len(modules) and modules[at][1] <= e.start_ns
+        instruction = _instruction_name(e.name)
+        if not inside:
+            scope = ""
+        elif modules[at][0].partition("(")[0] == step_module:
+            scope = scopes.get(instruction, "")
+        else:
+            scope = _module_scope(modules[at][0])
+        ops.append((instruction, scope, e.start_ns,
+                    e.start_ns + e.duration_ns))
+    return {"ops": ops, "modules": modules, "steps": steps}
+
+
+def _cpu_ops(plane, step_module: str, scopes: dict) -> Optional[dict]:
+    """The CPU backend has no device plane: its operations are host events
+    that carry ``hlo_op`` and ``hlo_module`` as stats."""
+    ops = []
+    for line in plane.lines:
+        for e in line.events:
+            stats = dict(e.stats)
+            if "hlo_op" not in stats:
+                continue
+            module = str(stats.get("hlo_module", ""))
+            scope = (scopes.get(str(stats["hlo_op"]), "")
+                     if module == step_module else _module_scope(module))
+            ops.append((str(stats["hlo_op"]), scope, e.start_ns,
+                        e.start_ns + e.duration_ns))
+    if not ops:
+        return None
+    return {"ops": sorted(ops, key=lambda o: o[2]), "modules": [],
+            "steps": []}
+
+
+def read_record(path: str, span_names, step_module: str = "",
+                scopes: Optional[dict] = None) -> dict:
+    """One ``.xplane.pb`` -> the record (see the module's docstring)."""
+    from jax.profiler import ProfileData
+
+    scopes = scopes or {}
+    names = set(span_names)
+    devices, host = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        suffix = plane.name[len(DEVICE_PREFIX):]
+        if plane.name.startswith(DEVICE_PREFIX) and suffix.isdigit():
+            found = _device_plane(plane, step_module, scopes)
+            if found:
+                devices[plane.name] = found
+        elif plane.name == HOST_PLANE:
+            for line in plane.lines:
+                host.extend(
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     line.name, {k: v for k, v in e.stats
+                                 if not k.startswith("_")})
+                    for e in line.events if e.name in names)
+            if not devices:
+                found = _cpu_ops(plane, step_module, scopes)
+                if found:
+                    devices[plane.name] = found
+    host.sort(key=lambda h: h[1])
+    return {"devices": devices, "host": host, "step_module": step_module}
+
+
+# ---------------------------------------------------- record -> summary ---
+
+
+def block_of(scope: str) -> str:
+    """The row of the table an operation's scope falls in:
+    ``fwd/pair_from_msa``, ``bwd/pair_axial`` (``layer_N`` merged, forward =
+    under ``jvp(``, backward = under ``transpose(``), ``fwd/loss``,
+    ``optimizer``, another program's ``jit(_threefry_split)``, or
+    ``unscoped``."""
+    if not scope:
+        return "unscoped"
+    parts = scope.split("/")
+    way = ("bwd/" if any(p.startswith("transpose(") for p in parts)
+           else "fwd/" if any(p.startswith("jvp(") for p in parts) else "")
+    plain = []
+    for p in parts:
+        while _WRAPPED.match(p):
+            p = _WRAPPED.match(p).group(1)
+        plain.append(p)
+    if "Alphafold2" in plain:
+        inner = plain[plain.index("Alphafold2") + 1:]
+        for i, p in enumerate(inner):
+            if _LAYER.match(p):
+                inner = inner[i + 1:]
+                break
+        return way + (inner[0] if inner else "Alphafold2")
+    phases = [p for p in plain if p in PHASES]
+    if phases:  # the innermost: grad_clip sits inside optimizer
+        return way + phases[-1]
+    if plain[0].startswith("jit(") and not plain[0].startswith("jit(step"):
+        return plain[0]
+    return "unscoped"  # bare jit(step)/..., or a copy named by its argument
+
+
+def own_times(ops) -> list:
+    """[(index, own_ns)]: each operation's duration less what the
+    operations nested inside it cover (a ``while`` holds its body's)."""
+    out, stack = [], []  # stack of [index, end, own]
+
+    def close(upto):
+        while stack and stack[-1][1] <= upto:
+            i, _, own = stack.pop()
+            out.append((i, own))
+
+    order = sorted(range(len(ops)), key=lambda i: (ops[i][2], -ops[i][3]))
+    for i in order:
+        start, end = ops[i][2], ops[i][3]
+        close(start)
+        if stack:
+            stack[-1][2] -= min(end, stack[-1][1]) - start
+        stack.append([i, end, end - start])
+    close(float("inf"))
+    return out
+
+
+def idle_gaps(ops) -> list:
+    """[(start_ns, end_ns)] between the merged intervals of ``ops``."""
+    gaps, reach = [], None
+    for _, _, start, end in sorted(ops, key=lambda o: o[2]):
+        if reach is not None and start > reach:
+            gaps.append((reach, start))
+        reach = end if reach is None else max(reach, end)
+    return gaps
+
+
+def loop_thread(host) -> Optional[str]:
+    """The thread most of the host's span events are on."""
+    counts: dict = {}
+    for _, _, _, thread, _ in host:
+        counts[thread] = counts.get(thread, 0) + 1
+    return max(counts, key=counts.get) if counts else None
+
+
+def idle_by_span(gaps, host, steps_name: str = "train") -> dict:
+    """{span name: ns}: every gap cut at the borders of the loop thread's
+    spans, each piece given to the innermost span that covers it (the one
+    that started last), ``outside_any_span`` where none does. The step
+    annotation (``steps_name``) is no span."""
+    thread = loop_thread(host)
+    spans = [(s, e, n) for n, s, e, t, _ in host
+             if t == thread and n != steps_name]
+    out: dict = {}
+    for a, b in gaps:
+        over = [(s, e, n) for s, e, n in spans if s < b and e > a]
+        cuts = sorted({a, b, *(x for s, e, _ in over for x in (s, e)
+                               if a < x < b)})
+        for x, y in zip(cuts, cuts[1:]):
+            covering = [(s, n) for s, e, n in over if s <= x and e >= y]
+            name = max(covering)[1] if covering else OUTSIDE_ANY_SPAN
+            out[name] = out.get(name, 0.0) + (y - x)
+    return out
+
+
+def summarize(record: dict) -> dict:
+    """The log line of one record, from its first device plane."""
+    out = {"stop_trace_s": round(record.get("stop_trace_s", 0.0), 3),
+           "read_s": round(record.get("read_s", 0.0), 3)}
+    if not record["devices"]:
+        return out
+    plane = next(iter(record["devices"].values()))
+    ops = plane["ops"]
+    step_runs = [m for m in plane["modules"]
+                 if m[0].partition("(")[0] == record["step_module"]]
+    steps = max(len(step_runs), 1)
+    blocks: dict = {}
+    collectives: dict = {}
+    for i, own in own_times(ops):
+        block = block_of(ops[i][1])
+        blocks[block] = blocks.get(block, 0.0) + own
+        if ops[i][0].startswith(COLLECTIVES):
+            collectives[block] = collectives.get(block, 0.0) + own
+    gaps = idle_gaps(ops)
+    window = max(o[3] for o in ops) - min(o[2] for o in ops)
+    idle = sum(b - a for a, b in gaps)
+    out["steps"] = len(step_runs)
+    if step_runs:
+        out["step_device_ms"] = round(
+            sum(e - s for _, s, e in step_runs) / steps / 1e6, 3)
+    out["idle_pct"] = round(100.0 * idle / window, 3) if window else 0.0
+    for name, table in (("block_ms", blocks),
+                        ("collective_own_ms", collectives),
+                        ("idle_ms", idle_by_span(gaps, record["host"]))):
+        for key, ns in sorted(table.items(), key=lambda kv: -kv[1]):
+            if ns / steps >= 500:  # what rounds to 0.000 ms is left out
+                out[f"{name}/{key}"] = round(ns / steps / 1e6, 3)
+    return out

@@ -11,6 +11,12 @@ thread — no explicit parent ids needed.
 A disabled tracer (no path, ``enabled=False``) is a near-zero-cost no-op,
 so instrumentation can stay permanently wired through hot paths (the serve
 engine, the train step) and be switched on per run.
+
+An enabled tracer also enters ``jax.profiler.TraceAnnotation`` for every
+span and instant, so that while a ``jax.profiler`` trace is being taken the
+same names land in its host plane, on the device trace's clock
+(``observe.profiler`` reads them back from there). And it registers, once per
+process, the ``jax.monitoring`` listeners behind :func:`compile_counts`.
 """
 
 from __future__ import annotations
@@ -19,7 +25,8 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from collections import deque
+from contextlib import contextmanager, nullcontext
 from typing import Optional, Tuple
 
 from alphafold2_tpu.observe.tracectx import current_trace, use_trace
@@ -31,6 +38,69 @@ _PROC_T0 = time.perf_counter()
 
 def _now_us() -> float:
     return (time.perf_counter() - _PROC_T0) * 1e6
+
+
+# ---------------------------------------------------------- compile counter
+
+_DURATIONS = {  # jax.monitoring's event -> the counter's key
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    # fires for a load from the persistent cache too
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+_COMPILES = {"compiles": 0, "cache_hits": 0, "trace_s": 0.0, "lower_s": 0.0,
+             "backend_s": 0.0}
+_COMPILES_LOCK = threading.Lock()
+_LISTENING = False
+
+
+def _on_duration(event: str, duration_s: float, **_) -> None:
+    key = _DURATIONS.get(event)
+    if key is None:
+        return
+    with _COMPILES_LOCK:
+        _COMPILES[key] += duration_s
+        if key == "backend_s":
+            _COMPILES["compiles"] += 1
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT:
+        with _COMPILES_LOCK:
+            _COMPILES["cache_hits"] += 1
+
+
+def _listen_for_compiles() -> None:
+    """Register the listeners, once per process (jax offers no way to tell
+    whether one is registered, and they are never taken off again)."""
+    global _LISTENING
+    with _COMPILES_LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
+
+
+def compile_counts() -> dict:
+    """The process-wide compile counter since the first enabled tracer:
+    ``compiles`` (programs handed to the backend's compiler, those it then
+    loaded from the persistent cache included), ``cache_hits`` (those
+    loads), and the summed seconds of tracing to a jaxpr (``trace_s``),
+    lowering to a module (``lower_s``) and the backend's compile or load
+    (``backend_s``). All zero while no tracer was ever enabled."""
+    with _COMPILES_LOCK:
+        return dict(_COMPILES)
+
+
+def _annotation_args(args: dict) -> dict:
+    """What a profiler annotation can carry: plain numbers and strings."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float, str))}
 
 
 class Span:
@@ -58,24 +128,31 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+_NULL_CONTEXT = nullcontext()
 
 
 class Tracer:
     """Thread-safe span tracer writing Chrome trace events.
 
-    ``path=None`` keeps events only in memory (tests, ``span_totals``);
-    ``enabled=False`` disables everything. Events are flushed to the file
+    ``path=None`` keeps events only in memory (tests, ``span_totals``), the
+    newest ``max_events`` of them if that is given; ``enabled=False``
+    disables everything. Events are flushed to the file
     as they complete, so a killed process still leaves a loadable trace.
     """
 
     def __init__(self, path: Optional[str] = None,
-                 enabled: Optional[bool] = None):
+                 enabled: Optional[bool] = None,
+                 max_events: Optional[int] = None):
         self.enabled = bool(path) if enabled is None else bool(enabled)
         self._path = path
         self._lock = threading.Lock()
-        self._events: list = []
+        # in memory: all of them, or the newest ``max_events``
+        self._events = deque(maxlen=max_events)
         self._sinks: list = []  # e.g. the flight recorder's ring buffer
         self._file = None
+        self._annotated: set = set()  # names sent to the profiler's plane
+        if self.enabled:
+            _listen_for_compiles()
         if self.enabled and path:
             d = os.path.dirname(os.path.abspath(path))
             os.makedirs(d, exist_ok=True)
@@ -134,6 +211,9 @@ class Tracer:
             if cur is not None:
                 ctx = cur.child()
                 sp.args.update(ctx.event_args())
+        # the same region on the profiler's clock (args as known on entry)
+        annotation = self._annotation(name, args)
+        annotation.__enter__()
         t0 = _now_us()
         try:
             if ctx is not None:
@@ -146,6 +226,7 @@ class Tracer:
             raise
         finally:
             t1 = _now_us()
+            annotation.__exit__(None, None, None)
             sp.duration_s = (t1 - t0) / 1e6
             self._emit({
                 "name": name, "ph": "X", "ts": round(t0, 1),
@@ -154,11 +235,33 @@ class Tracer:
                 **({"args": sp.args} if sp.args else {}),
             })
 
+    def _annotation(self, name: str, args: dict):
+        import jax.profiler
+
+        self._annotated.add(name)
+        return jax.profiler.TraceAnnotation(name, **_annotation_args(args))
+
+    def step(self, name: str, step_num: int):
+        """``jax.profiler.StepTraceAnnotation(name, step_num=...)`` around
+        one iteration of a loop, so that a profiler trace groups the device's
+        work by the loop's own step numbers; a null context when disabled."""
+        if not self.enabled:
+            return _NULL_CONTEXT
+        import jax.profiler
+
+        self._annotated.add(name)
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+
+    def annotated_names(self) -> set:
+        """Every name this tracer has sent to the profiler's host plane."""
+        return set(self._annotated)
+
     def span_event(self, name: str, t0_s: float, t1_s: float, **args) -> None:
         """Emit a complete span with EXPLICIT bounds (``time.perf_counter``
         seconds) — for retroactive regions whose start predates the call,
         e.g. the scheduler's per-request queue-residency span, known only
-        when the batch forms."""
+        when the batch forms. A profiler annotation cannot be backdated, so
+        this one stays on the tracer's own clock."""
         if not self.enabled:
             return
         ts = (t0_s - _PROC_T0) * 1e6
@@ -180,6 +283,8 @@ class Tracer:
             cur = current_trace()
             if cur is not None:
                 args = {**args, **cur.event_args()}
+        with self._annotation(name, args):
+            pass
         self._emit({
             "name": name, "ph": "i", "ts": round(_now_us(), 1), "s": "p",
             "pid": os.getpid(), "tid": threading.get_ident(),

@@ -116,8 +116,14 @@ def build_optimizer(cfg: Config) -> optax.GradientTransformation:
         decay_steps=max(t.num_steps, t.warmup_steps + 1),
         end_value=t.learning_rate * 0.1,
     )
+    clip = optax.clip_by_global_norm(1.0)
+
+    def clip_update(updates, state, params=None):
+        with jax.named_scope("grad_clip"):  # a name in the trace, no more
+            return clip.update(updates, state, params)
+
     tx = optax.chain(
-        optax.clip_by_global_norm(1.0),
+        optax.GradientTransformation(clip.init, clip_update),
         optax.adamw(schedule, weight_decay=t.weight_decay),
     )
     if t.gradient_accumulate_every > 1:
@@ -232,6 +238,18 @@ def tiny_init_state(
     return init_state(cfg, model, batch)
 
 
+def _scoped_loss(logits, batch: dict):
+    """The distogram loss of one batch, labels included, under the named
+    scope ``loss`` (shared by the train step and the triage step)."""
+    with jax.named_scope("loss"):
+        # native-loader batches carry host-precomputed labels
+        # (data/native.py); otherwise bucketize on device
+        labels = batch.get("labels")
+        if labels is None:
+            labels = get_bucketed_distance_matrix(batch["coords"], batch["mask"])
+        return distogram_cross_entropy(logits, labels)
+
+
 def _param_groups(tree) -> dict:
     """Split a param/grad tree into its top-level module groups (``trunk``,
     ``token_emb``, ...), unwrapping the flax ``params`` collection."""
@@ -292,65 +310,65 @@ def make_train_step(
                         deterministic=False,
                         rngs={"dropout": rng},
                     )
-                    # native-loader batches carry host-precomputed labels
-                    # (data/native.py); otherwise bucketize on device
-                    labels = batch.get("labels")
-                    if labels is None:
-                        labels = get_bucketed_distance_matrix(
-                            batch["coords"], batch["mask"]
-                        )
-                    loss = distogram_cross_entropy(logits, labels)
+                    loss = _scoped_loss(logits, batch)
                 return loss, (logits, col.stats())
 
             ((loss, (logits, act_stats)), grads) = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(state.params)
+            # The phases around the model carry a named scope each (HLO
+            # metadata only; Flax names the model's own modules): a profiler
+            # trace is reduced by these names (observe.profiler.block_of).
             # failure detection: skip the update on non-finite gradients
-            grads_ok = jnp.all(
-                jnp.asarray(
-                    [jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(grads)]
+            with jax.named_scope("grads_ok"):
+                grads_ok = jnp.all(
+                    jnp.asarray(
+                        [jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(grads)]
+                    )
                 )
-            )
-            safe_grads = jax.tree.map(
-                lambda g: jnp.where(grads_ok, g, jnp.zeros_like(g)), grads
-            )
-            new_state = state.apply_gradients(grads=safe_grads)
+                safe_grads = jax.tree.map(
+                    lambda g: jnp.where(grads_ok, g, jnp.zeros_like(g)), grads
+                )
+            # build_optimizer puts the clipping under "grad_clip" inside it
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads=safe_grads)
             new_state = new_state.replace(
                 skipped=state.skipped + jnp.where(grads_ok, 0, 1)
             )
-            gnorm = optax.global_norm(grads)
-            metrics = {
-                "loss": loss,
-                "grad_norm": gnorm,
-                "grads_ok": grads_ok,
-                "skipped": new_state.skipped,
-                "distogram_entropy": -jnp.mean(
-                    jnp.sum(
-                        jax.nn.softmax(logits, -1) * jax.nn.log_softmax(logits, -1),
-                        -1,
-                    )
-                ),
-            }
-            if numerics_mode in ("norms", "full"):
-                # per-parameter-group norm trajectories: which part of the
-                # model is drifting/spiking shows up long before the global
-                # grad_norm moves
-                groups_g = _param_groups(grads)
-                groups_new = _param_groups(new_state.params)
-                groups_old = _param_groups(state.params)
-                for k in groups_g:
-                    metrics[f"grad_norm/{k}"] = optax.global_norm(groups_g[k])
-                    metrics[f"param_norm/{k}"] = optax.global_norm(
-                        groups_new[k]
-                    )
-                    metrics[f"update_norm/{k}"] = optax.global_norm(
-                        jax.tree.map(
-                            lambda a, b: a - b, groups_new[k], groups_old[k]
+            with jax.named_scope("metrics"):
+                gnorm = optax.global_norm(grads)
+                metrics = {
+                    "loss": loss,
+                    "grad_norm": gnorm,
+                    "grads_ok": grads_ok,
+                    "skipped": new_state.skipped,
+                    "distogram_entropy": -jnp.mean(
+                        jnp.sum(
+                            jax.nn.softmax(logits, -1) * jax.nn.log_softmax(logits, -1),
+                            -1,
                         )
-                    )
-                metrics["param_norm"] = optax.global_norm(new_state.params)
-            if numerics_mode == "full":
-                metrics["numerics"] = act_stats
+                    ),
+                }
+                if numerics_mode in ("norms", "full"):
+                    # per-parameter-group norm trajectories: which part of the
+                    # model is drifting/spiking shows up long before the global
+                    # grad_norm moves
+                    groups_g = _param_groups(grads)
+                    groups_new = _param_groups(new_state.params)
+                    groups_old = _param_groups(state.params)
+                    for k in groups_g:
+                        metrics[f"grad_norm/{k}"] = optax.global_norm(groups_g[k])
+                        metrics[f"param_norm/{k}"] = optax.global_norm(
+                            groups_new[k]
+                        )
+                        metrics[f"update_norm/{k}"] = optax.global_norm(
+                            jax.tree.map(
+                                lambda a, b: a - b, groups_new[k], groups_old[k]
+                            )
+                        )
+                    metrics["param_norm"] = optax.global_norm(new_state.params)
+                if numerics_mode == "full":
+                    metrics["numerics"] = act_stats
             return new_state, metrics
 
     if not jit:
@@ -397,12 +415,7 @@ def make_triage_step(model: Alphafold2, mesh: Optional[Mesh] = None):
                         deterministic=False,
                         rngs={"dropout": rng},  # the skipped step's exact rng
                     )
-                    labels = batch.get("labels")
-                    if labels is None:
-                        labels = get_bucketed_distance_matrix(
-                            batch["coords"], batch["mask"]
-                        )
-                    loss = distogram_cross_entropy(logits, labels)
+                    loss = _scoped_loss(logits, batch)
                 return loss, col.stats()
 
             (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -465,23 +478,62 @@ def device_put_batch(batch: dict, mesh: Optional[Mesh] = None) -> dict:
 
 
 def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=()):
-    """Distogram pretraining driver (the runnable train_pre.py equivalent)."""
+    """Distogram pretraining driver (the runnable train_pre.py equivalent).
+
+    Observability (``observe``): host spans go to ``train.trace_events``
+    (Chrome trace events) and, while ``train.profile_dir``'s profiler trace
+    runs over ``train.profile_steps``, into that trace on the device's clock;
+    when it stops, one ``event: profile`` line gives device time by block
+    and idle time by host span. Setting ``train.profile_dir`` alone keeps the
+    spans in memory. With neither set the tracer is the null object: no
+    annotation, no listener, no file read.
+    """
+    from alphafold2_tpu.observe import MetricsLogger, Profiler, Tracer
+    from alphafold2_tpu.observe import profiler as profiler_mod
+    from alphafold2_tpu.observe.tracing import compile_counts
+
+    t = cfg.train
+    tracer = Tracer(
+        t.trace_events,
+        enabled=bool(t.trace_events or t.profile_dir),
+        # memory only: keep a long run's spans bounded
+        max_events=None if t.trace_events else 100_000,
+    )
+    logger = MetricsLogger(t.checkpoint_dir)
+    profiler = Profiler(
+        t.profile_dir, t.profile_steps,
+        span_names=tracer.annotated_names, log=logger.log,
+    )
+    try:
+        return _train(cfg, num_steps, dataset, callbacks, tracer, logger, profiler)
+    finally:
+        # also when a callback ended the run with an exception: whoever
+        # holds no handle on these locals still finds spans and record
+        profiler.close()
+        tracer.close()
+        if profiler.enabled:
+            profiler_mod.hand_over(tracer.events(), compile_counts())
+
+
+def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler):
     import os
     import time
 
-    from alphafold2_tpu.data.pipeline import make_dataset
-    from alphafold2_tpu.observe import MetricsLogger, Profiler, Tracer
-    from alphafold2_tpu.observe import flops as flops_mod
-    from alphafold2_tpu.observe.metrics import flatten_metrics
-    from alphafold2_tpu.train.checkpoint import CheckpointManager
+    with tracer.span("train.imports"):  # orbax comes in with the last one
+        from alphafold2_tpu.data.pipeline import make_dataset
+        from alphafold2_tpu.observe import flops as flops_mod
+        from alphafold2_tpu.observe.metrics import flatten_metrics
+        from alphafold2_tpu.observe.tracing import compile_counts
+        from alphafold2_tpu.train.checkpoint import CheckpointManager
 
     num_steps = num_steps or cfg.train.num_steps
     owns_dataset = dataset is None
     # fold the process index into the data seed: each host must feed a
     # DIFFERENT slice of the global batch (global_batch() stitches them)
     data_seed = cfg.train.seed + 7919 * jax.process_index()
-    dataset = dataset or make_dataset(cfg.data, seed=data_seed)
-    data_iter = apply_features(iter(dataset), cfg)
+    with tracer.span("train.dataset"):
+        dataset = dataset or make_dataset(cfg.data, seed=data_seed)
+        data_iter = apply_features(iter(dataset), cfg)
 
     mesh = None
     if cfg.mesh.grid_rows * cfg.mesh.grid_cols > 1:
@@ -511,11 +563,14 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
 
         mesh = pod_mesh(cfg.mesh.data_parallel, cfg.mesh.seq_parallel)
 
-    model = build_model(cfg)
-    sample = next(data_iter)
+    with tracer.span("train.build_model"):
+        model = build_model(cfg)
+    with tracer.span("train.first_batch"):
+        sample = next(data_iter)
     # init at tiny slices of the sample: identical params, none of the
     # full-size init compile (see tiny_init_state)
-    state = tiny_init_state(cfg, model, sample)
+    with tracer.span("train.init_state"):
+        state = tiny_init_state(cfg, model, sample)
     # numerics telemetry mode (observe.numerics): "off" | "triage" (fast
     # step widened with per-parameter-group norms; a fully-tagged rerun
     # fires only when the non-finite-grad skip does) | "full" (every step
@@ -544,19 +599,14 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
     )
     start_step = 0
     if ckpt is not None:
-        state, start_step = ckpt.maybe_restore(state)
+        with tracer.span("train.restore"):
+            state, start_step = ckpt.maybe_restore(state)
     if mesh is not None and jax.process_count() == 1:
         # place the state where every later step will find it (replicated
         # over the mesh): handed over on one device, step 0 compiles for
         # that placement and step 1 compiles the same program again
         state = jax.device_put(state, NamedSharding(mesh, P()))
 
-    logger = MetricsLogger(cfg.train.checkpoint_dir)
-    profiler = Profiler(cfg.train.profile_dir, cfg.train.profile_steps)
-    # host-side span trace beside the XLA profile: step dispatch, batch
-    # fetch and checkpoint writes as Chrome trace events (observe.Tracer);
-    # disabled (near-zero overhead) unless train.trace_events is set
-    tracer = Tracer(cfg.train.trace_events)
     rng = jax.random.key(cfg.train.seed + 1)
 
     # preemption safety (SURVEY.md S5.3 — the reference has no failure
@@ -605,21 +655,40 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
     # The mesh/multi-host path keeps implicit jit compilation: AOT-compiled
     # calls are strict about input shardings the loop does not guarantee.
     step_call = step_fn
-    step_flops = None
     if mesh is None and jax.process_count() == 1:
         t_c = time.perf_counter()
-        with tracer.span("train.compile"):
-            compiled = step_fn.lower(state, batch, rng).compile()
+        seen = compile_counts()
+        with tracer.span("train.lower") as sp:
+            lowered = step_fn.lower(state, batch, rng)
+            now = compile_counts()
+            sp.set(trace_s=now["trace_s"] - seen["trace_s"],
+                   lower_s=now["lower_s"] - seen["lower_s"])
+        with tracer.span("train.compile") as sp:
+            compiled = lowered.compile()
+            done = compile_counts()
+            sp.set(backend_s=done["backend_s"] - now["backend_s"],
+                   cache_hit=done["cache_hits"] > now["cache_hits"])
         compile_s = time.perf_counter() - t_c
-        costs = flops_mod.executable_costs(compiled)
-        step_flops = costs["flops"]
+        with tracer.span("train.cost_analysis"):
+            costs = flops_mod.executable_costs(compiled)
         step_call = compiled
+        # step_flops is XLA's own count and leaves out every custom call
+        # (the attention kernels, most of a TPU step): no rate is made of it
         logger.log(start_step, {
             "compile_s": round(compile_s, 3),
-            **({"step_flops": step_flops} if step_flops else {}),
+            **({"step_flops": costs["flops"]} if costs["flops"] else {}),
             **({"step_bytes_accessed": costs["bytes_accessed"]}
                if costs["bytes_accessed"] else {}),
         })
+        if profiler.enabled:
+            profiler.name_operations(compiled.as_text())
+    elif profiler.enabled and jax.process_count() == 1:
+        # no AOT step here, but the trace is reduced by the names in the
+        # compiled step's text. The compile moves here from step 0: jit's
+        # own call then finds the executable in jax's in-memory cache
+        with tracer.span("train.scope_names"):
+            profiler.name_operations(
+                step_fn.lower(state, batch, rng).compile().as_text())
 
     # NaN triage (numerics_mode "triage"/"full"): when a step's non-finite-
     # grad skip fired, rerun it fully tagged and report the first bad
@@ -632,7 +701,9 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
 
     def run_triage(ok, t_batch, t_rng, t_step):
         nonlocal triage_fn
-        if bool(ok):
+        with tracer.span("train.triage_wait", step=t_step):
+            ok = bool(ok)  # a fetch: waits until step t_step has finished
+        if ok:
             return
         if triage_fn is None:
             triage_fn = make_triage_step(model, mesh)
@@ -653,52 +724,68 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
     t0 = time.perf_counter()
     last_logged = None
     for i in range(start_step, num_steps):
-        if pending is not None:
-            run_triage(*pending)
-            pending = None
-        profiler.maybe_start(i)
-        rng, step_rng = jax.random.split(rng)
-        with tracer.span("train.step", step=i):
-            state, metrics = step_call(state, batch, step_rng)
-        profiler.maybe_stop(i)
-        if numerics_mode in ("triage", "full"):
-            pending = (metrics["grads_ok"], batch, step_rng, i)
-        if (i + 1) % cfg.train.log_every == 0 or i == start_step:
-            m = flatten_metrics(metrics)
-            now = time.perf_counter()
-            if last_logged is None:
-                # the session's first step is dispatch- (or, without AOT,
-                # compile-)dominated: record its wall time as its own
-                # metric instead of the old steps_per_sec=0.0 placeholder
-                m["first_step_s"] = round(now - t0, 4)
-            else:
-                m["steps_per_sec"] = (i - last_logged) / max(now - t0, 1e-9)
-                if step_flops:
-                    m["model_flops_per_s"] = step_flops * m["steps_per_sec"]
-                    mfu = flops_mod.mfu(step_flops, 1.0 / m["steps_per_sec"])
-                    if mfu is not None:
-                        m["mfu"] = round(mfu, 4)
-            if numerics_mode == "full" and isinstance(
-                metrics.get("numerics"), dict
-            ):
-                # same numerics/<name> vocabulary in the Perfetto trace
-                numerics.counters_to_tracer(metrics["numerics"], tracer)
-            last_logged = i
-            t0 = now
-            logger.log(i, m)
-        for cb in callbacks:
-            cb(i, state, metrics)
-        if ckpt is not None and (i + 1) % cfg.train.checkpoint_every == 0:
-            with tracer.span("train.checkpoint", step=i + 1):
-                ckpt.save(i + 1, state)
-        if ckpt is not None and stop_agreed():
-            stop["requested"] = True
-            logger.log(i, {"preempted": 1.0})
-            if ckpt.latest_step() != i + 1:
-                ckpt.save(i + 1, state)
-            break
-        with tracer.span("train.next_batch", step=i + 1):
-            batch = next(prefetched)
+        with tracer.step("train", i):
+            if pending is not None:
+                run_triage(*pending)
+                pending = None
+            if profiler.enabled:
+                with tracer.span("train.profiler", step=i):
+                    profiler.maybe_start(i)
+            with tracer.span("train.rng", step=i):
+                rng, step_rng = jax.random.split(rng)
+            # the running compile count rides on the span: the step at which
+            # it rose is the step that compiled
+            counted = (
+                {"compiles": compile_counts()["compiles"]}
+                if tracer.enabled else {}
+            )
+            with tracer.span("train.step", step=i, **counted):
+                state, metrics = step_call(state, batch, step_rng)
+            if profiler.enabled:
+                with tracer.span("train.profiler", step=i):
+                    profiler.maybe_stop(i)
+            if numerics_mode in ("triage", "full"):
+                pending = (metrics["grads_ok"], batch, step_rng, i)
+            if (i + 1) % cfg.train.log_every == 0 or i == start_step:
+                with tracer.span("train.log", step=i):
+                    m = flatten_metrics(metrics)  # blocks on the step
+                    now = time.perf_counter()
+                    if last_logged is None:
+                        # the session's first step is dispatch- (or, without
+                        # AOT, compile-)dominated: record its wall time as
+                        # its own metric, not as a rate
+                        m["first_step_s"] = round(now - t0, 4)
+                    else:
+                        m["steps_per_sec"] = (i - last_logged) / max(
+                            now - t0, 1e-9)
+                    if numerics_mode == "full" and isinstance(
+                        metrics.get("numerics"), dict
+                    ):
+                        # same numerics/<name> vocabulary in the Perfetto
+                        # trace
+                        numerics.counters_to_tracer(
+                            metrics["numerics"], tracer)
+                    last_logged = i
+                    t0 = now
+                    logger.log(i, m)
+            if callbacks:
+                with tracer.span("train.callbacks", step=i):
+                    for cb in callbacks:
+                        cb(i, state, metrics)
+            if ckpt is not None and (i + 1) % cfg.train.checkpoint_every == 0:
+                with tracer.span("train.checkpoint", step=i + 1):
+                    ckpt.save(i + 1, state)
+            if ckpt is not None:
+                with tracer.span("train.stop_agreed", step=i):
+                    stopping = stop_agreed()
+                if stopping:
+                    stop["requested"] = True
+                    logger.log(i, {"preempted": 1.0})
+                    if ckpt.latest_step() != i + 1:
+                        ckpt.save(i + 1, state)
+                    break
+            with tracer.span("train.next_batch", step=i + 1):
+                batch = next(prefetched)
     if pending is not None:  # a skip on the session's final step
         run_triage(*pending)
     if prev_handler is not None:
@@ -709,5 +796,4 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
         ckpt.wait()
     if owns_dataset and hasattr(dataset, "close"):
         dataset.close()  # shut down native prefetch workers
-    tracer.close()
     return state

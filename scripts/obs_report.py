@@ -306,9 +306,7 @@ def report_train(records: list) -> None:
         print(f"  step compile: {_fmt_s(compile_s)}")
     rates = _fin([r.get("steps_per_sec") for r in steps])
     if rates:
-        tail = f"  (mfu {steps[-1]['mfu']:.2%})" if "mfu" in steps[-1] else ""
-        print(f"  steps/sec: last {rates[-1]:.4g}  max {max(rates):.4g}"
-              + tail)
+        print(f"  steps/sec: last {rates[-1]:.4g}  max {max(rates):.4g}")
 
     # numerics anomalies: any logged tensor stat with NaN/Inf entries
     anomalies = sorted({

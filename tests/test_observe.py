@@ -332,7 +332,8 @@ class _FakeProfiler:
 
         monkeypatch.setattr(
             jax.profiler, "start_trace",
-            lambda d: self.calls.append(("start", d)),
+            # the options (Python tracer off) are the Profiler's business
+            lambda d, **options: self.calls.append(("start", d)),
         )
         monkeypatch.setattr(
             jax.profiler, "stop_trace", lambda: self.calls.append(("stop",))

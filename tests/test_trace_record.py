@@ -1,0 +1,398 @@
+"""Names on the train step, the tracer's spans on the profiler's clock, the
+compile counter, and the record a stopped trace leaves behind (CPU, tiny
+sizes)."""
+
+import glob
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from alphafold2_tpu.observe import profiler as profiler_mod
+from alphafold2_tpu.observe import tracing
+from alphafold2_tpu.observe.tracing import Tracer, compile_counts
+from benchmark.harness import scope_reduce
+
+BLOCKS = ("pair_axial", "msa_axial", "pair_from_msa", "msa_from_pair",
+          "pair_ff", "msa_ff")
+
+
+def tiny_config(depth=2, features="msa", **train):
+    """The flagship's shape of step (tied rows, depth 2, bf16) at toy
+    sizes. (``features="none"``, sequence only, halves the eager init.)"""
+    from alphafold2_tpu.config import (
+        Config, DataConfig, ModelConfig, TrainConfig,
+    )
+
+    return Config(
+        model=ModelConfig(dim=32, depth=depth, heads=2, dim_head=16,
+                          max_seq_len=32, msa_tie_row_attn=True,
+                          bfloat16=True),
+        data=DataConfig(crop_len=16, msa_depth=2, msa_len=16, batch_size=1,
+                        min_len_filter=16, features=features),
+        train=TrainConfig(gradient_accumulate_every=1, warmup_steps=2,
+                          num_steps=6, log_every=100, **train),
+    )
+
+
+# ------------------------------------------------------ (a) the names ---
+
+
+def heavy_op_scopes(lowered) -> list:
+    """The ``op_name`` of every dot, convolution and custom call of a
+    lowered program (StableHLO with debug locations)."""
+    text = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    found = []
+    for ref in re.findall(
+            r"stablehlo\.(?:dot_general|convolution|custom_call)\b"
+            r".*loc\((#loc\d+)\)", text):
+        name = re.match(r'"([^"]*)"', locs[ref])
+        found.append(name.group(1) if name else "")
+    return found
+
+
+@pytest.fixture(scope="module")
+def lowered_step():
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.train.loop import (
+        build_model, device_put_batch, make_train_step, tiny_init_state,
+    )
+
+    cfg = tiny_config()
+    sample = next(iter(make_dataset(cfg.data, seed=0)))
+    model = build_model(cfg)
+    # shapes are enough to lower: no init program is compiled
+    state = jax.eval_shape(lambda: tiny_init_state(cfg, model, sample))
+    return make_train_step(model).lower(
+        state, device_put_batch(sample), jax.random.key(1))
+
+
+def test_every_heavy_operation_of_the_step_is_under_a_named_scope(
+        lowered_step):
+    scopes = heavy_op_scopes(lowered_step)
+    assert len(scopes) > 100
+    seen = set()
+    for scope in scopes:
+        group, way, block, named = scope_reduce.classify(scope)
+        assert named, f"no block or phase scope on {scope!r}"
+        assert profiler_mod.block_of(scope) != "unscoped", scope
+        if group != scope_reduce.OUTSIDE_MODEL:
+            assert way in ("fwd", "bwd"), scope
+        seen.add((way, block))
+    # forward and backward of every trunk block, told apart by name (the
+    # last layer's MSA update feeds nothing, but layer 0's is there)
+    for block in BLOCKS:
+        assert ("fwd", block) in seen and ("bwd", block) in seen, block
+    assert ("fwd", "loss") in seen  # the labels' einsum
+
+
+def test_phases_around_the_model_are_named_in_the_compiled_step(
+        lowered_step):
+    """loss, grads_ok, grad_clip (inside optimizer), optimizer and metrics
+    reach the compiled program's metadata, which the trace is joined on."""
+    module, scopes = profiler_mod.instruction_scopes(
+        lowered_step.compile().as_text())
+    assert module == "jit_step"
+    blocks = {profiler_mod.block_of(s) for s in scopes.values()}
+    for phase in ("fwd/loss", "grads_ok", "grad_clip", "optimizer",
+                  "metrics"):
+        assert phase in blocks, (phase, sorted(blocks))
+
+
+@pytest.mark.parametrize("scope, expected", [
+    ("jit(step)/jvp(Alphafold2)/trunk/layer_0/pair_from_msa/to_q/dot_general",
+     ("cross_attn", "fwd", "pair_from_msa", True)),
+    ("jit(step)/transpose(jvp(Alphafold2))/trunk/layer_11/msa_ff/wi/dot",
+     ("feedforward", "bwd", "msa_ff", True)),
+    ("jit(step)/jvp(Alphafold2)/trunk/layer_1/pair_axial_norm/mul",
+     ("model_rest", "fwd", "pair_axial_norm", True)),
+    ("jit(step)/jvp(Alphafold2)/distogram_proj/dot_general",
+     ("model_rest", "fwd", "distogram_proj", True)),
+    ("jit(step)/transpose(jvp(loss))/mul",
+     ("outside_model", "bwd", "loss", True)),
+    ("jit(step)/optimizer/grad_clip/mul",
+     ("outside_model", "", "grad_clip", True)),
+    ("jit(_threefry_split)", ("outside_model", "", "jit(_threefry_split)",
+                              True)),
+    ("jit(step)/mul", ("outside_model", "", "unscoped", False)),
+    ("state.params['params']['trunk']", ("outside_model", "", "unscoped",
+                                         False)),
+    ("", ("outside_model", "", "unscoped", False)),
+])
+def test_classify_a_scope(scope, expected):
+    assert scope_reduce.classify(scope) == expected
+    # the program's own table uses the same rows
+    way, block = expected[1], expected[2]
+    assert profiler_mod.block_of(scope) == (
+        f"{way}/{block}" if way else block)
+
+
+# ------------------------------------- (b) spans on the profiler's clock ---
+
+
+def host_events(trace_dir, names) -> list:
+    paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    assert len(paths) == 1
+    return profiler_mod.read_record(paths[0], names)["host"]
+
+
+def test_enabled_tracer_span_lands_in_the_profilers_host_plane(tmp_path):
+    tracer = Tracer(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in (3, 4):
+            with tracer.step("train", i):
+                with tracer.span("train.step", step=i, compiles=7):
+                    jnp.ones((4,)).block_until_ready()
+        tracer.instant("train.mark", step=4)
+    finally:
+        jax.profiler.stop_trace()
+    assert tracer.annotated_names() == {"train", "train.step", "train.mark"}
+    host = host_events(str(tmp_path), tracer.annotated_names())
+    spans = [h for h in host if h[0] == "train.step"]
+    assert [h[4]["step"] for h in spans] == [3, 4]
+    assert spans[0][4]["compiles"] == 7
+    steps = [h for h in host if h[0] == "train"]
+    assert [h[4]["step_num"] for h in steps] == [3, 4]
+    # the span lies inside its step's annotation, on one clock and thread
+    assert steps[0][1] <= spans[0][1] <= spans[0][2] <= steps[0][2]
+    assert spans[0][3] == steps[0][3]
+    # and the tracer's own events are as before
+    assert [e["args"]["step"] for e in tracer.events()
+            if e["name"] == "train.step"] == [3, 4]
+
+
+def test_disabled_tracer_builds_no_annotation_and_no_listener(monkeypatch):
+    built = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(*args, **kwargs):
+        built.append(args)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", counting)
+    monkeypatch.setattr(tracing, "_listen_for_compiles",
+                        lambda: built.append("listener"))
+    tracer = Tracer(enabled=False)
+    with tracer.step("train", 0):
+        with tracer.span("train.step", step=0) as sp:
+            sp.set(x=1)
+    tracer.instant("train.mark")
+    assert built == [] and tracer.annotated_names() == set()
+    on = Tracer(enabled=True)
+    with on.span("train.step", step=0):
+        pass
+    assert built == ["listener", ("train.step",)]
+
+
+def test_tracer_keeps_the_newest_events_when_bounded():
+    tracer = Tracer(enabled=True, max_events=3)
+    for i in range(5):
+        tracer.instant("mark", i=i)
+    assert [e["args"]["i"] for e in tracer.events()] == [2, 3, 4]
+
+
+# -------------------------------------------------- (e) compile counter ---
+
+
+def test_compile_counter_rises_once_per_new_shape():
+    Tracer(enabled=True)  # registers the listeners, once per process
+
+    @jax.jit
+    def fresh(x):
+        return x * 2.0 + 1.0
+
+    a3 = jax.device_put(np.ones((3,), np.float32))
+    a5 = jax.device_put(np.ones((5,), np.float32))
+    before = compile_counts()
+    fresh(a3).block_until_ready()
+    first = compile_counts()
+    fresh(a3).block_until_ready()
+    repeat = compile_counts()
+    fresh(a5).block_until_ready()
+    second = compile_counts()
+    assert first["compiles"] == before["compiles"] + 1
+    assert repeat["compiles"] == first["compiles"]
+    assert second["compiles"] == first["compiles"] + 1
+    assert first["trace_s"] > before["trace_s"]
+    assert first["backend_s"] > before["backend_s"]
+    assert second["cache_hits"] <= second["compiles"]
+
+
+# ----------------------------------- (c) the reduction, on a written record
+
+
+def us(x):
+    return 1000.0 * x
+
+
+def written_record() -> dict:
+    """The benchmark's hand-written record (one execution of the step after
+    an RNG program, a ``while`` holding two kernels, a gap of 90 us between
+    the programs), its microseconds as nanoseconds."""
+    path = os.path.join(os.path.dirname(scope_reduce.__file__), "..", "tests",
+                        "data", "small_record.json")
+    with open(path) as f:
+        record = json.load(f)
+    for plane in record["devices"].values():
+        for key in ("ops", "modules", "steps"):
+            plane[key] = [(*row[:-2], us(row[-2]), us(row[-1]))
+                          for row in plane[key]]
+    record["host"] = [(n, us(a), us(b), t, args)
+                      for n, a, b, t, args in record["host"]]
+    return record
+
+
+def planes_of(record) -> dict:
+    """What ``harness/train.py`` keeps of the same trace."""
+    return {name: [(o[0], o[2], o[3]) for o in plane["ops"]]
+            for name, plane in record["devices"].items()}
+
+
+def test_own_time_by_block_forward_and_backward_apart():
+    plane = scope_reduce.first_plane(written_record())
+    sums = scope_reduce.by_block(plane)
+    assert sums["blocks"] == {
+        "jit(_threefry_split)": us(10), "fwd/trunk": us(100),
+        "fwd/pair_from_msa": us(300), "fwd/pair_axial": us(200),
+        "bwd/pair_from_msa": us(200), "bwd/msa_ff": us(50),
+        "fwd/loss": us(20), "grad_clip": us(10), "optimizer": us(20),
+        "unscoped": us(50), "bwd/pair_axial": us(50),
+    }
+    assert sums["groups"] == {
+        "cross_attn": us(500), "pair_axial": us(250), "msa_axial": 0.0,
+        "feedforward": us(50), "model_rest": us(100),
+        "outside_model": us(110),
+    }
+    assert sums["unnamed"] == us(50) and sums["total"] == us(1010)
+    assert scope_reduce.step_runs(plane, "jit_step") == [(us(100), us(1100))]
+    assert scope_reduce.collective_by_block(plane, ["all-reduce"]) == {
+        "bwd/pair_axial": us(50)}
+    assert scope_reduce.collective_by_block(plane, ["all-gather"]) == {}
+
+
+def test_gaps_go_to_the_innermost_covering_span():
+    record = written_record()
+    plane = scope_reduce.first_plane(record)
+    gaps = scope_reduce.idle_gaps(plane["ops"], us(20))
+    assert gaps == [(us(10), us(100))]
+    assert scope_reduce.idle_by_span(gaps, record["host"]) == {
+        "train.rng": us(20), "train.step": us(30), "train.dispatch": us(10),
+        "outside_any_span": us(30),
+    }
+    assert scope_reduce.idle_gaps(plane["ops"], us(100)) == []
+
+
+def test_the_programs_own_line_agrees_with_the_readers_arithmetic():
+    line = profiler_mod.summarize(written_record())
+    assert line["steps"] == 1 and line["step_device_ms"] == 1.0
+    assert line["block_ms/fwd/pair_from_msa"] == 0.3
+    assert line["block_ms/bwd/pair_from_msa"] == 0.2
+    assert line["block_ms/fwd/trunk"] == 0.1
+    assert line["block_ms/unscoped"] == 0.05
+    assert line["collective_own_ms/bwd/pair_axial"] == 0.05
+    assert line["idle_ms/train.dispatch"] == 0.01
+    assert line["idle_ms/outside_any_span"] == 0.03
+    assert line["idle_pct"] == round(100 * 90 / 1100, 3)
+
+
+def test_block_reader_checks_the_record_against_the_harness(monkeypatch):
+    from benchmark.readers import scope_device_ms
+
+    record = written_record()
+    monkeypatch.setattr(scope_reduce, "program_record", lambda: record)
+    run = {"trace": {"planes": planes_of(record)}}
+    assert scope_device_ms.read(run, {"group": "cross_attn"}) == 0.5
+    assert sum(scope_device_ms.read(run, {"group": g})
+               for g in scope_reduce.ALL_GROUPS) == pytest.approx(1.01)
+    events = next(iter(run["trace"]["planes"].values()))
+    events.remove(("ff.1", us(900), us(950)))  # 5% of the trace is missing
+    with pytest.raises(RuntimeError, match="not the same trace"):
+        scope_device_ms.read(run, {"group": "cross_attn"})
+
+
+# ------------------------ (d) the record after train() was left early ---
+
+
+class Stop(Exception):
+    pass
+
+
+def test_record_and_spans_survive_an_exception_from_a_callback(
+        tmp_path, monkeypatch, capsys):
+    from alphafold2_tpu.train.loop import train
+
+    closed = []
+    real_close = Tracer.close
+    monkeypatch.delattr(Tracer, "__del__")  # it would close a second time
+    monkeypatch.setattr(
+        Tracer, "close", lambda self: (closed.append(1), real_close(self)))
+
+    def leave_at_3(i, state, metrics):
+        if i == 3:
+            raise Stop
+
+    cfg = tiny_config(depth=1, features="none",
+                      profile_dir=str(tmp_path / "trace"),
+                      profile_steps=(1, 3))
+    with pytest.raises(Stop):
+        train(cfg, callbacks=[leave_at_3])
+    assert closed == [1]
+    record = profiler_mod.last_record()
+    plane = scope_reduce.first_plane(record)
+    assert plane is not None and record["step_module"] == "jit_step"
+    assert any(scope.startswith("jit(step)/") for _, scope, _, _ in
+               plane["ops"])
+    traced = {h[4].get("step") for h in record["host"]
+              if h[0] == "train.step"}
+    assert traced == {1, 2, 3}
+    assert {h[4]["step_num"] for h in record["host"] if h[0] == "train"} \
+        == {2}  # whole iterations inside the trace
+    names = {e["name"] for e in record["spans"]}
+    assert {"train.imports", "train.dataset", "train.build_model",
+            "train.first_batch", "train.init_state", "train.lower",
+            "train.compile", "train.cost_analysis", "train.rng",
+            "train.step", "train.profiler", "train.log", "train.callbacks",
+            "train.next_batch", "train.triage_wait"} <= names
+    compile_span = scope_reduce.span_events(record, "train.compile")[0]
+    assert set(compile_span["args"]) == {"backend_s", "cache_hit"}
+    assert set(scope_reduce.span_events(record, "train.lower")[0]["args"]) \
+        == {"trace_s", "lower_s"}
+    left = scope_reduce.span_events(record, "train.callbacks")[-1]
+    assert left["args"] == {"step": 3, "error": "Stop"}
+    assert record["compile_counts"]["compiles"] >= 1
+    # the one line: device time by block, idle time by host span
+    lines = [line for line in capsys.readouterr().out.splitlines()
+             if "event=profile" in line]
+    assert len(lines) == 1
+    assert "block_ms/fwd/" in lines[0] and "block_ms/bwd/" in lines[0]
+    assert "idle_ms/" in lines[0]
+    # and the next trace can start: none was left running
+    jax.profiler.start_trace(str(tmp_path / "again"))
+    jax.profiler.stop_trace()
+
+
+def test_train_hands_the_loop_a_null_tracer_unless_asked(
+        tmp_path, monkeypatch):
+    """With neither ``profile_dir`` nor ``trace_events`` the loop gets the
+    disabled tracer (which builds no annotation and registers no listener,
+    see above) and a profiler that reads nothing; ``profile_dir`` alone turns
+    both on, spans in memory."""
+    from alphafold2_tpu.train import loop
+
+    seen = []
+    monkeypatch.setattr(
+        loop, "_train", lambda cfg, n, data, cbs, tracer, logger, profiler:
+        seen.append((tracer.enabled, profiler.enabled)))
+    monkeypatch.setattr(profiler_mod, "_LAST", None)
+    loop.train(tiny_config())
+    assert seen == [(False, False)] and profiler_mod.last_record() is None
+    loop.train(tiny_config(profile_dir=str(tmp_path)))
+    assert seen[-1] == (True, True)
+    assert profiler_mod.last_record()["spans"] == []
