@@ -15,8 +15,10 @@ Default phases, on one chip, at the width of the flagship (bench.py):
    calls, for a few optimizer steps on synthetic data from a seed.
 2. serve  — a ``ServeEngine`` behind ``AsyncServeFrontend`` with the
    dispatch pipeline on, answering requests over three buckets, twice.
-3. kernels — the three in-repo Pallas kernels, forward and gradient,
-   against plain jnp references at float32 / highest matmul precision.
+3. kernels — the three in-repo Pallas kernels, and the stock flash kernel
+   through ``ops/flash.py`` at the blocks it picks for the flagship's
+   shapes, forward and gradient, against plain jnp references at float32 /
+   highest matmul precision.
 
 Every phase prints one JSON object on a line of its own. The LAST line of
 stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
@@ -344,6 +346,21 @@ def _ref_attention(q, k, v, q_mask, kv_mask, scale):
     return out
 
 
+def _ref_attention_by_head(q, k, v, kv_mask, scale):
+    """``_ref_attention`` one head at a time, recomputed in the backward:
+    the flagship cross-attention's float32 logits are 1 GB a head."""
+    import jax
+    import jax.numpy as jnp
+
+    def one_head(qkv):
+        q1, k1, v1 = (t[:, None] for t in qkv)
+        return _ref_attention(q1, k1, v1, None, kv_mask, scale)[:, 0]
+
+    heads_first = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v))
+    return jnp.moveaxis(
+        jax.lax.map(jax.checkpoint(one_head), heads_first), 0, 1)
+
+
 def _ref_tied(q, k, v, q_mask, kv_mask, scale):
     """Tied-row attention, (B, R, N, H, D) layout: one attention matrix per
     (batch, head), logits summed over the R rows and scaled by R**-0.5."""
@@ -369,7 +386,9 @@ def _tail_mask(b: int, n: int, pad: int):
 def kernel_cases(small: bool = False) -> list:
     """(name, kernel fn, reference fn, input shapes, dtype) for the three
     in-repo kernels at the shapes the flagship reaches, plus one masked
-    odd-length case each. ``small`` is the CPU rehearsal's size."""
+    odd-length case each, and the stock flash kernel at every shape class
+    the flagship sends it. ``small`` is the CPU rehearsal's size (no stock
+    flash there: off the TPU its wrapper declines)."""
     import jax.numpy as jnp
 
     from alphafold2_tpu.ops.pallas.axial import fused_attention
@@ -427,6 +446,19 @@ def kernel_cases(small: bool = False) -> list:
             (shape,) * 3, "float32",
         ))
 
+    def flash(name, q_shape, kv_shape, pad):
+        from alphafold2_tpu.ops.flash import flash_attention
+
+        d = q_shape[-1]
+        m = _tail_mask(kv_shape[0], kv_shape[2], pad)
+        cases.append((
+            name,
+            lambda q, k, v: flash_attention(
+                q, k, v, kv_mask=m, sm_scale=d ** -0.5),
+            lambda q, k, v: _ref_attention_by_head(q, k, v, m, d ** -0.5),
+            (q_shape, kv_shape, kv_shape), "bfloat16",
+        ))
+
     if small:
         axial("fused_axial_f32", (2, 2, 32, 16), "float32", 0)
         axial("fused_axial_masked_odd", (1, 2, 40, 16), "float32", 7)
@@ -442,6 +474,17 @@ def kernel_cases(small: bool = False) -> list:
     tied("tied_row_masked_odd", (1, 5, 200, 8, 64), "float32", 9)
     sparse("block_sparse_n512", 512, 128, 0)
     sparse("block_sparse_n1024_masked", 1024, 128, 17)
+    pair, msa = (1, 8, 256 * 256, 64), (1, 8, 16 * 256, 64)
+    flash("stock_flash_pair_axial", (256, 8, 256, 64), (256, 8, 256, 64), 0)
+    flash("stock_flash_pair_from_msa", pair, msa, 0)
+    flash("stock_flash_msa_from_pair", msa, pair, 0)
+    # 4,096 keys pooled by 3: 1,366, padded to 1,408 = 11 x 128, tail masked
+    flash("stock_flash_compressed_masked_odd", pair, (1, 8, 1366, 64), 9)
+    # 2,048 keys: the longest axis the block rule takes whole, against many
+    # query blocks and against one (MSA 4 x 128 queries)
+    flash("stock_flash_keys_2048_masked", pair, (1, 8, 2048, 64), 9)
+    flash("stock_flash_one_q_block_keys_2048", (1, 8, 512, 64),
+          (1, 8, 2048, 64), 0)
     return cases
 
 

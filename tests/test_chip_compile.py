@@ -90,8 +90,10 @@ def _tied_row(q, k, v, mask=None):
 
 
 def _stock_flash(q, k, v, kv_mask=None):
-    """The stock kernel through the repo's wrapper (padding to the 128
-    block and segment ids included), as Attention.__call__ reaches it."""
+    """The stock kernel through the repo's wrapper (padding to 128, segment
+    ids and the blocks ``block_sizes_for`` picks for the shape included), as
+    Attention.__call__ reaches it. The scoped-VMEM limit is the fence on
+    those blocks, and only this compile sees it."""
     from alphafold2_tpu.ops.flash import flash_attention
 
     return flash_attention(q, k, v, kv_mask=kv_mask, sm_scale=0.125)
@@ -116,6 +118,8 @@ def _block_sparse(n, block=128):
 
 
 AXIAL = (256, 8, 256, 64)  # (B*N rows, heads, N, dim_head) at crop 256
+AXIAL_MESH_HALF = (128, 8, 256, 64)  # per device under dp2 x sp2
+AXIAL_CROP384 = (384, 8, 384, 64)  # 384 = 3 x 128: one block of 384
 TIED = (1, 16, 256, 8, 64)  # (B, MSA rows, N, heads, dim_head)
 PAIR_Q = (1, 8, 256 * 256, 64)  # the flat pair stream as queries
 MSA_KV = (1, 8, 16 * 256, 64)  # the flat MSA stream as keys/values
@@ -129,9 +133,33 @@ CASES = {
     "tied_row_bf16": (_tied_row, [(TIED, "bfloat16")] * 3),
     "tied_row_f32": (_tied_row, [(TIED, "float32")] * 3),
     "stock_flash_axial": (_stock_flash, [(AXIAL, "bfloat16")] * 3),
-    "stock_flash_cross": (
+    "stock_flash_axial_mesh_half": (
+        _stock_flash, [(AXIAL_MESH_HALF, "bfloat16")] * 3,
+    ),
+    "stock_flash_axial_crop384": (
+        _stock_flash, [(AXIAL_CROP384, "bfloat16")] * 3,
+    ),
+    "stock_flash_cross": (  # pair_from_msa
         _stock_flash,
         [(PAIR_Q, "bfloat16"), (MSA_KV, "bfloat16"), (MSA_KV, "bfloat16")],
+    ),
+    "stock_flash_cross_msa_from_pair": (
+        _stock_flash,
+        [(MSA_KV, "bfloat16"), (PAIR_Q, "bfloat16"), (PAIR_Q, "bfloat16")],
+    ),
+    "stock_flash_cross_f32": (  # float32 operands: K/V tiles twice the bytes
+        _stock_flash,
+        [(PAIR_Q, "float32"), (MSA_KV, "float32"), (MSA_KV, "float32")],
+    ),
+    # a key axis of 2,048 is the largest the rule takes whole: forward and
+    # dkv hold a (·, 2048) key tile, against many query blocks and against one
+    "stock_flash_cross_keys_2048": (
+        _stock_flash,
+        [(PAIR_Q, "bfloat16")] + [((1, 8, 2048, 64), "bfloat16")] * 2,
+    ),
+    "stock_flash_one_q_block_keys_2048": (  # MSA 4 x 128 against 2,048 keys
+        _stock_flash,
+        [((1, 8, 512, 64), "bfloat16")] + [((1, 8, 2048, 64), "bfloat16")] * 2,
     ),
     "stock_flash_compressed_cross": (
         _stock_flash,

@@ -3,12 +3,17 @@ stock JAX Pallas TPU op (compiled only on TPU backends; AF2TPU_TEST_TPU=1
 runs these paths on hardware) — what is tested hermetically is the
 gating/fallback contract the model relies on."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from alphafold2_tpu.ops.attention import Attention
-from alphafold2_tpu.ops.flash import flash_attention, flash_available
+from alphafold2_tpu.ops.flash import (
+    block_sizes_for, flash_attention, flash_available,
+)
 
 
 def test_unavailable_off_tpu_returns_none():
@@ -201,3 +206,81 @@ def test_flash_engages_with_one_short_axis(monkeypatch):
     # both axes sub-block: dense stays preferred
     tiny = jnp.ones((1, 2, 64, 16))
     assert flash_mod.flash_attention(tiny, tiny, tiny) is None
+
+
+# (batch, heads, nq, nk, head_dim, dtype) as the wrapper hands them on: padded
+# to 128
+BLOCK_RULE_SHAPES = {
+    "pair_from_msa": (1, 8, 256 * 256, 16 * 256, 64, "bfloat16"),
+    "msa_from_pair": (1, 8, 16 * 256, 256 * 256, 64, "bfloat16"),
+    "pair_axial": (256, 8, 256, 256, 64, "bfloat16"),
+    "pair_axial_mesh_half": (128, 8, 256, 256, 64, "bfloat16"),
+    "pair_axial_crop384": (384, 8, 384, 384, 64, "bfloat16"),
+    "compressed_keys_1408": (1, 8, 256 * 256, 1408, 64, "bfloat16"),
+    "served_3L_stream": (2, 8, 768 * 768, 5 * 768, 64, "bfloat16"),
+    "short_query_axis": (1, 8, 128, 256 * 256, 64, "bfloat16"),  # nq 64 -> 128
+    "short_key_axis": (1, 8, 256 * 256, 128, 64, "bfloat16"),
+    "odd_multiple_queries": (1, 8, 17 * 128, 4096, 64, "bfloat16"),
+    "odd_multiple_keys": (1, 8, 4096, 17 * 128, 64, "bfloat16"),
+    "odd_multiples_both": (3, 4, 5 * 128, 9 * 128, 64, "float32"),
+    "one_block_odd_batch": (3, 4, 128, 128, 64, "float32"),
+    "wide_head_float32": (1, 8, 256 * 256, 4096, 256, "float32"),
+    # one query block against a whole key axis too large for block_b 2 or 4
+    "msa_queries_pair_keys_2048": (1, 8, 512, 2048, 64, "bfloat16"),
+    "one_q_block_keys_1408": (1, 8, 384, 1408, 64, "bfloat16"),
+    "one_q_block_even_batch": (2, 8, 512, 1152, 64, "bfloat16"),
+    "one_q_block_at_area_cap": (4, 8, 256, 512, 64, "bfloat16"),
+    "one_q_block_past_area_cap": (4, 8, 256, 640, 64, "bfloat16"),
+    # the dq wrapper's di, (b, h, nq, block_k_major_dq) float32, above 1 GiB
+    # at 512 keys
+    "pair_from_msa_crop384": (1, 8, 384 * 384, 16 * 384, 64, "bfloat16"),
+    "pair_from_msa_batch2": (2, 8, 256 * 256, 16 * 256, 64, "bfloat16"),
+    "pair_from_msa_16_heads": (1, 16, 256 * 256, 16 * 256, 64, "bfloat16"),
+    "whole_key_axis_2048": (1, 8, 256 * 256, 2048, 64, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BLOCK_RULE_SHAPES))
+def test_block_rule_gives_blocks_the_kernel_accepts(name):
+    """What the stock kernel's ``_verify_block`` and ``BlockSizes`` ask of a
+    block set, for every length the wrapper can hand it: multiples of 128
+    that divide the padded axis, inner blocks dividing their major blocks,
+    the backward's query blocks dividing ``nq`` (the forward's need not, and
+    still do)."""
+    batch, heads, nq, nk, head_dim, dtype = BLOCK_RULE_SHAPES[name]
+    bs = block_sizes_for(batch, heads, nq, nk, head_dim, dtype)  # constructs
+    assert bs.has_backward_blocks
+    axis = {"q": nq, "k": nk}
+    blocks = dataclasses.asdict(bs)
+    assert batch % blocks.pop("block_b") == 0
+    for field, size in blocks.items():
+        n = axis[field.split("_")[1]]
+        assert size % 128 == 0 and 128 <= size <= n and n % size == 0, field
+    for major, minor in [
+        ("block_k_major", "block_k"),
+        ("block_q_major_dkv", "block_q_dkv"),
+        ("block_k_major_dkv", "block_k_dkv"),
+        ("block_k_major_dq", "block_k_dq"),
+    ]:
+        assert blocks[major] % blocks[minor] == 0, (major, minor)
+    # one K or V tile stays within 1 MiB whatever the head width and dtype
+    row = head_dim * jnp.dtype(dtype).itemsize
+    assert max(bs.block_k_major, bs.block_k_major_dkv) * row <= max(
+        2**20, 128 * row)
+    # the dq wrapper's di stays within 1 GiB, or within the default's
+    di_row = batch * heads * nq * 4
+    assert bs.block_k_major_dq * di_row <= max(2**30, 128 * di_row)
+    if name in ("pair_from_msa", "msa_from_pair"):
+        # the flagship's cross-attentions leave the 128 x 128 default
+        assert min(blocks.values()) >= 256
+        assert bs.block_k >= 1024 and bs.block_q_major_dkv >= 1024
+        assert bs.block_k_major_dq == 512  # di exactly 1 GiB: measured
+    if name.startswith("pair_from_msa_"):
+        assert bs.block_k_major_dq < 512 and bs.block_k == 1024
+    if "one_q_block" in name or name == "msa_queries_pair_keys_2048":
+        assert bs.block_q == nq and bs.block_k == nk
+        assert bs.block_b == {"one_q_block_at_area_cap": 4,
+                              "one_q_block_past_area_cap": 2}.get(name, 1)
+    if name.startswith("pair_axial"):
+        assert bs.block_k == nk  # the kernel's single-step body
+        assert bs.block_b == {256: 4, 384: 2}[nq]  # sequences a grid step
