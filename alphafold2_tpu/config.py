@@ -18,6 +18,9 @@ from typing import Optional, Tuple
 
 @dataclass
 class ModelConfig:
+    # which (model, loss, batch spec) train() builds: "alphafold2" (the axial
+    # trunk, the fields below) | "mla_moe_lm" (the ``lm`` section)
+    arch: str = "alphafold2"
     dim: int = 256  # trunk embedding width (single-repr channels)
     max_seq_len: int = 2048  # positional-embedding table size (max residues)
     depth: int = 6  # trunk layers (MSA+pair block repeats)
@@ -59,6 +62,38 @@ class ModelConfig:
 
 
 @dataclass
+class LMConfig:
+    """Decoder-only language model with latent attention (MLA) and
+    sigmoid-routed experts (models/mla_moe_lm.py), read when ``model.arch``
+    is ``mla_moe_lm``. The defaults are the published sizes of a
+    DeepSeek-V3-shaped 30B-A3B model, whole; a chip's share of an
+    expert-parallel layer (``experts_held``, ``first_expert``, a slice of the
+    vocabulary) or fewer layers are for the caller to set."""
+
+    vocab_size: int = 128256  # vocabulary rows held here (ids 0..vocab_size-1)
+    hidden_size: int = 2048  # residual stream width
+    num_layers: int = 48  # blocks, the leading dense ones included
+    first_k_dense: int = 1  # leading blocks with a dense SwiGLU
+    num_heads: int = 32  # attention heads
+    qk_nope_head_dim: int = 128  # query/key head part without positions
+    qk_rope_head_dim: int = 64  # query/key head part under rotary positions
+    v_head_dim: int = 128  # value head width
+    kv_lora_rank: int = 512  # width of the key/value latent
+    intermediate_size: int = 6144  # dense SwiGLU width
+    moe_intermediate_size: int = 768  # one expert's SwiGLU width
+    n_routed_experts: int = 128  # the router's width, held or not
+    n_shared_experts: int = 2  # run as one SwiGLU of n_shared x the width
+    num_experts_per_tok: int = 6  # experts a token is routed to
+    routed_scaling_factor: float = 2.448  # on the normalised routing weights
+    rope_theta: float = 1e6  # rotary base
+    rms_norm_eps: float = 1e-6  # inside every RMSNorm's rsqrt
+    # the share: experts first_expert .. first_expert + experts_held - 1
+    experts_held: int = 128  # routed experts this chip holds a layer
+    first_expert: int = 0  # id of the first expert held
+    bfloat16: bool = True  # compute dtype (weights stay float32)
+
+
+@dataclass
 class MeshConfig:
     data_parallel: int = 1  # dp axis size; -1 = fill with all devices
     seq_parallel: int = 1  # sp axis size (pair-map row sharding)
@@ -76,9 +111,14 @@ class DataConfig:
     batch_size: int = 1  # examples per training batch
     max_len_filter: int = 250  # drop chains longer than this (train_pre.py:47)
     min_len_filter: int = 16  # drop chains shorter than this
-    source: str = "synthetic"  # "synthetic" | "native" | "npz" | "sidechainnet"
+    # "synthetic" | "native" | "npz" | "sidechainnet" | "tokens" (data/tokens.py)
+    source: str = "synthetic"
     casp_version: int = 12  # sidechainnet CASP release to load
     thinning: int = 30  # sidechainnet thinning percentage
+    # source "tokens": batch_size sequences of seq_len ids, Zipf over the
+    # vocabulary rows the model holds (lm.vocab_size)
+    seq_len: int = 8192  # positions a sequence
+    zipf_exponent: float = 1.0  # P(rank r) ~ r ** -zipf_exponent
     data_dir: Optional[str] = None  # on-disk dataset root for "npz"/"native"
     # feature stream fed beside the sequence (reference train_end2end.py:22-28
     # FEATURES): "msa" | "plm" (frozen PLM embeddings via data/plm.py) | "none"
@@ -194,6 +234,7 @@ def _tuplify(section, name):
 @dataclass
 class Config:
     model: ModelConfig = field(default_factory=ModelConfig)  # architecture
+    lm: LMConfig = field(default_factory=LMConfig)  # model.arch "mla_moe_lm"
     mesh: MeshConfig = field(default_factory=MeshConfig)  # device mesh axes
     data: DataConfig = field(default_factory=DataConfig)  # dataset + features
     train: TrainConfig = field(default_factory=TrainConfig)  # optimizer loop
@@ -207,6 +248,7 @@ class Config:
         raw = json.loads(s)
         return cls(
             model=ModelConfig(**raw.get("model", {})),
+            lm=LMConfig(**raw.get("lm", {})),
             mesh=MeshConfig(**raw.get("mesh", {})),
             data=DataConfig(**raw.get("data", {})),
             train=_tuplify(TrainConfig(**raw.get("train", {})), "profile_steps"),

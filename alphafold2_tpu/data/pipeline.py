@@ -480,9 +480,17 @@ class NpzShardDataset:
                 )
 
 
-def make_dataset(config: DataConfig, seed: int = 0):
+def make_dataset(config: DataConfig, seed: int = 0, vocab_size=None):
+    """The dataset ``config.source`` names. ``vocab_size`` is the language
+    model's (``lm.vocab_size``); only the token stream reads it."""
     if config.source == "synthetic":
         return SyntheticDataset(config, seed=seed)
+    if config.source == "tokens":
+        from alphafold2_tpu.data.tokens import SyntheticTokens
+
+        if vocab_size is None:
+            raise ValueError("data.source='tokens' needs the vocabulary size")
+        return SyntheticTokens(config, vocab_size, seed=seed)
     if config.source == "native":
         from alphafold2_tpu.data import native
 

@@ -81,8 +81,9 @@ def flatten_metrics(metrics: dict, prefix: str = "", sep: str = "/") -> dict:
     The train loops log through this so structured step metrics (the
     numerics stats tree, per-parameter-group norms) land in metrics.jsonl
     as flat greppable keys. Leaves are coerced with ``float()`` — which
-    also fetches device scalars — falling back to the raw value for
-    non-numeric leaves (strings)."""
+    also fetches device scalars — falling back to a list for an array with
+    axes (per-layer counters) and to the raw value for non-numeric leaves
+    (strings)."""
     out: dict = {}
     for k, v in metrics.items():
         key = f"{prefix}{k}"
@@ -92,7 +93,7 @@ def flatten_metrics(metrics: dict, prefix: str = "", sep: str = "/") -> dict:
         try:
             out[key] = float(v)
         except (TypeError, ValueError):
-            out[key] = v
+            out[key] = v.tolist() if hasattr(v, "tolist") else v
     return out
 
 

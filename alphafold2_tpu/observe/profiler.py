@@ -273,13 +273,16 @@ def block_of(scope: str) -> str:
         while _WRAPPED.match(p):
             p = _WRAPPED.match(p).group(1)
         plain.append(p)
-    if "Alphafold2" in plain:
-        inner = plain[plain.index("Alphafold2") + 1:]
+    # the model: what jvp( wraps that is no phase (Alphafold2, MlaMoeLM)
+    model = next((q for p, q in zip(parts, plain)
+                  if p != q and q not in PHASES), None)
+    if model is not None:
+        inner = plain[plain.index(model) + 1:]
         for i, p in enumerate(inner):
             if _LAYER.match(p):
                 inner = inner[i + 1:]
                 break
-        return way + (inner[0] if inner else "Alphafold2")
+        return way + (inner[0] if inner else model)
     phases = [p for p in plain if p in PHASES]
     if phases:  # the innermost: grad_clip sits inside optimizer
         return way + phases[-1]

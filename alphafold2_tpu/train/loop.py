@@ -19,8 +19,10 @@ gradient accumulation 16 — train_pre.py:13-24, 66-95) re-designed TPU-first:
 
 from __future__ import annotations
 
+import dataclasses
 from contextlib import nullcontext
-from typing import Optional
+from functools import partial
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +109,88 @@ def build_model(cfg: Config) -> Alphafold2:
     )
 
 
+@dataclasses.dataclass(frozen=True)
+class Task:
+    """What the loop needs of a model: the model, its loss and its batch
+    spec, chosen together by the configuration (:func:`build_task`).
+    ``make_train_step``, ``make_triage_step`` and the init functions take a
+    Task wherever they take a model; a bare model is the trunk's."""
+
+    model: Any
+    # (params, batch, rng) -> outputs, dropout on
+    forward: Callable[[Any, dict, jax.Array], Any]
+    loss: Callable[[Any, dict], jnp.ndarray]  # (outputs, batch), scope "loss"
+    metrics: Callable[[Any], dict]  # outputs -> what rides beside the loss
+    init: Callable[[jax.Array, dict], Any]  # (rng, batch) -> params
+    tiny_batch: Callable[[dict], dict]  # a real batch cut down for init
+
+    @property
+    def apply(self):
+        return self.model.apply
+
+
+def trunk_task(model: Alphafold2) -> Task:
+    """Distogram pretraining of the axial trunk: the default triple."""
+
+    def forward(params, batch, rng):
+        return model.apply(
+            params,
+            batch["seq"],
+            batch.get("msa"),
+            mask=batch["mask"],
+            msa_mask=batch.get("msa_mask"),
+            embedds=batch.get("embedds"),  # frozen-PLM feature path
+            deterministic=False,
+            rngs={"dropout": rng},
+        )
+
+    def metrics(logits):
+        return {
+            "distogram_entropy": -jnp.mean(
+                jnp.sum(
+                    jax.nn.softmax(logits, -1) * jax.nn.log_softmax(logits, -1),
+                    -1,
+                )
+            )
+        }
+
+    def init(rng, batch):
+        def opt(key):
+            v = batch.get(key)
+            return jnp.asarray(v) if v is not None else None
+
+        return model.init(
+            rng,
+            jnp.asarray(batch["seq"]),
+            opt("msa"),
+            mask=jnp.asarray(batch["mask"]),
+            msa_mask=opt("msa_mask"),
+            embedds=opt("embedds"),
+        )
+
+    return Task(model, forward, _scoped_loss, metrics, init, tiny_batch_like)
+
+
+def as_task(model) -> Task:
+    return model if isinstance(model, Task) else trunk_task(model)
+
+
+def build_task(cfg: Config) -> Task:
+    """The (model, loss, batch spec) that ``model.arch`` names. Another
+    architecture's modules are imported only when asked for."""
+    if cfg.model.arch == "alphafold2":
+        return trunk_task(build_model(cfg))
+    if cfg.model.arch == "mla_moe_lm":
+        from alphafold2_tpu.models import mla_moe_lm as lm
+
+        model = lm.MlaMoeLM(cfg.lm)
+        return Task(model, partial(lm.forward, model), lm.loss,
+                    lm.step_metrics, partial(lm.init, model), lm.tiny_batch)
+    raise ValueError(
+        f"unknown model.arch {cfg.model.arch!r}; expected 'alphafold2' or "
+        "'mla_moe_lm'")
+
+
 def build_optimizer(cfg: Config) -> optax.GradientTransformation:
     t = cfg.train
     schedule = optax.warmup_cosine_decay_schedule(
@@ -131,7 +215,8 @@ def build_optimizer(cfg: Config) -> optax.GradientTransformation:
     return tx
 
 
-def init_state(cfg: Config, model: Alphafold2, sample_batch: dict) -> TrainState:
+def init_state(cfg: Config, model, sample_batch: dict) -> TrainState:
+    task = as_task(model)
     # validate the init scheme BEFORE the (expensive) model.init trace
     if cfg.model.init_scheme == "torch":
         if cfg.model.scan_layers or cfg.model.reversible:
@@ -146,26 +231,20 @@ def init_state(cfg: Config, model: Alphafold2, sample_batch: dict) -> TrainState
             "expected 'flax' or 'torch'"
         )
     rng = jax.random.key(cfg.train.seed)
-
-    def opt(key):
-        v = sample_batch.get(key)
-        return jnp.asarray(v) if v is not None else None
-
-    params = model.init(
-        rng,
-        jnp.asarray(sample_batch["seq"]),
-        opt("msa"),
-        mask=jnp.asarray(sample_batch["mask"]),
-        msa_mask=opt("msa_mask"),
-        embedds=opt("embedds"),
-    )
+    params = task.init(rng, sample_batch)
     if cfg.model.init_scheme == "torch":
         # re-draw under the reference's torch module defaults (models/init.py)
         from alphafold2_tpu.models.init import torch_match_reinit
 
         params = torch_match_reinit(params, rng)
+    return state_from_params(cfg, task, params)
+
+
+def state_from_params(cfg: Config, model, params) -> TrainState:
+    """A fresh TrainState (step 0, new optimizer state) around ``params``.
+    The train step donates its state, ``params`` with it."""
     state = TrainState.create(
-        apply_fn=model.apply,
+        apply_fn=as_task(model).apply,
         params=params,
         tx=build_optimizer(cfg),
         skipped=jnp.zeros((), jnp.int32),
@@ -197,7 +276,7 @@ def tiny_batch_like(sample_batch: dict, n: int = 16, m: int = 2) -> dict:
 
 
 def tiny_init_state(
-    cfg: Config, model: Alphafold2, sample_batch: Optional[dict] = None
+    cfg: Config, model, sample_batch: Optional[dict] = None
 ) -> TrainState:
     """init_state at minimal data shapes with cfg's feature structure.
 
@@ -218,7 +297,8 @@ def tiny_init_state(
     from dataclasses import replace
 
     if sample_batch is not None:
-        return init_state(cfg, model, tiny_batch_like(sample_batch))
+        return init_state(
+            cfg, model, as_task(model).tiny_batch(sample_batch))
 
     from alphafold2_tpu.data.pipeline import SyntheticDataset
 
@@ -261,12 +341,13 @@ def _param_groups(tree) -> dict:
 
 
 def make_train_step(
-    model: Alphafold2,
+    model,
     mesh: Optional[Mesh] = None,
     jit: bool = True,
     numerics_mode: str = "off",
 ):
-    """Build the jitted distogram-pretraining step.
+    """Build the jitted training step of ``model``: a :class:`Task`, or a
+    bare trunk model for distogram pretraining.
 
     Returns step(state, batch, rng) -> (state, metrics). When a mesh is
     given, inputs/outputs carry explicit shardings and the model's internal
@@ -277,7 +358,7 @@ def make_train_step(
     ``numerics_mode`` widens the metrics dict (observe.numerics):
 
     - ``"off"`` — exactly the historic metrics (loss, grad_norm, grads_ok,
-      distogram_entropy, skipped).
+      skipped, and the task's own: distogram_entropy for the trunk).
     - ``"norms"`` — adds per-parameter-group grad/param/update norms
       (``grad_norm/<group>`` etc.) beside the existing global ``grad_norm``.
     - ``"full"`` — norms plus the in-graph activation stats of every
@@ -291,6 +372,7 @@ def make_train_step(
             f"unknown numerics_mode {numerics_mode!r}; "
             "expected 'off', 'norms' or 'full'"
         )
+    task = as_task(model)
 
     def step(state: TrainState, batch: dict, rng: jax.Array):
         ctx = use_mesh(mesh) if mesh is not None else nullcontext()
@@ -300,20 +382,11 @@ def make_train_step(
                 # the tagged activations are forward-pass tracers, valid
                 # only as loss_fn aux outputs (value_and_grad has_aux)
                 with numerics.collect(enabled=numerics_mode == "full") as col:
-                    logits = model.apply(
-                        params,
-                        batch["seq"],
-                        batch.get("msa"),
-                        mask=batch["mask"],
-                        msa_mask=batch.get("msa_mask"),
-                        embedds=batch.get("embedds"),  # frozen-PLM feature path
-                        deterministic=False,
-                        rngs={"dropout": rng},
-                    )
-                    loss = _scoped_loss(logits, batch)
-                return loss, (logits, col.stats())
+                    outputs = task.forward(params, batch, rng)
+                    loss = task.loss(outputs, batch)
+                return loss, (outputs, col.stats())
 
-            ((loss, (logits, act_stats)), grads) = jax.value_and_grad(
+            ((loss, (outputs, act_stats)), grads) = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(state.params)
             # The phases around the model carry a named scope each (HLO
@@ -342,12 +415,7 @@ def make_train_step(
                     "grad_norm": gnorm,
                     "grads_ok": grads_ok,
                     "skipped": new_state.skipped,
-                    "distogram_entropy": -jnp.mean(
-                        jnp.sum(
-                            jax.nn.softmax(logits, -1) * jax.nn.log_softmax(logits, -1),
-                            -1,
-                        )
-                    ),
+                    **task.metrics(outputs),
                 }
                 if numerics_mode in ("norms", "full"):
                     # per-parameter-group norm trajectories: which part of the
@@ -386,7 +454,7 @@ def make_train_step(
     )
 
 
-def make_triage_step(model: Alphafold2, mesh: Optional[Mesh] = None):
+def make_triage_step(model, mesh: Optional[Mesh] = None):
     """Fully-tagged diagnostic step for NaN triage.
 
     Returns triage(params, batch, rng) -> stats, where stats maps every
@@ -399,23 +467,15 @@ def make_triage_step(model: Alphafold2, mesh: Optional[Mesh] = None):
     (params, batch, rng) of a skipped step through this after the fast
     step's non-finite-grad skip fires.
     """
+    task = as_task(model)
 
     def triage(params, batch: dict, rng: jax.Array):
         ctx = use_mesh(mesh) if mesh is not None else nullcontext()
         with ctx:
             def loss_fn(p):
                 with numerics.collect() as col:
-                    logits = model.apply(
-                        p,
-                        batch["seq"],
-                        batch.get("msa"),
-                        mask=batch["mask"],
-                        msa_mask=batch.get("msa_mask"),
-                        embedds=batch.get("embedds"),
-                        deterministic=False,
-                        rngs={"dropout": rng},  # the skipped step's exact rng
-                    )
-                    loss = _scoped_loss(logits, batch)
+                    # rng: the skipped step's exact one
+                    loss = task.loss(task.forward(p, batch, rng), batch)
                 return loss, col.stats()
 
             (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -477,8 +537,15 @@ def device_put_batch(batch: dict, mesh: Optional[Mesh] = None) -> dict:
     return {k: jax.device_put(jnp.asarray(v), sh) for k, v in batch.items()}
 
 
-def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=()):
-    """Distogram pretraining driver (the runnable train_pre.py equivalent).
+def train(cfg: Config, num_steps: Optional[int] = None, dataset=None,
+          callbacks=(), init_params=None):
+    """Training driver (the runnable train_pre.py equivalent): distogram
+    pretraining of the trunk, or whatever ``model.arch`` names
+    (:func:`build_task`).
+
+    ``init_params``: start from these parameters (the model's own tree) in
+    place of a fresh init; step 0, new optimizer state. The step donates its
+    state, so the caller's arrays are consumed: pass a copy to keep them.
 
     Observability (``observe``): host spans go to ``train.trace_events``
     (Chrome trace events) and, while ``train.profile_dir``'s profiler trace
@@ -505,7 +572,8 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
         span_names=tracer.annotated_names, log=logger.log,
     )
     try:
-        return _train(cfg, num_steps, dataset, callbacks, tracer, logger, profiler)
+        return _train(cfg, num_steps, dataset, callbacks, tracer, logger,
+                      profiler, init_params)
     finally:
         # also when a callback ended the run with an exception: whoever
         # holds no handle on these locals still finds spans and record
@@ -515,7 +583,8 @@ def train(cfg: Config, num_steps: Optional[int] = None, dataset=None, callbacks=
             profiler_mod.hand_over(tracer.events(), compile_counts())
 
 
-def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler):
+def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler,
+           init_params=None):
     import os
     import time
 
@@ -532,7 +601,8 @@ def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler)
     # DIFFERENT slice of the global batch (global_batch() stitches them)
     data_seed = cfg.train.seed + 7919 * jax.process_index()
     with tracer.span("train.dataset"):
-        dataset = dataset or make_dataset(cfg.data, seed=data_seed)
+        dataset = dataset or make_dataset(
+            cfg.data, seed=data_seed, vocab_size=cfg.lm.vocab_size)
         data_iter = apply_features(iter(dataset), cfg)
 
     mesh = None
@@ -564,13 +634,16 @@ def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler)
         mesh = pod_mesh(cfg.mesh.data_parallel, cfg.mesh.seq_parallel)
 
     with tracer.span("train.build_model"):
-        model = build_model(cfg)
+        model = build_task(cfg)
     with tracer.span("train.first_batch"):
         sample = next(data_iter)
     # init at tiny slices of the sample: identical params, none of the
     # full-size init compile (see tiny_init_state)
     with tracer.span("train.init_state"):
-        state = tiny_init_state(cfg, model, sample)
+        if init_params is not None:
+            state = state_from_params(cfg, model, init_params)
+        else:
+            state = tiny_init_state(cfg, model, sample)
     # numerics telemetry mode (observe.numerics): "off" | "triage" (fast
     # step widened with per-parameter-group norms; a fully-tagged rerun
     # fires only when the non-finite-grad skip does) | "full" (every step

@@ -1,0 +1,49 @@
+"""A set of kernels' share of their roofline in a language-model step: the
+least time the chip could take for the operations and bytes the algorithm
+needs in one step (``harness/ops_from_shapes_lm.py``, ``params["work"]``:
+``attention`` = causal scores and values at the published head sizes,
+``routed`` = the held experts' products at the rows the traced steps sent
+them, the program's ``moe/assignments_here`` counter of those very steps;
+the larger of operations / peak FLOP/s and bytes / peak bytes/s), times the
+steps in the trace, over the summed device time of the kernels' events
+(``params["prefixes"]`` of their names). The traced steps are the first
+ones after the warm-up (``harness/train.py`` ``TRACE_FIRST``), before the
+window. Every execution's time counts, a recomputed forward's too, and the
+needed work does not grow with it: so a kernel called twice where the
+algorithm needs it once loses share, as does one that computes padding.
+Nothing where the trace holds no such event; never 0, never clamped."""
+
+from benchmark.harness import common, ops_from_shapes_lm, trace_reduce
+
+
+def traced_rows(run: dict, steps: int) -> float:
+    """Mean rows a step the held experts got in the ``steps`` traced steps."""
+    rows = (run["traced_counters"] or {}).get("moe/assignments_here") or []
+    if len(rows) < steps:
+        raise RuntimeError(
+            f"{steps} steps in the trace, routing counters of {len(rows)}")
+    return sum(rows[:steps]) / steps
+
+
+def read(run: dict, params: dict):
+    trace = run.get("trace")
+    if not trace or run["kind"] != "train_lm":
+        return None
+    events = next(iter(trace["planes"].values()))
+    kernel_ns = trace_reduce.kernel_ns(events, params["prefixes"])
+    steps = trace_reduce.executions(events, params["prefixes"])
+    if not kernel_ns or not steps:
+        return None
+    config = run["config"]
+    if params["work"] == "routed":
+        rows = traced_rows(run, steps)
+        least_bytes = ops_from_shapes_lm.routed_bytes(config, rows)
+    else:
+        rows = None
+        least_bytes = ops_from_shapes_lm.attention_bytes(config)
+    flops = ops_from_shapes_lm.train_step_flops(
+        config, run["traffic"]["seq_len"], rows)[params["work"]]
+    peaks = common.peaks_for(run["peaks"], run["device_kind"])
+    least_s = max(flops / peaks["bf16_flops_per_s"],
+                  least_bytes / peaks["hbm_bytes_per_s"])
+    return 100.0 * least_s * steps / (kernel_ns / 1e9)

@@ -361,6 +361,57 @@ def _ref_attention_by_head(q, k, v, kv_mask, scale):
         jax.lax.map(jax.checkpoint(one_head), heads_first), 0, 1)
 
 
+def _ref_causal_by_head(q, k, v, scale):
+    """Causal softmax attention in float32, one head at a time: q/k heads
+    may be wider than v heads (latent attention's 192 against 128)."""
+    import jax
+    import jax.numpy as jnp
+
+    n = q.shape[2]
+    keep = jnp.tril(jnp.ones((n, n), bool))
+
+    def one_head(qkv):
+        q1, k1, v1 = (t.astype(jnp.float32) for t in qkv)
+        dots = jnp.einsum("bid,bjd->bij", q1, k1) * scale
+        probs = jax.nn.softmax(jnp.where(keep, dots, -1e30), axis=-1)
+        return jnp.einsum("bij,bjd->bid", probs, v1)
+
+    heads_first = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v))
+    return jnp.moveaxis(
+        jax.lax.map(jax.checkpoint(one_head), heads_first), 0, 1)
+
+
+def _group_sizes(rows: int, held: int, seed: int = 0):
+    """Uneven group sizes over ``held`` experts that fill an eighth of the
+    rows (what 16 of 128 experts are sent on average)."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return rng.multinomial(rows // 8, rng.dirichlet(np.full(held, 2.0)))
+
+
+def _ref_expert_ffn(sizes):
+    """The expert SwiGLU an expert at a time in float32: rows of group g
+    through expert g's matrices, rows past the groups zero."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    ends = np.cumsum(sizes)
+
+    def ref(rows, w_in, w_down):
+        out = jnp.zeros((rows.shape[0], w_down.shape[-1]), jnp.float32)
+        for g, (start, end) in enumerate(zip(ends - sizes, ends)):
+            h = rows[start:end].astype(jnp.float32) @ w_in[g].astype(
+                jnp.float32)
+            gate, up = jnp.split(h, 2, axis=-1)
+            out = out.at[start:end].set(
+                (jax.nn.silu(gate) * up) @ w_down[g].astype(jnp.float32))
+        return out
+
+    return ref
+
+
 def _ref_tied(q, k, v, q_mask, kv_mask, scale):
     """Tied-row attention, (B, R, N, H, D) layout: one attention matrix per
     (batch, head), logits summed over the R rows and scaled by R**-0.5."""
@@ -459,7 +510,42 @@ def kernel_cases(small: bool = False) -> list:
             (q_shape, kv_shape, kv_shape), "bfloat16",
         ))
 
+    def mla_core(name, b, h, n, qk, dv):
+        from alphafold2_tpu.ops.mla import causal_core
+
+        cases.append((
+            name,
+            lambda q, k, v: causal_core(q, k, v, qk ** -0.5),
+            lambda q, k, v: _ref_causal_by_head(q, k, v, qk ** -0.5),
+            ((b, h, n, qk), (b, h, n, qk), (b, h, n, dv)), "bfloat16",
+        ))
+
+    def grouped(name, rows, d, f, held):
+        """The expert SwiGLU over sorted rows (``ops/moe.py``); its three
+        arguments stand where q, k, v do: rows, gate/up matrices stacked
+        (held, d, 2f), down matrices (held, f, d)."""
+        from alphafold2_tpu.ops import moe
+
+        sizes = _group_sizes(rows, held)
+        live = jnp.arange(rows) < int(sizes.sum())
+
+        def kernel(x, w_in, w_down):
+            w_gate, w_up = jnp.split(w_in * d ** -0.5, 2, axis=-1)
+            y = moe.expert_ffn(x, jnp.asarray(sizes, jnp.int32), w_gate, w_up,
+                               w_down * f ** -0.5, x.dtype)
+            return jnp.where(live[:, None], y, 0)
+
+        ref = _ref_expert_ffn(sizes)
+        cases.append((
+            name, kernel,
+            lambda x, w_in, w_down: ref(x, w_in * d ** -0.5,
+                                        w_down * f ** -0.5),
+            ((rows, d), (held, d, 2 * f), (held, f, d)), "bfloat16",
+        ))
+
     if small:
+        mla_core("mla_causal_core_small", 1, 2, 160, 24, 16)
+        grouped("moe_grouped_matmul_small", 256, 32, 16, 4)
         axial("fused_axial_f32", (2, 2, 32, 16), "float32", 0)
         axial("fused_axial_masked_odd", (1, 2, 40, 16), "float32", 7)
         tied("tied_row_f32", (1, 3, 32, 2, 16), "float32", 0)
@@ -485,14 +571,19 @@ def kernel_cases(small: bool = False) -> list:
     flash("stock_flash_keys_2048_masked", pair, (1, 8, 2048, 64), 9)
     flash("stock_flash_one_q_block_keys_2048", (1, 8, 512, 64),
           (1, 8, 2048, 64), 0)
+    # the language-model cell's two kernels at its shapes: causal, q/k heads
+    # of 192 against v heads of 128 (padded to 256 for the stock kernel), and
+    # the grouped product over 16 held experts, an eighth of the rows live
+    mla_core("mla_causal_core_8k", 2, 32, 8192, 192, 128)
+    grouped("moe_grouped_matmul_16_experts", 6 * 16384, 2048, 768, 16)
     return cases
 
 
-def phase_kernels(small: bool = False, seed: int = 0) -> dict:
-    """Forward and gradient of every case against its reference. The
-    reference runs in float32 at the highest matmul precision on the same
-    (dtype-rounded) inputs; the error is the largest absolute difference
-    over the reference's largest entry."""
+def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
+    """Forward and gradient of every case (whose name holds ``only``) against
+    its reference. The reference runs in float32 at the highest matmul
+    precision on the same (dtype-rounded) inputs; the error is the largest
+    absolute difference over the reference's largest entry."""
     import jax
     import jax.numpy as jnp
 
@@ -511,6 +602,8 @@ def phase_kernels(small: bool = False, seed: int = 0) -> dict:
     results, failed = [], []
     for i, (name, kernel, ref, shapes, dtype) in enumerate(
             kernel_cases(small)):
+        if only not in name:
+            continue
         keys = jax.random.split(jax.random.key(seed + i), len(shapes))
         args = [
             jax.random.normal(kk, s, jnp.float32).astype(dtype)

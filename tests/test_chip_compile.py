@@ -306,3 +306,71 @@ def test_flagship_train_step_compiles_for_v5e(topo, one_chip, monkeypatch,
         assert " all-reduce(" in text or " all-reduce-start(" in text
         assert (" collective-permute(" in text
                 or " collective-permute-start(" in text)
+
+
+# --------------------------------------------------- the language model ---
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("length", [8192, 8192 - 40])
+def test_causal_latent_core_compiles_for_v5e(one_chip, on_tpu_branch, length,
+                                             direction):
+    """The language-model cell's attention: causal, 2 x 32 heads, q/k heads
+    of 192 against v heads of 128, through ``ops/flash.py`` (heads padded to
+    256, the causal rule's square blocks), forward and backward; also at a
+    length that is no multiple of 128."""
+    from alphafold2_tpu.ops.mla import causal_core
+
+    def core(q, k, v):
+        return causal_core(q, k, v, 192 ** -0.5)
+
+    text = _compile(
+        core if direction == "fwd" else _grad_of(core), one_chip,
+        ((2, 32, length, 192), "bfloat16"), ((2, 32, length, 192), "bfloat16"),
+        ((2, 32, length, 128), "bfloat16"))
+    assert "tpu_custom_call" in text
+    assert "f32[2,32,8192,8192]" not in text  # no dense logits anywhere
+
+
+def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole jitted train step of the benchmark's language-model cell
+    (576 M parameters, 2 x 8,192 tokens) for one described chip: the flash
+    kernels and XLA's ragged-product kernels are in it, no dense 8,192^2
+    logits are, and weights + Adam + activations fit 16 GB."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.train import loop
+    from benchmark.harness import common, train_lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    resolved = common.resolve("train_kanana2_ep8_seq8k")
+    cfg = train_lm.program_config(resolved["config"], resolved["traffic"], 1)
+    task = loop.build_task(cfg)
+    sample = next(iter(make_dataset(
+        cfg.data, vocab_size=cfg.lm.vocab_size)))
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    state = jax.eval_shape(lambda: loop.tiny_init_state(cfg, task, sample))
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 575_955_968
+    rng = jax.eval_shape(lambda: jax.random.key(1))
+    compiled = loop.make_train_step(task, None, numerics_mode="norms").lower(
+        shapes(state), shapes({k: jnp.asarray(v) for k, v in sample.items()}),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "flash_attention" in text and "flash_mha_bwd_dkv" in text
+    assert "ragged-dot" in text  # the grouped product is a kernel, not dense
+    # (bf16[2,8192,8192] is there: 32 heads x 256 of keys and values)
+    assert "32,8192,8192]" not in text and "64,8192,8192]" not in text
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 8e9 < per_device < 15.75 * 2**30

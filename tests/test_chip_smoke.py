@@ -65,13 +65,20 @@ def test_serve_phase_answers_and_reuses_its_executables():
 def test_kernel_phase_agrees_with_the_references():
     rec = chip_smoke.phase_kernels(small=True)
     names = [c["case"] for c in rec["cases"]]
-    assert len(names) == 6 and all(c["ok"] for c in rec["cases"])
+    assert len(names) == 8 and all(c["ok"] for c in rec["cases"])
     for kernel in ("fused_axial", "tied_row", "block_sparse"):
         assert any(n.startswith(kernel) for n in names)
         assert any(n.startswith(kernel) and "masked" in n for n in names)
+    # the language model's two: bfloat16 against float32 references
+    assert {"mla_causal_core_small", "moe_grouped_matmul_small"} <= set(names)
     # float32 in interpret mode: far inside the chip's tolerance
-    assert max(c[k] for c in rec["cases"] for k in ("fwd", "dq", "dk", "dv")
-               ) < 1e-5
+    assert max(c[k] for c in rec["cases"] if c["dtype"] == "float32"
+               for k in ("fwd", "dq", "dk", "dv")) < 1e-5
+
+
+def test_kernel_phase_takes_a_filter_by_name():
+    rec = chip_smoke.phase_kernels(small=True, only="moe_grouped")
+    assert [c["case"] for c in rec["cases"]] == ["moe_grouped_matmul_small"]
 
 
 def test_kernel_phase_fails_on_disagreement(monkeypatch):

@@ -284,3 +284,70 @@ def test_block_rule_gives_blocks_the_kernel_accepts(name):
     if name.startswith("pair_axial"):
         assert bs.block_k == nk  # the kernel's single-step body
         assert bs.block_b == {256: 4, 384: 2}[nq]  # sequences a grid step
+
+
+# the eleven blocks the rule gave the two flagship cells' shape classes when
+# PR 26 set it (fields in BlockSizes' order): a causal call must not move them
+FLAGSHIP_BLOCKS = {
+    "pair_from_msa": (512, 4096, 1024, 1, 1024, 2048, 1024, 256, 512, 512,
+                      1024),
+    "msa_from_pair": (512, 4096, 1024, 1, 1024, 2048, 1024, 256, 512, 512,
+                      1024),
+    "pair_axial": (256, 256, 256, 4, 256, 256, 256, 256, 256, 256, 256),
+    "pair_axial_mesh_half": (256, 256, 256, 4, 256, 256, 256, 256, 256, 256,
+                             256),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLAGSHIP_BLOCKS))
+def test_flagship_blocks_are_the_ones_pr26_measured(name):
+    bs = block_sizes_for(*BLOCK_RULE_SHAPES[name])
+    assert dataclasses.astuple(bs) == FLAGSHIP_BLOCKS[name]
+    assert bs == block_sizes_for(*BLOCK_RULE_SHAPES[name], causal=False)
+
+
+# (batch, heads, n, head_dim, dtype): causal calls, n x n
+CAUSAL_SHAPES = {
+    "lm_cell_head_256": (2, 32, 8192, 256, "bfloat16"),  # 192/128 padded
+    "lm_cell_head_192": (2, 32, 8192, 192, "bfloat16"),
+    "one_sequence": (1, 32, 8192, 256, "bfloat16"),
+    "odd_multiple": (2, 4, 11 * 128, 128, "bfloat16"),
+    "one_block": (3, 2, 128, 64, "float32"),
+    "long_float32": (1, 8, 32768, 256, "float32"),
+    "many_heads": (8, 64, 8192, 128, "bfloat16"),  # di past 1 GiB at 512
+}
+
+
+@pytest.mark.parametrize("name", sorted(CAUSAL_SHAPES))
+def test_causal_block_rule_gives_square_blocks_the_kernel_accepts(name):
+    """A causal call's blocks: what ``_verify_block`` and ``BlockSizes`` ask
+    (multiples of 128 dividing the axis, inner dividing major), the forward's
+    and dkv's grid steps square so that the kernel's whole-block skip above
+    the diagonal leaves little over, dq's ``di`` within 1 GiB."""
+    batch, heads, n, head_dim, dtype = CAUSAL_SHAPES[name]
+    bs = block_sizes_for(batch, heads, n, n, head_dim, dtype, causal=True)
+    assert bs.has_backward_blocks and bs.block_b == 1
+    blocks = dataclasses.asdict(bs)
+    blocks.pop("block_b")
+    for field, size in blocks.items():
+        assert size % 128 == 0 and 128 <= size <= n and n % size == 0, field
+    for major, minor in [
+        ("block_k_major", "block_k"),
+        ("block_q_major_dkv", "block_q_dkv"),
+        ("block_k_major_dkv", "block_k_dkv"),
+        ("block_k_major_dq", "block_k_dq"),
+    ]:
+        assert blocks[major] % blocks[minor] == 0, (major, minor)
+    row = head_dim * jnp.dtype(dtype).itemsize
+    assert max(bs.block_k_major, bs.block_k_major_dkv) * row <= max(
+        2**20, 128 * row)
+    assert bs.block_k_major_dq * batch * heads * n * 4 <= max(
+        2**30, 128 * batch * heads * n * 4)
+    # square steps wherever the 1 MiB K/V tile allows: the part computed
+    # above the diagonal is at most one block in (n / block + 1)
+    assert bs.block_k_major <= bs.block_q and bs.block_k_major_dkv \
+        <= bs.block_q_major_dkv
+    assert bs.block_k_major_dq <= bs.block_q_dq
+    if name.startswith("lm_cell"):
+        assert bs.block_q == bs.block_k_major == bs.block_q_major_dkv \
+            == bs.block_k_major_dkv >= 512

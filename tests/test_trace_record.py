@@ -132,6 +132,24 @@ def test_classify_a_scope(scope, expected):
         f"{way}/{block}" if way else block)
 
 
+@pytest.mark.parametrize("scope,block", [
+    ("jit(step)/jvp(MlaMoeLM)/layer_2/mla_attn/core/pallas_call",
+     "fwd/mla_attn"),
+    ("jit(step)/transpose(jvp(MlaMoeLM))/jvp(MlaMoeLM)/checkpoint/"
+     "rematted_computation/layer_3/moe/experts/mul", "bwd/moe"),
+    ("jit(step)/transpose(jvp(MlaMoeLM))/jvp(MlaMoeLM)/checkpoint/layer_0/"
+     "dense_ffn/up_proj/dot_general", "bwd/dense_ffn"),
+    ("jit(step)/jvp(MlaMoeLM)/embed/jit(_take)/gather", "fwd/embed"),
+    ("jit(step)/transpose(jvp(MlaMoeLM))/head/dot_general", "bwd/head"),
+    ("jit(step)/jvp(loss)/reduce_sum", "fwd/loss"),
+    ("ragged-dot-none", "unscoped"),  # XLA's own name for the kernel
+])
+def test_the_programs_table_names_another_models_blocks(scope, block):
+    """The log line's rows are the model's own modules whatever the model is
+    called: what ``jvp(`` wraps, when it is no phase of the step."""
+    assert profiler_mod.block_of(scope) == block
+
+
 # ------------------------------------- (b) spans on the profiler's clock ---
 
 
@@ -388,7 +406,8 @@ def test_train_hands_the_loop_a_null_tracer_unless_asked(
 
     seen = []
     monkeypatch.setattr(
-        loop, "_train", lambda cfg, n, data, cbs, tracer, logger, profiler:
+        loop, "_train",
+        lambda cfg, n, data, cbs, tracer, logger, profiler, init_params:
         seen.append((tracer.enabled, profiler.enabled)))
     monkeypatch.setattr(profiler_mod, "_LAST", None)
     loop.train(tiny_config())
