@@ -32,6 +32,8 @@ the negative control is rejected.
 from __future__ import annotations
 
 import json
+import math
+import os
 import sys
 import time
 
@@ -170,6 +172,53 @@ def case_flash_bwd_256():
         return jnp.sum(o * o)
 
     lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+
+
+def case_ring_flash_step(nq_local=32768, nk_local=2048):
+    """The ring of flash blocks (parallel/seq_parallel.py) at a chip's share
+    of the mesh cell's cross-attention, sp = 2, bf16, forward and backward:
+    the stock kernel's three calls lower at the blocks ``block_sizes_for``
+    gives the local shape, and no visiting block's float32 logits
+    (8 x 32,768 x 2,048) exist anywhere in the module."""
+    import re
+    from unittest import mock
+
+    from alphafold2_tpu.ops import flash
+    from alphafold2_tpu.parallel.seq_parallel import (
+        sequence_parallel_attention,
+    )
+    from alphafold2_tpu.parallel.sharding import make_mesh
+
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            "the ring needs two devices: set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=2 (main() does)")
+    mesh = make_mesh(1, 2, devices=jax.devices()[:2])
+    q = jnp.ones((1, 8, 2 * nq_local, 64), jnp.bfloat16)
+    k = jnp.ones((1, 8, 2 * nk_local, 64), jnp.bfloat16)
+    mask = jnp.ones((1, 2 * nk_local), bool).at[:, -9:].set(False)
+
+    def loss(q, k, v):
+        o = sequence_parallel_attention(
+            q, k, v, mask=mask, mesh=mesh, impl="ring")
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    # the ring asks ops/flash.py whether a TPU is there; this process is
+    # pinned to the CPU, so the case answers for the chip
+    with mock.patch.object(flash, "flash_available", lambda: True):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, k, k).lower(lowering_platforms=("tpu",)).as_text()
+    kernels = text.count("tpu_custom_call")
+    if kernels != 6:  # forward, dq, dkv for each of the two ring steps
+        raise RuntimeError(f"expected 6 Mosaic kernels, lowered {kernels}")
+    logits = 8 * nq_local * nk_local
+    dense = sorted({
+        shape for shape in re.findall(r"tensor<([0-9x]+)xf32>", text)
+        if math.prod(int(n) for n in shape.split("x")) >= logits
+    })
+    if dense:
+        raise RuntimeError(f"float32 arrays the size of a block's logits: "
+                           f"{dense}")
 
 
 def case_fused_axial_fwd(n=256):
@@ -322,6 +371,8 @@ CASES = [
     ("flash_axial_256", case_flash_axial_256),
     ("flash_compressed_cross", case_flash_compressed_cross),
     ("flash_bwd_256", case_flash_bwd_256),
+    ("ring_flash_pair_from_msa", case_ring_flash_step),
+    ("ring_flash_msa_from_pair", lambda: case_ring_flash_step(2048, 32768)),
     ("fused_axial_fwd_256", case_fused_axial_fwd),
     ("fused_axial_bwd_256", case_fused_axial_bwd),
     ("tied_row_fwd_256", case_tied_row_fwd),
@@ -352,6 +403,12 @@ def run_gate(names=()) -> tuple:
 
 
 def main(argv=None) -> int:
+    # the ring cases shard over two devices; the CPU backend makes them up
+    # (read when the backend starts, which nothing has done yet)
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + " --xla_force_host_platform_device_count=2").strip()
     names = (argv or sys.argv)[1:]
     unknown = sorted(set(names) - {n for n, _ in CASES})
     if unknown:

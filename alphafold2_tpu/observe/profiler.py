@@ -21,10 +21,13 @@ loop's ``jax.random.split``) is given that program's name, ``jit(<name>)``.
 
 ``summarize`` turns a record into the one line ``train()`` logs: device ms a
 step by block, forward and backward apart, the step program's device ms, the
-idle share, idle time by the innermost host span that covers it, and the
-collectives' own time by block. ``last_record()`` hands the newest record to
-whoever asks (the benchmark's readers), also after ``train()`` was left by an
-exception. Nothing here runs unless a trace directory is configured.
+idle share, idle time by the innermost host span that covers it, the
+collectives' own time by block, and under a context-parallel ring how much
+of the cross-attention blocks is a ring step's kernels (``ring_block``) and
+how much the merging around them (``ring_merge``). ``last_record()`` hands
+the newest record to whoever asks (the benchmark's readers), also after
+``train()`` was left by an exception. Nothing here runs unless a trace
+directory is configured.
 """
 
 from __future__ import annotations
@@ -42,6 +45,9 @@ PHASES = ("loss", "grads_ok", "grad_clip", "optimizer", "rng", "metrics")
 COLLECTIVES = ("all-reduce", "collective-permute", "all-gather",
                "reduce-scatter", "all-to-all", "async-collective")
 OUTSIDE_ANY_SPAN = "outside_any_span"
+# parallel/seq_parallel.py's two scopes inside a cross-attention block: a
+# ring step's kernel calls, and the merging of the steps around them
+RING_PARTS = ("ring_block", "ring_merge")
 # The profiler's own Python tracer is off: it adds some 10,000 events of
 # Python calls a traced step to the host plane, none of which is read.
 PYTHON_TRACER_LEVEL = 0
@@ -363,11 +369,14 @@ def summarize(record: dict) -> dict:
     steps = max(len(step_runs), 1)
     blocks: dict = {}
     collectives: dict = {}
+    ring: dict = {}
     for i, own in own_times(ops):
         block = block_of(ops[i][1])
         blocks[block] = blocks.get(block, 0.0) + own
         if ops[i][0].startswith(COLLECTIVES):
             collectives[block] = collectives.get(block, 0.0) + own
+        for part in set(RING_PARTS).intersection(ops[i][1].split("/")):
+            ring[part] = ring.get(part, 0.0) + own
     gaps = idle_gaps(ops)
     window = max(o[3] for o in ops) - min(o[2] for o in ops)
     idle = sum(b - a for a, b in gaps)
@@ -378,6 +387,7 @@ def summarize(record: dict) -> dict:
     out["idle_pct"] = round(100.0 * idle / window, 3) if window else 0.0
     for name, table in (("block_ms", blocks),
                         ("collective_own_ms", collectives),
+                        ("ring_ms", ring),
                         ("idle_ms", idle_by_span(gaps, record["host"]))):
         for key, ns in sorted(table.items(), key=lambda kv: -kv[1]):
             if ns / steps >= 500:  # what rounds to 0.000 ms is left out

@@ -301,6 +301,7 @@ class Attention(nn.Module):
                     mask=kv_mask,
                     mesh=mesh,
                     impl=self.context_parallel,
+                    use_flash=self._use_flash(),
                 )  # (B, H, n, dh)
                 return project_out(out)
 

@@ -2,7 +2,8 @@
 """First light on the attached TPU: drive the main path once, check it, say so.
 
     python chip_smoke.py                # one chip: train, serve, kernels
-    python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh only
+    python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh, and
+                                        # the ring's blocks against references
 
 Needs a TPU. Where ``jax.devices()[0].platform`` is anything else the script
 says so and exits non-zero: there is no platform override and no CPU mode
@@ -62,7 +63,7 @@ CUTS = {
     "mesh": {
         "one_device_remat": "off -> on, one-device twin only: at global "
         "batch 2 its program needs 14.56 GB without remat and 7.94 GB with "
-        "it; the dp2 x sp2 program (6.64 GB per device) runs as the "
+        "it; the dp2 x sp2 program (4.33 GB per device) runs as the "
         "flagship does, and remat changes no value",
     },
 }
@@ -440,6 +441,7 @@ def kernel_cases(small: bool = False) -> list:
     odd-length case each, and the stock flash kernel at every shape class
     the flagship sends it. ``small`` is the CPU rehearsal's size (no stock
     flash there: off the TPU its wrapper declines)."""
+    import jax
     import jax.numpy as jnp
 
     from alphafold2_tpu.ops.pallas.axial import fused_attention
@@ -510,6 +512,26 @@ def kernel_cases(small: bool = False) -> list:
             (q_shape, kv_shape, kv_shape), "bfloat16",
         ))
 
+    def ring(name, q_shape, kv_shape, pad):
+        """Ring context parallelism over sp = 2 (two of the devices, batch
+        1): each device's half of the keys visits the other. On the chip the
+        blocks are the stock flash kernel's, elsewhere jnp."""
+        from alphafold2_tpu.parallel.seq_parallel import (
+            sequence_parallel_attention,
+        )
+        from alphafold2_tpu.parallel.sharding import make_mesh
+
+        mesh = make_mesh(1, 2, devices=jax.devices()[:2])
+        d = q_shape[-1]
+        m = _tail_mask(kv_shape[0], kv_shape[2], pad)
+        cases.append((
+            name,
+            lambda q, k, v: sequence_parallel_attention(
+                q, k, v, mask=m, mesh=mesh, impl="ring"),
+            lambda q, k, v: _ref_attention_by_head(q, k, v, m, d ** -0.5),
+            (q_shape, kv_shape, kv_shape), "bfloat16",
+        ))
+
     def mla_core(name, b, h, n, qk, dv):
         from alphafold2_tpu.ops.mla import causal_core
 
@@ -552,6 +574,8 @@ def kernel_cases(small: bool = False) -> list:
         tied("tied_row_masked_odd", (1, 3, 40, 2, 16), "float32", 5)
         sparse("block_sparse_n64", 64, 16, 0)
         sparse("block_sparse_masked", 64, 16, 5)
+        ring("ring_flash_small", (1, 2, 64, 16), (1, 2, 32, 16), 0)
+        ring("ring_flash_small_masked", (1, 2, 32, 16), (1, 2, 64, 16), 5)
         return cases
     for dt in ("bfloat16", "float32"):
         axial(f"fused_axial_{dt}", (256, 8, 256, 64), dt, 0)
@@ -576,6 +600,11 @@ def kernel_cases(small: bool = False) -> list:
     # the grouped product over 16 held experts, an eighth of the rows live
     mla_core("mla_causal_core_8k", 2, 32, 8192, 192, 128)
     grouped("moe_grouped_matmul_16_experts", 6 * 16384, 2048, 768, 16)
+    # the mesh cell's cross-attentions: a chip's blocks are 32,768 x 2,048
+    # and 2,048 x 32,768 (only where there is a second chip for the ring)
+    if len(jax.devices()) >= 2:
+        ring("ring_flash_pair_from_msa_masked", pair, msa, 9)
+        ring("ring_flash_msa_from_pair", msa, pair, 0)
     return cases
 
 
@@ -655,6 +684,9 @@ def phase_mesh(sizes: dict = {**FLAGSHIP, "batch": 2}, steps: int = 3,
 
     compiled, compile_s = compile_train_step(cfg_mesh)
     text = compiled.as_text()
+    ring_kernels = sum(
+        "tpu_custom_call" in line and "/ring_block/" in line
+        for line in text.splitlines())
     collectives = {
         name: text.count(f" {name}(") + text.count(f" {name}-start(")
         for name in ("all-reduce", "collective-permute", "all-gather",
@@ -666,6 +698,12 @@ def phase_mesh(sizes: dict = {**FLAGSHIP, "batch": 2}, steps: int = 3,
     for need in ("all-reduce", "collective-permute"):
         if not collectives[need]:
             raise RuntimeError(f"no {need} in the mesh program: {collectives}")
+    # off the TPU the ring's blocks are jnp by design; on it, a ring that
+    # fell back to them would write every block's logits to HBM in silence
+    if jax.devices()[0].platform == "tpu" and not ring_kernels:
+        raise RuntimeError(
+            "no Mosaic kernel under a ring_block scope in the mesh program: "
+            "the ring's cross-attention blocks did not take the flash kernel")
 
     mesh_run = run_train(cfg_mesh, steps)
     check_losses(mesh_run["losses"], mesh_run["skipped"], steps)
@@ -683,6 +721,7 @@ def phase_mesh(sizes: dict = {**FLAGSHIP, "batch": 2}, steps: int = 3,
         "context_parallel": "ring", "steps": steps, "seed": seed,
         "cut": CUTS["mesh"],
         "compile_s": round(compile_s, 2), "collectives": collectives,
+        "ring_block_kernels": ring_kernels,
         "per_device_program_bytes": per_device_program_bytes,
         "per_device_argument_bytes": argument_bytes,
         "mesh_run": mesh_run, "one_device_run": one_run,
@@ -743,6 +782,7 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         if args.four_chips:
             emit(phase_mesh())
+            emit(phase_kernels(only="ring_flash"))
         else:
             train_rec = phase_train()
             emit(train_rec)

@@ -306,6 +306,54 @@ def test_flagship_train_step_compiles_for_v5e(topo, one_chip, monkeypatch,
         assert " all-reduce(" in text or " all-reduce-start(" in text
         assert (" collective-permute(" in text
                 or " collective-permute-start(" in text)
+        # the ring's blocks are the flash kernel's, not dense jnp blocks: no
+        # visiting block's logits (float32 or bf16) are in the program
+        assert any("tpu_custom_call" in line and "/ring_block/" in line
+                   for line in text.splitlines())
+        assert "[1,8,32768,2048]" not in text
+        assert "[1,8,2048,32768]" not in text
+
+
+@pytest.mark.parametrize("direction", ["pair_from_msa", "msa_from_pair"])
+def test_ring_of_flash_blocks_compiles_for_v5e(topo, one_chip, on_tpu_branch,
+                                               direction):
+    """The mesh cell's cross-attention alone, forward and backward, on the
+    described dp2 x sp2 mesh: a chip's blocks are 32,768 x 2,048 and 2,048 x
+    32,768, and each of the two ring steps is the stock kernel's three calls
+    at the blocks ``block_sizes_for`` gives (the scoped-VMEM limit is the
+    fence on them), the key mask travelling as segment ids."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from alphafold2_tpu.parallel.seq_parallel import (
+        sequence_parallel_attention,
+    )
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "sp"))
+    nq, nk = PAIR_Q[2], MSA_KV[2]
+    if direction == "msa_from_pair":
+        nq, nk = nk, nq
+    rows = NamedSharding(mesh, P("dp", None, "sp", None))
+    q = jax.ShapeDtypeStruct((2, 8, nq, 64), jnp.bfloat16, sharding=rows)
+    kv = jax.ShapeDtypeStruct((2, 8, nk, 64), jnp.bfloat16, sharding=rows)
+    mask = jax.ShapeDtypeStruct(
+        (2, nk), jnp.bool_, sharding=NamedSharding(mesh, P("dp", "sp")))
+
+    def loss(q, k, v, mask):
+        out = sequence_parallel_attention(
+            q, k, v, mask=mask, mesh=mesh, impl="ring")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, mask).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "/ring_block/" in line]
+    assert len(kernels) == 6  # forward, dq, dkv for each of the two steps
+    assert sum("flash_mha_bwd_dq" in line for line in kernels) == 2
+    assert sum("flash_mha_bwd_dkv" in line for line in kernels) == 2
+    assert f"[1,8,{nq // 2},{nk // 2}]" not in text  # no block's logits
+    assert " collective-permute-start(" in text or (
+        " collective-permute(" in text)
 
 
 # --------------------------------------------------- the language model ---

@@ -65,12 +65,14 @@ def test_serve_phase_answers_and_reuses_its_executables():
 def test_kernel_phase_agrees_with_the_references():
     rec = chip_smoke.phase_kernels(small=True)
     names = [c["case"] for c in rec["cases"]]
-    assert len(names) == 8 and all(c["ok"] for c in rec["cases"])
+    assert len(names) == 10 and all(c["ok"] for c in rec["cases"])
     for kernel in ("fused_axial", "tied_row", "block_sparse"):
         assert any(n.startswith(kernel) for n in names)
         assert any(n.startswith(kernel) and "masked" in n for n in names)
     # the language model's two: bfloat16 against float32 references
     assert {"mla_causal_core_small", "moe_grouped_matmul_small"} <= set(names)
+    # the ring over two devices (jnp blocks here), one case masked
+    assert {"ring_flash_small", "ring_flash_small_masked"} <= set(names)
     # float32 in interpret mode: far inside the chip's tolerance
     assert max(c[k] for c in rec["cases"] if c["dtype"] == "float32"
                for k in ("fwd", "dq", "dk", "dv")) < 1e-5
@@ -109,6 +111,7 @@ def test_four_chip_phase_agrees_with_one_device_on_four_virtual_devices():
     assert rec["layout"] == "dp2 x sp2" and rec["context_parallel"] == "ring"
     assert rec["collectives"]["all-reduce"] > 0
     assert rec["collectives"]["collective-permute"] > 0
+    assert rec["ring_block_kernels"] == 0  # jnp blocks off the TPU
     assert max(rec["loss_abs_diff"]) <= 1e-4
     assert len(rec["mesh_run"]["losses"]) == 3
     # one compile on the mesh path: step 1 is no slower than a compile
@@ -143,8 +146,8 @@ def _fake_chip(monkeypatch, count=1, **phases):
     monkeypatch.setattr("alphafold2_tpu.enable_compile_cache", lambda: None)
     calls = []
     for name in ("phase_train", "phase_serve", "phase_kernels", "phase_mesh"):
-        def phase(name=name):
-            calls.append(name)
+        def phase(name=name, **kwargs):
+            calls.append((name, kwargs) if kwargs else name)
             if name in phases:
                 return phases[name]()
             return {"phase": name, "has_tpu_custom_call": True}
@@ -166,7 +169,7 @@ def test_success_line_has_exactly_the_contract_keys(monkeypatch, capsys):
 def test_four_chip_option_runs_that_phase_and_no_other(monkeypatch, capsys):
     device, calls = _fake_chip(monkeypatch, count=4)
     assert chip_smoke.main(["--four-chips"]) == 0
-    assert calls == ["phase_mesh"]
+    assert calls == ["phase_mesh", ("phase_kernels", {"only": "ring_flash"})]
     last = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert last == {"ok": True, "device": device} and device["count"] == 4
 

@@ -123,3 +123,124 @@ def test_ulysses_rejects_indivisible_heads():
     mesh = make_mesh(1, 8)
     with pytest.raises(ValueError, match="heads"):
         sequence_parallel_attention(q, k, v, mesh=mesh, impl="ulysses")
+
+
+# ------------------------------------------------- the ring of flash blocks ---
+#
+# Off the TPU the ring runs its jnp block triple, so what these cases run is
+# the skeleton itself: the log-sum-exp merge, the rotation of K/V/mask and of
+# the dk/dv accumulators, and the custom VJP.
+
+
+def _ring_inputs(kind, masking, sp, b=4, h=2, d=8):
+    nq, nk = (32, 32) if kind == "self" else (48, 32)
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (b, h, nq, d))
+    k = jax.random.normal(ks[1], (b, h, nk, d))
+    v = jax.random.normal(ks[2], (b, h, nk, d))
+    block = nk // sp
+    mask = np.ones((b, nk), bool)
+    if masking == "whole_block":  # one visiting block contributes nothing
+        mask[:, block:2 * block] = False
+    elif masking == "part_of_each":
+        for j in range(sp):
+            mask[:, j * block + block // 2 + j % 2:(j + 1) * block] = False
+    return q, k, v, jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("masking", ["whole_block", "part_of_each"])
+@pytest.mark.parametrize("kind", ["self", "cross_unequal"])
+@pytest.mark.parametrize("sp", [2, 4])
+def test_ring_output_and_gradients_match_dense_oracle(sp, kind, masking):
+    q, k, v, mask = _ring_inputs(kind, masking, sp)
+    mesh = make_mesh(8 // sp, sp)
+    weights = jax.random.normal(jax.random.key(12), q.shape)
+
+    def run(attend):
+        def loss(q, k, v):
+            out = attend(q, k, v)
+            return jnp.sum(out * weights), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))(q, k, v)
+
+    (_, out), grads = run(lambda q, k, v: sequence_parallel_attention(
+        q, k, v, mask=mask, mesh=mesh, impl="ring"))
+    (_, out_ref), grads_ref = run(
+        lambda q, k, v: _dense_oracle(q, k, v, mask=mask))
+    assert np.allclose(out, out_ref, atol=1e-5)
+    for name, g, g_ref in zip("qkv", grads, grads_ref):
+        assert np.allclose(g, g_ref, atol=1e-5), (
+            name, np.abs(np.asarray(g - g_ref)).max())
+    # masked keys get no gradient at all, wherever their block travelled
+    masked_out = ~np.asarray(mask)[:, None, :, None]
+    assert not (np.asarray(grads[1]) * masked_out).any()
+    assert not (np.asarray(grads[2]) * masked_out).any()
+
+
+@pytest.mark.parametrize("use_flash,n_local,kernel", [
+    (True, 200, True),   # 200 x 170, padded to the kernel's 128 lanes
+    (False, 200, False),  # the caller's _use_flash() said no
+    (True, 64, False),  # both local axes under one 128 block
+])
+def test_ring_block_choice_follows_platform_shape_and_caller(
+        monkeypatch, use_flash, n_local, kernel):
+    """On a TPU (steered here) the ring takes the kernel's blocks unless the
+    caller declines or the local block is under one lane tile. The kernel
+    itself cannot run on the CPU: a stand-in records the (padded) shape it
+    was asked for and hands back the jnp triple."""
+    from alphafold2_tpu.ops import flash
+    from alphafold2_tpu.parallel import seq_parallel as sp_mod
+
+    asked = []
+
+    def stand_in(b, h, nq, nk, d, dtype, scale):
+        asked.append((nq, nk))
+        return sp_mod._jnp_blocks(scale)
+
+    monkeypatch.setattr(flash, "flash_available", lambda: True)
+    monkeypatch.setattr(sp_mod, "_flash_blocks", stand_in)
+    ks = jax.random.split(jax.random.key(13), 3)
+    q = jax.random.normal(ks[0], (4, 2, 2 * n_local, 8))
+    k = jax.random.normal(ks[1], (4, 2, 2 * (n_local - 30), 8))
+    v = jax.random.normal(ks[2], (4, 2, 2 * (n_local - 30), 8))
+    mask = jnp.ones(k.shape[::2], bool).at[:, -9:].set(False)
+
+    def loss(q, k, v):
+        return jnp.sum(sequence_parallel_attention(
+            q, k, v, mask=mask, mesh=make_mesh(4, 2), use_flash=use_flash
+        ) ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    grads_ref = jax.grad(
+        lambda q, k, v: jnp.sum(_dense_oracle(q, k, v, mask=mask) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    assert asked == ([(256, 256)] if kernel else [])
+    for g, g_ref in zip(grads, grads_ref):
+        assert g.shape == g_ref.shape
+        assert np.allclose(g, g_ref, atol=1e-4)
+
+
+@pytest.mark.parametrize("use_flash", [False, None])
+def test_attention_hands_the_ring_its_flash_policy(monkeypatch, use_flash):
+    """``Attention`` passes ``_use_flash()`` down, the decision it makes for
+    the flat path: an explicit False, or the auto case off the TPU."""
+    from alphafold2_tpu.ops.attention import Attention
+    from alphafold2_tpu.parallel import seq_parallel as sp_mod
+    from alphafold2_tpu.parallel.sharding import use_mesh
+
+    seen = {}
+    real = sp_mod.sequence_parallel_attention
+
+    def spy(*args, **kwargs):
+        seen.update(kwargs)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(sp_mod, "sequence_parallel_attention", spy)
+    attn = Attention(dim=16, heads=2, dim_head=8, context_parallel="ring",
+                     use_flash=use_flash)
+    x = jax.random.normal(jax.random.key(14), (4, 32, 16))
+    with use_mesh(make_mesh(4, 2)):
+        params = attn.init(jax.random.key(0), x)
+        attn.apply(params, x)
+    assert seen["use_flash"] is False and seen["impl"] == "ring"

@@ -109,6 +109,14 @@ def test_phases_around_the_model_are_named_in_the_compiled_step(
      ("cross_attn", "fwd", "pair_from_msa", True)),
     ("jit(step)/transpose(jvp(Alphafold2))/trunk/layer_11/msa_ff/wi/dot",
      ("feedforward", "bwd", "msa_ff", True)),
+    # the ring's scopes sit under the Flax module's: the block is still the
+    # first name after layer_N, forward and under the ring's custom VJP
+    ("jit(step)/jvp(Alphafold2)/trunk/layer_0/pair_from_msa/shard_map/"
+     "ring_block/flash_attention/pallas_call",
+     ("cross_attn", "fwd", "pair_from_msa", True)),
+    ("jit(step)/transpose(jvp(Alphafold2))/trunk/layer_0/msa_from_pair/"
+     "shard_map/ring_merge/ppermute",
+     ("cross_attn", "bwd", "msa_from_pair", True)),
     ("jit(step)/jvp(Alphafold2)/trunk/layer_1/pair_axial_norm/mul",
      ("model_rest", "fwd", "pair_axial_norm", True)),
     ("jit(step)/jvp(Alphafold2)/distogram_proj/dot_general",
@@ -293,6 +301,29 @@ def test_own_time_by_block_forward_and_backward_apart():
     assert scope_reduce.collective_by_block(plane, ["all-reduce"]) == {
         "bwd/pair_axial": us(50)}
     assert scope_reduce.collective_by_block(plane, ["all-gather"]) == {}
+
+
+def test_the_programs_own_line_splits_a_ring_into_kernels_and_merging():
+    record = written_record()
+    plane = next(iter(record["devices"].values()))
+    cross = "jit(step)/transpose(jvp(Alphafold2))/trunk/layer_0/pair_from_msa"
+    end = max(o[3] for o in plane["ops"])
+    plane["ops"] = list(plane["ops"]) + [
+        ("flash_mha_bwd_dq.1", f"{cross}/shard_map/ring_block/"
+         "flash_mha_bwd_dq_block_q_major=1024/pallas_call", end, end + us(70)),
+        ("add_fusion.7", f"{cross}/shard_map/ring_merge/add",
+         end + us(70), end + us(80)),
+        ("collective-permute-done.3", f"{cross}/shard_map/ring_merge/ppermute",
+         end + us(80), end + us(85)),
+    ]
+    line = profiler_mod.summarize(record)
+    assert line["ring_ms/ring_block"] == 0.07
+    assert line["ring_ms/ring_merge"] == 0.015
+    assert line["block_ms/bwd/pair_from_msa"] == 0.285  # 0.2 before
+    assert line["collective_own_ms/bwd/pair_from_msa"] == 0.005
+    # no ring in the program, no such keys in its line
+    assert not [k for k in profiler_mod.summarize(written_record())
+                if k.startswith("ring_ms")]
 
 
 def test_gaps_go_to_the_innermost_covering_span():
