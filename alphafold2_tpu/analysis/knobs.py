@@ -238,8 +238,7 @@ _GROUPS = [
     ("AF2TPU_SERVE_SCAN_", "variant-scan bench driver"),
     ("AF2TPU_SERVE_", "serve bench sizing"),
     ("AF2TPU_FLEET_", "fleet frontend"),
-    ("AF2TPU_KERNELS_BENCH_", "kernel microbench"),
-    ("AF2TPU_KERNELS", "kernel backend selection"),
+    ("AF2TPU_KERNEL_", "kernel microbench"),
     ("AF2TPU_BENCH_", "bench harness"),
     ("AF2TPU_", "core / misc"),
 ]

@@ -46,8 +46,6 @@ class ModelConfig:
     # sequence/context parallelism for the cross-attention over the N^2 pair
     # tokens: None | "ring" | "ulysses" (parallel/seq_parallel.py)
     context_parallel: Optional[str] = None
-    # fused Pallas flash attention for dense paths: None = auto (on TPU)
-    flash_attention: Optional[bool] = None
     # 2D-sharded pair axial attention over a (dp, spr, spc) grid mesh
     grid_parallel: bool = False
     # compile the trunk as ONE scanned layer with stacked params (compile
@@ -158,11 +156,6 @@ class ServeConfig:
     # bounds tests/test_precision.py pins, and fingerprinted as distinct
     # graph-contract targets so precision changes are explicit diffs)
     dtype: str = "float32"
-    # kernel policy spec (ops/kernels.py KernelPolicy), e.g.
-    # "tied_row=pallas,axial=pallas"; "" = the process default
-    # (AF2TPU_KERNELS env var, all-auto when unset). The resolved identity
-    # keys the engine's executable cache, compile records and bench records.
-    kernels: str = ""
     donate_buffers: bool = True  # donate per-request feature buffers to XLA
     return_distogram: bool = False  # ship (3L,3L,K) logits back per request
     # --- pipelined dispatch (serve/pipeline.py: PipelinedDispatcher) ---

@@ -52,7 +52,6 @@ class TemplateBlock(nn.Module):
     dim_head: int
     dropout: float = 0.0
     gelu_exact: bool = False
-    use_flash: Optional[bool] = None
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -64,7 +63,7 @@ class TemplateBlock(nn.Module):
 
         x = AxialAttention(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,
-            dropout=self.dropout, use_flash=self.use_flash, dtype=self.dtype,
+            dropout=self.dropout, dtype=self.dtype,
             name="pair_axial",
         )(ln("pair_norm")(x), mask=pair_mask, deterministic=deterministic)
 
@@ -72,7 +71,7 @@ class TemplateBlock(nn.Module):
         tm_flat = t_mask.reshape(b * T, n, n) if t_mask is not None else None
         t_flat = t_flat + AxialAttention(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,
-            dropout=self.dropout, use_flash=self.use_flash, dtype=self.dtype,
+            dropout=self.dropout, dtype=self.dtype,
             name="template_axial",
         )(ln("template_norm")(t_flat), mask=tm_flat, deterministic=deterministic)
         t = t_flat.reshape(b, T, n, n, d)
@@ -86,7 +85,7 @@ class TemplateBlock(nn.Module):
             y_mask = jnp.moveaxis(ym, 1, 3).reshape(b * n * n, 1 + T)
         y = y + Attention(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,
-            dropout=self.dropout, use_flash=self.use_flash, dtype=self.dtype,
+            dropout=self.dropout, dtype=self.dtype,
             name="template_axis_attn",
         )(ln("template_axis_norm")(y), mask=y_mask, deterministic=deterministic)
         y = jnp.moveaxis(y.reshape(b, n, n, 1 + T, d), 3, 1)
@@ -130,7 +129,6 @@ class Alphafold2(nn.Module):
     msa_tie_row_attn: bool = False
     msa_row_shard: bool = False  # shard MSA rows over sp (tied-row psum)
     context_parallel: Optional[str] = None  # None | "ring" | "ulysses"
-    use_flash: Optional[bool] = None  # fused dense attention kernel on TPU
     grid_parallel: bool = False  # 2D-sharded pair axial passes (spr x spc mesh)
     scan_layers: bool = False  # roll the trunk depth loop into lax.scan
     template_attn_depth: int = 2
@@ -279,7 +277,6 @@ class Alphafold2(nn.Module):
                 x, t = TemplateBlock(
                     dim=self.dim, heads=self.heads, dim_head=self.dim_head,
                     dropout=self.attn_dropout, gelu_exact=self.gelu_exact,
-                    use_flash=self.use_flash,
                     dtype=dt, name=f"template_block_{i}",
                 )(x, t, pair_mask, t_mask, deterministic=deterministic)
             x = shard_pair(x)
@@ -301,7 +298,6 @@ class Alphafold2(nn.Module):
             msa_tie_row_attn=self.msa_tie_row_attn,
             msa_row_shard=self.msa_row_shard,
             context_parallel=self.context_parallel,
-            use_flash=self.use_flash,
             grid_parallel=self.grid_parallel,
             remat=self.remat,
             remat_policy=self.remat_policy,

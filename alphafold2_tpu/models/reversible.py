@@ -77,14 +77,13 @@ class RevLayerPair(nn.Module):
     sparse_use_pallas: Optional[bool] = None
     cross_attn_compress_ratio: int = 1
     msa_tie_row_attn: bool = False
-    use_flash: Optional[bool] = None
     dtype: jnp.dtype = jnp.float32
 
     def setup(self):
         dt = self.dtype
         ax = dict(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,
-            dropout=self.attn_dropout, use_flash=self.use_flash, dtype=dt,
+            dropout=self.attn_dropout, dtype=dt,
         )
         self.f_s_norm = nn.LayerNorm(dtype=dt)
         self.f_s = AxialAttention(
@@ -101,7 +100,7 @@ class RevLayerPair(nn.Module):
 
         at = dict(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,
-            dropout=self.attn_dropout, use_flash=self.use_flash, dtype=dt,
+            dropout=self.attn_dropout, dtype=dt,
         )
         self.f_c_norm = nn.LayerNorm(dtype=dt)
         self.f_c_ctx_norm = nn.LayerNorm(dtype=dt)
@@ -259,7 +258,6 @@ class ReversibleTrunk(nn.Module):
     sparse_use_pallas: Optional[bool] = None
     cross_attn_compress_ratio: int = 1
     msa_tie_row_attn: bool = False
-    use_flash: Optional[bool] = None
     use_custom_vjp: bool = True
     dtype: jnp.dtype = jnp.float32
 
@@ -287,7 +285,7 @@ class ReversibleTrunk(nn.Module):
             sparse_config=self.sparse_config,
             sparse_use_pallas=self.sparse_use_pallas,
             cross_attn_compress_ratio=self.cross_attn_compress_ratio,
-            msa_tie_row_attn=self.msa_tie_row_attn, use_flash=self.use_flash,
+            msa_tie_row_attn=self.msa_tie_row_attn,
             dtype=self.dtype,
         )
         h0 = (x, x, m, m)

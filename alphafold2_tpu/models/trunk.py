@@ -57,7 +57,6 @@ class TrunkLayer(nn.Module):
     msa_tie_row_attn: bool = False
     msa_row_shard: bool = False  # shard MSA rows over sp (tied psum via GSPMD)
     context_parallel: Optional[str] = None  # None | "ring" | "ulysses"
-    use_flash: Optional[bool] = None  # fused dense attention on TPU
     grid_parallel: bool = False  # 2D-sharded pair axial passes (spr x spc)
     dtype: jnp.dtype = jnp.float32
 
@@ -83,7 +82,6 @@ class TrunkLayer(nn.Module):
             seq_len=self.seq_len,
             sparse_config=self.sparse_config,
             sparse_use_pallas=self.sparse_use_pallas,
-            use_flash=self.use_flash,
             grid_parallel=self.grid_parallel,
             dtype=dt,
             name="pair_axial",
@@ -98,7 +96,6 @@ class TrunkLayer(nn.Module):
                 dim_head=self.dim_head,
                 dropout=self.attn_dropout,
                 tie_row_attn=self.msa_tie_row_attn,
-                use_flash=self.use_flash,
                 dtype=dt,
                 name="msa_axial",
             )(ln("msa_axial_norm")(m), mask=msa_mask, deterministic=deterministic)
@@ -123,7 +120,6 @@ class TrunkLayer(nn.Module):
                 dropout=self.attn_dropout,
                 compress_ratio=self.cross_attn_compress_ratio,
                 context_parallel=self.context_parallel,
-                use_flash=self.use_flash,
                 dtype=dt,
                 name="pair_from_msa",
             )(
@@ -139,7 +135,6 @@ class TrunkLayer(nn.Module):
                 dim_head=self.dim_head,
                 dropout=self.attn_dropout,
                 context_parallel=self.context_parallel,
-                use_flash=self.use_flash,
                 dtype=dt,
                 name="msa_from_pair",
             )(
@@ -249,7 +244,6 @@ class Trunk(nn.Module):
     msa_tie_row_attn: bool = False
     msa_row_shard: bool = False  # shard MSA rows over sp (tied psum via GSPMD)
     context_parallel: Optional[str] = None  # None | "ring" | "ulysses"
-    use_flash: Optional[bool] = None  # fused dense attention on TPU
     grid_parallel: bool = False  # 2D-sharded pair axial passes (spr x spc)
     remat: bool = False
     remat_policy: Optional[str] = None  # None/"nothing" | "dots" | "dots_no_batch"
@@ -273,7 +267,6 @@ class Trunk(nn.Module):
             msa_tie_row_attn=self.msa_tie_row_attn,
             msa_row_shard=self.msa_row_shard,
             context_parallel=self.context_parallel,
-            use_flash=self.use_flash,
             grid_parallel=self.grid_parallel,
             dtype=self.dtype,
         )
@@ -354,7 +347,6 @@ class Trunk(nn.Module):
                 sparse_use_pallas=self.sparse_use_pallas,
                 cross_attn_compress_ratio=self.cross_attn_compress_ratio,
                 msa_tie_row_attn=self.msa_tie_row_attn,
-                use_flash=self.use_flash,
                 dtype=self.dtype,
                 name="reversible",
             )(x, m, pair_mask=pair_mask, msa_mask=msa_mask,

@@ -1,7 +1,7 @@
 """Unified FLOPs/bytes accounting and MFU: the tree's ONE cost_analysis parser.
 
 ``compiled.cost_analysis()`` parsing used to be duplicated ad hoc in
-``bench.py`` and ``scripts/bisect_perf.py``; every consumer (the train
+``bench.py`` and a bisect script; every consumer (the train
 bench, the serve engine's ``compile_records``, the train loop's metrics and
 the microbenchmarks) now sources flops/bytes/MFU from here, so the peak
 tables and the plausibility ceiling cannot drift apart between call sites.

@@ -254,14 +254,13 @@ def comparable_reason(current: dict, baseline: dict) -> Optional[str]:
     if cur_dev and base_dev and cur_dev != base_dev:
         return f"device mismatch: current={cur_dev!r} baseline={base_dev!r}"
     # variant keys records carry only when non-default: mesh identity
-    # (sharded serving), serving dtype (bf16 mode), kernel policy
-    # (fused Pallas selection) and dispatch pipeline ("depth2"/"off" —
-    # pipelined and serial dispatch have different latency anatomy, so a
-    # pipelined record must never ratio against a pre-pipeline baseline).
-    # A sharded vs single-device number, a bf16 vs f32 one, or two
-    # different kernel selections are not comparisons — precision/kernel
-    # changes must surface as explicit no-data diffs (and their own
-    # baselines), never as silent ratio drift.
+    # (sharded serving), serving dtype (bf16 mode) and dispatch pipeline
+    # ("depth2"/"off" — pipelined and serial dispatch have different
+    # latency anatomy, so a pipelined record must never ratio against a
+    # pre-pipeline baseline). A sharded vs single-device number or a bf16
+    # vs f32 one is not a comparison — a precision change must surface as
+    # an explicit no-data diff (and its own baseline), never as silent
+    # ratio drift.
     # "scan" fences variant-scan fast-lane records: their value is an
     # amortized near-duplicate-traffic number that must never ratio
     # against a plain serve record (or vice versa). "replay" fences the
@@ -271,7 +270,7 @@ def comparable_reason(current: dict, baseline: dict) -> Optional[str]:
     # fleet records: goodput through 2 replica cells and through 4 are
     # different machines as far as a ratio is concerned.
     for key in (
-        "mesh", "dtype", "kernels", "pipeline", "scan", "replay", "replicas",
+        "mesh", "dtype", "pipeline", "scan", "replay", "replicas",
     ):
         if current.get(key) != baseline.get(key):
             return (

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from alphafold2_tpu.ops import flash
+
 MASK_VALUE = -1e9
 
 
@@ -53,8 +55,6 @@ def _kernel_hook(kernel_fn, sharded: bool):
     def attn_fn(q2, k2, v2, m2):
         return per_device(kernel_fn, q2, k2, v2, m2)
 
-    if hasattr(kernel_fn, "accepts"):
-        attn_fn.accepts = kernel_fn.accepts
     return attn_fn
 
 
@@ -135,7 +135,6 @@ class Attention(nn.Module):
     dropout: float = 0.0
     compress_ratio: int = 1
     context_parallel: Optional[str] = None  # None | "ring" | "ulysses"
-    use_flash: Optional[bool] = None  # None -> fused Pallas kernel on TPU
     dtype: jnp.dtype = jnp.float32
 
     def setup(self):
@@ -154,53 +153,31 @@ class Attention(nn.Module):
                 dtype=self.dtype,
             )
 
-    def _use_flash(self) -> bool:
-        """One place for the None -> auto-on-TPU flash policy (both the flat
-        __call__ path and grid_axial consult it). The explicit module-level
-        bool wins; the None case defers to the KernelPolicy switchboard
-        (ops/kernels.py — AF2TPU_KERNELS / ServeConfig.kernels)."""
-        if self.use_flash is None:
-            from alphafold2_tpu.ops.kernels import resolve_flash
-
-            return resolve_flash()
-        return self.use_flash
-
     def grid_axial(self, x, mask=None, attend_axis: int = 2,
                    sharded: bool = True):
         """Self-attention along ONE axis of a (B, H, W, D) grid. With
         ``sharded=True`` and an active (dp, spr, spc) mesh the grid is
         2D-sharded (parallel/grid_parallel.py): projections are pointwise
         and run on the local shard; the attended axis is gathered by an
-        all-to-all inside the primitive. On TPU the per-device
-        attended-axis pass runs the fused flash kernel (falling back to
-        exact dense attention); no tied rows / compression / broadcast
-        context here."""
+        all-to-all inside the primitive. The per-device attended-axis
+        pass runs the stock flash kernel where ``ops/flash.py`` takes the
+        shape (the whole attended axis is local there) and exact streamed
+        or dense attention elsewhere; no tied rows / compression /
+        broadcast context here."""
         dh = self.dim_head
-        from alphafold2_tpu.ops.kernels import resolve_axial
-
-        impl = resolve_axial()
-        if impl == "pallas":
-            # the in-repo fused kernel (ops/pallas/axial.py): compiled on
-            # TPU, interpret-mode (exact, slow) elsewhere — selected only
-            # by explicit KernelPolicy, never silently
-            from alphafold2_tpu.ops.pallas.axial import axial_attn_fn
-
-            attn_fn = _kernel_hook(axial_attn_fn(dh**-0.5), sharded)
-        elif impl == "dense":
-            attn_fn = None  # debug escape: plain per-device dense attention
-        elif self._use_flash():
-            from alphafold2_tpu.ops.flash import flash_attention
+        n = x.shape[attend_axis]
+        if flash.flash_takes(n, n, dh):
 
             def flash_fn(q2, k2, v2, m2):
-                return flash_attention(
+                return flash.flash_attention(
                     q2, k2, v2, q_mask=m2, kv_mask=m2, sm_scale=dh**-0.5
                 )
 
             attn_fn = _kernel_hook(flash_fn, sharded)
         else:
-            # off-TPU long-chain path: exact streamed attention once the
-            # per-device logits would cross the chunk threshold; declines
-            # (returns None) below it so small shapes stay dense
+            # exact streamed attention once the per-device logits would
+            # cross the chunk threshold; declines (returns None) below it
+            # so small shapes stay dense
             from alphafold2_tpu.ops.chunked import chunked_attn_fn
 
             attn_fn = chunked_attn_fn(dh**-0.5)
@@ -264,7 +241,8 @@ class Attention(nn.Module):
         # fused kernels — by this point k/v/context_mask are already the
         # compressed versions, and at large crops the fused path is what
         # keeps the (N^2 queries x compressed keys) logits out of HBM.
-        fused_ok = tie_dim is None and (self.dropout == 0.0 or deterministic)
+        needs_probs = self.dropout != 0.0 and not deterministic
+        fused_ok = tie_dim is None and not needs_probs
         kv_mask = context_mask
         if kv_mask is None and not has_context:
             kv_mask = mask
@@ -301,25 +279,23 @@ class Attention(nn.Module):
                     mask=kv_mask,
                     mesh=mesh,
                     impl=self.context_parallel,
-                    use_flash=self._use_flash(),
                 )  # (B, H, n, dh)
                 return project_out(out)
 
         # fused flash-attention path (TPU): the (n, n) attention matrix stays
-        # in VMEM instead of HBM.
-        if self._use_flash() and fused_ok:
-            from alphafold2_tpu.ops.flash import flash_attention
+        # in VMEM instead of HBM. Asked before the call is wrapped for a
+        # mesh, so a shape the kernel declines leaves nothing in the program.
+        if fused_ok and flash.flash_takes(q.shape[1], k.shape[1], dh):
             from alphafold2_tpu.parallel.sharding import per_device
 
             out = per_device(
-                lambda q, k, v, qm, km: flash_attention(
+                lambda q, k, v, qm, km: flash.flash_attention(
                     q, k, v, q_mask=qm, kv_mask=km, sm_scale=scale
                 ),
                 heads_first(q), heads_first(k), heads_first(v),
                 mask, kv_mask,
             )
-            if out is not None:  # declined: off-TPU, or under one block
-                return project_out(out)
+            return project_out(out)
 
         # exact streamed attention off-TPU once the dense logits would
         # cross the chunk threshold (ops/chunked.py): the long-chain serve
@@ -386,27 +362,21 @@ class Attention(nn.Module):
                 mask = qr.any(1)
                 context_mask = kr.any(1) if has_context else None
 
-            # fused tied-row kernel (ops/pallas/tied_row.py, selected by
-            # the KernelPolicy switchboard): the shared (B, H, n, j) logits
+            # fused tied-row kernel (ops/pallas/tied_row.py, where its
+            # own rule takes the call): the shared (B, H, n, j) logits
             # stay in VMEM via the fused (row, head_dim) contraction; the
             # abstention masking and voting-row tie scale above are already
             # applied, so the kernel sees exactly the dense inputs. Active
             # attention-weight dropout keeps the dense path (it needs
             # materialized probabilities).
-            from alphafold2_tpu.ops.kernels import resolve_tied_row
+            from alphafold2_tpu.ops.pallas import tied_row
 
-            if resolve_tied_row() == "pallas" and (
-                self.dropout == 0.0 or deterministic
-            ):
-                from alphafold2_tpu.ops.pallas.tied_row import (
-                    tied_row_attention,
-                )
-
+            if tied_row.tied_row_takes(needs_probs):
                 from alphafold2_tpu.parallel.sharding import per_device
 
                 km = context_mask if has_context else mask
                 out = per_device(
-                    lambda q, k, v, qm, km, ts: tied_row_attention(
+                    lambda q, k, v, qm, km, ts: tied_row.tied_row_attention(
                         q, k, v, q_mask=qm, kv_mask=km, sm_scale=scale,
                         tie_scale=ts,
                     ),
@@ -466,7 +436,6 @@ class AxialAttention(nn.Module):
     seq_len: Optional[int] = None  # static max length for sparse block layout
     sparse_config: Optional[object] = None  # ops.sparse.BlockSparseConfig
     sparse_use_pallas: Optional[bool] = None  # None -> auto (Pallas on TPU)
-    use_flash: Optional[bool] = None  # dense path: fused kernel on TPU
     grid_parallel: bool = False  # 2D-sharded passes over a (dp, spr, spc) mesh
     grid_native: bool = True  # grid-layout self-attn passes (no pair-map
     # transpose materialization); False forces the flat (B*, n, d) route
@@ -492,7 +461,6 @@ class AxialAttention(nn.Module):
             heads=self.heads,
             dim_head=self.dim_head,
             dropout=self.dropout,
-            use_flash=self.use_flash,
             dtype=self.dtype,
             name=name,
         )

@@ -34,6 +34,26 @@ def flash_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def flash_takes(nq: int, nk: int, head_dim: int, pads_heads: bool = True
+                ) -> bool:
+    """Whether the stock kernel serves a call of ``nq`` queries against
+    ``nk`` keys at this head size: the one rule, asked by
+    :func:`flash_attention`, by ``Attention`` before it wraps the call for a
+    mesh, and by the ring for its local blocks (parallel/seq_parallel.py).
+
+    On a TPU, and not where BOTH axes are under one 128 block: there the
+    dense attention matrix is trivially small and the kernel's tiling
+    overhead dominates. With one long axis (N^2 queries against a compressed
+    context) the kernel still pays off; the short axis is padded up to a
+    block. The kernel takes one head size, a multiple of 128 once it is over
+    128: :func:`flash_attention` zero-pads other sizes, a caller that hands
+    the kernel its operands as they are (``pads_heads=False``: the ring)
+    keeps such a call off it."""
+    if not flash_available() or (nq < 128 and nk < 128):
+        return False
+    return pads_heads or head_dim <= 128 or head_dim % 128 == 0
+
+
 def _block(n: int, cap: int, unit: int = 128) -> int:
     """The largest multiple of ``unit`` that divides ``n`` and is at most
     ``cap``; ``unit`` itself where nothing larger divides (1,408 = 11 x 128
@@ -168,9 +188,9 @@ def flash_attention(
 ) -> Optional[jnp.ndarray]:
     """Fused attention via the stock Pallas TPU kernel.
 
-    Returns None off-TPU and for short sequences (both axes under one 128
-    block) — the caller takes the dense jnp path there by design. Whatever
-    the kernel itself refuses propagates.
+    Returns None where :func:`flash_takes` says no (off the TPU, both axes
+    under one 128 block): the caller takes the dense jnp path there by
+    design. Whatever the kernel itself refuses propagates.
 
     ``causal`` (query i sees keys 0..i; needs Nq == Nk) skips the blocks
     above the diagonal inside the kernel. The kernel takes one head size for
@@ -179,25 +199,17 @@ def flash_attention(
     such size and the output sliced back. Zero columns change no logit and
     no output; they cost the MXU passes they fill (PERF.md section 6, PR 27).
     """
-    if not flash_available():
+    b, h, nq, d = q.shape
+    nk, dv = k.shape[2], v.shape[3]
+    if not flash_takes(nq, nk, max(d, dv)):
         return None
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         SegmentIds,
         flash_attention as _fa,
     )
 
-    b, h, nq, d = q.shape
-    nk, dv = k.shape[2], v.shape[3]
     if causal and nq != nk:
         raise ValueError(f"causal attention needs Nq == Nk, got {nq} and {nk}")
-
-    # short sequences (BOTH axes < one 128 block) use the dense path by
-    # design: at these sizes the dense attention matrix is trivially small
-    # and the kernel's MIN_BLOCK_SIZE tiling overhead dominates. With one
-    # long axis (e.g. N^2 queries against a compressed context) the fused
-    # path still pays off — the short axis is padded up to a block below.
-    if nq < 128 and nk < 128:
-        return None
 
     width = max(d, dv)
     if width > 128:

@@ -23,8 +23,9 @@ The tied-row MSA kernel (``tied_row.py``) reuses these kernels through an
 algebraic reduction: the tied logit sum over rows is one contraction over a
 fused (row, head_dim) feature axis, so the D dimension here may be R*D.
 
-Selected via :mod:`alphafold2_tpu.ops.kernels` (``KernelPolicy`` /
-``AF2TPU_KERNELS``); validated against the dense jnp oracle (values and
+The model reaches these kernels through ``tied_row.py`` only (its rule is
+``tied_row_takes``); the axial passes run the stock kernel of
+``ops/flash.py``. Validated against the dense jnp oracle (values and
 grads, masked + padded + odd lengths) in tests/test_pallas_kernels.py and
 Mosaic-lowered pre-hardware by ``analysis/lowering.py``.
 """
@@ -434,19 +435,3 @@ def fused_attention(
         out = jnp.where(q_mask[:, None, :, None], out, 0)
     return out
 
-
-def axial_attn_fn(sm_scale: float, interpret: Optional[bool] = None):
-    """An ``attn_fn`` hook for the (possibly 2D-sharded) axial passes
-    (parallel.grid_parallel._attend_last_grid_axis): row-flattened
-    ``(B*R, H, N, D)`` q/k/v and a ``(B*R, N)`` mask in, attended values in
-    the same layout out — the per-device computation after the all-to-all
-    gather runs this module's fused kernel instead of dense attention."""
-
-    def attn_fn(q2, k2, v2, m2):
-        return fused_attention(
-            q2, k2, v2, q_mask=m2, kv_mask=m2, sm_scale=sm_scale,
-            interpret=interpret,
-        )
-
-    attn_fn.accepts = lambda bsz, h, n: True
-    return attn_fn

@@ -50,6 +50,21 @@ import jax.numpy as jnp
 from alphafold2_tpu.ops.pallas.axial import fused_attention
 
 
+def tied_row_available() -> bool:
+    """The platform half of :func:`tied_row_takes`, apart from
+    ``ops/flash.py``'s: this kernel also runs interpreted off the TPU, which
+    is how the CPU tests reach it (they substitute this function)."""
+    return jax.default_backend() == "tpu"
+
+
+def tied_row_takes(needs_probabilities: bool) -> bool:
+    """Whether this kernel serves ``Attention``'s tied branch: on a TPU,
+    unless the call must materialise its probabilities (active
+    attention-weight dropout), which a fused kernel never does. Elsewhere
+    the branch keeps its dense einsum."""
+    return tied_row_available() and not needs_probabilities
+
+
 def tied_row_attention(
     q: jnp.ndarray,  # (B, R, Nq, H, D) — padded entries pre-zeroed
     k: jnp.ndarray,  # (B, R, Nk, H, D)

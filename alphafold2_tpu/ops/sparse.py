@@ -372,9 +372,7 @@ class SparseAttention(nn.Module):
         backend = getattr(self.config, "backend", "auto")
         # precedence: the explicit use_pallas bool (predates config.backend,
         # wins for back-compat) > a non-"auto" config.backend (a reviewed
-        # per-module choice) > the KernelPolicy switchboard (ops/kernels.py
-        # — one env var / ServeConfig field selects every kernel in the
-        # tree consistently, and its identity rides in serve records)
+        # per-module choice) > the Pallas kernel on a TPU, jnp elsewhere
         impls = {
             "jnp": block_sparse_attention,
             "pallas": block_sparse_attention_pallas,
@@ -393,9 +391,7 @@ class SparseAttention(nn.Module):
             )
         if backend != "auto":
             return impls[backend]
-        from alphafold2_tpu.ops.kernels import resolve_block_sparse
-
-        return impls[resolve_block_sparse()]
+        return impls["pallas" if jax.default_backend() == "tpu" else "jnp"]
 
     def _attend(self, q, k, v, mask, layout, wrap: bool):
         """The selected backend on (B, H, N, D) arrays. A kernel called on

@@ -109,9 +109,8 @@ def _flash_blocks(b, h, nq, nk, d, dtype, scale):
 
 
 def _jnp_blocks(scale):
-    """The same three calls in jnp, the block's logits in float32: off the
-    TPU, for blocks under one 128 lane tile, and where the caller's kernel
-    policy says no flash."""
+    """The same three calls in jnp, the block's logits in float32: wherever
+    ``ops/flash.py`` ``flash_takes`` keeps the local block off the kernel."""
 
     def logits(q, k, seg):
         s = jnp.einsum("bhid,bhjd->bhij", q, k).astype(jnp.float32) * scale
@@ -224,18 +223,17 @@ def ring_attention(
     v: jnp.ndarray,
     kmask: jnp.ndarray,  # (B, nk_local) bool: False keys are masked out
     axis_name: str = SEQ_AXIS_NAME,
-    use_flash: bool = True,
 ) -> jnp.ndarray:
     """Exact attention over the ring-sharded sequence axis.
 
     A ring of flash blocks under one custom VJP: K, V and the key mask visit
     every device by ``ppermute``, one hop a step; see :func:`_ring_fwd` and
-    :func:`_ring_bwd`. The block is the stock flash kernel on a TPU when the
-    caller allows it (``use_flash``: ``Attention._use_flash()``), some local
-    axis is at least one 128 block and the kernel takes the head size; the
-    jnp triple everywhere else — the rule by which ``ops/flash.py`` declines.
-    For the kernel both local axes are padded to the 128 lanes its blocks
-    need, padded keys masked out, as ``ops/flash.py`` does.
+    :func:`_ring_bwd`. The block is the stock flash kernel where
+    ``ops/flash.py`` ``flash_takes`` the local shape (the ring hands the
+    kernel its heads as they are, so a head size the flat wrapper would pad
+    stays off it), the jnp triple everywhere else. For the kernel both local
+    axes are padded to the 128 lanes its blocks need, padded keys masked
+    out, as ``ops/flash.py`` does.
     """
     from alphafold2_tpu.ops import flash
 
@@ -243,10 +241,7 @@ def ring_attention(
     nk = k.shape[2]
     scale = d**-0.5
     seg = kmask.astype(jnp.int32)
-    if not (
-        use_flash and flash.flash_available()
-        and (nq >= 128 or nk >= 128) and (d <= 128 or d % 128 == 0)
-    ):
+    if not flash.flash_takes(nq, nk, d, pads_heads=False):
         return _ring(q, k, v, seg, axis_name, _jnp_blocks(scale))
     pad_q, pad_k = (-nq) % 128, (-nk) % 128
     if pad_q:
@@ -355,12 +350,9 @@ def sequence_parallel_attention(
     mask: Optional[jnp.ndarray] = None,  # (B, N) bool key padding
     mesh: Optional[Mesh] = None,
     impl: str = "ring",
-    use_flash: bool = True,
 ) -> jnp.ndarray:
     """Host-level entry: shard the sequence axis over the mesh's sp axis and
-    run ring or ulysses attention; dense fallback without a mesh.
-    ``use_flash`` is the caller's kernel policy (``Attention._use_flash()``):
-    False keeps the ring's blocks in jnp on a TPU too."""
+    run ring or ulysses attention; dense fallback without a mesh."""
     if impl not in ("ring", "ulysses"):
         raise ValueError(f"unknown context-parallel impl {impl!r}")
     b = q.shape[0]
@@ -372,7 +364,7 @@ def sequence_parallel_attention(
         return _dense(q, k, v, bias)
 
     if impl == "ring":
-        fn, keys = partial(ring_attention, use_flash=use_flash), mask
+        fn, keys = ring_attention, mask
     else:
         fn, keys = ulysses_attention, bias
     qkv_spec = P(DATA_AXIS_NAME, None, SEQ_AXIS_NAME, None)

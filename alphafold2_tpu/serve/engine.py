@@ -262,18 +262,6 @@ class ServeEngine:
                 f"serve.dtype must be 'float32' or 'bfloat16', got "
                 f"{self.serve_dtype!r}"
             )
-        # kernel policy (ops/kernels.py): a per-engine spec wins over the
-        # process default; the RESOLVED identity keys every executable this
-        # engine builds (cache key, compile records, bench records)
-        from alphafold2_tpu.ops.kernels import current_policy, parse_policy
-
-        self.kernel_policy = (
-            parse_policy(cfg.serve.kernels) if cfg.serve.kernels else None
-        )
-        self.kernels_desc = (
-            self.kernel_policy if self.kernel_policy is not None
-            else current_policy()
-        ).describe()
         self.counters = counters if counters is not None else EventCounters()
         self.tracer = tracer if tracer is not None else Tracer(enabled=False)
         self.memory = MemorySampler()
@@ -364,7 +352,7 @@ class ServeEngine:
     def pipeline_desc(self) -> str:
         """The dispatch-path identity serve records carry (``"depth2"`` /
         ``"off"``) — regress.py refuses to compare across it, the same way
-        mesh/dtype/kernels variants are fenced."""
+        mesh/dtype variants are fenced."""
         return (
             f"depth{self.pipeline_depth}" if self.pipeline is not None
             else "off"
@@ -484,8 +472,7 @@ class ServeEngine:
         The in-process dict makes reuse O(1); the persistent XLA compilation
         cache behind it (enable_compile_cache) makes even the first build of
         a known HLO a deserialization instead of a compile."""
-        key = (bucket, batch, self.mesh_desc, self.serve_dtype,
-               self.kernels_desc)
+        key = self._exe_key(bucket, batch)
         hit = self._executables.get(key)
         if hit is not None:
             self.counters.bump("serve.cache_hits")
@@ -528,9 +515,7 @@ class ServeEngine:
             # actually reached XLA; everything else is re-emitted.
             with warnings.catch_warnings(record=True) as caught:
                 warnings.simplefilter("always")
-                from alphafold2_tpu.ops.kernels import use_kernel_policy
-
-                with ctx, use_kernel_policy(self.kernel_policy):
+                with ctx:
                     compiled = (
                         jax.jit(self._fwd, **jit_kwargs)
                         .lower(self.params, *abstract)
@@ -589,12 +574,10 @@ class ServeEngine:
                 r"\w+\[[^\]]*\]", str(donation_notes[0].message)))}
                if donate and donation_notes else {}),
             **({"mesh": self.mesh_desc} if self.mesh_desc else {}),
-            # precision/kernel keys ride only when non-default so records
-            # (and the committed baselines) predating them stay comparable
+            # the precision key rides only when non-default so records
+            # (and the committed baselines) predating it stay comparable
             **({"dtype": self.serve_dtype}
                if self.serve_dtype != "float32" else {}),
-            **({"kernels": self.kernels_desc}
-               if self.kernels_desc != "auto" else {}),
             **({"flops": costs["flops"]} if costs["flops"] else {}),
             **({"flops_breakdown": breakdown} if costs["flops"] else {}),
             **({"bytes_accessed": costs["bytes_accessed"]}
@@ -760,8 +743,7 @@ class ServeEngine:
         return refined, weights, disto
 
     def _exe_key(self, bucket: int, batch: int) -> tuple:
-        return (bucket, batch, self.mesh_desc, self.serve_dtype,
-                self.kernels_desc)
+        return (bucket, batch, self.mesh_desc, self.serve_dtype)
 
     def _account_flops(self, exe_key) -> None:
         # executed-flops accumulators are shared with the pipeline's

@@ -101,7 +101,6 @@ def build_model(cfg: Config) -> Alphafold2:
         msa_tie_row_attn=m.msa_tie_row_attn,
         msa_row_shard=m.msa_row_shard,
         context_parallel=m.context_parallel,
-        use_flash=m.flash_attention,
         grid_parallel=m.grid_parallel,
         scan_layers=m.scan_layers,
         template_attn_depth=m.template_attn_depth,

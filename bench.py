@@ -96,7 +96,7 @@ from alphafold2_tpu.observe import exposition, flightrec
 from alphafold2_tpu.observe.tracing import device_idle_fraction
 
 # the tree's single cost_analysis()/MFU implementation (observe.flops):
-# bench, the serve engine, the train loop and bisect_perf all share it
+# bench, the serve engine and the train loop all share it
 from alphafold2_tpu.observe.flops import (
     device_peak_flops as _device_peak_flops,
     estimate_mfu as _estimate_mfu,
@@ -398,9 +398,7 @@ _SERVE_INFRA_KNOBS = {"AF2TPU_SERVE_RECORD_BASELINE"}
 # rows), and the regression gate (observe.regress) refuses any cross-key
 # comparison — so records stay self-keyed and safe to compare against their
 # own committed baseline (bench_serve_mesh_baseline.json /
-# bench_serve_bf16_baseline.json). AF2TPU_KERNELS likewise selects a
-# kernel-policy variant: it is not an AF2TPU_SERVE_ size override, and its
-# resolved identity rides in the record's "kernels" key.
+# bench_serve_bf16_baseline.json).
 _SERVE_MESH_KNOBS = {
     "AF2TPU_SERVE_MESH",
     "AF2TPU_SERVE_LONG_BUCKETS",
@@ -648,13 +646,11 @@ def bench_serve(emit: bool = True, tracer: Tracer | None = None) -> dict:
         # ("off") numbers are different measurements — the regression
         # gate refuses any cross-key comparison (observe.regress)
         "pipeline": engine.pipeline_desc,
-        # precision/kernel variant keys, present only when non-default so
+        # precision variant key, present only when non-default so
         # pre-existing baselines stay comparable; the regression gate
         # refuses any cross-key comparison (observe.regress)
         **({"dtype": engine.serve_dtype}
            if engine.serve_dtype != "float32" else {}),
-        **({"kernels": engine.kernels_desc}
-           if engine.kernels_desc != "auto" else {}),
     }
     if idle is not None:
         # fraction of the dispatch window the device spent NOT inside a
@@ -735,10 +731,9 @@ def bench_serve(emit: bool = True, tracer: Tracer | None = None) -> dict:
             base.get("value")
             and base.get("metric") == record["metric"]
             and base.get("device") == record["device"]
-            # kernel policy and dispatch-path pipelining are variant keys
-            # the metric label does not encode: a different selection is
-            # a different measurement
-            and base.get("kernels") == record.get("kernels")
+            # dispatch-path pipelining is a variant key the metric label
+            # does not encode: a different selection is a different
+            # measurement
             and base.get("pipeline") == record.get("pipeline")
         ):
             vs = record["value"] / base["value"]
@@ -2636,10 +2631,10 @@ def _kernels_sizes() -> dict:
     attention shapes, sized so the fused kernels' interpret-mode grids stay
     small on CPU hosts (the committed CPU baseline is an interpret-mode
     record; TPU sessions re-record compiled numbers under the same metric
-    machinery, keyed by device). AF2TPU_KERNELS_BENCH_* overrides mark the
+    machinery, keyed by device). AF2TPU_KERNEL_BENCH_* overrides mark the
     record non-flagship (never baseline-compared)."""
     return {
-        "iters": _env_int("AF2TPU_KERNELS_BENCH_ITERS", 5),
+        "iters": _env_int("AF2TPU_KERNEL_BENCH_ITERS", 5),
         # (B, H, N, D) — the axial per-device pass after row-flattening
         "axial": ((2, 4, 128, 64), (1, 4, 256, 64), (1, 2, 384, 64)),
         # (B, R, N, H, D) — tied-row MSA attention
@@ -2649,7 +2644,7 @@ def _kernels_sizes() -> dict:
 
 
 def kernels_config_overridden() -> bool:
-    return any(k.startswith("AF2TPU_KERNELS_BENCH_") for k in os.environ)
+    return any(k.startswith("AF2TPU_KERNEL_BENCH_") for k in os.environ)
 
 
 def _kernels_metric(s: dict) -> str:
@@ -2673,7 +2668,6 @@ def bench_kernels(emit: bool = True, tracer: Tracer | None = None) -> dict:
     scripts/bench_compare.py against bench_kernels_baseline.json."""
     import numpy as np
 
-    from alphafold2_tpu.ops.kernels import current_policy
     from alphafold2_tpu.ops.pallas.axial import fused_attention
     from alphafold2_tpu.ops.pallas.tied_row import tied_row_attention
 
@@ -2782,7 +2776,6 @@ def bench_kernels(emit: bool = True, tracer: Tracer | None = None) -> dict:
         # interpret-mode fused timings are a canary, not a speed claim —
         # the flag keeps that explicit in the committed record
         "interpret": interpret,
-        "kernels": current_policy().describe(),
         "device": jax.devices()[0].device_kind,
     }
 
@@ -2798,7 +2791,6 @@ def bench_kernels(emit: bool = True, tracer: Tracer | None = None) -> dict:
             base.get("value")
             and base.get("metric") == record["metric"]
             and base.get("device") == record["device"]
-            and base.get("kernels") == record.get("kernels")
         ):
             vs = record["value"] / base["value"]
             compared = True
@@ -2806,7 +2798,7 @@ def bench_kernels(emit: bool = True, tracer: Tracer | None = None) -> dict:
     record["vs_baseline_valid"] = compared
 
     if (
-        os.environ.get("AF2TPU_KERNELS_RECORD_BASELINE") == "1"
+        os.environ.get("AF2TPU_KERNEL_RECORD_BASELINE") == "1"
         and not kernels_config_overridden()
     ):
         with open(baseline_path, "w") as f:

@@ -567,24 +567,21 @@ def report_fleet_timeline(events: list, max_shown: int = 20) -> None:
 
 
 def report_kernels(latest: dict) -> None:
-    """Kernels/precision section: printed when records carry the kernel-
-    policy or serving-dtype keys (ops/kernels.py KernelPolicy, serve.dtype)
-    or a --mode kernels microbench record rode the file. Shows the resolved
-    policy, the serving dtype and the per-kernel FLOPs attribution
-    (observe.flops: tied-row vs axial vs rest) so MFU conversations can
-    name the kernel responsible."""
+    """Kernels/precision section: printed when records carry the
+    serving-dtype key (serve.dtype) or a --mode kernels microbench record
+    rode the file. Shows the serving dtype and the per-kernel FLOPs
+    attribution (observe.flops: tied-row vs axial vs rest) so MFU
+    conversations can name the kernel responsible."""
     compile_records = latest.get("compile_records") or []
     by_kernel = latest.get("flops_by_kernel") or {}
     has_keys = (
-        latest.get("kernels") or latest.get("dtype")
+        latest.get("dtype")
         or latest.get("mode") == "kernels" or by_kernel
-        or any(c.get("kernels") or c.get("dtype") for c in compile_records)
+        or any(c.get("dtype") for c in compile_records)
     )
     if not has_keys:
         return
     print("-- kernels / precision --")
-    if latest.get("kernels"):
-        print(f"  kernel policy:  {latest['kernels']}")
     if latest.get("dtype"):
         print(f"  serve dtype:    {latest['dtype']}")
     if latest.get("mode") == "kernels":

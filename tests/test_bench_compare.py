@@ -149,7 +149,7 @@ def test_cli_default_baseline_routing(bench_compare):
 
 KERNELS_BASE = {
     "metric": "kernels fused-vs-stock speedup axial=... tied=... iters=5",
-    "device": "cpu", "mode": "kernels", "kernels": "auto",
+    "device": "cpu", "mode": "kernels",
     "value": 0.5, "fused_ms_total": 15.0, "stock_ms_total": 8.0,
     "interpret": True,
 }
@@ -168,17 +168,17 @@ def test_kernels_threshold_selection_and_cliff():
 
 
 def test_dtype_and_kernel_records_never_cross_compare():
-    """A bf16 record vs an f32 one — or two different kernel policies — is
-    no-data, exactly like a mesh mismatch: precision/kernel changes are
-    explicit diffs, never silent ratio drift."""
+    """A bf16 record vs an f32 one — or a pipelined vs a serial dispatch
+    path — is no-data, exactly like a mesh mismatch: a variant change is an
+    explicit diff, never silent ratio drift."""
     bf16 = {**BASE, "dtype": "bfloat16"}
     v = regress.compare(bf16, BASE)
     assert v["verdict"] == "no-data" and "dtype mismatch" in v["reason"]
     v = regress.compare(BASE, bf16)
     assert v["verdict"] == "no-data" and "dtype mismatch" in v["reason"]
-    pol = {**BASE, "kernels": "tied_row=pallas"}
-    v = regress.compare(pol, BASE)
-    assert v["verdict"] == "no-data" and "kernels mismatch" in v["reason"]
+    piped = {**BASE, "pipeline": "depth2"}
+    v = regress.compare(piped, BASE)
+    assert v["verdict"] == "no-data" and "pipeline mismatch" in v["reason"]
     # matching variant keys compare normally
     v = regress.compare({**bf16, "value": 95.0}, bf16)
     assert v["verdict"] == "pass"
@@ -203,13 +203,13 @@ def test_committed_kernels_and_bf16_baselines_are_valid():
     with open(os.path.join(REPO, "bench_kernels_baseline.json")) as f:
         kb = json.load(f)
     assert regress.record_invalid_reason(kb) is None
-    assert kb["mode"] == "kernels" and "kernels" in kb
+    assert kb["mode"] == "kernels" and "kernels" not in kb
     assert len(kb["shapes"]) == 6
     with open(os.path.join(REPO, "bench_serve_bf16_baseline.json")) as f:
         sb = json.load(f)
     assert regress.record_invalid_reason(sb) is None
     assert sb["dtype"] == "bfloat16" and "dtype=bfloat16" in sb["metric"]
-    assert sb["kernels"] == "tied_row=pallas"
+    assert "kernels" not in sb
     assert sb["flops_by_kernel"]["tied_row"] > 0
 
 

@@ -21,7 +21,6 @@ import chip_smoke
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = dict(dim=32, heads=2, dim_head=16, depth=1, crop=16, msa_depth=4,
             msa_len=16, batch=1)
-PALLAS = "tied_row=pallas,axial=pallas"  # interpret mode off the chip
 
 
 def _run(cmd, env=None, cwd=REPO, timeout=600):
@@ -37,7 +36,10 @@ def _run(cmd, env=None, cwd=REPO, timeout=600):
 
 
 def test_train_phase_takes_steps_and_the_loss_moves(monkeypatch):
-    monkeypatch.setenv("AF2TPU_KERNELS", PALLAS)
+    from alphafold2_tpu.ops.pallas import tied_row
+
+    # the tied-row kernel as on the chip, in interpret mode off it
+    monkeypatch.setattr(tied_row, "tied_row_available", lambda: True)
     rec = chip_smoke.phase_train(TINY, steps=3, bfloat16=False)
     assert rec["phase"] == "train" and len(rec["losses"]) == 3
     assert len(set(rec["losses"])) == 3 and rec["skipped"] == 0
@@ -95,16 +97,19 @@ def test_kernel_phase_fails_on_disagreement(monkeypatch):
 def test_four_chip_phase_agrees_with_one_device_on_four_virtual_devices():
     """Rehearsal 2: dp2 x sp2 with ring context parallelism on exactly four
     virtual CPU devices (this process has eight, and ``pod_mesh`` takes all
-    there are, so the rehearsal gets a process of its own), with the Pallas
-    kernels on — under the mesh they must run inside a shard_map."""
+    there are, so the rehearsal gets a process of its own), with the tied-row
+    Pallas kernel on (the child steers its platform predicate; interpret
+    mode) — under the mesh it must run inside a shard_map."""
     code = (
-        "import json, chip_smoke; print(json.dumps(chip_smoke.phase_mesh("
+        "import json, chip_smoke; "
+        "from alphafold2_tpu.ops.pallas import tied_row; "
+        "tied_row.tied_row_available = lambda: True; "
+        "print(json.dumps(chip_smoke.phase_mesh("
         f"{ {**TINY, 'batch': 2}!r}, steps=3, bfloat16=False, tol=1e-4)))"
     )
     proc, lines = _run(
         [sys.executable, "-c", code],
-        env={"XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-             "AF2TPU_KERNELS": PALLAS},
+        env={"XLA_FLAGS": "--xla_force_host_platform_device_count=4"},
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads(lines[-1])

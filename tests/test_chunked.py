@@ -112,7 +112,7 @@ def test_attention_module_chunked_branch_matches_dense(monkeypatch):
     x = jnp.asarray(rng.normal(size=(2, 40, 16)), jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 23, 16)), jnp.float32)
     cmask = jnp.asarray(rng.random((2, 23)) > 0.3)
-    mod = Attention(dim=16, heads=2, dim_head=8, use_flash=False)
+    mod = Attention(dim=16, heads=2, dim_head=8)
     params = mod.init(jax.random.key(0), x, context=ctx, context_mask=cmask)
     dense = mod.apply(params, x, context=ctx, context_mask=cmask)
     monkeypatch.setattr(chunked_mod, "CHUNK_THRESHOLD", 1)
@@ -132,7 +132,7 @@ def test_grid_axial_chunked_matches_dense(monkeypatch):
     x = jnp.asarray(rng.normal(size=(2, n, n, 16)), jnp.float32)
     mask = jnp.ones((2, n, n), bool).at[:, :, -2:].set(False)
     mod = AxialAttention(
-        dim=16, heads=2, dim_head=8, grid_parallel=True, use_flash=False
+        dim=16, heads=2, dim_head=8, grid_parallel=True
     )
     params = mod.init(jax.random.key(1), x, mask=mask)
     dense = mod.apply(params, x, mask=mask)

@@ -22,42 +22,30 @@ def test_unavailable_off_tpu_returns_none():
     assert flash_attention(q, q, q) is None
 
 
-def test_attention_use_flash_true_falls_back_cleanly():
-    # explicit use_flash=True off-TPU: wrapper returns None, dense path runs,
-    # numbers identical to use_flash=False
-    x = jax.random.normal(jax.random.key(0), (2, 24, 32))
-    mask = jnp.ones((2, 24), bool).at[:, 20:].set(False)
-    a_flash = Attention(dim=32, heads=2, dim_head=16, use_flash=True)
-    a_dense = Attention(dim=32, heads=2, dim_head=16, use_flash=False)
-    params = a_dense.init(jax.random.key(1), x, mask=mask)
-    out_f = a_flash.apply(params, x, mask=mask)
-    out_d = a_dense.apply(params, x, mask=mask)
-    assert np.allclose(out_f, out_d, atol=1e-6)
-
-
 def test_flash_skipped_for_tied_rows_and_dropout(monkeypatch):
-    # tied rows and attn dropout are dense-path features; flash gating must
-    # not change their outputs
-    x = jax.random.normal(jax.random.key(2), (4, 8, 32))  # (B*R, n, d)
-    a = Attention(dim=32, heads=2, dim_head=16, use_flash=True)
-    b = Attention(dim=32, heads=2, dim_head=16, use_flash=False)
-    params = b.init(jax.random.key(3), x, tie_dim=2)
-    assert np.allclose(
-        a.apply(params, x, tie_dim=2), b.apply(params, x, tie_dim=2), atol=1e-6
-    )
+    # tied rows and attn dropout are dense-path features: the flash path
+    # must not be taken for them even where the kernel takes the shape
+    from alphafold2_tpu.ops import flash as flash_mod
+
+    x = jax.random.normal(jax.random.key(2), (4, 128, 32))  # (B*R, n, d)
+    plain = Attention(dim=32, heads=2, dim_head=16)
+    drop = Attention(dim=32, heads=2, dim_head=16, dropout=0.5)
+    # before the mock: init is deterministic
+    params = plain.init(jax.random.key(3), x, tie_dim=2)
+    params_d = drop.init(jax.random.key(4), x)
+    tied = plain.apply(params, x, tie_dim=2)
+
+    def boom(*a, **kw):  # pragma: no cover - must not be reached
+        raise AssertionError("flash path taken for tied rows or dropout")
+
+    monkeypatch.setattr(flash_mod, "flash_available", lambda: True)
+    monkeypatch.setattr(flash_mod, "flash_attention", boom)
+    assert flash_mod.flash_takes(128, 128, 16)
+    assert np.allclose(plain.apply(params, x, tie_dim=2), tied, atol=1e-6)
 
     # dropout gate: with attn dropout active (deterministic=False), the flash
     # path must NOT be taken even when the kernel is "available" — attention-
     # weight dropout needs materialized probabilities
-    from alphafold2_tpu.ops import flash as flash_mod
-
-    def boom(*a, **kw):  # pragma: no cover - must not be reached
-        raise AssertionError("flash path taken despite active attn dropout")
-
-    drop = Attention(dim=32, heads=2, dim_head=16, dropout=0.5, use_flash=None)
-    params_d = drop.init(jax.random.key(4), x)  # before the mock: init is deterministic
-    monkeypatch.setattr(flash_mod, "flash_available", lambda: True)
-    monkeypatch.setattr(flash_mod, "flash_attention", boom)
     out = drop.apply(
         params_d, x, deterministic=False, rngs={"dropout": jax.random.key(5)}
     )
@@ -74,35 +62,35 @@ def test_compressed_cross_attention_routes_through_flash(monkeypatch):
     logits out of HBM (bench config 3)."""
     from alphafold2_tpu.ops import flash as flash_mod
 
-    b, n, nc, d = 2, 12, 30, 32
+    b, n, nc, d = 2, 130, 30, 32  # one long axis: the kernel takes the call
     x = jax.random.normal(jax.random.key(6), (b, n, d))
     ctx = jax.random.normal(jax.random.key(7), (b, nc, d))
     cmask = jnp.ones((b, nc), bool).at[:, 25:].set(False)
 
-    dense = Attention(dim=d, heads=2, dim_head=16, compress_ratio=3,
-                      use_flash=False)
-    params = dense.init(jax.random.key(8), x, context=ctx, context_mask=cmask)
+    attn = Attention(dim=d, heads=2, dim_head=16, compress_ratio=3)
+    params = attn.init(jax.random.key(8), x, context=ctx, context_mask=cmask)
+    out_d = attn.apply(params, x, context=ctx, context_mask=cmask)
 
     seen = {}
 
     def spy(q, k, v, q_mask=None, kv_mask=None, sm_scale=1.0):
         seen["kv_len"] = k.shape[2]
         seen["kv_mask"] = kv_mask
-        return None  # fall back to dense — output must be unchanged
+        # the kernel's answer, in jnp: the module must project this output
+        dots = jnp.einsum("bhid,bhjd->bhij", q, k) * sm_scale
+        dots = jnp.where(kv_mask[:, None, None, :], dots, -1e9)
+        return jnp.einsum("bhij,bhjd->bhid", jax.nn.softmax(dots, -1), v)
 
     monkeypatch.setattr(flash_mod, "flash_available", lambda: True)
     monkeypatch.setattr(flash_mod, "flash_attention", spy)
-    flashy = Attention(dim=d, heads=2, dim_head=16, compress_ratio=3,
-                       use_flash=True)
-    out_f = flashy.apply(params, x, context=ctx, context_mask=cmask)
-    out_d = dense.apply(params, x, context=ctx, context_mask=cmask)
+    out_f = attn.apply(params, x, context=ctx, context_mask=cmask)
 
     assert seen["kv_len"] == nc // 3  # kernel sees compressed KV
     assert seen["kv_mask"].shape == (b, nc // 3)  # ...and the pooled mask
     # pooled mask: windows [24..26] contain a valid position -> True;
     # windows [27..29] all padded -> False
     assert bool(seen["kv_mask"][0, 8]) and not bool(seen["kv_mask"][0, 9])
-    assert np.allclose(out_f, out_d, atol=1e-6)
+    assert np.allclose(out_f, out_d, atol=1e-5)
 
 
 def test_context_parallel_excludes_compression(monkeypatch):
@@ -128,16 +116,15 @@ def test_context_parallel_excludes_compression(monkeypatch):
     x = jax.random.normal(jax.random.key(9), (1, 8, 32))
     ctx = jax.random.normal(jax.random.key(10), (1, 12, 32))
     a = Attention(dim=32, heads=2, dim_head=16, compress_ratio=2,
-                  context_parallel="ring", use_flash=False)
+                  context_parallel="ring")
     params = a.init(jax.random.key(11), x, context=ctx)
     out = a.apply(params, x, context=ctx)  # compressed: gate skips the path
     assert np.all(np.isfinite(out)) and calls["n"] == 0
 
     # sanity that the fake-mesh plumbing reaches the path when uncompressed:
     # the same call without compression must enter it (and hit the mock)
-    b = Attention(dim=32, heads=2, dim_head=16, context_parallel="ring",
-                  use_flash=False)
-    plain = Attention(dim=32, heads=2, dim_head=16, use_flash=False)
+    b = Attention(dim=32, heads=2, dim_head=16, context_parallel="ring")
+    plain = Attention(dim=32, heads=2, dim_head=16)
     params_b = plain.init(jax.random.key(12), x, context=ctx)  # same params
     with np.testing.assert_raises(AssertionError):
         b.apply(params_b, x, context=ctx)
@@ -206,6 +193,98 @@ def test_flash_engages_with_one_short_axis(monkeypatch):
     # both axes sub-block: dense stays preferred
     tiny = jnp.ones((1, 2, 64, 16))
     assert flash_mod.flash_attention(tiny, tiny, tiny) is None
+
+
+# name: (nq, nk, q/k head size, v head size, causal, on a TPU, the flat path
+# takes the kernel, the ring takes it for a local block of this shape)
+ONE_RULE_CASES = {
+    # the flagship cells' cross-attentions, whole and as one ring step's block
+    "pair_from_msa": (65536, 4096, 64, 64, False, True, True, True),
+    "msa_from_pair": (4096, 65536, 64, 64, False, True, True, True),
+    "ring_local_pair_from_msa": (32768, 2048, 64, 64, False, True, True, True),
+    "ring_local_msa_from_pair": (2048, 32768, 64, 64, False, True, True, True),
+    "pair_axial": (256, 256, 64, 64, False, True, True, True),
+    "one_short_axis": (65536, 86, 64, 64, False, True, True, True),
+    # the language-model cell: the flat wrapper pads 192/128 to 256, the ring
+    # hands the kernel its heads as they are and keeps 192 off it
+    "lm_causal_192_128": (8192, 8192, 192, 128, True, True, True, False),
+    "under_one_block_64": (64, 64, 64, 64, False, True, False, False),
+    "under_one_block_100": (100, 100, 64, 64, False, True, False, False),
+    "off_the_tpu": (65536, 4096, 64, 64, False, False, False, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ONE_RULE_CASES))
+def test_flat_path_and_ring_ask_one_rule(monkeypatch, name):
+    """``Attention``'s flat path (``mla.causal_core`` for the causal call) and
+    the ring both ask ``ops/flash.py`` ``flash_takes`` whether the stock
+    kernel serves the shape, and each takes the kernel exactly where it says
+    so. Shapes only (``jax.eval_shape``): the kernel and the ring's kernel
+    blocks are stand-ins that record that they were asked for."""
+    import jax.experimental.pallas.ops.tpu.flash_attention as stock
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from alphafold2_tpu.ops import flash as flash_mod, mla
+    from alphafold2_tpu.parallel import seq_parallel as sp_mod
+
+    nq, nk, d, dv, causal, on_tpu, flat, ring = ONE_RULE_CASES[name]
+    answers = {True: [], False: []}  # by pads_heads
+    rule = flash_mod.flash_takes
+
+    def recording(nq, nk, head_dim, pads_heads=True):
+        answers[pads_heads].append(rule(nq, nk, head_dim, pads_heads))
+        return answers[pads_heads][-1]
+
+    kernel_calls, ring_blocks = [], []
+
+    def fake_kernel(q, k, v, **kw):
+        kernel_calls.append((q.shape[2], k.shape[2], q.shape[3]))
+        return jnp.zeros(q.shape, q.dtype)
+
+    def fake_blocks(b, h, nq, nk, d, dtype, scale):
+        ring_blocks.append((nq, nk, d))
+        return sp_mod._jnp_blocks(scale)
+
+    monkeypatch.setattr(flash_mod, "flash_available", lambda: on_tpu)
+    monkeypatch.setattr(flash_mod, "flash_takes", recording)
+    monkeypatch.setattr(stock, "flash_attention", fake_kernel)
+    monkeypatch.setattr(sp_mod, "_flash_blocks", fake_blocks)
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    if causal:
+        out = jax.eval_shape(
+            lambda q, k, v: mla.causal_core(q, k, v, d**-0.5),
+            shape(1, 1, nq, d), shape(1, 1, nk, d), shape(1, 1, nk, dv))
+        assert out.shape == (1, 1, nq, dv)
+    else:
+        attn = Attention(dim=8, heads=1, dim_head=d)
+        out = jax.eval_shape(
+            lambda x, c: attn.init_with_output(
+                jax.random.key(0), x, context=c)[0],
+            shape(1, nq, 8), shape(1, nk, 8))
+        assert out.shape == (1, nq, 8)
+    assert bool(kernel_calls) == flat
+    assert answers[True] and set(answers[True]) == {flat}
+    if flat:  # both axes padded to the kernel's lanes, wide heads to 128s
+        assert all(q % 128 == 0 and k % 128 == 0 and (w <= 128 or w % 128 == 0)
+                   for q, k, w in kernel_calls)
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
+    spec = P(None, None, "sp", None)
+    out = jax.eval_shape(
+        jax.shard_map(
+            lambda q, k, v, m: sp_mod.ring_attention(q, k, v, m),
+            mesh=mesh, in_specs=(spec, spec, spec, P(None, "sp")),
+            out_specs=spec, check_vma=False),
+        shape(1, 1, 2 * nq, d), shape(1, 1, 2 * nk, d),
+        shape(1, 1, 2 * nk, d), shape(1, 2 * nk, dtype=bool))
+    assert out.shape == (1, 1, 2 * nq, d)
+    assert bool(ring_blocks) == ring
+    assert answers[False] == [ring]
+    if d <= 128:  # no head padding at stake: one question, one answer
+        assert flat == ring
 
 
 # (batch, heads, nq, nk, head_dim, dtype) as the wrapper hands them on: padded

@@ -14,8 +14,7 @@ def test_reference_readme_usage_pattern():
     from alphafold2_tpu.utils.mds import MDScaling
     from alphafold2_tpu.utils.structure import center_distogram
 
-    model = Alphafold2(dim=32, depth=1, heads=2, dim_head=16, max_seq_len=64,
-                       use_flash=False)
+    model = Alphafold2(dim=32, depth=1, heads=2, dim_head=16, max_seq_len=64)
     k = jax.random.key(0)
     seq = jax.random.randint(jax.random.fold_in(k, 1), (1, 16), 0, 21)
     msa = jax.random.randint(jax.random.fold_in(k, 2), (1, 3, 16), 0, 21)

@@ -145,7 +145,7 @@ def test_axial_attention_broadcast_context():
     x = jax.random.normal(jax.random.fold_in(k, 0), (2, 6, 6, 16))
     ctx = jax.random.normal(jax.random.fold_in(k, 1), (2, 5, 16))
     ctx_mask = jnp.ones((2, 5), bool).at[:, 3:].set(False)
-    mod = AxialAttention(dim=16, heads=2, dim_head=8, use_flash=False)
+    mod = AxialAttention(dim=16, heads=2, dim_head=8)
     params = mod.init(jax.random.fold_in(k, 2), x, context=ctx,
                       context_mask=ctx_mask)
     out = mod.apply(params, x, context=ctx, context_mask=ctx_mask)
@@ -173,8 +173,7 @@ def test_templates_explicit_distogram():
 
     b, n, T = 1, 8, 2
     model = Alphafold2(dim=32, depth=1, heads=2, dim_head=16, max_seq_len=32,
-                       template_attn_depth=1, use_se3_template_embedder=False,
-                       use_flash=False)
+                       template_attn_depth=1, use_se3_template_embedder=False)
     k = jax.random.key(41)
     seq = jax.random.randint(jax.random.fold_in(k, 0), (b, n), 0, 21)
     msa = jax.random.randint(jax.random.fold_in(k, 1), (b, 2, n), 0, 21)

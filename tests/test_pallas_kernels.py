@@ -8,10 +8,9 @@ odd-length shapes (the acceptance bound is 1e-4; measured ~1e-6). The
 compiled-mode Mosaic lowering of the same kernels is certified separately
 by analysis/lowering.py (test_pallas_lowering.py).
 
-The KernelPolicy switchboard (ops/kernels.py) is pinned too: parse/describe
-round-trips, env + context precedence, and the actual dispatch sites —
-Attention.__call__'s tied path, the grid-axial hook, SparseAttention's
-backend choice — must route where the policy says and nowhere else.
+The dispatch sites are pinned too: Attention.__call__'s tied path takes the
+kernel exactly when ``tied_row.tied_row_takes`` says so, and SparseAttention's
+backend follows its two module options, then the platform.
 """
 
 import jax
@@ -19,14 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from alphafold2_tpu.ops.kernels import (
-    KernelPolicy,
-    parse_policy,
-    resolve_axial,
-    resolve_block_sparse,
-    resolve_tied_row,
-    use_kernel_policy,
-)
 from alphafold2_tpu.ops.pallas.axial import fused_attention
 from alphafold2_tpu.ops.pallas.tied_row import tied_row_attention
 
@@ -210,43 +201,14 @@ def test_tied_row_grad_matches_dense():
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=ATOL)
 
 
-# ------------------------------------------------------- policy switchboard
-
-
-def test_policy_parse_describe_roundtrip():
-    assert KernelPolicy().describe() == "auto"
-    p = parse_policy("tied_row=pallas, axial=dense")
-    assert p.tied_row == "pallas" and p.axial == "dense"
-    assert p.describe() == "tied_row=pallas,axial=dense"
-    assert parse_policy("") == KernelPolicy()
-    assert parse_policy("auto") == KernelPolicy()
-    with pytest.raises(ValueError):
-        parse_policy("tied_row=fast")  # unknown value
-    with pytest.raises(ValueError):
-        parse_policy("warp=pallas")  # unknown field
-    with pytest.raises(ValueError):
-        KernelPolicy(axial="bogus")
-
-
-def test_policy_env_and_context_precedence(monkeypatch):
-    monkeypatch.delenv("AF2TPU_KERNELS", raising=False)
-    assert resolve_tied_row() == "dense"  # auto off-TPU
-    assert resolve_axial() == "stock"
-    assert resolve_block_sparse() == "jnp"
-    monkeypatch.setenv("AF2TPU_KERNELS", "tied_row=pallas,block_sparse=splash")
-    assert resolve_tied_row() == "pallas"
-    assert resolve_block_sparse() == "splash"
-    # an explicit context wins over the env
-    with use_kernel_policy(parse_policy("tied_row=dense,axial=pallas")):
-        assert resolve_tied_row() == "dense"
-        assert resolve_axial() == "pallas"
-    assert resolve_tied_row() == "pallas"  # env restored
+# ----------------------------------------------------------- dispatch sites
 
 
 def test_attention_tied_path_dispatch(monkeypatch):
-    """The tied branch must route through the fused kernel exactly when the
-    policy says pallas and dropout is inactive — and produce the dense
-    numbers (valid region) when it does."""
+    """The tied branch must route through the fused kernel exactly when its
+    rule (ops/pallas/tied_row.py ``tied_row_takes``) says so — on a TPU,
+    steered here, with dropout inactive — and produce the dense numbers
+    (valid region) when it does."""
     from alphafold2_tpu.ops.attention import Attention
     from alphafold2_tpu.ops.pallas import tied_row as tied_mod
 
@@ -264,69 +226,35 @@ def test_attention_tied_path_dispatch(monkeypatch):
         return real(*a, **kw)
 
     monkeypatch.setattr(tied_mod, "tied_row_attention", spy)
-    with use_kernel_policy(parse_policy("tied_row=pallas")):
-        fused_out = attn.apply(params, x, mask=mask, tie_dim=2)
+    drop = Attention(dim=32, heads=2, dim_head=16, dropout=0.5)
+    params_d = drop.init(jax.random.key(7), x, tie_dim=2)
+    # off the TPU (this suite): the rule says no, the kernel is never touched
+    assert not tied_mod.tied_row_takes(False)
+    attn.apply(params, x, mask=mask, tie_dim=2)
+    assert calls["n"] == 0
+
+    monkeypatch.setattr(tied_mod, "tied_row_available", lambda: True)
+    assert tied_mod.tied_row_takes(False) and not tied_mod.tied_row_takes(True)
+    fused_out = attn.apply(params, x, mask=mask, tie_dim=2)
     assert calls["n"] == 1
     valid = np.asarray(mask)[:, :, None]
     assert np.max(np.abs(np.asarray(fused_out - dense_out)) * valid) < ATOL
 
-    # dense policy (and the off-TPU auto default): kernel never touched
-    attn.apply(params, x, mask=mask, tie_dim=2)
-    with use_kernel_policy(parse_policy("tied_row=dense")):
-        attn.apply(params, x, mask=mask, tie_dim=2)
-    assert calls["n"] == 1
-
     # active attention-weight dropout needs materialized probabilities:
-    # the kernel must NOT be taken even under a pallas policy
-    drop = Attention(dim=32, heads=2, dim_head=16, dropout=0.5)
-    params_d = drop.init(jax.random.key(7), x, tie_dim=2)
-    with use_kernel_policy(parse_policy("tied_row=pallas")):
-        out = drop.apply(
-            params_d, x, tie_dim=2, deterministic=False,
-            rngs={"dropout": jax.random.key(8)},
-        )
-    assert calls["n"] == 1 and bool(jnp.all(jnp.isfinite(out)))
-
-
-def test_axial_module_parity_under_policy():
-    """AxialAttention's grid route under axial=pallas: values and param
-    grads match the dense route on the valid region."""
-    from alphafold2_tpu.ops.attention import AxialAttention
-
-    x = jax.random.normal(jax.random.key(9), (2, 12, 20, 32))
-    mask = (
-        jnp.ones((2, 12, 20), bool)
-        .at[:, :, 17:].set(False)
-        .at[:, 10:, :].set(False)
+    # the kernel must NOT be taken even on a TPU
+    out = drop.apply(
+        params_d, x, tie_dim=2, deterministic=False,
+        rngs={"dropout": jax.random.key(8)},
     )
-    ax = AxialAttention(dim=32, heads=2, dim_head=16)
-    params = ax.init(jax.random.key(10), x, mask=mask)
-    dense_out = ax.apply(params, x, mask=mask)
-    with use_kernel_policy(parse_policy("axial=pallas")):
-        fused_out = ax.apply(params, x, mask=mask)
-    valid = np.asarray(mask)[..., None]
-    assert np.max(np.abs(np.asarray(fused_out - dense_out)) * valid) < ATOL
-
-    def grads(policy):
-        def inner(p):
-            ctx = (
-                use_kernel_policy(parse_policy(policy))
-                if policy else use_kernel_policy(None)
-            )
-            with ctx:
-                o = ax.apply(p, x, mask=mask)
-            return jnp.sum(jnp.sin(o) * mask[..., None])
-
-        return jax.tree.leaves(jax.grad(inner)(params))
-
-    for gd, gf in zip(grads(None), grads("axial=pallas")):
-        np.testing.assert_allclose(np.asarray(gd), np.asarray(gf), atol=ATOL)
+    assert calls["n"] == 1 and bool(jnp.all(jnp.isfinite(out)))
+    # ...and with the same module deterministic it is taken again
+    drop.apply(params_d, x, tie_dim=2, deterministic=True)
+    assert calls["n"] == 2
 
 
 def test_sparse_backend_policy_registration(monkeypatch):
-    """SparseAttention's backend resolves through the same switchboard:
-    explicit use_pallas > config.backend > KernelPolicy > auto."""
-    from alphafold2_tpu.ops import sparse as sparse_mod
+    """SparseAttention's backend: explicit use_pallas > config.backend >
+    the Pallas kernel on a TPU, jnp elsewhere."""
     from alphafold2_tpu.ops.sparse import BlockSparseConfig, SparseAttention
 
     def impl_name(module):
@@ -334,28 +262,32 @@ def test_sparse_backend_policy_registration(monkeypatch):
         return module._impl().__name__
 
     base = dict(dim=32, heads=2, dim_head=16, seq_len=64)
-    monkeypatch.delenv("AF2TPU_KERNELS", raising=False)
     assert impl_name(SparseAttention(**base)) == "block_sparse_attention"
-    with use_kernel_policy(parse_policy("block_sparse=pallas")):
-        assert (
-            impl_name(SparseAttention(**base))
-            == "block_sparse_attention_pallas"
+    assert (
+        impl_name(SparseAttention(**base, use_pallas=True))
+        == "block_sparse_attention_pallas"
+    )
+    splash = BlockSparseConfig(backend="splash")
+    assert (
+        impl_name(SparseAttention(**base, config=splash))
+        == "block_sparse_attention_splash"
+    )
+    # the explicit bool wins over the config's backend
+    assert (
+        impl_name(SparseAttention(**base, config=splash, use_pallas=False))
+        == "block_sparse_attention"
+    )
+    with pytest.raises(ValueError, match="unknown sparse backend"):
+        impl_name(SparseAttention(
+            **base, config=BlockSparseConfig(backend="warp")))
+    # on a TPU the auto case is the Pallas kernel; module choices still win
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert (
+        impl_name(SparseAttention(**base)) == "block_sparse_attention_pallas"
+    )
+    assert (
+        impl_name(
+            SparseAttention(**base, config=BlockSparseConfig(backend="jnp"))
         )
-    with use_kernel_policy(parse_policy("block_sparse=splash")):
-        assert (
-            impl_name(SparseAttention(**base))
-            == "block_sparse_attention_splash"
-        )
-        # explicit module choices still win over the policy
-        assert (
-            impl_name(SparseAttention(**base, use_pallas=True))
-            == "block_sparse_attention_pallas"
-        )
-        assert (
-            impl_name(
-                SparseAttention(
-                    **base, config=BlockSparseConfig(backend="jnp")
-                )
-            )
-            == "block_sparse_attention"
-        )
+        == "block_sparse_attention"
+    )

@@ -118,15 +118,27 @@ def test_bf16_logits_error_inside_bounds(drift):
     assert rel > 0
 
 
-def test_bf16_serve_engine_end_to_end():
-    """ServeEngine in the bf16 mode + fused tied-row kernel policy: params
-    actually cast, requests served ok with finite coords, and the
-    executable identity (compile records) carries the dtype+kernel keys
-    the regression gate refuses to cross-compare."""
+def test_bf16_serve_engine_end_to_end(monkeypatch):
+    """ServeEngine in the bf16 mode with the fused tied-row kernel (its
+    platform predicate steered, so it runs in interpret mode here): params
+    actually cast, requests served ok with finite coords, the kernel is in
+    every executable, and the executable identity (compile records)
+    carries the dtype key the regression gate refuses to cross-compare."""
     from alphafold2_tpu.config import (
         Config, DataConfig, ModelConfig, ServeConfig,
     )
+    from alphafold2_tpu.ops.pallas import tied_row
     from alphafold2_tpu.serve import ServeEngine
+
+    traced = []
+    kernel = tied_row.tied_row_attention
+
+    def recording(q, *args, **kwargs):
+        traced.append(q.dtype)
+        return kernel(q, *args, **kwargs)
+
+    monkeypatch.setattr(tied_row, "tied_row_available", lambda: True)
+    monkeypatch.setattr(tied_row, "tied_row_attention", recording)
 
     cfg = Config(
         model=ModelConfig(
@@ -136,12 +148,11 @@ def test_bf16_serve_engine_end_to_end():
         data=DataConfig(msa_depth=2),
         serve=ServeConfig(
             buckets=(8, 16), max_batch=2, mds_iters=8,
-            dtype="bfloat16", kernels="tied_row=pallas",
+            dtype="bfloat16",
         ),
     )
     engine = ServeEngine(cfg)
     assert engine.serve_dtype == "bfloat16"
-    assert engine.kernels_desc == "tied_row=pallas"
     float_leaves = [
         x for x in jax.tree.leaves(engine.params)
         if jnp.issubdtype(x.dtype, jnp.floating)
@@ -155,8 +166,11 @@ def test_bf16_serve_engine_end_to_end():
         assert np.all(np.isfinite(r.atom14))
     for rec in engine.compile_records:
         assert rec["dtype"] == "bfloat16"
-        assert rec["kernels"] == "tied_row=pallas"
+        assert "kernels" not in rec
         assert rec["flops_breakdown"]["tied_row"] > 0
+    # one tied row pass a layer in each executable, in the serving dtype
+    assert len(traced) >= len(engine.compile_records) > 0
+    assert set(traced) == {jnp.dtype("bfloat16")}
 
 
 def test_serve_dtype_validation():

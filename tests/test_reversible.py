@@ -29,7 +29,7 @@ def _inputs(key):
 
 
 def _trunk(**kw):
-    base = dict(dim=D, depth=3, heads=2, dim_head=8, use_flash=False)
+    base = dict(dim=D, depth=3, heads=2, dim_head=8)
     base.update(kw)
     return ReversibleTrunk(**base)
 
@@ -77,7 +77,7 @@ def test_reversible_grad_parity():
 def test_layer_inversion_exact():
     """invert(forward(h)) == h to float32 roundoff."""
     x, m, pm, mm = _inputs(jax.random.key(4))
-    layer = RevLayerPair(dim=D, heads=2, dim_head=8, use_flash=False)
+    layer = RevLayerPair(dim=D, heads=2, dim_head=8)
     h = (x, x * 0.5, m, m * 0.5)
     params = layer.init(jax.random.key(5), h, pm, mm, True)
     h_out = layer.apply(params, h, pm, mm, True)
@@ -169,7 +169,7 @@ def test_model_reversible_trains():
 
     model = Alphafold2(
         dim=32, depth=2, heads=2, dim_head=16, max_seq_len=32,
-        reversible=True, msa_tie_row_attn=True, use_flash=False,
+        reversible=True, msa_tie_row_attn=True,
     )
     k = jax.random.key(11)
     seq = jax.random.randint(jax.random.fold_in(k, 1), (1, 8), 0, 21)
@@ -223,7 +223,7 @@ def test_reversible_with_sparse_attention():
     x = jax.random.normal(jax.random.key(21), (B, 8, 8, D))
     pm = jnp.ones((B, 8, 8), bool)
     kw = dict(
-        dim=D, depth=2, heads=2, dim_head=8, use_flash=False,
+        dim=D, depth=2, heads=2, dim_head=8,
         sparse_attn=True, seq_len=8,
         sparse_config=BlockSparseConfig(block_size=4, num_random_blocks=0),
     )

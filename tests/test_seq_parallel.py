@@ -178,17 +178,17 @@ def test_ring_output_and_gradients_match_dense_oracle(sp, kind, masking):
     assert not (np.asarray(grads[2]) * masked_out).any()
 
 
-@pytest.mark.parametrize("use_flash,n_local,kernel", [
+@pytest.mark.parametrize("on_tpu,n_local,kernel", [
     (True, 200, True),   # 200 x 170, padded to the kernel's 128 lanes
-    (False, 200, False),  # the caller's _use_flash() said no
+    (False, 200, False),  # off the TPU
     (True, 64, False),  # both local axes under one 128 block
 ])
-def test_ring_block_choice_follows_platform_shape_and_caller(
-        monkeypatch, use_flash, n_local, kernel):
+def test_ring_block_choice_follows_platform_and_shape(
+        monkeypatch, on_tpu, n_local, kernel):
     """On a TPU (steered here) the ring takes the kernel's blocks unless the
-    caller declines or the local block is under one lane tile. The kernel
-    itself cannot run on the CPU: a stand-in records the (padded) shape it
-    was asked for and hands back the jnp triple."""
+    local block is under one lane tile: ``ops/flash.py``'s own rule. The
+    kernel itself cannot run on the CPU: a stand-in records the (padded)
+    shape it was asked for and hands back the jnp triple."""
     from alphafold2_tpu.ops import flash
     from alphafold2_tpu.parallel import seq_parallel as sp_mod
 
@@ -198,7 +198,7 @@ def test_ring_block_choice_follows_platform_shape_and_caller(
         asked.append((nq, nk))
         return sp_mod._jnp_blocks(scale)
 
-    monkeypatch.setattr(flash, "flash_available", lambda: True)
+    monkeypatch.setattr(flash, "flash_available", lambda: on_tpu)
     monkeypatch.setattr(sp_mod, "_flash_blocks", stand_in)
     ks = jax.random.split(jax.random.key(13), 3)
     q = jax.random.normal(ks[0], (4, 2, 2 * n_local, 8))
@@ -208,8 +208,7 @@ def test_ring_block_choice_follows_platform_shape_and_caller(
 
     def loss(q, k, v):
         return jnp.sum(sequence_parallel_attention(
-            q, k, v, mask=mask, mesh=make_mesh(4, 2), use_flash=use_flash
-        ) ** 2)
+            q, k, v, mask=mask, mesh=make_mesh(4, 2)) ** 2)
 
     grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     grads_ref = jax.grad(
@@ -219,28 +218,3 @@ def test_ring_block_choice_follows_platform_shape_and_caller(
     for g, g_ref in zip(grads, grads_ref):
         assert g.shape == g_ref.shape
         assert np.allclose(g, g_ref, atol=1e-4)
-
-
-@pytest.mark.parametrize("use_flash", [False, None])
-def test_attention_hands_the_ring_its_flash_policy(monkeypatch, use_flash):
-    """``Attention`` passes ``_use_flash()`` down, the decision it makes for
-    the flat path: an explicit False, or the auto case off the TPU."""
-    from alphafold2_tpu.ops.attention import Attention
-    from alphafold2_tpu.parallel import seq_parallel as sp_mod
-    from alphafold2_tpu.parallel.sharding import use_mesh
-
-    seen = {}
-    real = sp_mod.sequence_parallel_attention
-
-    def spy(*args, **kwargs):
-        seen.update(kwargs)
-        return real(*args, **kwargs)
-
-    monkeypatch.setattr(sp_mod, "sequence_parallel_attention", spy)
-    attn = Attention(dim=16, heads=2, dim_head=8, context_parallel="ring",
-                     use_flash=use_flash)
-    x = jax.random.normal(jax.random.key(14), (4, 32, 16))
-    with use_mesh(make_mesh(4, 2)):
-        params = attn.init(jax.random.key(0), x)
-        attn.apply(params, x)
-    assert seen["use_flash"] is False and seen["impl"] == "ring"

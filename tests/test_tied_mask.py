@@ -16,7 +16,7 @@ from alphafold2_tpu.ops.attention import Attention
 
 
 def _attn(key, dim=16, heads=2, dim_head=8):
-    mod = Attention(dim=dim, heads=heads, dim_head=dim_head, use_flash=False)
+    mod = Attention(dim=dim, heads=heads, dim_head=dim_head)
     x0 = jnp.zeros((2, 4, dim))
     params = mod.init(key, x0)
     return mod, params
