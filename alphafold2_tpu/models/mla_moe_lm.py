@@ -44,6 +44,23 @@ def _dense(features: int, dtype, name: str) -> nn.Dense:
                     kernel_init=fan_in_normal(), name=name)
 
 
+class ScaledDense(nn.Module):
+    """``x @ (kernel * scale)``, no bias: the scale goes into the float32
+    weights before their cast to ``dtype``, so the product is born scaled
+    and is rounded to ``dtype`` once, as an unscaled one is."""
+
+    features: int
+    scale: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", fan_in_normal(), (x.shape[-1], self.features))
+        return jnp.dot(x.astype(self.dtype),
+                       (kernel * self.scale).astype(self.dtype))
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.float32
@@ -78,7 +95,10 @@ class MLAttention(nn.Module):
         b, s, _ = x.shape
         heads, nope, rope, dv = (c.num_heads, c.qk_nope_head_dim,
                                  c.qk_rope_head_dim, c.v_head_dim)
-        q = _dense(heads * (nope + rope), self.dtype, "q_proj")(x)
+        # the softmax scale rides on q from its projection on: the core's
+        # kernel takes none, and scaling bf16 queries would round them twice
+        q = ScaledDense(heads * (nope + rope), (nope + rope) ** -0.5,
+                        self.dtype, name="q_proj")(x)
         q = q.reshape(b, s, heads, nope + rope)
         latent = _dense(c.kv_lora_rank + rope, self.dtype, "kv_down")(x)
         kv = RMSNorm(c.rms_norm_eps, self.dtype, name="kv_norm")(
@@ -99,8 +119,7 @@ class MLAttention(nn.Module):
         with jax.named_scope("core"):
             out = mla.causal_core(
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                kv[..., nope:].transpose(0, 2, 1, 3),
-                sm_scale=(nope + rope) ** -0.5)
+                kv[..., nope:].transpose(0, 2, 1, 3))
             out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * dv)
         return _dense(x.shape[-1], self.dtype, "o_proj")(out)
 

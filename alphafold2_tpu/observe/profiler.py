@@ -70,16 +70,21 @@ def last_record() -> Optional[dict]:
 
 def instruction_scopes(hlo_text: str) -> Tuple[str, dict]:
     """(module name, {instruction name: op_name}) of a compiled program's
-    text (``compiled.as_text()``)."""
+    text (``compiled.as_text()``). An instruction's text may run over
+    several lines (a kernel's frontend attributes can hold newlines: the
+    splash kernels' do), its metadata then on a later one: the ``op_name``
+    belongs to the last instruction that began."""
     module = hlo_text.split(None, 2)[1].rstrip(",") if hlo_text.startswith(
         "HloModule") else ""
-    scopes = {}
+    scopes, unnamed = {}, None
     for line in hlo_text.splitlines():
         named = _INSTRUCTION.match(line)
         if named:
-            op_name = _OP_NAME.search(line)
-            if op_name:
-                scopes[named.group(1)] = op_name.group(1)
+            unnamed = named.group(1)
+        op_name = _OP_NAME.search(line) if unnamed else None
+        if op_name:
+            scopes[unnamed] = op_name.group(1)
+            unnamed = None
     return module, scopes
 
 

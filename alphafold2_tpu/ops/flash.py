@@ -34,8 +34,7 @@ def flash_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def flash_takes(nq: int, nk: int, head_dim: int, pads_heads: bool = True
-                ) -> bool:
+def flash_takes(nq: int, nk: int, head_dim: int) -> bool:
     """Whether the stock kernel serves a call of ``nq`` queries against
     ``nk`` keys at this head size: the one rule, asked by
     :func:`flash_attention`, by ``Attention`` before it wraps the call for a
@@ -45,13 +44,11 @@ def flash_takes(nq: int, nk: int, head_dim: int, pads_heads: bool = True
     dense attention matrix is trivially small and the kernel's tiling
     overhead dominates. With one long axis (N^2 queries against a compressed
     context) the kernel still pays off; the short axis is padded up to a
-    block. The kernel takes one head size, a multiple of 128 once it is over
-    128: :func:`flash_attention` zero-pads other sizes, a caller that hands
-    the kernel its operands as they are (``pads_heads=False``: the ring)
-    keeps such a call off it."""
+    block. The kernel takes one head size for q, k and v, a multiple of 128
+    once it is over 128; nothing here pads heads."""
     if not flash_available() or (nq < 128 and nk < 128):
         return False
-    return pads_heads or head_dim <= 128 or head_dim % 128 == 0
+    return head_dim <= 128 or head_dim % 128 == 0
 
 
 def _block(n: int, cap: int, unit: int = 128) -> int:
@@ -66,7 +63,6 @@ def _block(n: int, cap: int, unit: int = 128) -> int:
 
 def block_sizes_for(
     batch: int, heads: int, nq: int, nk: int, head_dim: int, dtype,
-    causal: bool = False,
 ):
     """The stock kernel's ``BlockSizes`` for one call, from its shape alone.
 
@@ -99,18 +95,10 @@ def block_sizes_for(
 
     One K or V tile is held to 1 MiB, so wider heads or float32 operands
     shrink the key blocks instead of running out of VMEM.
-
-    ``causal``: the kernel leaves out a grid step whose whole (query block x
-    major key block) lies above the diagonal, and computes every other step
-    whole. Blocks that are long along the keys would compute most of what
-    the mask throws away (512 x 2,048 at 8,192 positions: 25% over the
-    causal half), so a causal call goes to :func:`_causal_block_sizes`.
     """
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
     k_cap = max(128, 2**20 // (head_dim * jnp.dtype(dtype).itemsize))
-    if causal:
-        return _causal_block_sizes(batch, heads, nq, nk, k_cap)
     block_q = _block(nq, 512)
     if nk <= min(2048, k_cap):  # the key axis whole, whatever divides it
         block_k = block_k_major = block_k_major_dkv = nk
@@ -140,84 +128,28 @@ def block_sizes_for(
     )
 
 
-CAUSAL_BLOCK = 1024  # the side of a causal call's square blocks
-
-
-def _causal_block_sizes(batch: int, heads: int, nq: int, nk: int, k_cap: int):
-    """Blocks of a causal call: square, CAUSAL_BLOCK on a side (a grid step
-    above the diagonal is skipped whole, one that touches it is computed
-    whole: 8,192 positions in squares of 1,024 compute 12.5% over the causal
-    half, in 512 x 2,048 blocks 25%). The side is where the on-chip sweep at
-    2 x 32 heads x 8,192 x 8,192 x 256 went flat (PERF.md section 6, PR 27).
-    dq's ``di`` (b, h, nq, block_k_major_dq) float32 stays within 1 GiB as
-    in the rule above."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
-    side_q = _block(nq, CAUSAL_BLOCK)
-    side_k = _block(nk, min(CAUSAL_BLOCK, k_cap))
-    inner_k = _block(side_k, 512)
-    di_cap = 2**30 // (batch * heads * nq * 4)
-    k_dq = _block(nk, min(side_k, max(128, di_cap)))
-    return BlockSizes(
-        block_q=side_q,
-        block_k_major=side_k,
-        block_k=inner_k,
-        block_b=1,
-        block_q_major_dkv=side_q,
-        block_k_major_dkv=side_k,
-        block_k_dkv=inner_k,
-        block_q_dkv=_block(side_q, 256),
-        block_k_major_dq=k_dq,
-        block_k_dq=_block(k_dq, 512),
-        block_q_dq=side_q,
-    )
-
-
-def _pad_last(x: jnp.ndarray, width: int) -> jnp.ndarray:
-    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),))
-
-
 def flash_attention(
     q: jnp.ndarray,  # (B, H, Nq, D)
     k: jnp.ndarray,  # (B, H, Nk, D)
-    v: jnp.ndarray,  # (B, H, Nk, Dv): Dv may differ from D
+    v: jnp.ndarray,  # (B, H, Nk, D)
     q_mask: Optional[jnp.ndarray] = None,  # (B, Nq) bool
     kv_mask: Optional[jnp.ndarray] = None,  # (B, Nk) bool
     sm_scale: float = 1.0,
-    causal: bool = False,
 ) -> Optional[jnp.ndarray]:
     """Fused attention via the stock Pallas TPU kernel.
 
     Returns None where :func:`flash_takes` says no (off the TPU, both axes
     under one 128 block): the caller takes the dense jnp path there by
     design. Whatever the kernel itself refuses propagates.
-
-    ``causal`` (query i sees keys 0..i; needs Nq == Nk) skips the blocks
-    above the diagonal inside the kernel. The kernel takes one head size for
-    q, k and v, a multiple of 128 once it is over 128: other sizes (latent
-    attention's 192 for q/k beside 128 for v) are zero-padded to the next
-    such size and the output sliced back. Zero columns change no logit and
-    no output; they cost the MXU passes they fill (PERF.md section 6, PR 27).
     """
     b, h, nq, d = q.shape
-    nk, dv = k.shape[2], v.shape[3]
-    if not flash_takes(nq, nk, max(d, dv)):
+    nk = k.shape[2]
+    if not flash_takes(nq, nk, d):
         return None
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         SegmentIds,
         flash_attention as _fa,
     )
-
-    if causal and nq != nk:
-        raise ValueError(f"causal attention needs Nq == Nk, got {nq} and {nk}")
-
-    width = max(d, dv)
-    if width > 128:
-        width += (-width) % 128
-    if d != width:
-        q, k = _pad_last(q, width), _pad_last(k, width)
-    if dv != width:
-        v = _pad_last(v, width)
 
     # the kernel's block verification requires both sequence axes divisible
     # by its blocks, and block_sizes_for finds blocks for any multiple of
@@ -251,8 +183,7 @@ def flash_attention(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     out = _fa(
-        q, k, v, segment_ids=segment_ids, causal=causal, sm_scale=sm_scale,
-        block_sizes=block_sizes_for(
-            b, h, nq + pad_q, nk + pad_k, width, q.dtype, causal=causal),
+        q, k, v, segment_ids=segment_ids, sm_scale=sm_scale,
+        block_sizes=block_sizes_for(b, h, nq + pad_q, nk + pad_k, d, q.dtype),
     )
-    return out[:, :, :nq, :dv] if pad_q or dv != width else out
+    return out[:, :, :nq] if pad_q else out

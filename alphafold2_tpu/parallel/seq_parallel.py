@@ -229,11 +229,9 @@ def ring_attention(
     A ring of flash blocks under one custom VJP: K, V and the key mask visit
     every device by ``ppermute``, one hop a step; see :func:`_ring_fwd` and
     :func:`_ring_bwd`. The block is the stock flash kernel where
-    ``ops/flash.py`` ``flash_takes`` the local shape (the ring hands the
-    kernel its heads as they are, so a head size the flat wrapper would pad
-    stays off it), the jnp triple everywhere else. For the kernel both local
-    axes are padded to the 128 lanes its blocks need, padded keys masked
-    out, as ``ops/flash.py`` does.
+    ``ops/flash.py`` ``flash_takes`` the local shape, the jnp triple
+    everywhere else. For the kernel both local axes are padded to the 128
+    lanes its blocks need, padded keys masked out, as ``ops/flash.py`` does.
     """
     from alphafold2_tpu.ops import flash
 
@@ -241,7 +239,7 @@ def ring_attention(
     nk = k.shape[2]
     scale = d**-0.5
     seg = kmask.astype(jnp.int32)
-    if not flash.flash_takes(nq, nk, d, pads_heads=False):
+    if not flash.flash_takes(nq, nk, d):
         return _ring(q, k, v, seg, axis_name, _jnp_blocks(scale))
     pad_q, pad_k = (-nq) % 128, (-nk) % 128
     if pad_q:

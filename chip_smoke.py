@@ -362,9 +362,10 @@ def _ref_attention_by_head(q, k, v, kv_mask, scale):
         jax.lax.map(jax.checkpoint(one_head), heads_first), 0, 1)
 
 
-def _ref_causal_by_head(q, k, v, scale):
-    """Causal softmax attention in float32, one head at a time: q/k heads
-    may be wider than v heads (latent attention's 192 against 128)."""
+def _ref_causal_by_head(q, k, v):
+    """Causal softmax attention in float32, one head at a time, the scale
+    on q already: q/k heads may be wider than v heads (latent attention's
+    192 against 128)."""
     import jax
     import jax.numpy as jnp
 
@@ -373,7 +374,7 @@ def _ref_causal_by_head(q, k, v, scale):
 
     def one_head(qkv):
         q1, k1, v1 = (t.astype(jnp.float32) for t in qkv)
-        dots = jnp.einsum("bid,bjd->bij", q1, k1) * scale
+        dots = jnp.einsum("bid,bjd->bij", q1, k1)
         probs = jax.nn.softmax(jnp.where(keep, dots, -1e30), axis=-1)
         return jnp.einsum("bij,bjd->bid", probs, v1)
 
@@ -533,12 +534,18 @@ def kernel_cases(small: bool = False) -> list:
         ))
 
     def mla_core(name, b, h, n, qk, dv):
+        """The causal core takes q with the softmax scale on it (the model's
+        query projection puts it there): both sides get the same scaled,
+        rounded q."""
         from alphafold2_tpu.ops.mla import causal_core
+
+        def scaled(q):
+            return (q * qk ** -0.5).astype(q.dtype)
 
         cases.append((
             name,
-            lambda q, k, v: causal_core(q, k, v, qk ** -0.5),
-            lambda q, k, v: _ref_causal_by_head(q, k, v, qk ** -0.5),
+            lambda q, k, v: causal_core(scaled(q), k, v),
+            lambda q, k, v: _ref_causal_by_head(scaled(q), k, v),
             ((b, h, n, qk), (b, h, n, qk), (b, h, n, dv)), "bfloat16",
         ))
 
@@ -596,7 +603,7 @@ def kernel_cases(small: bool = False) -> list:
     flash("stock_flash_one_q_block_keys_2048", (1, 8, 512, 64),
           (1, 8, 2048, 64), 0)
     # the language-model cell's two kernels at its shapes: causal, q/k heads
-    # of 192 against v heads of 128 (padded to 256 for the stock kernel), and
+    # of 192 against v heads of 128 (the splash kernel, nothing padded), and
     # the grouped product over 16 held experts, an eighth of the rows live
     mla_core("mla_causal_core_8k", 2, 32, 8192, 192, 128)
     grouped("moe_grouped_matmul_16_experts", 6 * 16384, 2048, 768, 16)

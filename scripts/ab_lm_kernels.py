@@ -1,12 +1,17 @@
 #!/usr/bin/env python3
 """A/B of the language model's two kernels at the benchmark cell's shapes.
 
+    JAX_PLATFORMS=cpu python scripts/ab_lm_kernels.py --compile-only
+                                  # which attention candidates Mosaic takes
     chiprun -- python scripts/ab_lm_kernels.py        # times, on a TPU
+    chiprun -- python scripts/ab_lm_kernels.py --kernels attention
 
-(a) The stock flash kernel, causal, 2 x 32 heads x 8,192 x 8,192 at head 256
-    (q/k 192 and v 128 zero-padded, as ``ops/flash.py`` sends them): forward
-    and forward + backward over square blocks of several sides and over the
-    non-causal rule's blocks, which are long along the keys.
+(a) The causal core, 2 x 32 heads x 8,192 x 8,192, forward and forward +
+    backward: the stock flash kernel at head 256 (q/k 192 and v 128
+    zero-padded, as the tree ran it until PR 31) in square blocks, against
+    the splash kernel at 192/128 under a causal mask over its block sizes,
+    with the two-kernel backward and the fused one, and at what
+    ``ops/mla.py`` ``splash_block_sizes`` gives.
 (b) The grouped matrix product over 16 held experts, 98,304 sorted rows of
     which an eighth is live: ``jax.lax.ragged_dot`` against the stock
     megablox ``gmm`` at three tilings, forward + backward of the expert
@@ -15,7 +20,7 @@
 Each candidate is jitted alone, warmed once, timed best of ``--reps`` with
 ``block_until_ready``. One JSON line a candidate on stdout and in
 ``chiprun_out/ab_lm_kernels.jsonl``. Not part of the library: the winner is
-written into ``ops/flash.py`` / ``ops/moe.py`` by hand, with PERF.md's record.
+written into ``ops/mla.py`` / ``ops/moe.py`` by hand, with PERF.md's record.
 """
 
 from __future__ import annotations
@@ -44,40 +49,118 @@ def best_ms(fn, args, reps):
     return 1e3 * min(times)
 
 
-def flash_candidates(reps):
+B, H, N, D_QK, D_V = 2, 32, 8192, 192, 128  # the cell's causal core
+
+
+def _measure(rec, fwd, shapes, reps, sharding, backward=True):
+    """Times of ``fwd`` and (``backward``) its gradient at ``shapes``; under
+    ``--compile-only`` (``sharding`` a described chip) the compile's verdict
+    and the bytes of its temporaries."""
+    def both(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    fwd, both = jax.jit(fwd), jax.jit(both)
+    try:
+        if sharding is not None:
+            args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding)
+                    for s in shapes]
+            fwd.lower(*args).compile()
+            if backward:
+                rec["fwd_bwd_temp_bytes"] = both.lower(
+                    *args).compile().memory_analysis().temp_size_in_bytes
+        else:
+            keys = jax.random.split(jax.random.key(0), len(shapes))
+            args = [jax.random.normal(kk, s, jnp.bfloat16)
+                    for kk, s in zip(keys, shapes)]
+            rec["fwd_ms"] = best_ms(fwd, args, reps)
+            if backward:
+                rec["fwd_bwd_ms"] = best_ms(both, args, reps)
+    except Exception as e:  # a set the compiler refuses
+        rec["error"] = repr(e)[:300]
+    return rec
+
+
+def flash_candidates(reps, sharding):
+    """The stock flash kernel at head 256 (q/k 192 and v 128 zero-padded, as
+    the tree sent them until PR 31), causal, in square blocks."""
     from jax.experimental.pallas.ops.tpu import flash_attention as stock
 
-    from alphafold2_tpu.ops import flash
+    shapes = [(B, H, N, 256)] * 3
+    for side, inner in ((512, 512), (1024, 512)):
+        blocks = stock.BlockSizes(
+            block_q=side, block_k_major=side, block_k=inner, block_b=1,
+            block_q_major_dkv=side, block_k_major_dkv=side,
+            block_k_dkv=inner, block_q_dkv=256,
+            # di (b, h, n, block_k_major_dq) float32 within 1 GiB
+            block_k_major_dq=512, block_k_dq=512, block_q_dq=side)
 
-    b, h, n, d = 2, 32, 8192, 256
-    keys = jax.random.split(jax.random.key(0), 3)
-    q, k, v = (jax.random.normal(kk, (b, h, n, d), jnp.bfloat16)
-               for kk in keys)
-    sets = {"rule_noncausal": flash.block_sizes_for(
-        b, h, n, n, d, jnp.bfloat16)}
-    chosen = flash.CAUSAL_BLOCK
-    for side in (256, 512, 1024, 2048):
-        flash.CAUSAL_BLOCK = side
-        sets[f"square_{side}"] = flash.block_sizes_for(
-            b, h, n, n, d, jnp.bfloat16, causal=True)
-    flash.CAUSAL_BLOCK = chosen
-    for name, blocks in sets.items():
         def fwd(q, k, v, blocks=blocks):
             return stock.flash_attention(
-                q, k, v, causal=True, sm_scale=192 ** -0.5,
+                q, k, v, causal=True, sm_scale=D_QK ** -0.5,
                 block_sizes=blocks)
 
-        def both(q, k, v, fwd=fwd):
-            return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
-                            argnums=(0, 1, 2))(q, k, v)
+        yield _measure(
+            {"kernel": "flash_causal_256", "blocks": f"square_{side}"},
+            fwd, shapes, reps, sharding)
 
-        rec = {"kernel": "flash_causal", "blocks": name}
-        try:
-            rec["fwd_ms"] = best_ms(jax.jit(fwd), (q, k, v), reps)
-            rec["fwd_bwd_ms"] = best_ms(jax.jit(both), (q, k, v), reps)
-        except Exception as e:  # a set the compiler refuses
-            rec["error"] = repr(e)[:300]
-        yield rec
+
+def splash_candidates(reps, sharding):
+    """The splash kernel at 192/128 under a causal mask: forward blocks
+    (block_q, block_kv, block_kv_compute) alone, then the two backward forms
+    at (block_q_dkv, block_kv_dkv, block_kv_dkv_compute) behind one forward
+    (the two-kernel form's dq at the dkv's block_q, block_kv). The last
+    candidate is what ``ops/mla.py`` ``splash_block_sizes`` gives."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    from alphafold2_tpu.ops import mla
+
+    shapes = [(B, H, N, D_QK), (B, H, N, D_QK), (B, H, N, D_V)]
+    mask = sm.MultiHeadMask([sm.CausalMask((N, N))] * H)
+    # a block of 2,048 on either axis runs out of scoped VMEM in every
+    # kernel (compile for a described v5e, PR 31), so 512 and 1,024 it is
+    sets = {}
+    grid = ((512, 512, 512), (512, 1024, 512), (1024, 512, 512),
+            (1024, 1024, 256), (1024, 1024, 512), (1024, 1024, 1024))
+    for bq, bkv, comp in grid:
+        sets[f"fwd_{bq}_{bkv}_{comp}"] = sk.BlockSizes(
+            block_q=bq, block_kv=bkv, block_kv_compute=comp)
+    forward = dict(block_q=1024, block_kv=1024, block_kv_compute=512)
+    for bq, bkv, comp in grid:
+        back = dict(block_q_dkv=bq, block_kv_dkv=bkv,
+                    block_kv_dkv_compute=comp)
+        sets[f"unfused_{bq}_{bkv}_{comp}"] = sk.BlockSizes(
+            **forward, **back, block_q_dq=bq, block_kv_dq=bkv)
+        sets[f"fused_{bq}_{bkv}_{comp}"] = sk.BlockSizes(
+            **forward, **back, use_fused_bwd_kernel=True)
+    # the layouts: keys or values handed over positions-minor (a transpose
+    # by XLA before the call for one inside the kernel a block)
+    seq_minor = sk.QKVLayout.SEQ_MINOR
+    for name, layouts in (("k", dict(k_layout=seq_minor)),
+                          ("v", dict(v_layout=seq_minor)),
+                          ("kv", dict(k_layout=seq_minor,
+                                      v_layout=seq_minor))):
+        sets[f"fwd_1024_1024_256_{name}_seq_minor"] = sk.BlockSizes(
+            block_q=1024, block_kv=1024, block_kv_compute=256, **layouts)
+    sets["fused_1024_1024_512_k_seq_minor"] = sk.BlockSizes(
+        **forward, block_q_dkv=1024, block_kv_dkv=1024,
+        block_kv_dkv_compute=512, use_fused_bwd_kernel=True,
+        k_layout=seq_minor)
+    sets["rule"] = mla.splash_block_sizes(H, N, D_QK, D_V, jnp.bfloat16)
+    for name, blocks in sets.items():
+        with jax.ensure_compile_time_eval():
+            kernel = sk.make_splash_mha(
+                mask, head_shards=1, q_seq_shards=1, block_sizes=blocks)
+
+        def fwd(q, k, v, kernel=kernel):
+            return jax.vmap(kernel)(q * D_QK ** -0.5, k, v)
+
+        yield _measure({"kernel": "splash_causal_192_128", "blocks": name},
+                       fwd, shapes, reps, sharding,
+                       backward=blocks.has_backward_blocks)
 
 
 def gmm_candidates(reps):
@@ -133,12 +216,39 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--out", default="chiprun_out/ab_lm_kernels.jsonl")
+    ap.add_argument("--kernels", choices=("attention", "experts", "all"),
+                    default="all")
+    ap.add_argument("--compile-only", action="store_true",
+                    help="compile the attention candidates for one chip of a "
+                         "described v5e (JAX_PLATFORMS=cpu, no TPU attached): "
+                         "which sets Mosaic takes, and their temporaries")
     args = ap.parse_args(argv)
+    sharding = None
+    if args.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        jax.config.update("jax_enable_compilation_cache", False)
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        sharding = SingleDeviceSharding(topo.devices[0])
+        args.kernels = "attention"
+    elif jax.default_backend() != "tpu":
+        print("needs a TPU (or --compile-only)", file=sys.stderr)
+        return 1
+    gens = []
+    if args.kernels in ("attention", "all"):
+        gens += [lambda: flash_candidates(args.reps, sharding),
+                 lambda: splash_candidates(args.reps, sharding)]
+    if args.kernels in ("experts", "all"):
+        gens.append(lambda: gmm_candidates(args.reps))
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "a") as out:
-        for gen in (flash_candidates, gmm_candidates):
-            for rec in gen(args.reps):
-                rec["device"] = jax.devices()[0].device_kind
+        for gen in gens:
+            for rec in gen():
+                rec["device"] = ("described v5e, compile only" if sharding
+                                 else jax.devices()[0].device_kind)
                 line = json.dumps(rec)
                 print(line, flush=True)
                 out.write(line + "\n")

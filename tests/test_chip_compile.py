@@ -361,30 +361,34 @@ def test_ring_of_flash_blocks_compiles_for_v5e(topo, one_chip, on_tpu_branch,
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("length", [8192, 8192 - 40])
-def test_causal_latent_core_compiles_for_v5e(one_chip, on_tpu_branch, length,
+def test_causal_latent_core_compiles_for_v5e(one_chip, monkeypatch, length,
                                              direction):
     """The language-model cell's attention: causal, 2 x 32 heads, q/k heads
-    of 192 against v heads of 128, through ``ops/flash.py`` (heads padded to
-    256, the causal rule's square blocks), forward and backward; also at a
-    length that is no multiple of 128."""
-    from alphafold2_tpu.ops.mla import causal_core
+    of 192 against v heads of 128 as they are, through the splash kernel at
+    the blocks ``ops/mla.py`` ``splash_block_sizes`` gives (Mosaic takes a
+    192-deep score product; the scoped-VMEM limit is the fence on the
+    blocks), forward and the fused backward; also at a length that is no
+    multiple of 128."""
+    from alphafold2_tpu.ops import mla
 
-    def core(q, k, v):
-        return causal_core(q, k, v, 192 ** -0.5)
-
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = _compile(
-        core if direction == "fwd" else _grad_of(core), one_chip,
-        ((2, 32, length, 192), "bfloat16"), ((2, 32, length, 192), "bfloat16"),
-        ((2, 32, length, 128), "bfloat16"))
-    assert "tpu_custom_call" in text
+        mla.causal_core if direction == "fwd" else _grad_of(mla.causal_core),
+        one_chip, ((2, 32, length, 192), "bfloat16"),
+        ((2, 32, length, 192), "bfloat16"), ((2, 32, length, 128), "bfloat16"))
+    assert "tpu_custom_call" in text and "splash_mha_fwd" in text
+    if direction == "bwd":  # one backward kernel: dq comes with dk and dv
+        assert "splash_mha_dkv" in text and "splash_mha_dq" not in text
     assert "f32[2,32,8192,8192]" not in text  # no dense logits anywhere
+    assert "256]" not in text  # no head padded to 256
 
 
 def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted train step of the benchmark's language-model cell
-    (576 M parameters, 2 x 8,192 tokens) for one described chip: the flash
-    kernels and XLA's ragged-product kernels are in it, no dense 8,192^2
-    logits are, and weights + Adam + activations fit 16 GB."""
+    (576 M parameters, 2 x 8,192 tokens) for one described chip: the splash
+    kernels and XLA's ragged-product kernels are in it, the stock flash
+    kernel and dense 8,192^2 logits are not, and weights + Adam + activations
+    fit 16 GB."""
     import os
     import sys
 
@@ -414,11 +418,12 @@ def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
     ).compile()
     text = compiled.as_text()
-    assert "flash_attention" in text and "flash_mha_bwd_dkv" in text
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert "flash_attention" not in text and "flash_mha_bwd" not in text
     assert "ragged-dot" in text  # the grouped product is a kernel, not dense
-    # (bf16[2,8192,8192] is there: 32 heads x 256 of keys and values)
     assert "32,8192,8192]" not in text and "64,8192,8192]" not in text
     ma = compiled.memory_analysis()
     per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"compiled step, bytes on the device: {per_device}")
     assert 8e9 < per_device < 15.75 * 2**30

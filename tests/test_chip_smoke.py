@@ -64,7 +64,11 @@ def test_serve_phase_answers_and_reuses_its_executables():
     assert rec["max_batch"] == chip_smoke.SERVE_MAX_BATCH and rec["cut"]
 
 
-def test_kernel_phase_agrees_with_the_references():
+def test_kernel_phase_agrees_with_the_references(monkeypatch):
+    from alphafold2_tpu.ops import mla
+
+    # the causal core's splash kernel as on the chip, interpreted off it
+    monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
     rec = chip_smoke.phase_kernels(small=True)
     names = [c["case"] for c in rec["cases"]]
     assert len(names) == 10 and all(c["ok"] for c in rec["cases"])

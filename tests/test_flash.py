@@ -195,45 +195,47 @@ def test_flash_engages_with_one_short_axis(monkeypatch):
     assert flash_mod.flash_attention(tiny, tiny, tiny) is None
 
 
-# name: (nq, nk, q/k head size, v head size, causal, on a TPU, the flat path
-# takes the kernel, the ring takes it for a local block of this shape)
+# name: (nq, nk, head size, on a TPU, the kernel takes it: the flat path and
+# the ring, for a local block of this shape, alike)
 ONE_RULE_CASES = {
     # the flagship cells' cross-attentions, whole and as one ring step's block
-    "pair_from_msa": (65536, 4096, 64, 64, False, True, True, True),
-    "msa_from_pair": (4096, 65536, 64, 64, False, True, True, True),
-    "ring_local_pair_from_msa": (32768, 2048, 64, 64, False, True, True, True),
-    "ring_local_msa_from_pair": (2048, 32768, 64, 64, False, True, True, True),
-    "pair_axial": (256, 256, 64, 64, False, True, True, True),
-    "one_short_axis": (65536, 86, 64, 64, False, True, True, True),
-    # the language-model cell: the flat wrapper pads 192/128 to 256, the ring
-    # hands the kernel its heads as they are and keeps 192 off it
-    "lm_causal_192_128": (8192, 8192, 192, 128, True, True, True, False),
-    "under_one_block_64": (64, 64, 64, 64, False, True, False, False),
-    "under_one_block_100": (100, 100, 64, 64, False, True, False, False),
-    "off_the_tpu": (65536, 4096, 64, 64, False, False, False, False),
+    "pair_from_msa": (65536, 4096, 64, True, True),
+    "msa_from_pair": (4096, 65536, 64, True, True),
+    "ring_local_pair_from_msa": (32768, 2048, 64, True, True),
+    "ring_local_msa_from_pair": (2048, 32768, 64, True, True),
+    "pair_axial": (256, 256, 64, True, True),
+    "one_short_axis": (65536, 86, 64, True, True),
+    # the kernel takes one head size, a multiple of 128 once it is over 128,
+    # and nothing pads heads: 192 stays off it (the language model's causal
+    # core at 192/128 has a kernel of its own, ops/mla.py)
+    "head_192": (8192, 8192, 192, True, False),
+    "head_256": (512, 512, 256, True, True),
+    "under_one_block_64": (64, 64, 64, True, False),
+    "under_one_block_100": (100, 100, 64, True, False),
+    "off_the_tpu": (65536, 4096, 64, False, False),
 }
 
 
 @pytest.mark.parametrize("name", sorted(ONE_RULE_CASES))
 def test_flat_path_and_ring_ask_one_rule(monkeypatch, name):
-    """``Attention``'s flat path (``mla.causal_core`` for the causal call) and
-    the ring both ask ``ops/flash.py`` ``flash_takes`` whether the stock
-    kernel serves the shape, and each takes the kernel exactly where it says
-    so. Shapes only (``jax.eval_shape``): the kernel and the ring's kernel
-    blocks are stand-ins that record that they were asked for."""
+    """``Attention``'s flat path and the ring both ask ``ops/flash.py``
+    ``flash_takes`` whether the stock kernel serves the shape, and each takes
+    the kernel exactly where it says so. Shapes only (``jax.eval_shape``):
+    the kernel and the ring's kernel blocks are stand-ins that record that
+    they were asked for."""
     import jax.experimental.pallas.ops.tpu.flash_attention as stock
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from alphafold2_tpu.ops import flash as flash_mod, mla
+    from alphafold2_tpu.ops import flash as flash_mod
     from alphafold2_tpu.parallel import seq_parallel as sp_mod
 
-    nq, nk, d, dv, causal, on_tpu, flat, ring = ONE_RULE_CASES[name]
-    answers = {True: [], False: []}  # by pads_heads
+    nq, nk, d, on_tpu, takes = ONE_RULE_CASES[name]
+    answers = []
     rule = flash_mod.flash_takes
 
-    def recording(nq, nk, head_dim, pads_heads=True):
-        answers[pads_heads].append(rule(nq, nk, head_dim, pads_heads))
-        return answers[pads_heads][-1]
+    def recording(nq, nk, head_dim):
+        answers.append(rule(nq, nk, head_dim))
+        return answers[-1]
 
     kernel_calls, ring_blocks = [], []
 
@@ -253,23 +255,18 @@ def test_flat_path_and_ring_ask_one_rule(monkeypatch, name):
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype)
 
-    if causal:
-        out = jax.eval_shape(
-            lambda q, k, v: mla.causal_core(q, k, v, d**-0.5),
-            shape(1, 1, nq, d), shape(1, 1, nk, d), shape(1, 1, nk, dv))
-        assert out.shape == (1, 1, nq, dv)
-    else:
-        attn = Attention(dim=8, heads=1, dim_head=d)
-        out = jax.eval_shape(
-            lambda x, c: attn.init_with_output(
-                jax.random.key(0), x, context=c)[0],
-            shape(1, nq, 8), shape(1, nk, 8))
-        assert out.shape == (1, nq, 8)
-    assert bool(kernel_calls) == flat
-    assert answers[True] and set(answers[True]) == {flat}
-    if flat:  # both axes padded to the kernel's lanes, wide heads to 128s
-        assert all(q % 128 == 0 and k % 128 == 0 and (w <= 128 or w % 128 == 0)
+    attn = Attention(dim=8, heads=1, dim_head=d)
+    out = jax.eval_shape(
+        lambda x, c: attn.init_with_output(
+            jax.random.key(0), x, context=c)[0],
+        shape(1, nq, 8), shape(1, nk, 8))
+    assert out.shape == (1, nq, 8)
+    assert bool(kernel_calls) == takes
+    assert answers and set(answers) == {takes}
+    if takes:  # both axes padded to the kernel's lanes, heads as they are
+        assert all(q % 128 == 0 and k % 128 == 0 and w == d
                    for q, k, w in kernel_calls)
+    del answers[:]
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
     spec = P(None, None, "sp", None)
@@ -281,10 +278,8 @@ def test_flat_path_and_ring_ask_one_rule(monkeypatch, name):
         shape(1, 1, 2 * nq, d), shape(1, 1, 2 * nk, d),
         shape(1, 1, 2 * nk, d), shape(1, 2 * nk, dtype=bool))
     assert out.shape == (1, 1, 2 * nq, d)
-    assert bool(ring_blocks) == ring
-    assert answers[False] == [ring]
-    if d <= 128:  # no head padding at stake: one question, one answer
-        assert flat == ring
+    assert bool(ring_blocks) == takes
+    assert answers == [takes]
 
 
 # (batch, heads, nq, nk, head_dim, dtype) as the wrapper hands them on: padded
@@ -366,7 +361,7 @@ def test_block_rule_gives_blocks_the_kernel_accepts(name):
 
 
 # the eleven blocks the rule gave the two flagship cells' shape classes when
-# PR 26 set it (fields in BlockSizes' order): a causal call must not move them
+# PR 26 set it (fields in BlockSizes' order)
 FLAGSHIP_BLOCKS = {
     "pair_from_msa": (512, 4096, 1024, 1, 1024, 2048, 1024, 256, 512, 512,
                       1024),
@@ -382,51 +377,5 @@ FLAGSHIP_BLOCKS = {
 def test_flagship_blocks_are_the_ones_pr26_measured(name):
     bs = block_sizes_for(*BLOCK_RULE_SHAPES[name])
     assert dataclasses.astuple(bs) == FLAGSHIP_BLOCKS[name]
-    assert bs == block_sizes_for(*BLOCK_RULE_SHAPES[name], causal=False)
 
 
-# (batch, heads, n, head_dim, dtype): causal calls, n x n
-CAUSAL_SHAPES = {
-    "lm_cell_head_256": (2, 32, 8192, 256, "bfloat16"),  # 192/128 padded
-    "lm_cell_head_192": (2, 32, 8192, 192, "bfloat16"),
-    "one_sequence": (1, 32, 8192, 256, "bfloat16"),
-    "odd_multiple": (2, 4, 11 * 128, 128, "bfloat16"),
-    "one_block": (3, 2, 128, 64, "float32"),
-    "long_float32": (1, 8, 32768, 256, "float32"),
-    "many_heads": (8, 64, 8192, 128, "bfloat16"),  # di past 1 GiB at 512
-}
-
-
-@pytest.mark.parametrize("name", sorted(CAUSAL_SHAPES))
-def test_causal_block_rule_gives_square_blocks_the_kernel_accepts(name):
-    """A causal call's blocks: what ``_verify_block`` and ``BlockSizes`` ask
-    (multiples of 128 dividing the axis, inner dividing major), the forward's
-    and dkv's grid steps square so that the kernel's whole-block skip above
-    the diagonal leaves little over, dq's ``di`` within 1 GiB."""
-    batch, heads, n, head_dim, dtype = CAUSAL_SHAPES[name]
-    bs = block_sizes_for(batch, heads, n, n, head_dim, dtype, causal=True)
-    assert bs.has_backward_blocks and bs.block_b == 1
-    blocks = dataclasses.asdict(bs)
-    blocks.pop("block_b")
-    for field, size in blocks.items():
-        assert size % 128 == 0 and 128 <= size <= n and n % size == 0, field
-    for major, minor in [
-        ("block_k_major", "block_k"),
-        ("block_q_major_dkv", "block_q_dkv"),
-        ("block_k_major_dkv", "block_k_dkv"),
-        ("block_k_major_dq", "block_k_dq"),
-    ]:
-        assert blocks[major] % blocks[minor] == 0, (major, minor)
-    row = head_dim * jnp.dtype(dtype).itemsize
-    assert max(bs.block_k_major, bs.block_k_major_dkv) * row <= max(
-        2**20, 128 * row)
-    assert bs.block_k_major_dq * batch * heads * n * 4 <= max(
-        2**30, 128 * batch * heads * n * 4)
-    # square steps wherever the 1 MiB K/V tile allows: the part computed
-    # above the diagonal is at most one block in (n / block + 1)
-    assert bs.block_k_major <= bs.block_q and bs.block_k_major_dkv \
-        <= bs.block_q_major_dkv
-    assert bs.block_k_major_dq <= bs.block_q_dq
-    if name.startswith("lm_cell"):
-        assert bs.block_q == bs.block_k_major == bs.block_q_major_dkv \
-            == bs.block_k_major_dkv >= 512

@@ -310,44 +310,10 @@ def _dense_causal(q, k, v, scale):
     return out
 
 
-def _fake_stock_kernel(seen):
-    """Stands where the stock Pallas kernel does (it runs on a TPU only):
-    dense attention under the segment ids and the causal flag it is handed,
-    noting what it was handed."""
-
-    def kernel(q, k, v, *, segment_ids=None, causal=False, sm_scale=1.0,
-               block_sizes=None, **kw):
-        seen.update(q=q.shape, k=k.shape, v=v.shape, causal=causal,
-                    blocks=block_sizes)
-        assert q.shape[-1] == k.shape[-1] == v.shape[-1]
-        logits = jnp.einsum("bhid,bhjd->bhij", q, k).astype(jnp.float32) \
-            * sm_scale
-        keep = jnp.ones(logits.shape[-2:], bool)
-        if causal:
-            keep = jnp.tril(keep)
-        keep = keep[None, None]
-        if segment_ids is not None:
-            keep = keep & (segment_ids.q[:, None, :, None]
-                           == segment_ids.kv[:, None, None, :])
-        p = jax.nn.softmax(jnp.where(keep, logits, -1e30), -1)
-        return jnp.einsum("bhij,bhjd->bhid", p, v.astype(jnp.float32)).astype(
-            q.dtype)
-
-    return kernel
-
-
-@pytest.mark.parametrize("path,length", [
-    ("dense", 40), ("dense", 128), ("dense", 200), ("dense", 384),
-    # under one 128 block the wrapper declines by design: no 40 here
-    ("through_flash_wrapper", 128), ("through_flash_wrapper", 200),
-    ("through_flash_wrapper", 384)])
-def test_causal_core_with_wider_query_key_heads(monkeypatch, path, length):
-    """q/k heads of 24 (16 + 8 rotary) against v heads of 16, causal,
-    rotary on interleaved pairs, against attention written out query by
-    query; also through ``ops/flash.py``'s wrapper (head sizes padded to a
-    common width, lengths to 128s, the causal flag and causal blocks handed
-    on), with a dense stand-in where the TPU kernel would run."""
-    b, h, nope, rope, dv = 2, 3, 16, 8, 16
+def _rotated_qkv(length, b=2, h=3, nope=16, rope=8, dv=16):
+    """q, k, v (B, H, S, .) as the model hands them to the core: rotary on
+    the last ``rope`` of q/k's ``nope + rope``, one rotary key head shared,
+    the softmax scale on q."""
     keys = jax.random.split(jax.random.key(length), 4)
     q = jax.random.normal(keys[0], (b, length, h, nope + rope))
     k_nope = jax.random.normal(keys[1], (b, length, h, nope))
@@ -360,25 +326,161 @@ def test_causal_core_with_wider_query_key_heads(monkeypatch, path, length):
         [k_nope, jnp.broadcast_to(
             mla.rotary_interleaved(k_rope, pos, 1e4), (b, length, h, rope))],
         -1)
-    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    seen = {}
-    if path == "through_flash_wrapper":
-        import jax.experimental.pallas.ops.tpu.flash_attention as stock
+    q = q * (nope + rope) ** -0.5
+    return tuple(t.transpose(0, 2, 1, 3) for t in (q, k, v))
 
-        from alphafold2_tpu.ops import flash
 
-        monkeypatch.setattr(flash, "flash_available", lambda: True)
-        monkeypatch.setattr(stock, "flash_attention", _fake_stock_kernel(seen))
-    scale = (nope + rope) ** -0.5
-    out = mla.causal_core(q, k, v, scale)
-    assert out.shape == (b, h, length, dv)
+@pytest.mark.parametrize("length", [40, 128, 200, 384])
+def test_causal_core_with_wider_query_key_heads(length):
+    """q/k heads of 24 (16 + 8 rotary) against v heads of 16, causal,
+    rotary on interleaved pairs, against attention written out query by
+    query: the dense path, which serves every length off the TPU."""
+    q, k, v = _rotated_qkv(length)
+    out = mla.causal_core(q, k, v)
+    assert out.shape == v.shape
     np.testing.assert_allclose(
-        out, _dense_causal(q, k, v, scale), rtol=2e-4, atol=2e-5)
-    if seen:
-        padded = length + (-length) % 128
-        assert seen["causal"] is True
-        assert seen["q"] == seen["k"] == seen["v"] == (b, h, padded, 24)
-        assert seen["blocks"].block_q == seen["blocks"].block_k_major
+        out, _dense_causal(q, k, v, 1.0), rtol=2e-4, atol=2e-5)
+
+
+def _dense_causal_jnp(q, k, v):
+    """The same in jnp, for gradients (float32)."""
+    s = q.shape[2]
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    p = jax.nn.softmax(
+        jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+@pytest.fixture
+def kernel_path_on_the_cpu(monkeypatch):
+    """The splash path wherever the process is: off the TPU ``causal_core``
+    builds the kernel in Pallas interpret mode."""
+    monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
+    cached = mla._causal_kernel
+    cached.cache_clear()
+    yield
+    cached.cache_clear()
+
+
+@pytest.mark.parametrize("backward", ["fused", "two_kernels"])
+@pytest.mark.parametrize("length", [128, 256, 384])
+@pytest.mark.parametrize("heads", ["24_16", "192_128"])
+def test_splash_path_agrees_with_dense_attention(
+        monkeypatch, kernel_path_on_the_cpu, heads, length, backward):
+    """The kernel ``causal_core`` takes on a TPU, interpreted here: q/k heads
+    wider than v heads at a small size and at the published 192/128, one to
+    three blocks of 128 (384: blocks on, under and over the diagonal), the
+    output and all three gradients against dense attention, with the fused
+    backward and with the two-kernel one (which the block rule takes where
+    the fused one's partial dq would pass its byte limit)."""
+    d_qk, d_v = map(int, heads.split("_"))
+    if backward == "two_kernels":
+        monkeypatch.setattr(mla, "PARTIAL_DQ_BYTES", 0)
+    blocks = mla.splash_block_sizes(2, length, d_qk, d_v, jnp.float32)
+    assert blocks.use_fused_bwd_kernel == (backward == "fused")
+    keys = jax.random.split(jax.random.key(length + d_qk), 4)
+    q = jax.random.normal(keys[0], (1, 2, length, d_qk)) * d_qk ** -0.5
+    k = jax.random.normal(keys[1], (1, 2, length, d_qk))
+    v = jax.random.normal(keys[2], (1, 2, length, d_v))
+    weight = jax.random.normal(keys[3], (1, 2, length, d_v))
+
+    def both(core):
+        return jax.value_and_grad(
+            lambda q, k, v: (core(q, k, v) * weight).sum(), argnums=(0, 1, 2))
+
+    (loss, grads), (want_loss, want) = (
+        both(c)(q, k, v) for c in (mla.causal_core, _dense_causal_jnp))
+    np.testing.assert_allclose(
+        mla.causal_core(q, k, v), _dense_causal(q, k, v, 1.0),
+        rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-4, atol=1e-4)
+    for name, got, ref_grad in zip("qkv", grads, want):
+        np.testing.assert_allclose(
+            got, ref_grad, rtol=2e-3, atol=2e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("length", [130, 200])
+def test_splash_path_pads_a_length_off_the_128_grid(
+        monkeypatch, kernel_path_on_the_cpu, length):
+    """No segment ids: under the causal mask the zero rows appended to q, k
+    and v reach no kept row, forward or backward."""
+    q, k, v = _rotated_qkv(length)
+    asked = []
+    real = mla._causal_kernel
+    monkeypatch.setattr(
+        mla, "_causal_kernel",
+        lambda *key, **kw: asked.append(key) or real(*key, **kw))
+
+    def grads(core):
+        return jax.grad(lambda q, k, v: (core(q, k, v) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    out = mla.causal_core(q, k, v)
+    assert out.shape == v.shape and asked[0][:2] == (3, 256)  # heads, padded
+    np.testing.assert_allclose(
+        out, _dense_causal(q, k, v, 1.0), rtol=2e-4, atol=2e-5)
+    for got, want in zip(grads(mla.causal_core), grads(_dense_causal_jnp)):
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
+
+
+def test_the_kernel_is_built_once_for_a_models_layers(kernel_path_on_the_cpu):
+    """Five layers, and their recomputation under ``nn.remat``, ask for one
+    (heads, length, head sizes, dtype): the causal mask's block schedule is
+    made once and found in the cache after that, across traces too."""
+    model = lm.MlaMoeLM(lm_config(num_layers=5))
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.key(0), tokens)
+
+    def loss(p):
+        return model.apply(p, tokens)["logits"].sum()
+
+    jax.jit(jax.grad(loss)).lower(params)
+    info = mla._causal_kernel.cache_info()
+    # asked for by init's trace, the forward's and the recomputation's
+    assert info.misses == 1 and info.hits >= 9
+
+
+# (heads, length, q/k head, v head, dtype): causal calls
+CAUSAL_SHAPES = {
+    "lm_cell": (32, 8192, 192, 128, "bfloat16"),
+    "equal_heads_256": (32, 8192, 256, 256, "bfloat16"),
+    "odd_multiple": (4, 11 * 128, 128, 128, "bfloat16"),
+    "one_block": (2, 128, 64, 64, "float32"),
+    "long_float32": (8, 32768, 256, 256, "float32"),
+    "many_heads": (64, 8192, 128, 128, "bfloat16"),
+    "many_heads_float32": (64, 8192, 128, 128, "float32"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CAUSAL_SHAPES))
+def test_splash_block_rule_gives_blocks_the_kernel_accepts(name):
+    """What the kernels' own checks ask (multiples of 128 dividing the
+    length, the compute block dividing the key block), square grid steps of
+    at most 1,024 (past it nothing compiles for a v5e), a tile of a block's
+    rows within 512 KiB, and the fused backward exactly where its partial dq
+    stays within ``PARTIAL_DQ_BYTES`` a sequence."""
+    heads, n, d_qk, d_v, dtype = CAUSAL_SHAPES[name]
+    bs = mla.splash_block_sizes(heads, n, d_qk, d_v, dtype)
+    assert bs.has_backward_blocks
+    itemsize = jnp.dtype(dtype).itemsize
+    sides = {bs.block_q, bs.block_kv, bs.block_q_dkv, bs.block_kv_dkv}
+    assert len(sides) == 1
+    side = sides.pop()
+    assert side % 128 == 0 and 128 <= side <= 1024 and n % side == 0
+    row = max(d_qk, d_v) * itemsize
+    assert side * row <= max(2**19, 128 * row)
+    for compute in (bs.block_kv_compute, bs.block_kv_dkv_compute):
+        assert compute % 128 == 0 and side % compute == 0
+    partial_dq = n // side * heads * n * d_qk * itemsize
+    assert bs.use_fused_bwd_kernel == (partial_dq <= mla.PARTIAL_DQ_BYTES)
+    if bs.use_fused_bwd_kernel:
+        assert bs.block_q_dq is None and bs.block_kv_dq is None
+    else:
+        assert bs.block_q_dq == bs.block_kv_dq == side
+    if name == "lm_cell":  # what the on-chip sweep chose (PERF.md, PR 31)
+        assert (side, bs.block_kv_compute, bs.block_kv_dkv_compute,
+                bs.use_fused_bwd_kernel) == (1024, 256, 512, True)
+        assert partial_dq == 805_306_368
 
 
 def test_rotary_turns_interleaved_pairs_by_position():
@@ -393,15 +495,6 @@ def test_rotary_turns_interleaved_pairs_by_position():
     # position 0 is left as it is; the reference turns the same way
     np.testing.assert_array_equal(out[:, 0], np.asarray(x)[:, 0])
     np.testing.assert_allclose(ref.rotary(x, 1e6), out, rtol=1e-6, atol=1e-6)
-
-
-def test_causal_core_refuses_unequal_lengths_on_the_kernel_path(monkeypatch):
-    from alphafold2_tpu.ops import flash
-
-    monkeypatch.setattr(flash, "flash_available", lambda: True)
-    q = jnp.ones((1, 2, 256, 24))
-    with pytest.raises(ValueError, match="Nq == Nk"):
-        flash.flash_attention(q, q[:, :, :128], q[:, :, :128], causal=True)
 
 
 # --------------------------------------------- (e) through train(), 3 steps ---

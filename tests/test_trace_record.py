@@ -143,6 +143,17 @@ def test_classify_a_scope(scope, expected):
 @pytest.mark.parametrize("scope,block", [
     ("jit(step)/jvp(MlaMoeLM)/layer_2/mla_attn/core/pallas_call",
      "fwd/mla_attn"),
+    # the splash kernels of the causal core, forward, recomputed and backward
+    ("jit(step)/jvp(MlaMoeLM)/layer_0/mla_attn/core/vmap(jit(_splash_"
+     "attention))/splash_mha_fwd_residuals/splash_mha_fwd_residuals/"
+     "pallas_call", "fwd/mla_attn"),
+    ("jit(step)/transpose(jvp(MlaMoeLM))/jvp(MlaMoeLM)/checkpoint/"
+     "rematted_computation/layer_4/mla_attn/core/vmap(jit(_splash_"
+     "attention))/splash_mha_fwd_residuals/splash_mha_fwd_residuals/"
+     "pallas_call", "bwd/mla_attn"),
+    ("jit(step)/transpose(jvp(MlaMoeLM))/jvp(MlaMoeLM)/checkpoint/layer_4/"
+     "mla_attn/core/vmap(jit(_splash_attention))/splash_mha_dkv_no_residuals"
+     "/splash_mha_dkv_no_residuals/pallas_call", "bwd/mla_attn"),
     ("jit(step)/transpose(jvp(MlaMoeLM))/jvp(MlaMoeLM)/checkpoint/"
      "rematted_computation/layer_3/moe/experts/mul", "bwd/moe"),
     ("jit(step)/transpose(jvp(MlaMoeLM))/jvp(MlaMoeLM)/checkpoint/layer_0/"
@@ -156,6 +167,34 @@ def test_the_programs_table_names_another_models_blocks(scope, block):
     """The log line's rows are the model's own modules whatever the model is
     called: what ``jvp(`` wraps, when it is no phase of the step."""
     assert profiler_mod.block_of(scope) == block
+
+
+def test_an_instruction_that_runs_over_lines_keeps_its_scope():
+    """The splash kernels' frontend attributes hold newlines, so the
+    compiled text breaks such an instruction over three lines with its
+    metadata on the last: the trace is joined on that ``op_name`` all the
+    same, and an instruction without one takes none from its neighbours."""
+    text = """HloModule jit_step, entry_computation_layout={()->f32[]}
+
+ENTRY %main () -> f32[] {
+  %copy-start.1 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%p.1)
+  %splash_mha_fwd_residuals.10 = (f32[2,1024,128]{2,1,0}, bf16[2,32,8192,128]{3,2,1,0}) custom-call(%copy-done.583), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 1024, \\"block_kv\\": 1024}"
+}}, metadata={op_name="jit(step)/jvp(MlaMoeLM)/layer_0/mla_attn/core/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/pallas_call" stack_frame_id=104}, backend_config={}
+  %copy-done.1 = f32[8]{0} copy-done(%copy-start.1)
+  ROOT %fusion.2 = f32[] fusion(%copy-done.1), kind=kLoop, metadata={op_name="jit(step)/jvp(loss)/reduce_sum"}
+}
+"""
+    module, scopes = profiler_mod.instruction_scopes(text)
+    assert module == "jit_step"
+    assert scopes == {
+        "splash_mha_fwd_residuals.10":
+            "jit(step)/jvp(MlaMoeLM)/layer_0/mla_attn/core/vmap(jit(_splash_"
+            "attention))/splash_mha_fwd_residuals/pallas_call",
+        "fusion.2": "jit(step)/jvp(loss)/reduce_sum",
+    }
+    assert profiler_mod.block_of(
+        scopes["splash_mha_fwd_residuals.10"]) == "fwd/mla_attn"
 
 
 # ------------------------------------- (b) spans on the profiler's clock ---
