@@ -221,6 +221,42 @@ def case_ring_flash_step(nq_local=32768, nk_local=2048):
                            f"{dense}")
 
 
+def case_grouped_causal_core(window=None, heads=28, groups=4, n=16384,
+                             width=128):
+    """The language models' causal core (ops/mla.py) as the grouped-query
+    model calls it at its cell's shape: 28 query heads over 4 key/value
+    heads of 128 at 16,384 positions in bf16, under the causal mask or a
+    window, forward and the two-kernel backward: three splash kernels lower,
+    and nothing the size of a head's logits or of keys broadcast to the
+    query heads exists in the module."""
+    import re
+    from unittest import mock
+
+    from alphafold2_tpu.ops import mla
+
+    q = jnp.ones((1, heads, n, width), jnp.bfloat16)
+    k = jnp.ones((1, groups, n, width), jnp.bfloat16)
+
+    def loss(q, k, v):
+        o = mla.causal_core(q, k, v, window=window)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    # the core asks JAX whether a TPU is there; this process is pinned to
+    # the CPU, so the case answers for the chip
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, k, k).lower(lowering_platforms=("tpu",)).as_text()
+    kernels = text.count("tpu_custom_call")
+    if kernels != 3:  # forward, dq, dkv
+        raise RuntimeError(f"expected 3 Mosaic kernels, lowered {kernels}")
+    big = sorted({
+        shape for shape in re.findall(r"tensor<([0-9x]+)x(?:f32|bf16)>", text)
+        if math.prod(int(d) for d in shape.split("x")) > heads * n * width
+    })
+    if big:
+        raise RuntimeError(f"arrays larger than the queries: {big}")
+
+
 def case_fused_axial_fwd(n=256):
     """The in-repo fused dense attention kernel (ops/pallas/axial.py) at
     the axial-pass shape, compiled-mode Mosaic lowering with a padding
@@ -373,6 +409,8 @@ CASES = [
     ("flash_bwd_256", case_flash_bwd_256),
     ("ring_flash_pair_from_msa", case_ring_flash_step),
     ("ring_flash_msa_from_pair", lambda: case_ring_flash_step(2048, 32768)),
+    ("grouped_causal_core_16k", case_grouped_causal_core),
+    ("grouped_window_core_16k", lambda: case_grouped_causal_core(4096)),
     ("fused_axial_fwd_256", case_fused_axial_fwd),
     ("fused_axial_bwd_256", case_fused_axial_bwd),
     ("tied_row_fwd_256", case_tied_row_fwd),

@@ -19,7 +19,8 @@ from typing import Optional, Tuple
 @dataclass
 class ModelConfig:
     # which (model, loss, batch spec) train() builds: "alphafold2" (the axial
-    # trunk, the fields below) | "mla_moe_lm" (the ``lm`` section)
+    # trunk, the fields below) | "mla_moe_lm" (the ``lm`` section) |
+    # "swa_moe_lm" (the ``swa`` section)
     arch: str = "alphafold2"
     dim: int = 256  # trunk embedding width (single-repr channels)
     max_seq_len: int = 2048  # positional-embedding table size (max residues)
@@ -87,6 +88,37 @@ class LMConfig:
     rms_norm_eps: float = 1e-6  # inside every RMSNorm's rsqrt
     # the share: experts first_expert .. first_expert + experts_held - 1
     experts_held: int = 128  # routed experts this chip holds a layer
+    first_expert: int = 0  # id of the first expert held
+    bfloat16: bool = True  # compute dtype (weights stay float32)
+
+
+@dataclass
+class SwaLMConfig:
+    """Decoder-only language model with grouped-query attention, one global
+    layer without positions among window layers under rotary ones, and
+    softmax-routed ReGLU experts chosen from the stream that enters the
+    layer (models/swa_moe_lm.py), read when ``model.arch`` is
+    ``swa_moe_lm``. The defaults are the published sizes of a
+    SmallThinker-shaped 21B-A3B model, whole; the share (``experts_held``,
+    ``first_expert``, a slice of the vocabulary) and fewer layers are for the
+    caller to set, as in ``LMConfig``."""
+
+    vocab_size: int = 151936  # vocabulary rows held here (ids 0..vocab_size-1)
+    hidden_size: int = 2560  # residual stream width
+    num_layers: int = 52  # blocks, every one an expert layer
+    num_heads: int = 28  # query heads
+    num_kv_heads: int = 4  # key/value heads: query head h reads h // (28 / 4)
+    head_dim: int = 128  # width of every head
+    sliding_window: int = 4096  # keys a window layer's query sees, its own too
+    global_every: int = 4  # layer i is global (full causal, no positions)
+    # where i % global_every == 0, a window layer under rotary ones otherwise
+    moe_intermediate_size: int = 768  # one expert's ReGLU width
+    n_routed_experts: int = 64  # the router's width, held or not
+    num_experts_per_tok: int = 6  # experts a token is routed to
+    rope_theta: float = 1.5e6  # rotary base
+    rms_norm_eps: float = 1e-6  # inside every RMSNorm's rsqrt
+    # the share: experts first_expert .. first_expert + experts_held - 1
+    experts_held: int = 64  # routed experts this chip holds a layer
     first_expert: int = 0  # id of the first expert held
     bfloat16: bool = True  # compute dtype (weights stay float32)
 
@@ -228,6 +260,7 @@ def _tuplify(section, name):
 class Config:
     model: ModelConfig = field(default_factory=ModelConfig)  # architecture
     lm: LMConfig = field(default_factory=LMConfig)  # model.arch "mla_moe_lm"
+    swa: SwaLMConfig = field(default_factory=SwaLMConfig)  # "swa_moe_lm"
     mesh: MeshConfig = field(default_factory=MeshConfig)  # device mesh axes
     data: DataConfig = field(default_factory=DataConfig)  # dataset + features
     train: TrainConfig = field(default_factory=TrainConfig)  # optimizer loop
@@ -236,12 +269,18 @@ class Config:
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self), indent=2)
 
+    def language_model(self):
+        """The section of the language model that ``model.arch`` names: its
+        ``vocab_size`` is what ``data.source`` "tokens" draws over."""
+        return self.swa if self.model.arch == "swa_moe_lm" else self.lm
+
     @classmethod
     def from_json(cls, s: str) -> "Config":
         raw = json.loads(s)
         return cls(
             model=ModelConfig(**raw.get("model", {})),
             lm=LMConfig(**raw.get("lm", {})),
+            swa=SwaLMConfig(**raw.get("swa", {})),
             mesh=MeshConfig(**raw.get("mesh", {})),
             data=DataConfig(**raw.get("data", {})),
             train=_tuplify(TrainConfig(**raw.get("train", {})), "profile_steps"),

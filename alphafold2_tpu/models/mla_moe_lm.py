@@ -135,12 +135,7 @@ class ExpertLayer(nn.Module):
     def __call__(self, x):
         c = self.cfg
         b, s, d = x.shape
-        held, width, k = c.experts_held, c.moe_intermediate_size, \
-            c.num_experts_per_tok
-        if c.first_expert < 0 or c.first_expert + held > c.n_routed_experts:
-            raise ValueError(
-                f"experts {c.first_expert}..{c.first_expert + held - 1} are "
-                f"not among the router's {c.n_routed_experts}")
+        held, width = c.experts_held, c.moe_intermediate_size
         tokens = x.reshape(b * s, d)
         with jax.named_scope("router"):
             w_router = self.param(
@@ -151,19 +146,15 @@ class ExpertLayer(nn.Module):
             bias = self.param(
                 "router_bias", nn.initializers.zeros, (c.n_routed_experts,))
             experts, weights = moe.route(
-                tokens, w_router, bias, k, c.routed_scaling_factor)
+                tokens, w_router, bias, c.num_experts_per_tok,
+                c.routed_scaling_factor)
         w_gate = self.param("w_gate", fan_in_normal(1), (held, d, width))
         w_up = self.param("w_up", fan_in_normal(1), (held, d, width))
         w_down = self.param("w_down", fan_in_normal(1), (held, width, d))
-        with jax.named_scope("dispatch"):
-            plan = moe.dispatch(
-                experts, c.first_expert, held, c.n_routed_experts)
-            rows = moe.gather_rows(tokens, plan, k)
-        with jax.named_scope("experts"):
-            rows = moe.expert_ffn(
-                rows, plan["group_sizes"], w_gate, w_up, w_down, self.dtype)
-        with jax.named_scope("combine"):
-            routed = moe.combine(rows, weights, plan, k).reshape(b, s, d)
+        routed, plan = moe.held_experts_sum(
+            tokens, experts, weights, w_gate, w_up, w_down, c.first_expert,
+            c.n_routed_experts, self.dtype)
+        routed = routed.reshape(b, s, d)
         shared = SwiGLU(c.n_shared_experts * width, self.dtype,
                         name="shared")(x)
         return shared + routed, moe.load_counters(plan)
@@ -210,25 +201,36 @@ class MlaMoeLM(nn.Module):
         """tokens (B, S) int32 -> {"logits" (B, S, vocab) float32, "moe":
         the routing counters, each stacked over the expert layers}."""
         c = self.cfg
-        dtype = jnp.bfloat16 if c.bfloat16 else jnp.float32
-        x = nn.Embed(c.vocab_size, c.hidden_size, dtype=dtype,
-                     embedding_init=fan_in_normal(1), name="embed")(tokens)
         # a layer's forward is recomputed in the backward pass: at 16,384
         # tokens one layer's activations are 2.6 GB
         block = nn.remat(Block)
-        counters = []
-        for i in range(c.num_layers):
-            x, counted = block(c, i < c.first_k_dense, dtype,
-                               name=f"layer_{i}")(x)
-            if counted:
-                counters.append(counted)
-        x = RMSNorm(c.rms_norm_eps, dtype, name="final_norm")(x)
-        logits = Head(c.vocab_size, dtype, name="head")(x)
-        return {
-            "logits": logits,
-            "moe": jax.tree.map(lambda *v: jnp.stack(v), *counters)
-            if counters else {},
-        }
+        return decoder_stack(
+            tokens, c, lambda i, dtype: block(
+                c, i < c.first_k_dense, dtype, name=f"layer_{i}"))
+
+
+def decoder_stack(tokens, cfg, layer):
+    """Embedding, ``cfg.num_layers`` blocks, final RMSNorm and untied head,
+    made inside the calling model's ``__call__`` (their parameters are the
+    model's). ``layer(i, dtype)`` is block ``i``, a module that maps the
+    stream to (stream, its routing counters or {}); ``cfg`` gives
+    ``vocab_size``, ``hidden_size``, ``num_layers``, ``rms_norm_eps`` and
+    ``bfloat16``."""
+    dtype = jnp.bfloat16 if cfg.bfloat16 else jnp.float32
+    x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=dtype,
+                 embedding_init=fan_in_normal(1), name="embed")(tokens)
+    counters = []
+    for i in range(cfg.num_layers):
+        x, counted = layer(i, dtype)(x)
+        if counted:
+            counters.append(counted)
+    x = RMSNorm(cfg.rms_norm_eps, dtype, name="final_norm")(x)
+    logits = Head(cfg.vocab_size, dtype, name="head")(x)
+    return {
+        "logits": logits,
+        "moe": jax.tree.map(lambda *v: jnp.stack(v), *counters)
+        if counters else {},
+    }
 
 
 def next_token_cross_entropy(logits, tokens):
@@ -248,7 +250,7 @@ def next_token_cross_entropy(logits, tokens):
 # ------------------------------------- what train.loop makes a Task of ---
 
 
-def forward(model: MlaMoeLM, params, batch: dict, rng=None):
+def forward(model: nn.Module, params, batch: dict, rng=None):
     del rng  # no dropout
     return model.apply(params, batch["tokens"])
 
@@ -264,7 +266,7 @@ def step_metrics(outputs: dict) -> dict:
     return {f"moe/{k}": v for k, v in outputs["moe"].items()}
 
 
-def init(model: MlaMoeLM, rng, batch: dict):
+def init(model: nn.Module, rng, batch: dict):
     return model.init(rng, jnp.asarray(batch["tokens"]))
 
 

@@ -1,18 +1,25 @@
-"""The pieces of latent attention (MLA) that are not projections: rotary
-positions on interleaved pairs, and the causal core whose query/key heads
-are wider than its value heads (192 = 128 + 64 rotary against 128).
+"""The pieces of the language models' attention that are not projections:
+rotary positions (on interleaved pairs, or on the two halves of a head) and
+the causal core, which serves latent attention (MLA: query/key heads of 192
+= 128 + 64 rotary against value heads of 128, every head with its own keys)
+and grouped-query attention (28 query heads over 4 key/value heads, a
+sliding window in some layers) alike.
 
-Training uses the unabsorbed form: keys and values are expanded from the
+MLA trains in the unabsorbed form: keys and values are expanded from the
 latent and attended as ordinary heads. The absorbed form (scores against
 the latent itself) is for decoding through a cache, which this tree does
 not have.
 
-The core runs at the published head sizes: on a TPU it is the splash kernel
-shipped with JAX (``jax.experimental.pallas.ops.tpu.splash_attention``),
-which takes a value head narrower than the query/key head, schedules only
-the blocks a causal mask leaves and keeps its softmax statistics at (heads,
-positions). Nothing is padded to a common head size (PERF.md section 6,
-PR 31, has the A/B against the stock flash kernel at 256).
+The core runs at the published head sizes and head counts: on a TPU it is
+the splash kernel shipped with JAX
+(``jax.experimental.pallas.ops.tpu.splash_attention``), which takes a value
+head narrower than the query/key head and fewer key/value heads than query
+heads (query head h reads key/value head h // (H / G): nothing is broadcast
+to H), schedules only the blocks its mask leaves (the causal half, or the
+band of a window) and keeps its softmax statistics at (heads, positions).
+Nothing is padded to a common head size (PERF.md section 6, PR 31, has the
+A/B against the stock flash kernel at 256; PR 32 the grouped, windowed
+calls).
 """
 
 from __future__ import annotations
@@ -38,6 +45,22 @@ def rotary_interleaved(x, positions, theta: float):
     return turned.reshape(x.shape).astype(x.dtype)
 
 
+def rotary_half_split(x, positions, theta: float):
+    """Turn the pairs (i, i + width/2) of the last axis by ``positions *
+    theta**(-2i / width)``: the convention of the decoders whose config
+    carries no ``rope_interleave``. ``x`` (..., S, H, width), ``positions``
+    (S,). Float32 inside, ``x``'s type out."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None, None] * inv_freq  # S,1,w/2
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32)
+    low, high = x32[..., :half], x32[..., half:]
+    return jnp.concatenate(
+        [low * cos - high * sin, high * cos + low * sin], axis=-1
+    ).astype(x.dtype)
+
+
 def causal_kernel_takes(length: int) -> bool:
     """Whether the splash kernel serves a causal call of this length: on a
     TPU from one 128 block up. Below that the logits are a few KB a head and
@@ -58,13 +81,17 @@ def _block(n: int, cap: int) -> int:
 # sums them afterwards: (length / block, heads, length, d_qk), 0.8 GB a
 # sequence at the language-model cell's 32 heads x 8,192 x 192 in bf16. A
 # call whose partials would pass this many bytes a sequence takes the
-# two-kernel backward, which holds nothing of the kind.
+# two-kernel backward, which holds nothing of the kind (the grouped-query
+# cell's 28 heads x 16,384 x 128: 1.9 GB a layer).
 PARTIAL_DQ_BYTES = 2**30
 
 
 def splash_block_sizes(heads: int, length: int, d_qk: int, d_v: int, dtype):
     """The splash kernel's ``BlockSizes`` for one causal call, from its shape
-    alone. ``length`` is the padded length (a multiple of 128).
+    alone. ``heads`` are the query heads, ``length`` the padded length (a
+    multiple of 128). A window does not enter: its band is whole squares of
+    the same side plus the two it cuts (at 16,384 positions and a window of
+    4,096, 70 of the causal mask's 136 squares of 1,024).
 
     Every kernel takes square blocks, the largest 128-multiple under the cap
     that divides the length (any padded length gets a valid set; one that
@@ -100,16 +127,21 @@ def splash_block_sizes(heads: int, length: int, d_qk: int, d_v: int, dtype):
 
 @functools.lru_cache(maxsize=16)
 def _causal_kernel(heads: int, length: int, d_qk: int, d_v: int,
-                   dtype_name: str, interpret: bool):
-    """The splash kernel of one (heads, length, head sizes, dtype): the
-    causal mask's block schedule is host-side work at trace time, done once
-    for a model's layers and their recomputation."""
+                   dtype_name: str, window, interpret: bool):
+    """The splash kernel of one (query heads, length, head sizes, dtype,
+    window): the mask's block schedule is host-side work at trace time, done
+    once for a model's layers of that kind and their recomputation. The
+    key/value heads are not part of it: the kernel reads their number off
+    the arrays it is called with."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
 
-    mask = sm.MultiHeadMask([sm.CausalMask((length, length))] * heads)
+    shape = (length, length)
+    one = sm.CausalMask(shape) if window is None else sm.LocalMask(
+        shape, window_size=(window - 1, 0), offset=0)
+    mask = sm.MultiHeadMask([one] * heads)
     # built under a trace or not, the schedule's arrays are constants
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mha(
@@ -118,35 +150,43 @@ def _causal_kernel(heads: int, length: int, d_qk: int, d_v: int,
                 heads, length, d_qk, d_v, jnp.dtype(dtype_name)))
 
 
-def causal_core(q, k, v):
-    """softmax(q k^T, keys 0..i for query i) v. ``q``, ``k`` (B, H, S, Dqk),
-    ``v`` (B, H, S, Dv); ``q`` carries the softmax scale (the kernel has
-    none, and scaling bf16 queries here would round them a second time: the
-    model folds it into the query projection's weights before their cast).
+def causal_core(q, k, v, window=None):
+    """softmax(q k^T, keys j <= i for query i, and i - j < ``window`` where
+    one is given) v. ``q`` (B, H, S, Dqk), ``k`` (B, G, S, Dqk), ``v`` (B, G,
+    S, Dv) with G dividing H: query head h reads key/value head h // (H / G),
+    and nothing is broadcast to H. ``q`` carries the softmax scale (the
+    kernel has none, and scaling bf16 queries here would round them a second
+    time: the models fold it into the query projection's weights before
+    their cast). A window that covers the sequence is no window.
 
     On a TPU from 128 positions up this is the splash kernel at the heads'
-    own sizes (a shape it refuses is an error: 32 heads of 8,192^2 float32
-    logits are 8.6 GB a sequence, there is no dense run to fall back to);
-    elsewhere and below 128 positions, dense ``jnp`` with float32 logits and
-    softmax.
+    own sizes and counts, under the mask the call names (a shape it refuses
+    is an error: 32 heads of 8,192^2 float32 logits are 8.6 GB a sequence,
+    there is no dense run to fall back to); elsewhere and below 128
+    positions, dense ``jnp`` with float32 logits and softmax.
 
     A length that is no multiple of 128 is zero-padded at the end and the
-    output sliced: under a causal mask no query sees a later key, so the
+    output sliced: under either mask no query sees a later key, so the
     padding reaches no kept row and needs no segment ids.
     """
-    _, h, s, d_qk = q.shape
-    d_v = v.shape[-1]
+    b, h, s, d_qk = q.shape
+    g, d_v = k.shape[1], v.shape[-1]
+    if window is not None and window >= s:
+        window = None
     if not causal_kernel_takes(s):
-        logits = jnp.einsum(
-            "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
-        keep = jnp.tril(jnp.ones((s, s), bool))
+        grouped = q.reshape(b, g, h // g, s, d_qk)
+        logits = jnp.einsum("bgrqd,bgkd->bgrqk", grouped, k,
+                            preferred_element_type=jnp.float32)
+        ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        keep = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
         p = jax.nn.softmax(jnp.where(keep, logits, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+        out = jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(v.dtype), v)
+        return out.reshape(b, h, s, d_v)
     pad = (-s) % 128
     if pad:
         q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for x in (q, k, v))
-    kernel = _causal_kernel(h, s + pad, d_qk, d_v, q.dtype.name,
+    kernel = _causal_kernel(h, s + pad, d_qk, d_v, q.dtype.name, window,
                             interpret=jax.default_backend() != "tpu")
     out = jax.vmap(kernel)(q, k, v)
     return out[:, :, :s] if pad else out

@@ -25,21 +25,33 @@ import jax
 import jax.numpy as jnp
 
 
+def router_logits(x, w_router):
+    """``x`` (T, D) against ``w_router`` (D, E) in float32."""
+    return jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision="highest", preferred_element_type=jnp.float32,
+    )
+
+
 def route(x, w_router, bias, top_k: int, scaling: float):
     """Sigmoid scores over all experts, the ``top_k`` largest ``score +
     bias`` a token, weights ``scaling * s / sum(selected s)`` (the bias picks
     and does not weigh). ``x`` (T, D), ``w_router`` (D, E), ``bias`` (E,).
     Returns (experts (T, k) int32, weights (T, k) float32)."""
-    logits = jnp.dot(
-        x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision="highest", preferred_element_type=jnp.float32,
-    )
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(router_logits(x, w_router))
     _, experts = jax.lax.top_k(
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
     weights = scaling * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), weights
+
+
+def route_softmax(x, w_router, top_k: int):
+    """The ``top_k`` largest logits a token, weights their softmax over the
+    selected (a softmax over all experts renormalised over the selected is
+    the same numbers). No bias, no scaling. Returns as :func:`route`."""
+    picked, experts = jax.lax.top_k(router_logits(x, w_router), top_k)
+    return experts.astype(jnp.int32), jax.nn.softmax(picked, axis=-1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -117,21 +129,48 @@ def grouped_matmul(rows, w, group_sizes):
         rows, w, group_sizes, preferred_element_type=rows.dtype)
 
 
-def expert_ffn(rows, group_sizes, w_gate, w_up, w_down, dtype):
-    """SwiGLU of each held expert over its own rows. Weights are stacked
-    (held, D, F) / (held, F, D); gate and up run as one product. Rows past
-    the groups are masked on the way in and on the way out, so zeros come
-    out of them and zeros go back into them: neither a token nor a token's
-    gradient sees what the kernel left there."""
+def expert_ffn(rows, group_sizes, w_gate, w_up, w_down, dtype,
+               activation=jax.nn.silu):
+    """``W_down (activation(W_gate x) * W_up x)`` of each held expert over
+    its own rows (SwiGLU as it stands, ReGLU with ``jax.nn.relu``). Weights
+    are stacked (held, D, F) / (held, F, D); gate and up run as one product.
+    Rows past the groups are masked on the way in and on the way out, so
+    zeros come out of them and zeros go back into them: neither a token nor
+    a token's gradient sees what the kernel left there."""
     live = (jnp.arange(rows.shape[0], dtype=jnp.int32)
             < group_sizes.sum())[:, None]
     w_in = jnp.concatenate([w_gate.astype(dtype), w_up.astype(dtype)], axis=-1)
     h = grouped_matmul(
         jnp.where(live, rows.astype(dtype), 0), w_in, group_sizes)
     gate, up = jnp.split(h, 2, axis=-1)
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * up
+    act = activation(gate.astype(jnp.float32)).astype(dtype) * up
     return jnp.where(
         live, grouped_matmul(act, w_down.astype(dtype), group_sizes), 0)
+
+
+def held_experts_sum(tokens, experts, weights, w_gate, w_up, w_down,
+                     first: int, n_experts: int, dtype,
+                     activation=jax.nn.silu):
+    """``sum over the experts held here of w_e Expert_e(token)`` for tokens
+    (T, D) routed to ``experts`` (T, k) under ``weights`` (T, k); the held
+    experts are ``first .. first + w_gate.shape[0] - 1`` of ``n_experts``.
+    Returns ((T, D), the dispatch plan, which :func:`load_counters` reads).
+    The three steps are the named scopes ``dispatch``, ``experts`` and
+    ``combine``."""
+    held, top_k = w_gate.shape[0], experts.shape[-1]
+    if first < 0 or first + held > n_experts:
+        raise ValueError(
+            f"experts {first}..{first + held - 1} are not among the "
+            f"router's {n_experts}")
+    with jax.named_scope("dispatch"):
+        plan = dispatch(experts, first, held, n_experts)
+        rows = gather_rows(tokens, plan, top_k)
+    with jax.named_scope("experts"):
+        rows = expert_ffn(rows, plan["group_sizes"], w_gate, w_up, w_down,
+                          dtype, activation)
+    with jax.named_scope("combine"):
+        out = combine(rows, weights, plan, top_k)
+    return out, plan
 
 
 def load_counters(plan) -> dict:

@@ -179,15 +179,20 @@ def build_task(cfg: Config) -> Task:
     architecture's modules are imported only when asked for."""
     if cfg.model.arch == "alphafold2":
         return trunk_task(build_model(cfg))
-    if cfg.model.arch == "mla_moe_lm":
+    if cfg.model.arch in ("mla_moe_lm", "swa_moe_lm"):
         from alphafold2_tpu.models import mla_moe_lm as lm
 
-        model = lm.MlaMoeLM(cfg.lm)
+        if cfg.model.arch == "mla_moe_lm":
+            model = lm.MlaMoeLM(cfg.lm)
+        else:
+            from alphafold2_tpu.models.swa_moe_lm import SwaMoeLM
+
+            model = SwaMoeLM(cfg.swa)
         return Task(model, partial(lm.forward, model), lm.loss,
                     lm.step_metrics, partial(lm.init, model), lm.tiny_batch)
     raise ValueError(
-        f"unknown model.arch {cfg.model.arch!r}; expected 'alphafold2' or "
-        "'mla_moe_lm'")
+        f"unknown model.arch {cfg.model.arch!r}; expected 'alphafold2', "
+        "'mla_moe_lm' or 'swa_moe_lm'")
 
 
 def build_optimizer(cfg: Config) -> optax.GradientTransformation:
@@ -601,7 +606,8 @@ def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler,
     data_seed = cfg.train.seed + 7919 * jax.process_index()
     with tracer.span("train.dataset"):
         dataset = dataset or make_dataset(
-            cfg.data, seed=data_seed, vocab_size=cfg.lm.vocab_size)
+            cfg.data, seed=data_seed,
+            vocab_size=cfg.language_model().vocab_size)
         data_iter = apply_features(iter(dataset), cfg)
 
     mesh = None

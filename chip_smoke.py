@@ -362,15 +362,19 @@ def _ref_attention_by_head(q, k, v, kv_mask, scale):
         jax.lax.map(jax.checkpoint(one_head), heads_first), 0, 1)
 
 
-def _ref_causal_by_head(q, k, v):
+def _ref_causal_by_head(q, k, v, window=None):
     """Causal softmax attention in float32, one head at a time, the scale
     on q already: q/k heads may be wider than v heads (latent attention's
-    192 against 128)."""
+    192 against 128), there may be fewer key/value heads than query heads
+    (query head h reads h // (H / G): repeated to H here), and a query may
+    see only the last ``window`` keys."""
     import jax
     import jax.numpy as jnp
 
     n = q.shape[2]
-    keep = jnp.tril(jnp.ones((n, n), bool))
+    ahead = jnp.arange(n)[:, None] - jnp.arange(n)[None, :]
+    keep = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+    k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
 
     def one_head(qkv):
         q1, k1, v1 = (t.astype(jnp.float32) for t in qkv)
@@ -533,20 +537,24 @@ def kernel_cases(small: bool = False) -> list:
             (q_shape, kv_shape, kv_shape), "bfloat16",
         ))
 
-    def mla_core(name, b, h, n, qk, dv):
-        """The causal core takes q with the softmax scale on it (the model's
-        query projection puts it there): both sides get the same scaled,
-        rounded q."""
+    def causal(name, b, h, n, qk, dv, groups=None, window=None):
+        """The language models' causal core. It takes q with the softmax
+        scale on it (the models' query projections put it there): both sides
+        get the same scaled, rounded q. ``groups``: key/value heads where
+        they are fewer than the ``h`` query heads; ``window``: the keys a
+        query sees (None: all before it)."""
         from alphafold2_tpu.ops.mla import causal_core
+
+        g = groups or h
 
         def scaled(q):
             return (q * qk ** -0.5).astype(q.dtype)
 
         cases.append((
             name,
-            lambda q, k, v: causal_core(scaled(q), k, v),
-            lambda q, k, v: _ref_causal_by_head(scaled(q), k, v),
-            ((b, h, n, qk), (b, h, n, qk), (b, h, n, dv)), "bfloat16",
+            lambda q, k, v: causal_core(scaled(q), k, v, window=window),
+            lambda q, k, v: _ref_causal_by_head(scaled(q), k, v, window),
+            ((b, h, n, qk), (b, g, n, qk), (b, g, n, dv)), "bfloat16",
         ))
 
     def grouped(name, rows, d, f, held):
@@ -573,7 +581,9 @@ def kernel_cases(small: bool = False) -> list:
         ))
 
     if small:
-        mla_core("mla_causal_core_small", 1, 2, 160, 24, 16)
+        causal("mla_causal_core_small", 1, 2, 160, 24, 16)
+        causal("swa_core_small_global", 1, 6, 160, 16, 16, groups=2)
+        causal("swa_core_small_window", 1, 6, 160, 16, 16, groups=2, window=50)
         grouped("moe_grouped_matmul_small", 256, 32, 16, 4)
         axial("fused_axial_f32", (2, 2, 32, 16), "float32", 0)
         axial("fused_axial_masked_odd", (1, 2, 40, 16), "float32", 7)
@@ -605,8 +615,15 @@ def kernel_cases(small: bool = False) -> list:
     # the language-model cell's two kernels at its shapes: causal, q/k heads
     # of 192 against v heads of 128 (the splash kernel, nothing padded), and
     # the grouped product over 16 held experts, an eighth of the rows live
-    mla_core("mla_causal_core_8k", 2, 32, 8192, 192, 128)
+    causal("mla_causal_core_8k", 2, 32, 8192, 192, 128)
     grouped("moe_grouped_matmul_16_experts", 6 * 16384, 2048, 768, 16)
+    # the second language model's attention at its cell's shape: 28 query
+    # heads over 4 key/value heads of 128 at 16,384 positions, a global
+    # layer's causal mask and a window layer's 4,096 keys (the two-kernel
+    # backward: the fused one's partial dq would be 1.9 GB)
+    causal("swa_core_16k_global", 1, 28, 16384, 128, 128, groups=4)
+    causal("swa_core_16k_window", 1, 28, 16384, 128, 128, groups=4,
+           window=4096)
     # the mesh cell's cross-attentions: a chip's blocks are 32,768 x 2,048
     # and 2,048 x 32,768 (only where there is a second chip for the ring)
     if len(jax.devices()) >= 2:

@@ -427,3 +427,91 @@ def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     print(f"compiled step, bytes on the device: {per_device}")
     assert 8e9 < per_device < 15.75 * 2**30
+
+
+# --------------------------------------- the grouped-query language model ---
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("window", [None, 4096])
+def test_grouped_window_core_compiles_for_v5e(one_chip, monkeypatch, window,
+                                              direction):
+    """The second language-model cell's attention: 28 query heads over 4
+    key/value heads of 128 at 16,384 positions, under the causal mask and
+    under the window of 4,096, through the splash kernel at the blocks
+    ``splash_block_sizes`` gives: forward, and the two-kernel backward (the
+    fused one's partial dq would be 1.9 GB). No dense logits, and no keys
+    broadcast to the query heads."""
+    import functools
+    import re
+
+    from alphafold2_tpu.ops import mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    core = functools.partial(mla.causal_core, window=window)
+    text = _compile(
+        core if direction == "fwd" else _grad_of(core), one_chip,
+        ((1, 28, 16384, 128), "bfloat16"), ((1, 4, 16384, 128), "bfloat16"),
+        ((1, 4, 16384, 128), "bfloat16"))
+    assert "tpu_custom_call" in text and "splash_mha_fwd" in text
+    if direction == "bwd":
+        assert "splash_mha_dkv" in text and "splash_mha_dq" in text
+    assert "16384,16384]" not in text  # no dense logits anywhere
+    # nothing of k's or v's is broadcast to the 28 query heads
+    assert not re.search(r"= bf16\[1,28,16384,128\]\S* broadcast\(", text)
+
+
+def test_swa_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole jitted train step of the benchmark's second language-model
+    cell (371 M parameters, 1 x 16,384 tokens) for one described chip: splash
+    kernels under both kinds of layer and XLA's ragged-product kernels are
+    in it, dense 16,384^2 logits are not, and weights + Adam + activations
+    fit the chip with room (under 14.5 GB)."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.train import loop
+    from benchmark.harness import common, train_swa_lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    resolved = common.resolve("train_smallthinker_ep8_seq16k")
+    cfg = train_swa_lm.program_config(
+        resolved["config"], resolved["traffic"], 1)
+    task = loop.build_task(cfg)
+    sample = next(iter(make_dataset(
+        cfg.data, vocab_size=cfg.language_model().vocab_size)))
+    assert sample["tokens"].shape == (1, 16384)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    state = jax.eval_shape(lambda: loop.tiny_init_state(cfg, task, sample))
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 370_547_200
+    rng = jax.eval_shape(lambda: jax.random.key(1))
+    compiled = loop.make_train_step(task, None, numerics_mode="norms").lower(
+        shapes(state), shapes({k: jnp.asarray(v) for k, v in sample.items()}),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # the three kernels, and each kind of layer's core has its own
+    for kernel in ("splash_mha_fwd", "splash_mha_dkv", "splash_mha_dq"):
+        assert kernel in text
+    from alphafold2_tpu.observe.profiler import instruction_scopes
+
+    kernel_scopes = [scope for name, scope in instruction_scopes(text)[1]
+                     .items() if "splash_mha" in scope]
+    for kind in ("attn_global/core", "attn_window/core"):
+        assert any(kind in scope for scope in kernel_scopes), kind
+    assert "flash_attention" not in text
+    assert "ragged-dot" in text  # the grouped product is a kernel, not dense
+    assert "16384,16384]" not in text
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"compiled step, bytes on the device: {per_device}")
+    assert 6e9 < per_device < 14.5e9

@@ -71,12 +71,14 @@ def test_kernel_phase_agrees_with_the_references(monkeypatch):
     monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
     rec = chip_smoke.phase_kernels(small=True)
     names = [c["case"] for c in rec["cases"]]
-    assert len(names) == 10 and all(c["ok"] for c in rec["cases"])
+    assert len(names) == 12 and all(c["ok"] for c in rec["cases"])
     for kernel in ("fused_axial", "tied_row", "block_sparse"):
         assert any(n.startswith(kernel) for n in names)
         assert any(n.startswith(kernel) and "masked" in n for n in names)
     # the language model's two: bfloat16 against float32 references
     assert {"mla_causal_core_small", "moe_grouped_matmul_small"} <= set(names)
+    # the grouped-query core under both of its masks
+    assert {"swa_core_small_global", "swa_core_small_window"} <= set(names)
     # the ring over two devices (jnp blocks here), one case masked
     assert {"ring_flash_small", "ring_flash_small_masked"} <= set(names)
     # float32 in interpret mode: far inside the chip's tolerance

@@ -443,6 +443,7 @@ def test_the_kernel_is_built_once_for_a_models_layers(kernel_path_on_the_cpu):
 # (heads, length, q/k head, v head, dtype): causal calls
 CAUSAL_SHAPES = {
     "lm_cell": (32, 8192, 192, 128, "bfloat16"),
+    "swa_cell": (28, 16384, 128, 128, "bfloat16"),  # 28 query heads over 4
     "equal_heads_256": (32, 8192, 256, 256, "bfloat16"),
     "odd_multiple": (4, 11 * 128, 128, 128, "bfloat16"),
     "one_block": (2, 128, 64, 64, "float32"),
@@ -481,6 +482,10 @@ def test_splash_block_rule_gives_blocks_the_kernel_accepts(name):
         assert (side, bs.block_kv_compute, bs.block_kv_dkv_compute,
                 bs.use_fused_bwd_kernel) == (1024, 256, 512, True)
         assert partial_dq == 805_306_368
+    if name == "swa_cell":  # 1.88 GB of partial dq: the two-kernel backward
+        assert (side, bs.use_fused_bwd_kernel, bs.block_q_dq) == (
+            1024, False, 1024)
+        assert partial_dq == 1_879_048_192
 
 
 def test_rotary_turns_interleaved_pairs_by_position():
