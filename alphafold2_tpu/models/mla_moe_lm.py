@@ -201,12 +201,21 @@ class MlaMoeLM(nn.Module):
         """tokens (B, S) int32 -> {"logits" (B, S, vocab) float32, "moe":
         the routing counters, each stacked over the expert layers}."""
         c = self.cfg
-        # a layer's forward is recomputed in the backward pass: at 16,384
-        # tokens one layer's activations are 2.6 GB
-        block = nn.remat(Block)
+        block = remat_layer(Block)
         return decoder_stack(
             tokens, c, lambda i, dtype: block(
                 c, i < c.first_k_dense, dtype, name=f"layer_{i}"))
+
+
+def remat_layer(block):
+    """``block`` under ``nn.remat``: the backward pass recomputes a layer
+    from its input (at 16,384 tokens one layer's activations are 2.6 GB)
+    and keeps across that only what the causal core's kernel names, its
+    output and log-sum-exp (0.14 GB a layer), so the recomputation runs
+    every operation of the layer but the attention kernel."""
+    return nn.remat(
+        block, policy=jax.checkpoint_policies.save_only_these_names(
+            mla.CORE_RESIDUALS))
 
 
 def decoder_stack(tokens, cfg, layer):

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from alphafold2_tpu.config import SwaLMConfig
 from alphafold2_tpu.models.mla_moe_lm import (
-    RMSNorm, ScaledDense, _dense, decoder_stack, fan_in_normal,
+    RMSNorm, ScaledDense, _dense, decoder_stack, fan_in_normal, remat_layer,
 )
 from alphafold2_tpu.ops import mla, moe
 
@@ -133,8 +133,7 @@ class SwaMoeLM(nn.Module):
         """tokens (B, S) int32 -> {"logits" (B, S, vocab) float32, "moe":
         the routing counters, each stacked over the layers}."""
         c = self.cfg
-        # a layer's forward is recomputed in the backward pass
-        block = nn.remat(Block)
+        block = remat_layer(Block)
         return decoder_stack(
             tokens, c, lambda i, dtype: block(
                 c, None if i % c.global_every == 0 else c.sliding_window,

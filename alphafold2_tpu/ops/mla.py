@@ -85,6 +85,13 @@ def _block(n: int, cap: int) -> int:
 # cell's 28 heads x 16,384 x 128: 1.9 GB a layer).
 PARTIAL_DQ_BYTES = 2**30
 
+# The name (``jax.ad_checkpoint.checkpoint_name``) the kernel's forward gives
+# its two results, the output (B, H, S, Dv) and the log-sum-exp (B, H, S)
+# float32: all its backward needs beside q, k and v. A ``jax.checkpoint``
+# whose policy saves this name keeps them, and its recomputation runs no
+# forward kernel. The dense path names nothing: it has no log-sum-exp.
+CORE_RESIDUALS = "causal_core"
+
 
 def splash_block_sizes(heads: int, length: int, d_qk: int, d_v: int, dtype):
     """The splash kernel's ``BlockSizes`` for one causal call, from its shape
@@ -146,6 +153,7 @@ def _causal_kernel(heads: int, length: int, d_qk: int, d_v: int,
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mha(
             mask, head_shards=1, q_seq_shards=1, interpret=interpret,
+            residual_checkpoint_name=CORE_RESIDUALS,
             block_sizes=splash_block_sizes(
                 heads, length, d_qk, d_v, jnp.dtype(dtype_name)))
 

@@ -542,19 +542,28 @@ def kernel_cases(small: bool = False) -> list:
         scale on it (the models' query projections put it there): both sides
         get the same scaled, rounded q. ``groups``: key/value heads where
         they are fewer than the ``h`` query heads; ``window``: the keys a
-        query sees (None: all before it)."""
-        from alphafold2_tpu.ops.mla import causal_core
+        query sees (None: all before it). A sixth entry: the core under
+        ``jax.checkpoint`` as the models' layers are, keeping what the core
+        names (its recomputation runs no forward kernel) and keeping nothing
+        (it runs it again): the same kernels on the same operands, so the
+        two are to agree outright."""
+        from alphafold2_tpu.ops.mla import CORE_RESIDUALS, causal_core
 
         g = groups or h
 
         def scaled(q):
             return (q * qk ** -0.5).astype(q.dtype)
 
+        def core(q, k, v):
+            return causal_core(scaled(q), k, v, window=window)
+
         cases.append((
-            name,
-            lambda q, k, v: causal_core(scaled(q), k, v, window=window),
+            name, core,
             lambda q, k, v: _ref_causal_by_head(scaled(q), k, v, window),
             ((b, h, n, qk), (b, g, n, qk), (b, g, n, dv)), "bfloat16",
+            (jax.checkpoint(
+                core, policy=jax.checkpoint_policies.save_only_these_names(
+                    CORE_RESIDUALS)), jax.checkpoint(core)),
         ))
 
     def grouped(name, rows, d, f, held):
@@ -636,7 +645,11 @@ def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
     """Forward and gradient of every case (whose name holds ``only``) against
     its reference. The reference runs in float32 at the highest matmul
     precision on the same (dtype-rounded) inputs; the error is the largest
-    absolute difference over the reference's largest entry."""
+    absolute difference over the reference's largest entry. The causal
+    core's cases read one more, ``kept_vs_recomputed``: the largest such
+    error, over output and gradients, between the core under a
+    ``jax.checkpoint`` that keeps its named results and under one that
+    recomputes them."""
     import jax
     import jax.numpy as jnp
 
@@ -653,7 +666,7 @@ def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
                                           has_aux=True))
 
     results, failed = [], []
-    for i, (name, kernel, ref, shapes, dtype) in enumerate(
+    for i, (name, kernel, ref, shapes, dtype, *under_remat) in enumerate(
             kernel_cases(small)):
         if only not in name:
             continue
@@ -670,6 +683,12 @@ def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
             **{f"d{n}": rel_err(g, gr)
                for n, g, gr in zip("qkv", grads, grads_r)},
         }
+        for kept, recomputed in under_remat:
+            ((_, out), grads), ((_, out_r), grads_r) = (
+                fwd_and_grad(fn)(*args) for fn in (kept, recomputed))
+            errs["kept_vs_recomputed"] = max(
+                rel_err(a, b)
+                for a, b in zip((out, *grads), (out_r, *grads_r)))
         tol = KERNEL_TOL[dtype]
         ok = all(e == e and e <= tol for e in errs.values())  # e==e: no NaN
         if not ok:

@@ -57,6 +57,51 @@ def no_implicit_transfers():
         yield
 
 
+@pytest.fixture
+def named_eqns():
+    """``named_eqns(primitive, fn, *args)``: the equations of that primitive
+    (``"pallas_call"``: a kernel call; ``"name"``: a ``checkpoint_name``) in
+    ``fn``'s jaxpr, counted by their ``name``. Every site counts, wherever
+    it sits (a jitted call, a remat's recomputation, the bodies ``jax.grad``
+    has made of a custom VJP); the pretty-printed jaxpr would not do, it
+    prints a shared sub-jaxpr once."""
+    import collections
+
+    import jax
+
+    from alphafold2_tpu.analysis.jaxpr_audit import iter_eqns
+
+    def count(primitive, fn, *args):
+        return collections.Counter(
+            eqn.params["name"]
+            for eqn in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == primitive)
+
+    return count
+
+
+@pytest.fixture
+def kept_across_remat(capsys):
+    """``kept_across_remat(layer, *args)``: what
+    ``jax.ad_checkpoint.print_saved_residuals`` lists for the gradient of a
+    Flax ``layer``'s first output (type and shape, sorted), the layer's own
+    arguments (parameters, inputs) and constants (a mask's block schedule)
+    left out: what a remat'd layer keeps between forward and backward."""
+    import jax
+
+    def kept(layer, *args):
+        variables = jax.eval_shape(layer.init, jax.random.key(0), *args)
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(
+            lambda p, *a: layer.apply(p, *a)[0], variables, *args)
+        return sorted(
+            line.split()[0] for line in capsys.readouterr().out.splitlines()
+            if " from the argument " not in line
+            and " from a constant" not in line)
+
+    return kept
+
+
 class LockWitness:
     """Test-only instrumented-lock recorder for validating the static
     lock-order graph (alphafold2_tpu/analysis/concurrency.py) against

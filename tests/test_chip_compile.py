@@ -383,12 +383,24 @@ def test_causal_latent_core_compiles_for_v5e(one_chip, monkeypatch, length,
     assert "256]" not in text  # no head padded to 256
 
 
+def _kernel_scopes(text, kernel):
+    """The scope of every call of the Pallas kernel ``kernel`` in a compiled
+    program's text: XLA names the custom call after the kernel
+    (``kernel.N``), as a trace's device operations are named."""
+    from alphafold2_tpu.observe.profiler import instruction_scopes
+
+    return [scope for name, scope in instruction_scopes(text)[1].items()
+            if name.split(".")[0] == kernel]
+
+
 def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted train step of the benchmark's language-model cell
     (576 M parameters, 2 x 8,192 tokens) for one described chip: the splash
     kernels and XLA's ragged-product kernels are in it, the stock flash
-    kernel and dense 8,192^2 logits are not, and weights + Adam + activations
-    fit 16 GB."""
+    kernel and dense 8,192^2 logits are not, the forward kernel runs once a
+    layer (the layers' recomputation finds its output and log-sum-exp kept),
+    and weights + Adam + activations fit the 15.75 GiB the compiler
+    leaves."""
     import os
     import sys
 
@@ -418,7 +430,8 @@ def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
     ).compile()
     text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert len(_kernel_scopes(text, "splash_mha_fwd_residuals")) == 5
+    assert len(_kernel_scopes(text, "splash_mha_dkv_no_residuals")) == 5
     assert "flash_attention" not in text and "flash_mha_bwd" not in text
     assert "ragged-dot" in text  # the grouped product is a kernel, not dense
     assert "32,8192,8192]" not in text and "64,8192,8192]" not in text
@@ -465,8 +478,10 @@ def test_swa_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted train step of the benchmark's second language-model
     cell (371 M parameters, 1 x 16,384 tokens) for one described chip: splash
     kernels under both kinds of layer and XLA's ragged-product kernels are
-    in it, dense 16,384^2 logits are not, and weights + Adam + activations
-    fit the chip with room (under 14.5 GB)."""
+    in it, each kernel once a layer (the forward too: the layers'
+    recomputation finds its output and log-sum-exp kept), dense 16,384^2
+    logits are not, and weights + Adam + activations fit the chip with room
+    (under 14.5 GB of the 15.75 GiB the compiler leaves)."""
     import os
     import sys
 
@@ -498,15 +513,14 @@ def test_swa_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
     ).compile()
     text = compiled.as_text()
-    # the three kernels, and each kind of layer's core has its own
-    for kernel in ("splash_mha_fwd", "splash_mha_dkv", "splash_mha_dq"):
-        assert kernel in text
-    from alphafold2_tpu.observe.profiler import instruction_scopes
-
-    kernel_scopes = [scope for name, scope in instruction_scopes(text)[1]
-                     .items() if "splash_mha" in scope]
-    for kind in ("attn_global/core", "attn_window/core"):
-        assert any(kind in scope for scope in kernel_scopes), kind
+    # the three kernels, each once a layer: one global layer's, three window
+    # layers'
+    for kernel in ("splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals",
+                   "splash_mha_dq_no_residuals"):
+        scopes = _kernel_scopes(text, kernel)
+        assert len(scopes) == 4, kernel
+        assert sum("attn_global/core" in scope for scope in scopes) == 1
+        assert sum("attn_window/core" in scope for scope in scopes) == 3
     assert "flash_attention" not in text
     assert "ragged-dot" in text  # the grouped product is a kernel, not dense
     assert "16384,16384]" not in text

@@ -79,6 +79,11 @@ def test_kernel_phase_agrees_with_the_references(monkeypatch):
     assert {"mla_causal_core_small", "moe_grouped_matmul_small"} <= set(names)
     # the grouped-query core under both of its masks
     assert {"swa_core_small_global", "swa_core_small_window"} <= set(names)
+    # the three cores under a remat that keeps their named results and under
+    # one that runs the forward kernel again: the same numbers
+    kept = [c["kept_vs_recomputed"] for c in rec["cases"]
+            if "kept_vs_recomputed" in c]
+    assert kept == [0.0, 0.0, 0.0]
     # the ring over two devices (jnp blocks here), one case masked
     assert {"ring_flash_small", "ring_flash_small_masked"} <= set(names)
     # float32 in interpret mode: far inside the chip's tolerance

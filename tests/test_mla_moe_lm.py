@@ -11,6 +11,7 @@ import itertools
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -438,6 +439,69 @@ def test_the_kernel_is_built_once_for_a_models_layers(kernel_path_on_the_cpu):
     info = mla._causal_kernel.cache_info()
     # asked for by init's trace, the forward's and the recomputation's
     assert info.misses == 1 and info.hits >= 9
+
+
+def _logits_sum_grad(model, toks):
+    return jax.grad(lambda p: model.apply(p, toks)["logits"].sum())
+
+
+@pytest.mark.parametrize("remat, forwards", [("kept", 2), ("bare", 4)])
+def test_the_forward_kernel_runs_once_a_layer(
+        monkeypatch, kernel_path_on_the_cpu, named_eqns, remat, forwards):
+    """Two layers: the gradient calls the forward kernel twice and the
+    backward kernel twice, because each layer's remat keeps what the core
+    names and its recomputation's forward call is dead code. Under a bare
+    ``nn.remat`` the recomputation calls it again: were that count 2 as
+    well, the name or the policy would have gone inert."""
+    if remat == "bare":
+        monkeypatch.setattr(lm, "remat_layer", nn.remat)
+    model = lm.MlaMoeLM(lm_config())
+    toks = tokens(batch=1, seq=128)
+    shapes = jax.eval_shape(model.init, jax.random.key(0), toks)
+    assert named_eqns(
+            "pallas_call", _logits_sum_grad(model, toks), shapes) == {
+        "splash_mha_fwd_residuals": forwards,
+        "splash_mha_dkv_no_residuals": 2}
+
+
+@pytest.mark.parametrize("remat", ["kept", "bare"])
+def test_a_layer_keeps_the_cores_two_results_and_nothing_else(
+        kernel_path_on_the_cpu, kept_across_remat, remat):
+    """Across a layer's recomputation: the core's output (B, H, S, Dv) and
+    log-sum-exp (B, H, S) float32, no projection, norm or expert
+    intermediate; a bare ``nn.remat`` keeps nothing."""
+    wrap = lm.remat_layer if remat == "kept" else nn.remat
+    layer = wrap(lm.Block)(lm_config(), False)
+    kept = kept_across_remat(layer, jnp.ones((1, 128, 64)))
+    assert kept == (["f32[1,4,128,16]", "f32[1,4,128]"]
+                    if remat == "kept" else [])
+
+
+def test_keeping_the_cores_results_leaves_the_gradients_as_they_were(
+        monkeypatch, kernel_path_on_the_cpu, params):
+    """The same kernels on the same operands, one call fewer: every leaf's
+    gradient is bitwise the bare ``nn.remat``'s (no tolerance)."""
+    toks = tokens(batch=1, seq=128)
+
+    def grads():
+        return jax.jit(_logits_sum_grad(lm.MlaMoeLM(lm_config()), toks))(
+            params)
+
+    kept = grads()
+    monkeypatch.setattr(lm, "remat_layer", nn.remat)
+    jax.tree.map(np.testing.assert_array_equal, kept, grads())
+
+
+@pytest.mark.parametrize("path, named", [("kernel", 2), ("dense", 0)])
+def test_only_the_kernel_path_names_its_results(
+        kernel_path_on_the_cpu, named_eqns, path, named):
+    """Under a gradient the kernel's forward names its output and
+    log-sum-exp ``mla.CORE_RESIDUALS``; the dense path (here: under 128
+    positions) has no log-sum-exp to keep and names nothing."""
+    grad = jax.grad(lambda q, k, v: mla.causal_core(q, k, v).sum())
+    names = named_eqns(
+        "name", grad, *_rotated_qkv(128 if path == "kernel" else 40))
+    assert names == ({mla.CORE_RESIDUALS: named} if named else {})
 
 
 # (heads, length, q/k head, v head, dtype): causal calls

@@ -13,6 +13,7 @@ import itertools
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -441,6 +442,80 @@ def test_a_kernel_is_built_once_for_each_kind_of_layer(
     jax.jit(jax.grad(loss)).lower(shapes)
     info = mla._causal_kernel.cache_info()
     assert info.misses == 2 and info.hits >= 14
+
+
+# a global layer and a window layer, whose band the 128 positions exceed
+TWO_LAYERS = dict(num_layers=2, sliding_window=64)
+
+
+def _logits_sum_grad(model, toks):
+    return jax.grad(lambda p: model.apply(p, toks)["logits"].sum())
+
+
+@pytest.mark.parametrize("remat, forwards", [("kept", 2), ("bare", 4)])
+def test_the_forward_kernel_runs_once_a_layer(
+        monkeypatch, kernel_path_on_the_cpu, named_eqns, remat, forwards):
+    """A global and a window layer, the two-kernel backward as in the cell:
+    the gradient calls every kernel once a layer, the forward too, because
+    each layer's remat keeps what the core names. Under a bare ``nn.remat``
+    the recomputation calls the forward again: were that count 2 as well,
+    the name or the policy would have gone inert."""
+    monkeypatch.setattr(mla, "PARTIAL_DQ_BYTES", 0)
+    if remat == "bare":
+        monkeypatch.setattr(swa, "remat_layer", nn.remat)
+    model = swa.SwaMoeLM(swa_config(**TWO_LAYERS))
+    toks = tokens(batch=1, seq=128)
+    shapes = jax.eval_shape(model.init, jax.random.key(0), toks)
+    assert named_eqns(
+            "pallas_call", _logits_sum_grad(model, toks), shapes) == {
+        "splash_mha_fwd_residuals": forwards,
+        "splash_mha_dkv_no_residuals": 2, "splash_mha_dq_no_residuals": 2}
+
+
+@pytest.mark.parametrize("remat", ["kept", "bare"])
+@pytest.mark.parametrize("window", [None, 64])
+def test_a_layer_keeps_the_cores_two_results_and_nothing_else(
+        kernel_path_on_the_cpu, kept_across_remat, window, remat):
+    """Across the recomputation of a layer of either kind: the core's output
+    (B, H, S, D) and log-sum-exp (B, H, S) float32 and no other
+    intermediate, the layer's own arguments (parameters, stream) and
+    constants (the mask's block schedule) aside; a bare ``nn.remat`` keeps
+    nothing."""
+    wrap = lm.remat_layer if remat == "kept" else nn.remat
+    layer = wrap(swa.Block)(swa_config(), window)
+    kept = kept_across_remat(layer, jnp.ones((1, 128, 64)))
+    assert kept == (["f32[1,4,128,16]", "f32[1,4,128]"]
+                    if remat == "kept" else [])
+
+
+def test_keeping_the_cores_results_leaves_the_gradients_as_they_were(
+        monkeypatch, kernel_path_on_the_cpu):
+    """The same kernels on the same operands, one call fewer: every leaf's
+    gradient is bitwise the bare ``nn.remat``'s (no tolerance)."""
+    toks = tokens(batch=1, seq=128)
+    seeded = ref.init_params({**SIZES, "num_hidden_layers": 2}, 7)
+
+    def grads():
+        model = swa.SwaMoeLM(swa_config(**TWO_LAYERS))
+        return jax.jit(_logits_sum_grad(model, toks))(seeded)
+
+    kept = grads()
+    monkeypatch.setattr(swa, "remat_layer", nn.remat)
+    jax.tree.map(np.testing.assert_array_equal, kept, grads())
+
+
+@pytest.mark.parametrize("length, window, named", [
+    (256, None, 2), (256, 128, 2), (40, None, 0), (40, 16, 0)])
+def test_only_the_kernel_path_names_its_results(
+        kernel_path_on_the_cpu, named_eqns, length, window, named):
+    """Under a gradient the kernel's forward names its output and
+    log-sum-exp ``mla.CORE_RESIDUALS``, under either mask; the dense path
+    (here: under 128 positions) has no log-sum-exp to keep and names
+    nothing."""
+    grad = jax.grad(
+        lambda q, k, v: mla.causal_core(q, k, v, window=window).sum())
+    names = named_eqns("name", grad, *_qkv(length, b=1))
+    assert names == ({mla.CORE_RESIDUALS: named} if named else {})
 
 
 def test_window_schedules_fewer_blocks_than_the_causal_mask():
