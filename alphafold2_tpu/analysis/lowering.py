@@ -222,13 +222,15 @@ def case_ring_flash_step(nq_local=32768, nk_local=2048):
 
 
 def case_grouped_causal_core(window=None, heads=28, groups=4, n=16384,
-                             width=128):
+                             width=128, fused=False):
     """The language models' causal core (ops/mla.py) as the grouped-query
     model calls it at its cell's shape: 28 query heads over 4 key/value
     heads of 128 at 16,384 positions in bf16, under the causal mask or a
-    window, forward and the two-kernel backward: three splash kernels lower,
-    and nothing the size of a head's logits or of keys broadcast to the
-    query heads exists in the module."""
+    window, forward and the two-kernel backward: three splash kernels lower
+    (forward, dq, dkv), and nothing the size of a head's logits or of keys
+    broadcast to the query heads exists in the module. The hybrid model's
+    attention layer calls it at 32 query heads over 2 key/value heads and
+    8,192 positions, where the fused backward fits (``fused``): two kernels."""
     import re
     from unittest import mock
 
@@ -246,12 +248,17 @@ def case_grouped_causal_core(window=None, heads=28, groups=4, n=16384,
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
             q, k, k).lower(lowering_platforms=("tpu",)).as_text()
-    kernels = text.count("tpu_custom_call")
-    if kernels != 3:  # forward, dq, dkv
-        raise RuntimeError(f"expected 3 Mosaic kernels, lowered {kernels}")
+    kernels, want_kernels = text.count("tpu_custom_call"), 2 if fused else 3
+    if kernels != want_kernels:
+        raise RuntimeError(
+            f"expected {want_kernels} Mosaic kernels, lowered {kernels}")
+    # the fused backward's partial dq, one a key block, is the one array
+    # over the queries' size that it is entitled to
+    partial = f"{heads}x{n}x{width}"
     big = sorted({
         shape for shape in re.findall(r"tensor<([0-9x]+)x(?:f32|bf16)>", text)
         if math.prod(int(d) for d in shape.split("x")) > heads * n * width
+        and not (fused and shape.endswith(partial))
     })
     if big:
         raise RuntimeError(f"arrays larger than the queries: {big}")
@@ -411,6 +418,8 @@ CASES = [
     ("ring_flash_msa_from_pair", lambda: case_ring_flash_step(2048, 32768)),
     ("grouped_causal_core_16k", case_grouped_causal_core),
     ("grouped_window_core_16k", lambda: case_grouped_causal_core(4096)),
+    ("grouped_causal_core_32_2_8k", lambda: case_grouped_causal_core(
+        heads=32, groups=2, n=8192, fused=True)),
     ("fused_axial_fwd_256", case_fused_axial_fwd),
     ("fused_axial_bwd_256", case_fused_axial_bwd),
     ("tied_row_fwd_256", case_tied_row_fwd),

@@ -20,7 +20,7 @@ from typing import Optional, Tuple
 class ModelConfig:
     # which (model, loss, batch spec) train() builds: "alphafold2" (the axial
     # trunk, the fields below) | "mla_moe_lm" (the ``lm`` section) |
-    # "swa_moe_lm" (the ``swa`` section)
+    # "swa_moe_lm" (the ``swa`` section) | "ssm_moe_lm" (the ``ssm`` section)
     arch: str = "alphafold2"
     dim: int = 256  # trunk embedding width (single-repr channels)
     max_seq_len: int = 2048  # positional-embedding table size (max residues)
@@ -119,6 +119,47 @@ class SwaLMConfig:
     rms_norm_eps: float = 1e-6  # inside every RMSNorm's rsqrt
     # the share: experts first_expert .. first_expert + experts_held - 1
     experts_held: int = 64  # routed experts this chip holds a layer
+    first_expert: int = 0  # id of the first expert held
+    bfloat16: bool = True  # compute dtype (weights stay float32)
+
+
+@dataclass
+class SsmLMConfig:
+    """Decoder-only hybrid language model whose layer is one norm and one
+    mixer, the mixer's kind read from ``layer_pattern``: a Mamba-2
+    state-space mixer (``M``), sigmoid-routed ungated relu^2 experts beside
+    one shared expert (``E``), or full causal grouped-query attention without
+    positions (``*``) (models/ssm_moe_lm.py), read when ``model.arch`` is
+    ``ssm_moe_lm``. The defaults are the published sizes of a
+    Nemotron-H-shaped 30B-A3B model, whole; the share (``experts_held``,
+    ``first_expert``, a slice of the vocabulary) and fewer layers are for the
+    caller to set, as in ``LMConfig``."""
+
+    vocab_size: int = 131072  # vocabulary rows held here (ids 0..vocab_size-1)
+    hidden_size: int = 2688  # residual stream width
+    num_layers: int = 52  # layers run: the pattern's first num_layers entries
+    # one character a layer: M state-space, E experts, * attention
+    layer_pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    mamba_num_heads: int = 64  # state-space heads
+    mamba_head_dim: int = 64  # width of a state-space head (inner 64 x 64)
+    ssm_groups: int = 8  # groups sharing B and C: head h reads h // (64 / 8)
+    ssm_state_size: int = 128  # state rows a head
+    conv_kernel: int = 4  # taps of the causal depthwise convolution
+    chunk_size: int = 128  # steps a chunk of the chunked scan (ops/ssm.py)
+    time_step_min: float = 0.001  # dt_bias starts at softplus^-1 of a time
+    time_step_max: float = 0.1  # step drawn log-uniform between these two
+    time_step_floor: float = 1e-4  # and floored at this
+    num_heads: int = 32  # attention query heads
+    num_kv_heads: int = 2  # key/value heads: query head h reads h // (32 / 2)
+    head_dim: int = 128  # width of every attention head
+    moe_intermediate_size: int = 1856  # one routed expert's width
+    moe_shared_expert_intermediate_size: int = 3712  # the shared expert's
+    n_routed_experts: int = 128  # the router's width, held or not
+    num_experts_per_tok: int = 6  # experts a token is routed to
+    routed_scaling_factor: float = 2.5  # on the normalised routing weights
+    rms_norm_eps: float = 1e-5  # inside every norm's rsqrt
+    # the share: experts first_expert .. first_expert + experts_held - 1
+    experts_held: int = 128  # routed experts this chip holds a layer
     first_expert: int = 0  # id of the first expert held
     bfloat16: bool = True  # compute dtype (weights stay float32)
 
@@ -261,6 +302,7 @@ class Config:
     model: ModelConfig = field(default_factory=ModelConfig)  # architecture
     lm: LMConfig = field(default_factory=LMConfig)  # model.arch "mla_moe_lm"
     swa: SwaLMConfig = field(default_factory=SwaLMConfig)  # "swa_moe_lm"
+    ssm: SsmLMConfig = field(default_factory=SsmLMConfig)  # "ssm_moe_lm"
     mesh: MeshConfig = field(default_factory=MeshConfig)  # device mesh axes
     data: DataConfig = field(default_factory=DataConfig)  # dataset + features
     train: TrainConfig = field(default_factory=TrainConfig)  # optimizer loop
@@ -272,7 +314,8 @@ class Config:
     def language_model(self):
         """The section of the language model that ``model.arch`` names: its
         ``vocab_size`` is what ``data.source`` "tokens" draws over."""
-        return self.swa if self.model.arch == "swa_moe_lm" else self.lm
+        return {"swa_moe_lm": self.swa, "ssm_moe_lm": self.ssm}.get(
+            self.model.arch, self.lm)
 
     @classmethod
     def from_json(cls, s: str) -> "Config":
@@ -281,6 +324,7 @@ class Config:
             model=ModelConfig(**raw.get("model", {})),
             lm=LMConfig(**raw.get("lm", {})),
             swa=SwaLMConfig(**raw.get("swa", {})),
+            ssm=SsmLMConfig(**raw.get("ssm", {})),
             mesh=MeshConfig(**raw.get("mesh", {})),
             data=DataConfig(**raw.get("data", {})),
             train=_tuplify(TrainConfig(**raw.get("train", {})), "profile_steps"),

@@ -270,9 +270,12 @@ def loss(outputs: dict, batch: dict):
 
 
 def step_metrics(outputs: dict) -> dict:
-    """The routing counters, carried beside the loss so that they cost no
-    fetch of their own; each has a leading axis over the expert layers."""
-    return {f"moe/{k}": v for k, v in outputs["moe"].items()}
+    """The model's counters (every group beside the logits: ``moe``, the
+    routing's, with a leading axis over the expert layers; a hybrid model's
+    ``ssm``), carried beside the loss so that they cost no fetch of their
+    own."""
+    return {f"{group}/{k}": v for group, counters in outputs.items()
+            if group != "logits" for k, v in counters.items()}
 
 
 def init(model: nn.Module, rng, batch: dict):

@@ -132,18 +132,24 @@ def grouped_matmul(rows, w, group_sizes):
 def expert_ffn(rows, group_sizes, w_gate, w_up, w_down, dtype,
                activation=jax.nn.silu):
     """``W_down (activation(W_gate x) * W_up x)`` of each held expert over
-    its own rows (SwiGLU as it stands, ReGLU with ``jax.nn.relu``). Weights
-    are stacked (held, D, F) / (held, F, D); gate and up run as one product.
+    its own rows (SwiGLU as it stands, ReGLU with ``jax.nn.relu``), or, with
+    ``w_gate`` None, the ungated ``W_down activation(W_up x)``: two grouped
+    products of one width, not three. Weights are stacked (held, D, F) /
+    (held, F, D); gate and up run as one product.
     Rows past the groups are masked on the way in and on the way out, so
     zeros come out of them and zeros go back into them: neither a token nor
     a token's gradient sees what the kernel left there."""
     live = (jnp.arange(rows.shape[0], dtype=jnp.int32)
             < group_sizes.sum())[:, None]
-    w_in = jnp.concatenate([w_gate.astype(dtype), w_up.astype(dtype)], axis=-1)
+    w_in = w_up.astype(dtype) if w_gate is None else jnp.concatenate(
+        [w_gate.astype(dtype), w_up.astype(dtype)], axis=-1)
     h = grouped_matmul(
         jnp.where(live, rows.astype(dtype), 0), w_in, group_sizes)
-    gate, up = jnp.split(h, 2, axis=-1)
-    act = activation(gate.astype(jnp.float32)).astype(dtype) * up
+    if w_gate is None:
+        act = activation(h.astype(jnp.float32)).astype(dtype)
+    else:
+        gate, up = jnp.split(h, 2, axis=-1)
+        act = activation(gate.astype(jnp.float32)).astype(dtype) * up
     return jnp.where(
         live, grouped_matmul(act, w_down.astype(dtype), group_sizes), 0)
 
@@ -153,11 +159,12 @@ def held_experts_sum(tokens, experts, weights, w_gate, w_up, w_down,
                      activation=jax.nn.silu):
     """``sum over the experts held here of w_e Expert_e(token)`` for tokens
     (T, D) routed to ``experts`` (T, k) under ``weights`` (T, k); the held
-    experts are ``first .. first + w_gate.shape[0] - 1`` of ``n_experts``.
+    experts are ``first .. first + w_up.shape[0] - 1`` of ``n_experts``;
+    ``w_gate`` None for ungated experts (:func:`expert_ffn`).
     Returns ((T, D), the dispatch plan, which :func:`load_counters` reads).
     The three steps are the named scopes ``dispatch``, ``experts`` and
     ``combine``."""
-    held, top_k = w_gate.shape[0], experts.shape[-1]
+    held, top_k = w_up.shape[0], experts.shape[-1]
     if first < 0 or first + held > n_experts:
         raise ValueError(
             f"experts {first}..{first + held - 1} are not among the "

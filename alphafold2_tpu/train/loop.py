@@ -179,20 +179,24 @@ def build_task(cfg: Config) -> Task:
     architecture's modules are imported only when asked for."""
     if cfg.model.arch == "alphafold2":
         return trunk_task(build_model(cfg))
-    if cfg.model.arch in ("mla_moe_lm", "swa_moe_lm"):
+    if cfg.model.arch in ("mla_moe_lm", "swa_moe_lm", "ssm_moe_lm"):
         from alphafold2_tpu.models import mla_moe_lm as lm
 
         if cfg.model.arch == "mla_moe_lm":
             model = lm.MlaMoeLM(cfg.lm)
-        else:
+        elif cfg.model.arch == "swa_moe_lm":
             from alphafold2_tpu.models.swa_moe_lm import SwaMoeLM
 
             model = SwaMoeLM(cfg.swa)
+        else:
+            from alphafold2_tpu.models.ssm_moe_lm import SsmMoeLM
+
+            model = SsmMoeLM(cfg.ssm)
         return Task(model, partial(lm.forward, model), lm.loss,
                     lm.step_metrics, partial(lm.init, model), lm.tiny_batch)
     raise ValueError(
         f"unknown model.arch {cfg.model.arch!r}; expected 'alphafold2', "
-        "'mla_moe_lm' or 'swa_moe_lm'")
+        "'mla_moe_lm', 'swa_moe_lm' or 'ssm_moe_lm'")
 
 
 def build_optimizer(cfg: Config) -> optax.GradientTransformation:

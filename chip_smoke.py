@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """First light on the attached TPU: drive the main path once, check it, say so.
 
-    python chip_smoke.py                # one chip: train, serve, kernels
+    python chip_smoke.py                # one chip: train, serve, kernels, scan
     python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh, and
                                         # the ring's blocks against references
 
@@ -20,6 +20,9 @@ Default phases, on one chip, at the width of the flagship (bench.py):
    through ``ops/flash.py`` at the blocks it picks for the flagship's
    shapes, forward and gradient, against plain jnp references at float32 /
    highest matmul precision.
+4. ssd_scan_8k — the chunked state-space scan (``ops/ssm.py``) in bfloat16
+   at the hybrid language model's shape, output and five gradients against
+   the float32 per-step recurrence.
 
 Every phase prints one JSON object on a line of its own. The LAST line of
 stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
@@ -31,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import sys
 import time
 
@@ -706,6 +710,98 @@ def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
     return {"phase": "kernels", "cases": results}
 
 
+# ---------------------------------------------------- the chunked scan ---
+
+# Readings on the chip (my chip run, PR 34; PERF.md section 6): output 0.0054,
+# gradients towards x / B / C / dt / A 0.0040 / 0.0030 / 0.0038 / 0.0029 /
+# 0.0036 of the reference's largest entry. The products take bfloat16
+# operands (2**-8 each) and a step's output sums a chunk's 128 terms and the
+# carried state's; the reference is the float32 recurrence. 3.7 times the
+# largest reading; a chunk's state lost or a decay misplaced reads 0.1 to 1.
+SSD_SCAN_TOL = 2e-2
+
+
+def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
+                   chunk=128, seed=0, tol=SSD_SCAN_TOL) -> dict:
+    """``ops/ssm.py`` ``ssd_scan`` in bfloat16 at the hybrid model's cell's
+    shape (64 heads of 64 over 8 groups of 128 state rows, 8,192 steps,
+    chunks of 128) under the published initialisation (a uniform on [1, 16],
+    dt = softplus(N(0, 1) + softplus^-1 of a log-uniform [0.001, 0.1])):
+    the output and its gradients towards x, B, C, dt and A against the
+    float32 per-step recurrence (a ``lax.scan`` over the steps, walked in
+    segments under ``jax.checkpoint``), each error the largest absolute
+    difference over the reference's largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.ops import ssm
+
+    keys = jax.random.split(jax.random.key(seed), 7)
+    x = jax.random.normal(keys[0], (1, length, heads, width))
+    b = jax.random.normal(keys[1], (1, length, groups, n))
+    c = jax.random.normal(keys[2], (1, length, groups, n))
+    a = -jax.random.uniform(keys[3], (heads,), jnp.float32, 1.0, 16.0)
+    step = jnp.exp(jax.random.uniform(
+        keys[4], (heads,), jnp.float32, math.log(1e-3), math.log(0.1)))
+    dt = jax.nn.softplus(
+        jax.random.normal(keys[5], (1, length, heads))
+        + step + jnp.log(-jnp.expm1(-step)))
+    # both sides read the same bfloat16-rounded x, B and C
+    x, b, c = (t.astype(jnp.bfloat16).astype(jnp.float32) for t in (x, b, c))
+    weight = jax.random.normal(keys[6], x.shape)
+
+    def recurrence(x, b, c, dt, a):
+        rep = heads // groups
+        b, c = (jnp.repeat(t, rep, axis=2) for t in (b, c))
+
+        def one(h, at):
+            x_t, dt_t, b_t, c_t = at
+            h = jnp.exp(dt_t * a)[..., None, None] * h \
+                + (dt_t[..., None] * b_t)[..., :, None] * x_t[..., None, :]
+            return h, jnp.sum(c_t[..., :, None] * h, axis=-2)
+
+        walk = jax.checkpoint(lambda h, seg: jax.lax.scan(one, h, seg))
+        seg = chunk if length % chunk == 0 else length
+
+        def segments(t):
+            t = jnp.moveaxis(t, 1, 0)
+            return t.reshape(length // seg, seg, *t.shape[1:])
+
+        _, y = jax.lax.scan(
+            walk, jnp.zeros((1, heads, n, width)),
+            tuple(segments(t) for t in (x, dt, b, c)))
+        return jnp.moveaxis(y.reshape(length, 1, heads, width), 0, 1)
+
+    def chunked(x, b, c, dt, a):
+        return ssm.ssd_scan(x, dt, a, b, c, chunk, jnp.bfloat16)[0]
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *args: (jnp.sum(fn(*args) * weight), fn(*args)),
+            argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    def rel_err(got, want):
+        return float(jnp.max(jnp.abs(got - want))
+                     / (jnp.max(jnp.abs(want)) + 1e-30))
+
+    (_, out), grads = both(chunked)(x, b, c, dt, a)
+    with jax.default_matmul_precision("highest"):
+        (_, out_r), grads_r = both(recurrence)(x, b, c, dt, a)
+    errs = {"fwd": rel_err(out, out_r),
+            **{f"d{name}": rel_err(g, gr) for name, g, gr in zip(
+                ("x", "B", "C", "dt", "A"), grads, grads_r)}}
+    ok = all(e == e and e <= tol for e in errs.values())
+    record = {"phase": "ssd_scan_8k" if length == 8192 else "ssd_scan",
+              "shape": [1, length, heads, width], "groups": groups,
+              "state": n, "chunk": chunk, "tol": tol, "ok": ok,
+              **{k: float(f"{v:.3g}") for k, v in errs.items()}}
+    if not ok:
+        raise RuntimeError(
+            "the chunked scan disagrees with the recurrence: "
+            + json.dumps(record))
+    return record
+
+
 # ------------------------------------------------------------- four chips ---
 
 
@@ -837,6 +933,7 @@ def main(argv=None) -> int:
                 )
             emit(phase_serve())
             emit(phase_kernels())
+            emit(phase_ssd_scan())
         emit({"phase": "done", "seconds": round(time.perf_counter() - t0, 1)})
     except Exception as e:  # the boundary: report, then fail
         import traceback

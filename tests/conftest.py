@@ -58,6 +58,20 @@ def no_implicit_transfers():
 
 
 @pytest.fixture
+def kernel_path_on_the_cpu(monkeypatch):
+    """The language models' splash path wherever the process is: off the TPU
+    ``ops/mla.py`` ``causal_core`` builds the kernel in Pallas interpret
+    mode, from 128 positions up as on the chip."""
+    from alphafold2_tpu.ops import mla
+
+    monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
+    cached = mla._causal_kernel
+    cached.cache_clear()
+    yield
+    cached.cache_clear()
+
+
+@pytest.fixture
 def named_eqns():
     """``named_eqns(primitive, fn, *args)``: the equations of that primitive
     (``"pallas_call"``: a kernel call; ``"name"``: a ``checkpoint_name``) in

@@ -94,16 +94,18 @@ def test_the_cell_resolves_to_files_of_its_own_kind():
 
 def test_the_manifest_keeps_what_it_had():
     """The three cells and their metrics as before; every list only gained
-    the new cell at its end."""
+    the new cell at its end (and, since, the cells of later PRs after it)."""
     m = manifest()
-    assert [w["name"] for w in m["workloads"]] == [
+    assert [w["name"] for w in m["workloads"]][:4] == [
         "train_flagship", "train_mesh_dp2sp2", "train_kanana2_ep8_seq8k",
         CELL]
-    assert [c["name"] for c in m["configs"]][-1] == CONFIG
+    assert [c["name"] for c in m["configs"]][3] == CONFIG
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
     for metric in m["end_to_end"] + m["per_layer"]:
         cells = metric.get("workloads", [])
-        assert CELL not in cells[:-1]
+        if CELL in cells:  # nothing after it but what later PRs appended
+            assert cells[cells.index(CELL) + 1:] in (
+                [], ["train_nemotron3_nano_ep16_seq8k"])
     assert m["run_seconds"] == 45
 
 

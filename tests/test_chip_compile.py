@@ -529,3 +529,97 @@ def test_swa_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     print(f"compiled step, bytes on the device: {per_device}")
     assert 6e9 < per_device < 14.5e9
+
+
+# ------------------------------------------------ the hybrid language model ---
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_grouped_core_32_over_2_compiles_for_v5e(one_chip, monkeypatch,
+                                                 direction):
+    """The third language-model cell's attention layer: 32 query heads over
+    2 key/value heads of 128 at 8,192 positions under the causal mask: a
+    shape no other cell runs. The fused backward (its partial dq is 0.54 GB,
+    under ``PARTIAL_DQ_BYTES``) over grouped heads: one backward kernel, no
+    dq kernel; no dense logits, no keys broadcast to the query heads."""
+    import re
+
+    from alphafold2_tpu.ops import mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile(
+        mla.causal_core if direction == "fwd" else _grad_of(mla.causal_core),
+        one_chip, ((1, 32, 8192, 128), "bfloat16"),
+        ((1, 2, 8192, 128), "bfloat16"), ((1, 2, 8192, 128), "bfloat16"))
+    assert "tpu_custom_call" in text and "splash_mha_fwd" in text
+    if direction == "bwd":
+        assert "splash_mha_dkv" in text and "splash_mha_dq" not in text
+    assert "8192,8192]" not in text
+    assert not re.search(r"= bf16\[1,32,8192,128\]\S* broadcast\(", text)
+
+
+def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole jitted train step of the benchmark's third language-model
+    cell (667 M parameters, 1 x 8,192 tokens) for one described chip: the
+    splash kernels under the attention layer's core, each once (the forward
+    too: the layer's recomputation finds its output and log-sum-exp kept;
+    the backward fused), XLA's ragged-product kernels for the held experts,
+    the chunk recurrence's ``while`` body under the scan's scope, no array
+    of length x length anywhere (a state-space layer's largest is the decay
+    matrix, chunks x heads x 128 x 128), and weights + Adam + activations
+    under 15.0e9 B of the 15.75 GiB the compiler leaves."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.observe.profiler import instruction_scopes
+    from alphafold2_tpu.train import loop
+    from benchmark.harness import common, train_ssm_lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    resolved = common.resolve("train_nemotron3_nano_ep16_seq8k")
+    cfg = train_ssm_lm.program_config(
+        resolved["config"], resolved["traffic"], 1)
+    task = loop.build_task(cfg)
+    sample = next(iter(make_dataset(
+        cfg.data, vocab_size=cfg.language_model().vocab_size)))
+    assert sample["tokens"].shape == (1, 8192)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    state = jax.eval_shape(lambda: loop.tiny_init_state(cfg, task, sample))
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 666_963_456
+    rng = jax.eval_shape(lambda: jax.random.key(1))
+    compiled = loop.make_train_step(task, None, numerics_mode="norms").lower(
+        shapes(state), shapes({k: jnp.asarray(v) for k, v in sample.items()}),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"):
+        scopes = _kernel_scopes(text, kernel)
+        assert len(scopes) == 1 and "layer_5/attn_global/core" in scopes[0]
+    assert not _kernel_scopes(text, "splash_mha_dq_no_residuals")
+    assert "flash_attention" not in text
+    assert "ragged-dot" in text  # the grouped product is a kernel, not dense
+    assert "8192,8192]" not in text  # no T x T array, in any layer
+    scopes = instruction_scopes(text)[1].values()
+    # the recurrence over the chunks is a while loop, and its body's
+    # instructions carry the scan's scope: a trace reduced by names finds them
+    looped = [s for s in scopes if "/while/body/" in s]
+    assert looped and all("/ssm/scan/" in s for s in looped)
+    for layer in (0, 2, 4, 7):
+        for part in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
+            assert any(f"layer_{layer}/ssm/{part}/" in s for s in scopes), \
+                (layer, part)
+    for part in ("router", "dispatch", "experts", "combine", "shared"):
+        assert any(f"layer_1/moe/{part}/" in s for s in scopes), part
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"compiled step, bytes on the device: {per_device}")
+    assert 11e9 < per_device < 15.0e9

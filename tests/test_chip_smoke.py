@@ -91,6 +91,20 @@ def test_kernel_phase_agrees_with_the_references(monkeypatch):
                for k in ("fwd", "dq", "dk", "dv")) < 1e-5
 
 
+def test_scan_phase_agrees_with_the_recurrence_at_a_small_size():
+    """The chunked scan's phase, small and off the chunk grid's end: every
+    error of its bfloat16 products under the chip's tolerance, and a
+    tolerance nothing meets fails the phase."""
+    small = dict(heads=4, width=8, groups=2, n=16, length=96, chunk=16)
+    rec = chip_smoke.phase_ssd_scan(**small)
+    assert rec["ok"] and rec["phase"] == "ssd_scan"
+    assert set(rec) >= {"fwd", "dx", "dB", "dC", "ddt", "dA"}
+    assert 0 < max(rec[k] for k in ("fwd", "dx", "dB", "dC", "ddt", "dA")) \
+        <= chip_smoke.SSD_SCAN_TOL
+    with pytest.raises(RuntimeError, match="disagrees with the recurrence"):
+        chip_smoke.phase_ssd_scan(**small, tol=1e-9)
+
+
 def test_kernel_phase_takes_a_filter_by_name():
     rec = chip_smoke.phase_kernels(small=True, only="moe_grouped")
     assert [c["case"] for c in rec["cases"]] == ["moe_grouped_matmul_small"]
@@ -161,7 +175,8 @@ def _fake_chip(monkeypatch, count=1, **phases):
     monkeypatch.setattr(chip_smoke, "device_record", lambda: device)
     monkeypatch.setattr("alphafold2_tpu.enable_compile_cache", lambda: None)
     calls = []
-    for name in ("phase_train", "phase_serve", "phase_kernels", "phase_mesh"):
+    for name in ("phase_train", "phase_serve", "phase_kernels",
+                 "phase_ssd_scan", "phase_mesh"):
         def phase(name=name, **kwargs):
             calls.append((name, kwargs) if kwargs else name)
             if name in phases:
@@ -177,7 +192,8 @@ def test_success_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     lines = capsys.readouterr().out.splitlines()
     assert json.loads(lines[-1]) == {"ok": True, "device": device}
     assert sorted(device) == ["count", "kind", "platform"]
-    assert calls == ["phase_train", "phase_serve", "phase_kernels"]
+    assert calls == ["phase_train", "phase_serve", "phase_kernels",
+                     "phase_ssd_scan"]
     for ln in lines:  # one JSON object per earlier line
         assert isinstance(json.loads(ln), dict)
 

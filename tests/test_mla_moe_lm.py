@@ -352,17 +352,6 @@ def _dense_causal_jnp(q, k, v):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-@pytest.fixture
-def kernel_path_on_the_cpu(monkeypatch):
-    """The splash path wherever the process is: off the TPU ``causal_core``
-    builds the kernel in Pallas interpret mode."""
-    monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
-    cached = mla._causal_kernel
-    cached.cache_clear()
-    yield
-    cached.cache_clear()
-
-
 @pytest.mark.parametrize("backward", ["fused", "two_kernels"])
 @pytest.mark.parametrize("length", [128, 256, 384])
 @pytest.mark.parametrize("heads", ["24_16", "192_128"])

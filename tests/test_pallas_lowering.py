@@ -52,4 +52,5 @@ def test_all_pallas_kernels_lower_for_tpu():
         "block_sparse_custom_vjp_n512", "flash_axial_256",
         "ring_flash_pair_from_msa", "ring_flash_msa_from_pair",
         "grouped_causal_core_16k", "grouped_window_core_16k",
+        "grouped_causal_core_32_2_8k",
     } <= cases

@@ -368,17 +368,6 @@ def test_rotary_turns_the_two_halves_by_position():
                                atol=1e-6)
 
 
-@pytest.fixture
-def kernel_path_on_the_cpu(monkeypatch):
-    """The splash path wherever the process is: off the TPU ``causal_core``
-    builds the kernel in Pallas interpret mode."""
-    monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
-    cached = mla._causal_kernel
-    cached.cache_clear()
-    yield
-    cached.cache_clear()
-
-
 @pytest.mark.parametrize("backward", ["fused", "two_kernels"])
 @pytest.mark.parametrize("length, window", [
     (256, None), (384, None), (384, 128), (384, 200), (256, 1)])
