@@ -9,9 +9,19 @@ exchange.
 
 Dropless: every assignment to a held expert is computed whatever the
 imbalance. The ``tokens x top_k`` assignments are sorted by expert (those to
-absent experts last), the held experts' rows go through a grouped matrix
-product whose group sizes are data, and the shapes are static at
-``tokens x top_k`` rows, the most one chip can be sent.
+absent experts last) and the held experts' rows go through a grouped matrix
+product whose group sizes are data.
+
+What is static is the **rung**: the number of sorted rows the path from the
+gather to the sum over a token's rows is built for. :func:`row_ladder` lists
+the rungs, from twice the balanced share ``tokens x top_k x held /
+n_experts`` by doubling up to ``tokens x top_k``, the most one chip can be
+sent; the step picks the lowest rung that holds the live count
+(``group_sizes.sum()``, which the sort has anyway) with one
+``jax.lax.switch``, on the device. The sort, the histogram and the counters
+stay outside the switch, on ``tokens x top_k`` integers. A layer that holds
+every expert has one rung and no switch. ``rows_computed`` among the
+counters is the rung a layer took in a step.
 
 Router numerics (logits, scores, top-k, weights) are float32 whatever the
 compute type: a score rounded to bfloat16 reorders near-ties.
@@ -78,6 +88,74 @@ def _take_rows_bwd(fan, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
+# the ragged product's row tile on the TPU: a rung is a whole number of them
+ROW_TILE = 512
+
+
+def row_ladder(n_rows: int, held: int, n_experts: int) -> tuple:
+    """The rungs, ascending: twice the balanced share ``n_rows x held /
+    n_experts`` of the ``n_rows`` assignments (a balanced router hovers at
+    the share itself, and a rung's edge there would flip every few steps),
+    doubled while under ``n_rows``, each rounded up to :data:`ROW_TILE`, and
+    last ``n_rows`` itself, which holds whatever the router sends. One rung
+    where the held experts are half of all or more, or ``n_rows`` is too few
+    for two tiles."""
+    ladder, rung = [], max(1, -(-2 * n_rows * held // n_experts))
+    while True:
+        rows = -(-rung // ROW_TILE) * ROW_TILE
+        if rows >= n_rows:
+            return (*ladder, n_rows)
+        if rows not in ladder:  # small shares round up to one tile
+            ladder.append(rows)
+        rung *= 2
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_by_token(x, order, inverse, top_k: int):
+    """``x[order // top_k]``: the token of each of a rung's sorted rows
+    (``order`` is the plan's, cut to the rung; ``inverse`` the plan's,
+    whole). The backward pass is :func:`_sum_by_token`."""
+    return x[order // top_k]
+
+
+def _take_by_token_fwd(x, order, inverse, top_k):
+    return _take_by_token(x, order, inverse, top_k), (order, inverse)
+
+
+def _take_by_token_bwd(top_k, res, g):
+    return _sum_by_token(g, *res, top_k), None, None
+
+
+_take_by_token.defvjp(_take_by_token_fwd, _take_by_token_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_by_token(rows, order, inverse, top_k: int):
+    """(T, D): each token's sum over those of a rung's R sorted rows that are
+    its own (row ``r`` is token ``order[r] // top_k``'s; at most ``top_k`` a
+    token). ``inverse``, read as (T, k), lists each token's k sorted rows:
+    those inside the rung are gathered, the others read a zero row put after
+    the rung's, and the k are summed as at T*k rows (in float32). Of the
+    forms timed on the chip (``scripts/ab_moe_rows.py``, PERF.md section 6,
+    PR 35) the fastest inside the whole path, though its gather is still
+    over T x top_k positions. The backward pass is :func:`_take_by_token`."""
+    n = rows.shape[0]
+    table = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+    back = table[jnp.minimum(inverse, n)]
+    return back.reshape(-1, top_k, rows.shape[-1]).sum(1)
+
+
+def _sum_by_token_fwd(rows, order, inverse, top_k):
+    return _sum_by_token(rows, order, inverse, top_k), (order, inverse)
+
+
+def _sum_by_token_bwd(top_k, res, g):
+    return _take_by_token(g, *res, top_k), None, None
+
+
+_sum_by_token.defvjp(_sum_by_token_fwd, _sum_by_token_bwd)
+
+
 def dispatch(experts, first: int, held: int, n_experts: int):
     """Sort the (T, k) assignments by held expert. Returns a dict:
 
@@ -86,7 +164,9 @@ def dispatch(experts, first: int, held: int, n_experts: int):
     - ``inverse`` (T*k,): the sorted row of each assignment;
     - ``group_sizes`` (held,) int32: rows of each held expert;
     - ``here`` (T*k,) bool: the assignment is to a held expert;
-    - ``hist`` (n_experts,) int32: assignments of every expert, held or not.
+    - ``hist`` (n_experts,) int32: assignments of every expert, held or not;
+    - ``rung`` () int32: the lowest rung of :func:`row_ladder` that holds
+      the held experts' rows, and ``rows_computed`` () int32, its rows.
     """
     flat = experts.reshape(-1)
     local = flat - first
@@ -94,27 +174,42 @@ def dispatch(experts, first: int, held: int, n_experts: int):
     order = jnp.argsort(
         jnp.where(here, local, held), stable=True).astype(jnp.int32)
     hist = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    group_sizes = jax.lax.dynamic_slice_in_dim(hist, first, held)
+    ladder = row_ladder(flat.shape[0], held, n_experts)
+    live = group_sizes.sum()
+    # a count of the rungs too short: the integer 0 where there is but one
+    rung = sum((live > rows).astype(jnp.int32) for rows in ladder[:-1])
     return {
         "order": order, "inverse": jnp.argsort(order).astype(jnp.int32),
-        "group_sizes": jax.lax.dynamic_slice_in_dim(hist, first, held),
-        "here": here, "hist": hist,
+        "group_sizes": group_sizes, "here": here, "hist": hist,
+        "rung": jnp.asarray(rung, jnp.int32),
+        "rows_computed": jnp.asarray(ladder, jnp.int32)[rung],
     }
 
 
-def gather_rows(x, plan, top_k: int):
-    """(T, D) tokens -> (T*k, D) rows in sorted order. ``inverse``, read as
-    (T, k), lists each token's k sorted rows: the backward pass sums them."""
-    return _take_rows(x, plan["order"] // top_k, plan["inverse"], top_k)
+def gather_rows(x, plan, top_k: int, n_rows=None):
+    """(T, D) tokens -> the first ``n_rows`` rows in sorted order (a rung;
+    all T*k if None), each its token's. At T*k rows ``inverse``, read as
+    (T, k), lists each token's k sorted rows and the backward pass sums
+    them; under that a token's rows are found among the rung's
+    (:func:`_sum_by_token`)."""
+    if n_rows is None or n_rows == plan["order"].shape[0]:
+        return _take_rows(x, plan["order"] // top_k, plan["inverse"], top_k)
+    return _take_by_token(
+        x, plan["order"][:n_rows], plan["inverse"], top_k)
 
 
 def combine(rows, weights, plan, top_k: int):
-    """Sum each token's ``top_k`` rows under its weights. ``rows`` (T*k, D)
-    in sorted order, zero past the held experts' groups (as ``expert_ffn``
+    """Sum each token's rows under its weights. ``rows`` (R, D) are the
+    first R in sorted order (a rung, which holds every held expert's rows,
+    or all T*k), zero past the held experts' groups (as ``expert_ffn``
     leaves them). Returns (T, D)."""
-    n = rows.shape[0]
-    w_sorted = weights.reshape(-1)[plan["order"]].astype(rows.dtype)
-    back = _take_rows(
-        rows * w_sorted[:, None], plan["inverse"], plan["order"], 1)
+    n, full = rows.shape[0], plan["order"].shape[0]
+    order = plan["order"][:n]
+    rows = rows * weights.reshape(-1)[order].astype(rows.dtype)[:, None]
+    if n < full:
+        return _sum_by_token(rows, order, plan["inverse"], top_k)
+    back = _take_rows(rows, plan["inverse"], order, 1)
     return back.reshape(n // top_k, top_k, rows.shape[-1]).sum(1)
 
 
@@ -136,9 +231,11 @@ def expert_ffn(rows, group_sizes, w_gate, w_up, w_down, dtype,
     ``w_gate`` None, the ungated ``W_down activation(W_up x)``: two grouped
     products of one width, not three. Weights are stacked (held, D, F) /
     (held, F, D); gate and up run as one product.
-    Rows past the groups are masked on the way in and on the way out, so
-    zeros come out of them and zeros go back into them: neither a token nor
-    a token's gradient sees what the kernel left there."""
+    ``rows`` are a rung's (or all T*k): every shape here follows their
+    count, the products towards the weights included. Rows past the groups
+    are masked on the way in and on the way out, so zeros come out of them
+    and zeros go back into them: neither a token nor a token's gradient sees
+    what the kernel left there."""
     live = (jnp.arange(rows.shape[0], dtype=jnp.int32)
             < group_sizes.sum())[:, None]
     w_in = w_up.astype(dtype) if w_gate is None else jnp.concatenate(
@@ -154,6 +251,76 @@ def expert_ffn(rows, group_sizes, w_gate, w_up, w_down, dtype,
         live, grouped_matmul(act, w_down.astype(dtype), group_sizes), 0)
 
 
+# the scope the models give this layer. An operation in a branch of the
+# switch is named .../moe/cond/branch_1_fun/...: the branch opens the scope
+# again, so that whoever looks for "moe" and "experts" side by side in an
+# operation's name (the benchmark's readers do) finds them as before, and
+# the switch itself stands under no step's scope, or a branch's operations
+# would be found under two
+LAYER_SCOPE = "moe"
+
+
+def _rung_sum(static, tokens, weights, mats, plan):
+    """The held experts' weighted sum over the first ``n_rows`` sorted rows:
+    the three named scopes ``dispatch``, ``experts`` and ``combine``. Of the
+    plan it reads ``order``, ``inverse`` and ``group_sizes``."""
+    n_rows, top_k, dtype, activation = static
+    with jax.named_scope("dispatch"):
+        rows = gather_rows(tokens, plan, top_k, n_rows)
+    with jax.named_scope("experts"):
+        rows = expert_ffn(rows, plan["group_sizes"], *mats, dtype, activation)
+    with jax.named_scope("combine"):
+        return combine(rows, weights, plan, top_k)
+
+
+def _rung_pull_back(static, g, tokens, weights, mats, plan):
+    """The gradients of :func:`_rung_sum` towards tokens, weights and the
+    experts' matrices: the rung run forward again, ``g`` pulled back."""
+    _, pull = jax.vjp(
+        lambda *diff: _rung_sum(static, *diff, plan), tokens, weights, mats)
+    return pull(g)
+
+
+@functools.lru_cache(maxsize=None)
+def _branches(run, ladder, static):
+    """``run((rung's rows, *static), *operands)`` for each rung, under the
+    layer's scope. The same functions for the same arguments, whichever
+    layer asks: ``jax.lax.switch`` traces a branch it has seen at these
+    shapes once, not once a layer."""
+    def branch(n_rows):
+        def scoped(*operands):
+            with jax.named_scope(LAYER_SCOPE):
+                return run((n_rows, *static), *operands)
+        return scoped
+    return tuple(branch(n_rows) for n_rows in ladder)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _ladder_sum(ladder, static, tokens, weights, mats, plan):
+    """:func:`_rung_sum` on the rung ``plan["rung"]`` names. Differentiated
+    as a whole: the backward pass is a switch of its own whose branch runs
+    the rung forward again and pulls the gradient back through it, so no
+    rung's intermediate results leave a branch (autodiff of a switch would
+    have every branch write zeros for all the other rungs' residuals)."""
+    return jax.lax.switch(
+        plan["rung"], _branches(_rung_sum, ladder, static),
+        tokens, weights, mats, plan)
+
+
+def _ladder_sum_fwd(ladder, static, tokens, weights, mats, plan):
+    return (_ladder_sum(ladder, static, tokens, weights, mats, plan),
+            (tokens, weights, mats, plan))
+
+
+def _ladder_sum_bwd(ladder, static, res, g):
+    grads = jax.lax.switch(
+        res[-1]["rung"], _branches(_rung_pull_back, ladder, static), g, *res)
+    return (*grads, None)
+
+
+_ladder_sum.defvjp(_ladder_sum_fwd, _ladder_sum_bwd)
+
+
 def held_experts_sum(tokens, experts, weights, w_gate, w_up, w_down,
                      first: int, n_experts: int, dtype,
                      activation=jax.nn.silu):
@@ -163,7 +330,8 @@ def held_experts_sum(tokens, experts, weights, w_gate, w_up, w_down,
     ``w_gate`` None for ungated experts (:func:`expert_ffn`).
     Returns ((T, D), the dispatch plan, which :func:`load_counters` reads).
     The three steps are the named scopes ``dispatch``, ``experts`` and
-    ``combine``."""
+    ``combine``; past the sort they run on the rung the plan names, inside
+    a switch over :func:`row_ladder`'s rungs where there is more than one."""
     held, top_k = w_up.shape[0], experts.shape[-1]
     if first < 0 or first + held > n_experts:
         raise ValueError(
@@ -171,26 +339,37 @@ def held_experts_sum(tokens, experts, weights, w_gate, w_up, w_down,
             f"router's {n_experts}")
     with jax.named_scope("dispatch"):
         plan = dispatch(experts, first, held, n_experts)
-        rows = gather_rows(tokens, plan, top_k)
+    ladder = row_ladder(experts.size, held, n_experts)
+    static = (top_k, dtype, activation)
     with jax.named_scope("experts"):
-        rows = expert_ffn(rows, plan["group_sizes"], w_gate, w_up, w_down,
-                          dtype, activation)
-    with jax.named_scope("combine"):
-        out = combine(rows, weights, plan, top_k)
+        # cast here: a branch then hands back gradients of the compute type
+        mats = tuple(None if w is None else w.astype(dtype)
+                     for w in (w_gate, w_up, w_down))
+    sort = {k: plan[k] for k in ("order", "inverse", "group_sizes", "rung")}
+    if len(ladder) == 1:
+        out = _rung_sum((*ladder, *static), tokens, weights, mats, sort)
+    else:
+        out = _ladder_sum(ladder, static, tokens, weights, mats, sort)
     return out, plan
 
 
 def load_counters(plan) -> dict:
-    """One layer's routing counters of the step (PERF.md section 3)."""
+    """One layer's routing counters of the step (PERF.md section 3).
+    ``rows_computed`` is the rung: the sorted rows the step gathered, ran
+    through the experts and summed in this layer, of which
+    ``assignments_here`` were a held expert's."""
     sizes = plan["group_sizes"]
-    computed = sizes.sum()
+    live = sizes.sum()
     # an assignment to a held expert is computed when its sorted row lies
-    # inside the groups: the sort puts every one there, so none is dropped
-    reached = jnp.sum(plan["here"] & (plan["inverse"] < computed))
+    # inside the groups and inside the rung: the sort puts every one in the
+    # groups and the rung is chosen to hold them, so none is dropped
+    reached = jnp.sum(plan["here"] & (
+        plan["inverse"] < jnp.minimum(live, plan["rows_computed"])))
     mean = jnp.maximum(sizes.astype(jnp.float32).mean(), 1.0)
     return {
         "hist": plan["hist"],
-        "assignments_here": computed,
+        "assignments_here": live,
+        "rows_computed": plan["rows_computed"],
         "load_max_over_mean": sizes.max().astype(jnp.float32) / mean,
         "dropped": jnp.sum(plan["here"]) - reached,
     }

@@ -616,8 +616,21 @@ def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         for part in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
             assert any(f"layer_{layer}/ssm/{part}/" in s for s in scopes), \
                 (layer, part)
+    # as the benchmark's reader finds them (names side by side): what follows
+    # the sort sits in a branch of the rung's switch, which opens moe again
+    from benchmark.readers.scope_paths_device_ms import holds, names
     for part in ("router", "dispatch", "experts", "combine", "shared"):
-        assert any(f"layer_1/moe/{part}/" in s for s in scopes), part
+        assert any("/layer_1/" in s and holds(names(s), f"moe/{part}")
+                   for s in scopes), part
+    # one switch a layer forward and one in its gradient, over four rungs
+    assert text.count(" conditional(") == 8
+    in_switch = [s for s in scopes if "/cond/branch_" in s]
+    for layer in (1, 3, 6, 8):  # each under its own layer's name
+        assert any(f"/layer_{layer}/moe/" in s for s in in_switch), layer
+    assert all(
+        sum(holds(names(s), f"moe/{part}")
+            for part in ("dispatch", "experts", "combine")) == 1
+        for s in in_switch)
     ma = compiled.memory_analysis()
     per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)

@@ -229,8 +229,11 @@ def test_the_scans_counters_ride_beside_the_routings(both_sides):
     metrics = lm.step_metrics(out)
     assert sorted(metrics) == [
         "moe/assignments_here", "moe/dropped", "moe/hist",
-        "moe/load_max_over_mean", "ssm/chunk_decay_mean",
-        "ssm/chunk_decay_min", "ssm/dt_mean"]
+        "moe/load_max_over_mean", "moe/rows_computed",
+        "ssm/chunk_decay_mean", "ssm/chunk_decay_min", "ssm/dt_mean"]
+    # every rung's rows hold what the layer was sent (at this size: one rung)
+    assert (metrics["moe/rows_computed"]
+            >= metrics["moe/assignments_here"]).all()
     for name in ("ssm/chunk_decay_min", "ssm/chunk_decay_mean",
                  "ssm/dt_mean"):
         assert metrics[name].shape == (4,)  # a row a state-space layer
