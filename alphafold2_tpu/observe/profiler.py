@@ -6,18 +6,26 @@ Complements the span tracing in :mod:`alphafold2_tpu.observe.tracing`:
 the ``.xplane.pb`` it has just written into a **record** kept in memory:
 
 - per device plane the operations of the ``XLA Ops`` line as ``(instruction,
-  scope, start_ns, end_ns)``, the program executions of ``XLA Modules`` and
-  the ``Steps`` line, each as ``(name, start_ns, end_ns)``;
+  scope, start_ns, end_ns)`` and the program executions of ``XLA Modules``
+  as ``(name, start_ns, end_ns)``;
 - the host plane's annotation events whose name an enabled ``Tracer`` sent
-  there, as ``(name, start_ns, end_ns, thread, args)``, on the same clock.
+  there, as ``(name, start_ns, end_ns, thread, args)``, moved onto the
+  device's clock by ``clock_offset_ns`` (``clock_offset``);
+- ``inferred``: ``{instruction: rule}`` for the step's instructions whose
+  scope the compiled text does not give and ``infer_scopes`` found.
 
 An operation's scope is the ``op_name`` of its HLO instruction
 (``jit(step)/jvp(Alphafold2)/trunk/layer_0/pair_from_msa/to_q/dot_general``:
 Flax names the modules, ``make_train_step`` the phases around the model). The
 TPU's trace does not carry it (an event is named by the instruction's text
 without metadata), so it is joined by instruction name from the compiled
-step's text (``name_operations``); an operation of another program (the
-loop's ``jax.random.split``) is given that program's name, ``jit(<name>)``.
+step's text (``name_operations``). The compiled step names a third of what
+runs: the compiler's own moves, layout copies, some fusions and the kernels
+it writes itself carry no ``op_name``, so the same pass reads the text as a
+graph and takes such an instruction's scope from the fusion's body, from what
+uses its result, or from what made its operand (``infer_scopes``). An
+operation of another program (the loop's ``jax.random.split``) is given that
+program's name, ``jit(<name>)``.
 
 ``summarize`` turns a record into the one line ``train()`` logs: device ms a
 step by block, forward and backward apart, the step program's device ms, the
@@ -36,24 +44,38 @@ import glob
 import os
 import re
 import time
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
-OPS_LINE, MODULES_LINE, STEPS_LINE = "XLA Ops", "XLA Modules", "Steps"
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
-PHASES = ("loss", "grads_ok", "grad_clip", "optimizer", "rng", "metrics")
+PHASES = ("loss", "grads_ok", "grad_clip", "optimizer", "metrics")
 COLLECTIVES = ("all-reduce", "collective-permute", "all-gather",
                "reduce-scatter", "all-to-all", "async-collective")
 OUTSIDE_ANY_SPAN = "outside_any_span"
+# the loop thread's spans inside which the step's program and the key
+# split's (jax's name for it) are sent to the device
+STEP_SPAN, RNG_SPAN = "train.step", "train.rng"
+RNG_PROGRAM = "jit__threefry_split"
 # parallel/seq_parallel.py's two scopes inside a cross-attention block: a
 # ring step's kernel calls, and the merging of the steps around them
 RING_PARTS = ("ring_block", "ring_merge")
 # The profiler's own Python tracer is off: it adds some 10,000 events of
 # Python calls a traced step to the host plane, none of which is read.
 PYTHON_TRACER_LEVEL = 0
+# how far ``infer_scopes`` walks from an instruction without a scope: a move
+# between memory spaces is two steps from its kernel (start, done), a tuple
+# and its element two more
+WALK_DEPTH = 6
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+# `` fusion(%a, /*index=5*/%b)``: the first lower-case word before a bracket
+# (a type's ``T(8,128)`` and ``S(1)`` follow no blank), and its operands
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(([^)]*)\)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(")
 _WRAPPED = re.compile(r"^(?:jvp|transpose)\((.*)\)$")
 _LAYER = re.compile(r"^layer_\d+$")
 
@@ -68,24 +90,155 @@ def last_record() -> Optional[dict]:
     return _LAST
 
 
-def instruction_scopes(hlo_text: str) -> Tuple[str, dict]:
-    """(module name, {instruction name: op_name}) of a compiled program's
-    text (``compiled.as_text()``). An instruction's text may run over
-    several lines (a kernel's frontend attributes can hold newlines: the
-    splash kernels' do), its metadata then on a later one: the ``op_name``
-    belongs to the last instruction that began."""
+class Instruction(NamedTuple):
+    """One instruction of a compiled program's text."""
+    op_name: str          # "" where the text gives none
+    opcode: str
+    operands: Tuple[str, ...]
+    calls: str            # the computation a fusion calls, else ""
+    computation: str      # the computation it sits in
+
+
+def instruction_graph(hlo_text: str) -> Tuple[str, dict]:
+    """(module name, {instruction name: Instruction}) of a compiled
+    program's text (``compiled.as_text()``), in the text's order. An
+    instruction's text may run over several lines (a kernel's frontend
+    attributes can hold newlines: the splash kernels' do), its metadata then
+    on a later one: the ``op_name`` and the ``calls=`` belong to the last
+    instruction that began."""
     module = hlo_text.split(None, 2)[1].rstrip(",") if hlo_text.startswith(
         "HloModule") else ""
-    scopes, unnamed = {}, None
+    graph, computation, last = {}, "", None
     for line in hlo_text.splitlines():
         named = _INSTRUCTION.match(line)
         if named:
-            unnamed = named.group(1)
-        op_name = _OP_NAME.search(line) if unnamed else None
-        if op_name:
-            scopes[unnamed] = op_name.group(1)
-            unnamed = None
-    return module, scopes
+            last = named.group(1)
+            opcode = _OPCODE.search(line, named.end())
+            graph[last] = Instruction(
+                "", opcode.group(1) if opcode else "",
+                tuple(_OPERAND.findall(opcode.group(2))) if opcode else (),
+                "", computation)
+        elif line.endswith("{"):
+            opened = _COMPUTATION.match(line)
+            if opened:
+                computation, last = opened.group(1), None
+        if last is None:
+            continue
+        at = graph[last]
+        if not at.calls and at.opcode == "fusion":
+            calls = _CALLS.search(line)
+            if calls:
+                at = graph[last] = at._replace(calls=calls.group(1))
+        if not at.op_name:
+            op_name = _OP_NAME.search(line)
+            if op_name:
+                graph[last] = at._replace(op_name=op_name.group(1))
+    return module, graph
+
+
+def instruction_scopes(hlo_text: str) -> Tuple[str, dict]:
+    """(module name, {instruction name: op_name}) of a compiled program's
+    text, for the instructions that carry one."""
+    module, graph = instruction_graph(hlo_text)
+    return module, {name: at.op_name for name, at in graph.items()
+                    if at.op_name}
+
+
+def read_scope(op_name: str) -> bool:
+    """Whether an ``op_name`` is a path from a jitted program's root
+    (``jit(step)/...``). XLA's own names for a kernel (``ragged-dot-none``)
+    and a copy named by the argument it copies are no path."""
+    return op_name.startswith("jit(")
+
+
+def infer_scopes(graph: dict) -> Tuple[dict, dict]:
+    """({instruction: scope}, {instruction: rule}) of one program's graph.
+    An instruction whose ``op_name`` is a path keeps it. One without takes,
+    stopping at the first that gives a path: ``body``, for a fusion, the
+    commonest path among the instructions of the computation it calls (the
+    first in the body's order where two are as common); ``user``, the path
+    (for a fusion without, its body's) of the nearest instruction that
+    consumes its result, breadth-first through consumers that have none, in
+    its own computation and ``WALK_DEPTH`` steps at most (``copy-start`` ->
+    ``copy-done`` -> the kernel); ``operand``, the same walk backwards
+    through what made its operands. What none reaches keeps what the text
+    gave it.
+
+    ``kin`` comes before the three for the instructions that carry a name of
+    XLA's own (``ragged-dot-none``: a kernel XLA wrote in place of one
+    operation of the program, whose path it dropped). Such a kernel computes,
+    so neither its consumer nor its producer says where it was called (the
+    experts' second product feeds ``combine``, their weight gradients feed
+    ``metrics``); but those of one name in one computation were called from
+    one place, so together they take ``<path>/<XLA's name>``, the path being
+    the one most of them find next to them, users or operands, less its
+    last name."""
+    bodies: dict = {}  # computation -> {path: count}, in the body's order
+    users: dict = {}   # instruction -> its consumers, in the text's order
+    kin: dict = {}     # (computation, XLA's own name) -> instructions
+    for name, at in graph.items():
+        if read_scope(at.op_name):
+            counts = bodies.setdefault(at.computation, {})
+            counts[at.op_name] = counts.get(at.op_name, 0) + 1
+        elif at.op_name:
+            kin.setdefault((at.computation, at.op_name), []).append(name)
+        for operand in at.operands:
+            users.setdefault(operand, []).append(name)
+    scopes, inferred = {}, {}
+
+    def known(name):
+        at = graph[name]
+        if read_scope(at.op_name):
+            return at.op_name
+        if at.op_name:  # XLA's own name: what its kin settled on, if they did
+            return scopes[name] if inferred.get(name) == "kin" else ""
+        counts = bodies.get(at.calls)
+        return max(counts, key=counts.get) if counts else ""
+
+    def nearest(name, neighbours):
+        """The paths of the nearest instructions that have one, along
+        ``neighbours``, nearest level first and in the text's order."""
+        seen, level = {name}, [name]
+        for _ in range(WALK_DEPTH):
+            level = [n for near in level for n in neighbours(near)
+                     if n in graph and n not in seen
+                     and graph[n].computation == graph[name].computation
+                     and not seen.add(n)]
+            found = [path for path in map(known, level) if path]
+            if found:
+                return found
+        return []
+
+    def consumers(name):
+        return users.get(name, ())
+
+    def producers(name):
+        return graph[name].operands
+
+    # consumers' kin first: a walk that meets one then stops at its path
+    for (_, own_name), members in reversed(list(kin.items())):
+        votes: dict = {}
+        for name in members:
+            around = nearest(name, consumers) + nearest(name, producers)
+            for path in dict.fromkeys(p.rpartition("/")[0] for p in around):
+                votes[path] = votes.get(path, 0) + 1
+        if votes:
+            path = max(votes, key=votes.get)
+            for name in members:
+                scopes[name], inferred[name] = f"{path}/{own_name}", "kin"
+    for name, at in graph.items():
+        if name in inferred:  # settled with its kin
+            continue
+        scope, rule = known(name), "body"
+        if not scope:
+            scope, rule = next(iter(nearest(name, consumers)), ""), "user"
+        if not scope:
+            scope, rule = next(iter(nearest(name, producers)), ""), "operand"
+        if scope and not read_scope(at.op_name):
+            inferred[name] = rule
+        if scope or at.op_name:
+            scopes[name] = scope or at.op_name
+    return scopes, inferred
 
 
 class Profiler:
@@ -106,7 +259,8 @@ class Profiler:
         self._active = False
         self._span_names = span_names or set
         self._log = log
-        self._module, self._scopes = "", {}
+        self._module, self._scopes, self._inferred = "", {}, {}
+        self._names_s = 0.0
         if trace_dir:
             _LAST = {"devices": {}, "host": [], "step_module": ""}
 
@@ -116,8 +270,11 @@ class Profiler:
 
     def name_operations(self, hlo_text: str) -> None:
         """The compiled step's text: which program is the step, and the
-        scope of each of its instructions."""
-        self._module, self._scopes = instruction_scopes(hlo_text)
+        scope of each of its instructions, read or inferred."""
+        t0 = time.perf_counter()
+        self._module, graph = instruction_graph(hlo_text)
+        self._scopes, self._inferred = infer_scopes(graph)
+        self._names_s = time.perf_counter() - t0
 
     def maybe_start(self, step: int) -> None:
         if self._dir and step == self._start and not self._active:
@@ -157,7 +314,8 @@ class Profiler:
         if not found:
             return
         record = read_record(found[-1], self._span_names(), self._module,
-                             self._scopes)
+                             self._scopes, self._inferred)
+        record["names_s"] = self._names_s  # set-up's share of the join
         record["stop_trace_s"] = stop_s
         record["read_s"] = time.perf_counter() - t0
         _LAST = record
@@ -197,7 +355,6 @@ def _device_plane(plane, step_module: str, scopes: dict) -> Optional[dict]:
     if OPS_LINE not in lines:
         return None
     modules = _spans(lines[MODULES_LINE]) if MODULES_LINE in lines else []
-    steps = _spans(lines[STEPS_LINE]) if STEPS_LINE in lines else []
     ops = []
     at = 0  # modules run one after another: walk them with the operations
     for e in sorted(lines[OPS_LINE].events, key=lambda e: e.start_ns):
@@ -213,7 +370,7 @@ def _device_plane(plane, step_module: str, scopes: dict) -> Optional[dict]:
             scope = _module_scope(modules[at][0])
         ops.append((instruction, scope, e.start_ns,
                     e.start_ns + e.duration_ns))
-    return {"ops": ops, "modules": modules, "steps": steps}
+    return {"ops": ops, "modules": modules}
 
 
 def _cpu_ops(plane, step_module: str, scopes: dict) -> Optional[dict]:
@@ -232,12 +389,30 @@ def _cpu_ops(plane, step_module: str, scopes: dict) -> Optional[dict]:
                         e.start_ns + e.duration_ns))
     if not ops:
         return None
-    return {"ops": sorted(ops, key=lambda o: o[2]), "modules": [],
-            "steps": []}
+    return {"ops": sorted(ops, key=lambda o: o[2]), "modules": []}
+
+
+def clock_offset(modules, host, step_module: str) -> int:
+    """The nanoseconds to add to the host's events so that no execution of
+    the step's program or of the key split in ``modules`` starts before the
+    loop thread's span that sent it does: the smallest such shift, so 0
+    where none starts early (the two clocks of one trace differ by up to
+    about a millisecond). Executions and spans are paired from the last
+    backwards: the first of a trace may have been sent before it began."""
+    thread = loop_thread(host)
+    early = 0
+    for program, span in ((step_module, STEP_SPAN), (RNG_PROGRAM, RNG_SPAN)):
+        runs = [s for name, s, _ in modules
+                if name.partition("(")[0] == program]
+        sent = [s for name, s, _, t, _ in host if name == span and t == thread]
+        for run, span_start in zip(reversed(runs), reversed(sent)):
+            early = min(early, run - span_start)
+    return early
 
 
 def read_record(path: str, span_names, step_module: str = "",
-                scopes: Optional[dict] = None) -> dict:
+                scopes: Optional[dict] = None,
+                inferred: Optional[dict] = None) -> dict:
     """One ``.xplane.pb`` -> the record (see the module's docstring)."""
     from jax.profiler import ProfileData
 
@@ -261,8 +436,13 @@ def read_record(path: str, span_names, step_module: str = "",
                 found = _cpu_ops(plane, step_module, scopes)
                 if found:
                     devices[plane.name] = found
-    host.sort(key=lambda h: h[1])
-    return {"devices": devices, "host": host, "step_module": step_module}
+    plane = next(iter(devices.values()), {"modules": []})
+    offset = clock_offset(plane["modules"], host, step_module)
+    host = sorted(((name, start + offset, end + offset, thread, args)
+                   for name, start, end, thread, args in host),
+                  key=lambda h: h[1])
+    return {"devices": devices, "host": host, "step_module": step_module,
+            "inferred": inferred or {}, "clock_offset_ns": offset}
 
 
 # ---------------------------------------------------- record -> summary ---
@@ -364,7 +544,9 @@ def idle_by_span(gaps, host, steps_name: str = "train") -> dict:
 def summarize(record: dict) -> dict:
     """The log line of one record, from its first device plane."""
     out = {"stop_trace_s": round(record.get("stop_trace_s", 0.0), 3),
-           "read_s": round(record.get("read_s", 0.0), 3)}
+           "read_s": round(record.get("read_s", 0.0), 3),
+           "names_s": round(record.get("names_s", 0.0), 3),
+           "clock_offset_ns": record.get("clock_offset_ns", 0)}
     if not record["devices"]:
         return out
     plane = next(iter(record["devices"].values()))
@@ -375,9 +557,14 @@ def summarize(record: dict) -> dict:
     blocks: dict = {}
     collectives: dict = {}
     ring: dict = {}
+    inferred, total, inferred_ns = record.get("inferred", {}), 0.0, 0.0
     for i, own in own_times(ops):
         block = block_of(ops[i][1])
         blocks[block] = blocks.get(block, 0.0) + own
+        total += own
+        # another program's operation carries its program's name, no path
+        if ops[i][0] in inferred and "/" in ops[i][1]:
+            inferred_ns += own
         if ops[i][0].startswith(COLLECTIVES):
             collectives[block] = collectives.get(block, 0.0) + own
         for part in set(RING_PARTS).intersection(ops[i][1].split("/")):
@@ -390,6 +577,8 @@ def summarize(record: dict) -> dict:
         out["step_device_ms"] = round(
             sum(e - s for _, s, e in step_runs) / steps / 1e6, 3)
     out["idle_pct"] = round(100.0 * idle / window, 3) if window else 0.0
+    out["inferred_pct"] = round(
+        100.0 * inferred_ns / total, 3) if total else 0.0
     for name, table in (("block_ms", blocks),
                         ("collective_own_ms", collectives),
                         ("ring_ms", ring),

@@ -598,7 +598,6 @@ def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler,
 
     with tracer.span("train.imports"):  # orbax comes in with the last one
         from alphafold2_tpu.data.pipeline import make_dataset
-        from alphafold2_tpu.observe import flops as flops_mod
         from alphafold2_tpu.observe.metrics import flatten_metrics
         from alphafold2_tpu.observe.tracing import compile_counts
         from alphafold2_tpu.train.checkpoint import CheckpointManager
@@ -731,9 +730,7 @@ def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler,
     batch = next(prefetched)
 
     # AOT-compile the step on the single-mesh path: compile time becomes an
-    # explicit metric instead of polluting the first step's rate, and the
-    # compiled executable's XLA cost analysis gives flops/bytes for MFU
-    # accounting (observe.flops — the same parser bench and serve use).
+    # explicit metric instead of polluting the first step's rate.
     # The mesh/multi-host path keeps implicit jit compilation: AOT-compiled
     # calls are strict about input shardings the loop does not guarantee.
     step_call = step_fn
@@ -751,17 +748,8 @@ def _train(cfg: Config, num_steps, dataset, callbacks, tracer, logger, profiler,
             sp.set(backend_s=done["backend_s"] - now["backend_s"],
                    cache_hit=done["cache_hits"] > now["cache_hits"])
         compile_s = time.perf_counter() - t_c
-        with tracer.span("train.cost_analysis"):
-            costs = flops_mod.executable_costs(compiled)
         step_call = compiled
-        # step_flops is XLA's own count and leaves out every custom call
-        # (the attention kernels, most of a TPU step): no rate is made of it
-        logger.log(start_step, {
-            "compile_s": round(compile_s, 3),
-            **({"step_flops": costs["flops"]} if costs["flops"] else {}),
-            **({"step_bytes_accessed": costs["bytes_accessed"]}
-               if costs["bytes_accessed"] else {}),
-        })
+        logger.log(start_step, {"compile_s": round(compile_s, 3)})
         if profiler.enabled:
             profiler.name_operations(compiled.as_text())
     elif profiler.enabled and jax.process_count() == 1:

@@ -77,7 +77,12 @@ def test_the_cell_resolves_to_files_of_its_own_kind():
             "embed_head_loss_device_ms", "lm_rest_device_ms",
             "mla_attn_kernels_roofline_pct",
             "moe_grouped_matmul_roofline_pct", "expert_load_max_over_mean",
-            "unscoped_device_pct")}
+            "unscoped_device_pct", "update_device_ms")}
+    assert "inferred_scope_device_pct.train" in names
+    # every reader and metric file the cell names is there
+    for spec in resolved["per_layer"]:
+        assert os.path.exists(
+            os.path.join(BENCH, "readers", spec["reader"] + ".py"))
     # the set-up spans' reader reads this cell's one-chip AOT compile too
     assert {"setup_lower_s.train", "setup_compile_s.train"} <= names
     # the trunk's own readers are not asked to read this cell
@@ -454,6 +459,51 @@ def test_unscoped_share_of_a_language_model_step(record, monkeypatch):
         100.0 * 44 / 51)
     monkeypatch.setattr(scope_reduce, "program_record", lambda: None)
     assert reader.read(record, spec) is None
+
+
+def test_update_passes_are_a_part_of_the_rest_not_a_block_beside_it(record):
+    """``update_device_ms.train_lm``: ``grads_ok``, ``optimizer`` (the clip
+    inside it) and ``metrics``, all of which ``lm_rest`` already holds."""
+    from benchmark.readers import scope_paths_device_ms as reader
+
+    ms = 1e-6
+    spec = metric("update_device_ms.train_lm")
+    assert spec["reader"] == "scope_paths_device_ms"
+    assert reader.read(record, spec["params"]) == pytest.approx(5 * ms)
+    rest = metric("lm_rest_device_ms.train_lm")["params"]
+    assert not set(spec["params"]["scopes"]) & set(rest["all_but"])
+    assert reader.read(record, rest) == pytest.approx(6 * ms)  # + the copy
+
+
+def test_inferred_share_reads_the_records_own_account(record, monkeypatch):
+    """``inferred_scope_device_pct.train``: own time of the operations whose
+    instruction the record lists as inferred, over all own time; an
+    instruction of that name in another program is not the step's; nothing
+    where the record has no such key (a program from before it)."""
+    from benchmark.harness import scope_reduce
+    from benchmark.readers import inferred_scope_device_pct as reader
+
+    spec = metric("inferred_scope_device_pct.train")
+    assert spec == {"reader": "inferred_scope_device_pct", "params": {}}
+    assert reader.read(record, {}) is None  # the record has no account
+    rec = scope_reduce.program_record()
+    plane = rec["devices"]["/device:TPU:0"]
+    path = "jit(step)/jvp(MlaMoeLM)/layer_1"
+    named = {"ragged-dot-none.3": f"{path}/moe/experts/ragged-dot-none",
+             "copy.5": f"{path}/mla_attn/core/pallas_call"}
+    plane["ops"] = [(n, named.get(n, scope), s, e)
+                    for n, scope, s, e in plane["ops"]]
+    end = plane["ops"][-1][3]
+    plane["ops"].append(("copy.5", "jit(_threefry_split)", end, end + 2))
+    rec["inferred"] = {"ragged-dot-none.3": "kin", "copy.5": "user",
+                       "fusion.77": "body"}
+    # of 2 x 51 + 2: the ragged product's 6 and the copy's 1, twice
+    assert reader.read(record, {}) == pytest.approx(100.0 * 14 / 104)
+    rec["inferred"] = {}
+    assert reader.read(record, {}) == 0.0
+    monkeypatch.setattr(scope_reduce, "program_record", lambda: None)
+    assert reader.read(record, {}) is None
+    assert reader.read({"trace": None}, {}) is None
 
 
 def test_counter_reader_means_over_the_window():

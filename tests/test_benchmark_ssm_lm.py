@@ -114,11 +114,13 @@ def test_the_cell_resolves_to_files_of_its_own_kind():
             "dense_shared_ffn_device_ms.train_lm",
             "embed_head_loss_device_ms.train_lm",
             "expert_load_max_over_mean.train_lm",
+            "update_device_ms.train_lm",
             "attn_global_device_ms.train_swa_lm"}
     assert {"setup_lower_s.train", "setup_compile_s.train",
             "step_device_ms.train", "compiles_after_warmup.train",
             "step_ms_p50.train", "device_idle_pct.train",
-            "idle_attributed_pct.train"} <= names
+            "idle_attributed_pct.train",
+            "inferred_scope_device_pct.train"} <= names
     assert [m["name"] for m in resolved["end_to_end"]] == [
         "pairs_per_s", "setup_s"]
     # every reader and metric file the cell names is there
@@ -139,10 +141,14 @@ def test_the_manifest_keeps_what_it_had():
     for metric in m["end_to_end"] + m["per_layer"]:
         cells = metric.get("workloads", [])
         assert CELL not in cells[:-1]
-    new = [p["name"] for p in m["per_layer"]][-7:]
+    # PR 36 appended two metrics of the record's inferred scopes after them
+    assert [p["name"] for p in m["per_layer"]][-2:] == [
+        "inferred_scope_device_pct.train", "update_device_ms.train_lm"]
+    assert [p["workloads"][-1] for p in m["per_layer"][-2:]] == [CELL, CELL]
+    new = [p["name"] for p in m["per_layer"]][-9:-2]
     assert all(name.endswith(".train_ssm_lm") for name in new)
     assert all(p["workloads"] == [CELL] and p["moves"] == "pairs_per_s"
-               for p in m["per_layer"][-7:])
+               for p in m["per_layer"][-9:-2])
     assert m["run_seconds"] == 45
     assert len(json.dumps(m, indent=2)) < 64 * 1024
 

@@ -81,9 +81,11 @@ def test_the_cell_resolves_to_files_of_its_own_kind():
     assert {m for m in names if m.endswith(".train_lm")} == {
         f"{stem}.train_lm" for stem in (
             "moe_dispatch_device_ms", "moe_experts_device_ms",
-            "embed_head_loss_device_ms", "expert_load_max_over_mean")}
+            "embed_head_loss_device_ms", "expert_load_max_over_mean",
+            "update_device_ms")}
     assert {"setup_lower_s.train", "setup_compile_s.train",
-            "step_device_ms.train", "compiles_after_warmup.train"} <= names
+            "step_device_ms.train", "compiles_after_warmup.train",
+            "inferred_scope_device_pct.train"} <= names
     assert [m["name"] for m in resolved["end_to_end"]] == [
         "pairs_per_s", "setup_s"]
     # every reader and metric file the cell names is there

@@ -393,14 +393,11 @@ def _kernel_scopes(text, kernel):
             if name.split(".")[0] == kernel]
 
 
-def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def lm_step(one_chip):
     """The whole jitted train step of the benchmark's language-model cell
-    (576 M parameters, 2 x 8,192 tokens) for one described chip: the splash
-    kernels and XLA's ragged-product kernels are in it, the stock flash
-    kernel and dense 8,192^2 logits are not, the forward kernel runs once a
-    layer (the layers' recomputation finds its output and log-sum-exp kept),
-    and weights + Adam + activations fit the 15.75 GiB the compiler
-    leaves."""
+    (576 M parameters, 2 x 8,192 tokens) compiled once for one described
+    chip: its text, its parameter count and its bytes on the device."""
     import os
     import sys
 
@@ -410,36 +407,127 @@ def test_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     from alphafold2_tpu.train import loop
     from benchmark.harness import common, train_lm
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    resolved = common.resolve("train_kanana2_ep8_seq8k")
-    cfg = train_lm.program_config(resolved["config"], resolved["traffic"], 1)
-    task = loop.build_task(cfg)
-    sample = next(iter(make_dataset(
-        cfg.data, vocab_size=cfg.lm.vocab_size)))
+    with pytest.MonkeyPatch.context() as patched:
+        patched.setattr(jax, "default_backend", lambda: "tpu")
+        resolved = common.resolve("train_kanana2_ep8_seq8k")
+        cfg = train_lm.program_config(
+            resolved["config"], resolved["traffic"], 1)
+        task = loop.build_task(cfg)
+        sample = next(iter(make_dataset(
+            cfg.data, vocab_size=cfg.lm.vocab_size)))
 
-    def shapes(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
+        def shapes(tree):
+            return jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=one_chip), tree)
 
-    state = jax.eval_shape(lambda: loop.tiny_init_state(cfg, task, sample))
-    assert sum(x.size for x in jax.tree.leaves(state.params)) == 575_955_968
-    rng = jax.eval_shape(lambda: jax.random.key(1))
-    compiled = loop.make_train_step(task, None, numerics_mode="norms").lower(
-        shapes(state), shapes({k: jnp.asarray(v) for k, v in sample.items()}),
-        jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
-    ).compile()
-    text = compiled.as_text()
+        state = jax.eval_shape(
+            lambda: loop.tiny_init_state(cfg, task, sample))
+        rng = jax.eval_shape(lambda: jax.random.key(1))
+        compiled = loop.make_train_step(
+            task, None, numerics_mode="norms").lower(
+            shapes(state),
+            shapes({k: jnp.asarray(v) for k, v in sample.items()}),
+            jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
+        ).compile()
+    ma = compiled.memory_analysis()
+    return {
+        "text": compiled.as_text(),
+        "parameters": sum(x.size for x in jax.tree.leaves(state.params)),
+        "bytes": (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes),
+    }
+
+
+def test_lm_train_step_compiles_for_v5e(lm_step):
+    """The whole jitted train step of the benchmark's language-model cell
+    for one described chip: the splash kernels and XLA's ragged-product
+    kernels are in it, the stock flash kernel and dense 8,192^2 logits are
+    not, the forward kernel runs once a layer (the layers' recomputation
+    finds its output and log-sum-exp kept), and weights + Adam + activations
+    fit the 15.75 GiB the compiler leaves."""
+    text = lm_step["text"]
+    assert lm_step["parameters"] == 575_955_968
     assert len(_kernel_scopes(text, "splash_mha_fwd_residuals")) == 5
     assert len(_kernel_scopes(text, "splash_mha_dkv_no_residuals")) == 5
     assert "flash_attention" not in text and "flash_mha_bwd" not in text
     assert "ragged-dot" in text  # the grouped product is a kernel, not dense
     assert "32,8192,8192]" not in text and "64,8192,8192]" not in text
-    ma = compiled.memory_analysis()
-    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    print(f"compiled step, bytes on the device: {per_device}")
-    assert 8e9 < per_device < 15.75 * 2**30
+    print(f"compiled step, bytes on the device: {lm_step['bytes']}")
+    assert 8e9 < lm_step["bytes"] < 15.75 * 2**30
+
+
+def _inferred_scopes_hold(text, attention):
+    """What ``observe.profiler.infer_scopes`` makes of a compiled step's
+    text: a path the text gives is never replaced; at most 0.5% of what
+    executes (the entry, ``while`` bodies and conditions, a switch's
+    branches; parameters, tuples and their elements, constants and bitcasts
+    left out) stays without one; every kernel XLA names itself
+    (``ragged-dot*``) lands under the experts and under no other block of
+    the expert layer; every layout ``copy`` without a name that a splash
+    kernel consumes, at most two instructions between them, lands under one
+    of ``attention``.
+    Returns (executing, read from the text, those copies)."""
+    import re
+
+    from alphafold2_tpu.observe import profiler
+    from benchmark.readers.scope_paths_device_ms import holds, names
+
+    graph = profiler.instruction_graph(text)[1]
+    scopes, inferred = profiler.infer_scopes(graph)
+    for name, at in graph.items():
+        if profiler.read_scope(at.op_name):
+            assert scopes[name] == at.op_name and name not in inferred
+    runs = set(re.findall(
+        r"(?:^ENTRY |\b(?:body|condition|true_computation|"
+        r"false_computation)=)%([\w.\-]+)", text, re.M))
+    for branches in re.findall(r"branch_computations=\{([^}]*)\}", text):
+        runs.update(re.findall(r"%([\w.\-]+)", branches))
+    executing = {
+        name: at for name, at in graph.items() if at.computation in runs
+        and at.opcode not in ("parameter", "tuple", "get-tuple-element",
+                              "constant", "bitcast")}
+    read = [n for n, at in executing.items()
+            if profiler.read_scope(at.op_name)]
+    left = [n for n in executing
+            if not profiler.read_scope(scopes.get(n, ""))]
+    assert len(left) <= 0.005 * len(executing), left
+    kernels = [n for n in graph if n.startswith("ragged-dot")]
+    assert kernels and all(n in executing for n in kernels)
+    for name in kernels:
+        found = names(scopes[name])
+        assert inferred[name] == "kin" and holds(found, "moe/experts"), name
+        assert not any(holds(found, f"moe/{other}") for other in
+                       ("router", "dispatch", "combine", "shared")), name
+    users = {}
+    for name, at in graph.items():
+        for operand in at.operands:
+            users.setdefault(operand, []).append(name)
+    copies = []
+    for name, at in executing.items():
+        if at.opcode != "copy" or at.op_name:
+            continue
+        level = [name]
+        for _ in range(3):  # the copy, a pad or a bitcast or two, the kernel
+            level = [u for n in level for u in users.get(n, ())]
+            if any(u.startswith("splash_mha_") for u in level):
+                copies.append(name)
+                assert any(holds(names(scopes[name]), path)
+                           for path in attention), name
+                break
+    return executing, read, copies
+
+
+def test_lm_step_instructions_find_a_scope(lm_step):
+    """Two thirds of what the compiled step executes carry no ``op_name``
+    (the compiler's moves between memory spaces, layout copies, the kernels
+    XLA writes itself); the program's record takes those scopes from the
+    instructions around them."""
+    executing, read, copies = _inferred_scopes_hold(
+        lm_step["text"], ["mla_attn"])
+    assert len(executing) > 5000 and len(read) < 0.4 * len(executing)
+    # q, k and v heads-first for the splash kernels, forward and backward
+    assert len(copies) >= 20
 
 
 # --------------------------------------- the grouped-query language model ---
@@ -524,6 +612,7 @@ def test_swa_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     assert "flash_attention" not in text
     assert "ragged-dot" in text  # the grouped product is a kernel, not dense
     assert "16384,16384]" not in text
+    _inferred_scopes_hold(text, ["attn_global", "attn_window"])
     ma = compiled.memory_analysis()
     per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
@@ -631,6 +720,7 @@ def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         sum(holds(names(s), f"moe/{part}")
             for part in ("dispatch", "experts", "combine")) == 1
         for s in in_switch)
+    _inferred_scopes_hold(text, ["attn_global"])
     ma = compiled.memory_analysis()
     per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)

@@ -197,6 +197,263 @@ ENTRY %main () -> f32[] {
         scopes["splash_mha_fwd_residuals.10"]) == "fwd/mla_attn"
 
 
+# ------------------------------ (a') scopes the compiled step does not give
+
+LM = "jit(step)/jvp(M)"
+# One of each case (the numbers in the names are arbitrary): a fusion without
+# a name whose body has one; a move between memory spaces, two steps from
+# the kernel it feeds; a kernel whose text runs over three lines; a layout
+# copy between an unnamed producer and a named consumer; two kernels under
+# XLA's own name, one feeding the experts' product, one feeding combine; a
+# copy that only the result's tuple consumes; a copy nothing reaches.
+UNNAMED_TEXT = """HloModule jit_step, entry_computation_layout={(f32[8]{0}, f32[8]{0})->(f32[8]{0}, f32[8]{0})}
+
+%fused_computation.2 (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  %add.0 = f32[8]{0} add(%param_0, %param_0), metadata={op_name="jit(step)/jvp(M)/layer_0/mla_attn/rope/add" stack_frame_id=3}
+  %mul.1 = f32[8]{0} multiply(%add.0, %param_0), metadata={op_name="jit(step)/jvp(M)/layer_0/mla_attn/rope/mul" stack_frame_id=3}
+  ROOT %mul.2 = f32[8]{0} multiply(%mul.1, %mul.1), metadata={op_name="jit(step)/jvp(M)/layer_0/mla_attn/rope/mul" stack_frame_id=3}
+}
+
+ENTRY %main.1 (p.1: f32[8], p.2: f32[8]) -> (f32[8], f32[8]) {
+  %p.1 = f32[8]{0} parameter(0), metadata={op_name="state.params['x']"}
+  %p.2 = f32[8]{0} parameter(1)
+  %fusion.2 = f32[8]{0:T(1024)} fusion(%p.1), kind=kLoop, calls=%fused_computation.2
+  %copy-start.1 = (f32[8]{0:T(1024)S(1)}, f32[8]{0:T(1024)}, u32[]{:S(2)}) copy-start(%fusion.2)
+  %copy-done.1 = f32[8]{0:T(1024)S(1)} copy-done(%copy-start.1)
+  %splash_mha_fwd_residuals.10 = (f32[8]{0}, f32[8]{0}) custom-call(%copy-done.1), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 1024, \\"block_kv\\": 1024}"
+}}, metadata={op_name="jit(step)/jvp(M)/layer_0/mla_attn/core/pallas_call" stack_frame_id=104}, backend_config={}
+  %get-tuple-element.3 = f32[8]{0} get-tuple-element(%splash_mha_fwd_residuals.10), index=0
+  %bitcast.6 = f32[8]{0} bitcast(%p.2)
+  %copy.7 = f32[8]{0:T(256)} copy(%bitcast.6)
+  %fusion.8 = f32[8]{0} fusion(%copy.7, %get-tuple-element.3), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(step)/jvp(M)/layer_1/moe/dispatch/gather" stack_frame_id=7}
+  %ragged-dot-none.1 = f32[8]{0} custom-call(%fusion.8, /*index=1*/%p.2), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %fusion.11 = f32[8]{0} fusion(%ragged-dot-none.1), kind=kLoop, calls=%fused_computation.11, metadata={op_name="jit(step)/jvp(M)/layer_1/moe/experts/mul" stack_frame_id=8}
+  %ragged-dot-none.2 = f32[8]{0} custom-call(%fusion.11, /*index=1*/%p.2), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %fusion.12 = f32[8]{0} fusion(%ragged-dot-none.2), kind=kLoop, calls=%fused_computation.12, metadata={op_name="jit(step)/jvp(M)/layer_1/moe/combine/mul" stack_frame_id=9}
+  %copy.13 = f32[8]{0} copy(%fusion.12)
+  %copy.9 = f32[8]{0} copy(%p.2)
+  ROOT %tuple.14 = (f32[8]{0}, f32[8]{0}) tuple(%copy.13, %copy.9)
+}
+"""
+
+
+@pytest.mark.parametrize("instruction, scope, rule", [
+    ("fusion.2", f"{LM}/layer_0/mla_attn/rope/mul", "body"),
+    ("copy-start.1", f"{LM}/layer_0/mla_attn/core/pallas_call", "user"),
+    ("copy-done.1", f"{LM}/layer_0/mla_attn/core/pallas_call", "user"),
+    # read from the text's third line of it, and never replaced
+    ("splash_mha_fwd_residuals.10",
+     f"{LM}/layer_0/mla_attn/core/pallas_call", None),
+    ("get-tuple-element.3", f"{LM}/layer_1/moe/dispatch/gather", "user"),
+    ("copy.7", f"{LM}/layer_1/moe/dispatch/gather", "user"),
+    ("fusion.8", f"{LM}/layer_1/moe/dispatch/gather", None),
+    # the experts' two products: the first feeds their own multiply, the
+    # second feeds combine and is fed by that multiply
+    ("ragged-dot-none.1", f"{LM}/layer_1/moe/experts/ragged-dot-none", "kin"),
+    ("ragged-dot-none.2", f"{LM}/layer_1/moe/experts/ragged-dot-none", "kin"),
+    ("fusion.12", f"{LM}/layer_1/moe/combine/mul", None),
+    ("copy.13", f"{LM}/layer_1/moe/combine/mul", "operand"),
+    ("copy.9", "", None),  # between an argument and the result: nothing near
+])
+def test_a_scope_is_inferred_where_the_compiled_step_gives_none(
+        instruction, scope, rule):
+    module, graph = profiler_mod.instruction_graph(UNNAMED_TEXT)
+    assert module == "jit_step"
+    scopes, inferred = profiler_mod.infer_scopes(graph)
+    assert scopes.get(instruction, "") == scope
+    assert inferred.get(instruction) == rule
+    # the text's own view of it is as before: a name or nothing
+    read = profiler_mod.instruction_scopes(UNNAMED_TEXT)[1]
+    if rule is None and scope:
+        assert read[instruction] == scope
+    elif rule != "kin":
+        assert instruction not in read
+
+
+def test_the_graph_keeps_operands_calls_and_computations():
+    graph = profiler_mod.instruction_graph(UNNAMED_TEXT)[1]
+    assert graph["fusion.2"] == profiler_mod.Instruction(
+        "", "fusion", ("p.1",), "fused_computation.2", "main.1")
+    assert graph["mul.1"].computation == "fused_computation.2"
+    assert graph["mul.1"].operands == ("add.0", "param_0")
+    assert graph["copy-start.1"].opcode == "copy-start"  # a tuple's type
+    kernel = graph["splash_mha_fwd_residuals.10"]
+    assert kernel.opcode == "custom-call" and kernel.calls == ""
+    assert kernel.operands == ("copy-done.1",)
+    assert graph["ragged-dot-none.1"].operands == ("fusion.8", "p.2")
+    assert graph["tuple.14"].operands == ("copy.13", "copy.9")
+
+
+def test_a_walk_stops_at_its_depth_and_at_its_computation(monkeypatch):
+    scopes = profiler_mod.infer_scopes(
+        profiler_mod.instruction_graph(UNNAMED_TEXT)[1])[0]
+    # the body's parameter is consumed by named instructions of the body
+    assert scopes["param_0"] == f"{LM}/layer_0/mla_attn/rope/add"
+    monkeypatch.setattr(profiler_mod, "WALK_DEPTH", 1)
+    scopes, inferred = profiler_mod.infer_scopes(
+        profiler_mod.instruction_graph(UNNAMED_TEXT)[1])
+    # the kernel is two steps from the move's start: out of reach, so that
+    # takes what made its operand (the fusion, by its body)
+    assert inferred["copy-start.1"] == "operand"
+    assert scopes["copy-start.1"] == f"{LM}/layer_0/mla_attn/rope/mul"
+    assert inferred["copy-done.1"] == "user"
+
+
+# A trace of that step, as the TPU writes one: the key split's program, then
+# the step's, with the loop thread's spans on a host clock that lags the
+# device's by a millisecond. Own device microseconds of the step: fusion.2
+# 100, copy-start.1 10, copy-done.1 40, the kernel 300, copy.7 50, the two
+# ragged products 100 each, fusion.11 250 (read), copy.9 50: 1,000. The
+# key split's one operation is named fusion.2 as well.
+UNNAMED_TRACE = """
+planes { name: "/device:TPU:0" id: 1
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 2000000
+    events { metadata_id: 20 offset_ps: 0 duration_ps: 10000000 }
+    events { metadata_id: 21 offset_ps: 100000000 duration_ps: 1000000000 }
+  }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 2000000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000 }
+    events { metadata_id: 1 offset_ps: 100000000 duration_ps: 100000000 }
+    events { metadata_id: 2 offset_ps: 200000000 duration_ps: 10000000 }
+    events { metadata_id: 3 offset_ps: 210000000 duration_ps: 40000000 }
+    events { metadata_id: 4 offset_ps: 250000000 duration_ps: 300000000 }
+    events { metadata_id: 5 offset_ps: 550000000 duration_ps: 50000000 }
+    events { metadata_id: 6 offset_ps: 600000000 duration_ps: 100000000 }
+    events { metadata_id: 7 offset_ps: 700000000 duration_ps: 250000000 }
+    events { metadata_id: 8 offset_ps: 950000000 duration_ps: 100000000 }
+    events { metadata_id: 9 offset_ps: 1050000000 duration_ps: 50000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "%fusion.2 = f32[8]{0:T(1024)} fusion(f32[8]{0} %p.1)" } }
+  event_metadata { key: 2 value { id: 2 name: "%copy-start.1 = (f32[8]{0:T(1024)S(1)}, f32[8]{0:T(1024)}, u32[]{:S(2)}) copy-start(%fusion.2)" } }
+  event_metadata { key: 3 value { id: 3 name: "%copy-done.1 = f32[8]{0:T(1024)S(1)} copy-done(%copy-start.1)" } }
+  event_metadata { key: 4 value { id: 4 name: "%splash_mha_fwd_residuals.10 = (f32[8]{0}, f32[8]{0}) custom-call(%copy-done.1)" } }
+  event_metadata { key: 5 value { id: 5 name: "%copy.7 = f32[8]{0:T(256)} copy(%bitcast.6)" } }
+  event_metadata { key: 6 value { id: 6 name: "%ragged-dot-none.1 = f32[8]{0} custom-call(%fusion.8, %p.2)" } }
+  event_metadata { key: 7 value { id: 7 name: "%fusion.11 = f32[8]{0} fusion(%ragged-dot-none.1)" } }
+  event_metadata { key: 8 value { id: 8 name: "%ragged-dot-none.2 = f32[8]{0} custom-call(%fusion.11, %p.2)" } }
+  event_metadata { key: 9 value { id: 9 name: "%copy.9 = f32[8]{0} copy(%p.2)" } }
+  event_metadata { key: 20 value { id: 20 name: "jit__threefry_split(11)" } }
+  event_metadata { key: 21 value { id: 21 name: "jit_step(12)" } }
+}
+planes { name: "/host:CPU" id: 2
+  lines { id: 1 name: "python3" timestamp_ns: 2900000
+    events { metadata_id: 1 offset_ps: 80000000 duration_ps: 15000000 }
+    events { metadata_id: 2 offset_ps: 120000000 duration_ps: 40000000 }
+    events { metadata_id: 3 offset_ps: 0 duration_ps: 1500000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "train.rng" } }
+  event_metadata { key: 2 value { id: 2 name: "train.step" } }
+  event_metadata { key: 3 value { id: 3 name: "train" } }
+}
+"""
+
+
+@pytest.fixture
+def unnamed_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(
+        UNNAMED_TRACE))
+    return str(path)
+
+
+def test_inferred_scopes_reach_the_record_and_the_log_line(unnamed_trace,
+                                                           monkeypatch):
+    spans = {"train", "train.rng", "train.step"}
+    module, read = profiler_mod.instruction_scopes(UNNAMED_TEXT)
+    before = profiler_mod.summarize(
+        profiler_mod.read_record(unnamed_trace, spans, module, read))
+    # all but the kernel and the experts' multiply: 450 of the step's 1,000
+    # (XLA's own name for the ragged products is no scope)
+    assert before["block_ms/unscoped"] == 0.45
+    assert before["inferred_pct"] == 0.0 and before["steps"] == 1
+    scopes, inferred = profiler_mod.infer_scopes(
+        profiler_mod.instruction_graph(UNNAMED_TEXT)[1])
+    record = profiler_mod.read_record(unnamed_trace, spans, module, scopes,
+                                      inferred)
+    assert record["inferred"] == inferred
+    plane = scope_reduce.first_plane(record)
+    assert [(name, scope) for name, scope, _, _ in plane["ops"]][:3] == [
+        ("fusion.2", "jit(_threefry_split)"),  # the other program's, as ever
+        ("fusion.2", f"{LM}/layer_0/mla_attn/rope/mul"),
+        ("copy-start.1", f"{LM}/layer_0/mla_attn/core/pallas_call")]
+    assert set(plane) == {"ops", "modules"}
+    after = profiler_mod.summarize(record)
+    assert after["block_ms/unscoped"] == 0.05  # copy.9: nothing reaches it
+    assert after["block_ms/fwd/mla_attn"] == 0.45  # the kernel's 300 before
+    assert after["block_ms/fwd/moe"] == 0.5
+    # of 1,010 us of own time (the key split's 10 are not the step's)
+    assert after["inferred_pct"] == round(100 * 400 / 1010, 3)
+    assert after["step_device_ms"] == before["step_device_ms"] == 1.0
+    # the benchmark's readers, from the same record: the ragged products are
+    # found once, by name and by scope; the new reader agrees with the line
+    from benchmark.readers import (
+        inferred_scope_device_pct, scope_paths_device_ms,
+        unscoped_model_device_pct,
+    )
+
+    run = {"trace": {"planes": {"/device:TPU:0": [
+        (o[0], o[2], o[3]) for o in plane["ops"]]}}}
+    monkeypatch.setattr(scope_reduce, "program_record", lambda: record)
+    assert inferred_scope_device_pct.read(run, {}) == pytest.approx(
+        100 * 400 / 1010)
+    assert unscoped_model_device_pct.read(run, {"model": "M"}) == \
+        pytest.approx(100 * 50 / 1010)
+    experts = {"scopes": ["moe/experts"], "instructions": ["ragged-dot"]}
+    assert scope_paths_device_ms.read(run, experts) == pytest.approx(0.45)
+    assert scope_paths_device_ms.read(
+        run, {"scopes": ["moe/router", "moe/dispatch", "moe/combine"]}
+    ) == pytest.approx(0.05)  # copy.7, and no ragged product
+    assert scope_paths_device_ms.read(
+        run, {"scopes": ["mla_attn"]}) == pytest.approx(0.45)
+    assert scope_paths_device_ms.read(
+        run, {"all_but": ["mla_attn", "moe"],
+              "instructions": ["ragged-dot"]}) == pytest.approx(0.06)
+
+
+def test_host_spans_are_moved_onto_the_devices_clock(unnamed_trace):
+    """The host's clock lags by a millisecond in the hand-made trace: as
+    stamped, the key split runs 980 us before ``train.rng`` begins and the
+    step 920 us before ``train.step`` does. The smallest shift that mends
+    both puts ``train.rng`` at the split's start, and the 90 us between the
+    two programs then fall to the spans that cover them."""
+    spans = {"train", "train.rng", "train.step"}
+    record = profiler_mod.read_record(unnamed_trace, spans, "jit_step", {})
+    assert record["clock_offset_ns"] == -980_000
+    assert [(h[0], h[1], h[2]) for h in record["host"]] == [
+        ("train", 1_920_000, 3_420_000), ("train.rng", 2_000_000, 2_015_000),
+        ("train.step", 2_040_000, 2_080_000)]
+    line = profiler_mod.summarize(record)
+    assert line["clock_offset_ns"] == -980_000
+    assert line["idle_ms/train.rng"] == 0.005
+    assert line["idle_ms/train.step"] == 0.04
+    assert line["idle_ms/outside_any_span"] == 0.045
+
+
+@pytest.mark.parametrize("host_lag_ns, offset", [
+    (0, 0), (-500_000, 0),  # a host clock that is right, or early: causal
+    (15_000, 0),  # late by less than the dispatch takes
+    (1_000_000, -980_000),
+])
+def test_clock_offset_is_the_smallest_shift_that_mends_causality(
+        host_lag_ns, offset):
+    modules = [("jit_step(1)", 1_000_000, 1_900_000),  # sent before the trace
+               ("jit__threefry_split(2)", 2_000_000, 2_010_000),
+               ("jit_other(4)", 2_050_000, 2_060_000),
+               ("jit_step(1)", 2_100_000, 3_100_000)]
+    host = [("train", 1_900_000, 3_400_000, "python3", {}),
+            ("train.rng", 1_980_000, 1_995_000, "python3", {}),
+            ("train.step", 2_020_000, 2_060_000, "python3", {}),
+            ("train.step", 0, 1, "another-thread", {})]
+    host = [(n, a + host_lag_ns, b + host_lag_ns, t, args)
+            for n, a, b, t, args in host]
+    assert profiler_mod.clock_offset(modules, host, "jit_step") == offset
+    assert profiler_mod.clock_offset([], host, "jit_step") == 0  # CPU
+
+
 # ------------------------------------- (b) spans on the profiler's clock ---
 
 
@@ -445,9 +702,11 @@ def test_record_and_spans_survive_an_exception_from_a_callback(
     names = {e["name"] for e in record["spans"]}
     assert {"train.imports", "train.dataset", "train.build_model",
             "train.first_batch", "train.init_state", "train.lower",
-            "train.compile", "train.cost_analysis", "train.rng",
+            "train.compile", "train.rng",
             "train.step", "train.profiler", "train.log", "train.callbacks",
             "train.next_batch", "train.triage_wait"} <= names
+    assert "train.cost_analysis" not in names  # XLA's count: nothing read it
+    assert "inferred" in record and record["clock_offset_ns"] == 0  # CPU
     compile_span = scope_reduce.span_events(record, "train.compile")[0]
     assert set(compile_span["args"]) == {"backend_s", "cache_hit"}
     assert set(scope_reduce.span_events(record, "train.lower")[0]["args"]) \
@@ -485,3 +744,23 @@ def test_train_hands_the_loop_a_null_tracer_unless_asked(
     loop.train(tiny_config(profile_dir=str(tmp_path)))
     assert seen[-1] == (True, True)
     assert profiler_mod.last_record()["spans"] == []
+
+
+def test_an_untraced_run_reads_no_text_and_builds_no_graph(monkeypatch):
+    """Without ``profile_dir`` the loop never asks for the compiled step's
+    text on the profiler's behalf: nothing is parsed, no graph is built."""
+    from alphafold2_tpu.train import loop
+
+    reached = []
+    for name in ("instruction_graph", "infer_scopes"):
+        monkeypatch.setattr(
+            profiler_mod, name,
+            lambda *args, name=name: reached.append(name) or ("", {}))
+    monkeypatch.setattr(
+        profiler_mod.Profiler, "name_operations",
+        lambda self, text: reached.append("name_operations"))
+    monkeypatch.setattr(profiler_mod, "_LAST", None)
+    cfg = tiny_config(depth=1, features="none")
+    cfg.train.num_steps = 2
+    loop.train(cfg)
+    assert reached == [] and profiler_mod.last_record() is None
