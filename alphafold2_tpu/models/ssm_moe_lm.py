@@ -149,8 +149,9 @@ class Mamba2Mixer(nn.Module):
     """Returns (output, the layer's counters: the smallest and the mean
     decay of a whole chunk, ``exp(sum_chunk dt A)``, over heads and chunks
     (how little of a state survives one chunk: where even the mean is 0 the
-    carried term is dead and the recurrence over chunks does no work), and
-    the mean time step)."""
+    carried term is dead and the recurrence over chunks does no work), the
+    mean time step, and ``scan_in_kernel``: 1.0 where the scan ran through
+    the Pallas kernels, 0.0 where it fell back to the XLA form)."""
 
     cfg: SsmLMConfig
     dtype: Any = jnp.float32
@@ -175,13 +176,15 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("scan"):
             x = x.reshape(b, s, heads, width)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            b_in, c_in = (t.reshape(b, s, groups, n) for t in (b_in, c_in))
             y, _, chunk_decay = ssm.ssd_scan(
-                x, dt, -jnp.exp(a_log), b_in.reshape(b, s, groups, n),
-                c_in.reshape(b, s, groups, n), c.chunk_size, self.dtype)
+                x, dt, -jnp.exp(a_log), b_in, c_in, c.chunk_size, self.dtype)
             y = y + skip[:, None] * x.astype(jnp.float32)
             counters = {"chunk_decay_min": chunk_decay.min(),
                         "chunk_decay_mean": chunk_decay.mean(),
-                        "dt_mean": dt.mean()}
+                        "dt_mean": dt.mean(),
+                        "scan_in_kernel": jnp.float32(ssm.scan_kernel_takes(
+                            x.shape, b_in.shape, c.chunk_size))}
         normed = GatedGroupRMSNorm(
             groups, c.rms_norm_eps, self.dtype, name="gate_norm")(
             y.reshape(b, s, inner), z)

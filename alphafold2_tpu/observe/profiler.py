@@ -67,6 +67,11 @@ PYTHON_TRACER_LEVEL = 0
 # between memory spaces is two steps from its kernel (start, done), a tuple
 # and its element two more
 WALK_DEPTH = 6
+# and how far where neither walk of that depth found a path: a prefetch the
+# compiler slices, lays out anew and slices again is nine steps from the
+# fusion that reads it (copy-start, copy-done, slice-start, slice-done, a
+# ConcatBitcast, a copy, slice-start, slice-done, a ConcatBitcast)
+FAR_WALK_DEPTH = 12
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -161,8 +166,9 @@ def infer_scopes(graph: dict) -> Tuple[dict, dict]:
     consumes its result, breadth-first through consumers that have none, in
     its own computation and ``WALK_DEPTH`` steps at most (``copy-start`` ->
     ``copy-done`` -> the kernel); ``operand``, the same walk backwards
-    through what made its operands. What none reaches keeps what the text
-    gave it.
+    through what made its operands; then ``user`` once more,
+    ``FAR_WALK_DEPTH`` steps at most, so that what the short walks settled
+    stays as they settled it. What none reaches keeps what the text gave it.
 
     ``kin`` comes before the three for the instructions that carry a name of
     XLA's own (``ragged-dot-none``: a kernel XLA wrote in place of one
@@ -195,11 +201,11 @@ def infer_scopes(graph: dict) -> Tuple[dict, dict]:
         counts = bodies.get(at.calls)
         return max(counts, key=counts.get) if counts else ""
 
-    def nearest(name, neighbours):
+    def nearest(name, neighbours, depth=None):
         """The paths of the nearest instructions that have one, along
         ``neighbours``, nearest level first and in the text's order."""
         seen, level = {name}, [name]
-        for _ in range(WALK_DEPTH):
+        for _ in range(depth or WALK_DEPTH):
             level = [n for near in level for n in neighbours(near)
                      if n in graph and n not in seen
                      and graph[n].computation == graph[name].computation
@@ -234,6 +240,9 @@ def infer_scopes(graph: dict) -> Tuple[dict, dict]:
             scope, rule = next(iter(nearest(name, consumers)), ""), "user"
         if not scope:
             scope, rule = next(iter(nearest(name, producers)), ""), "operand"
+        if not scope:
+            scope, rule = next(iter(nearest(
+                name, consumers, FAR_WALK_DEPTH)), ""), "user"
         if scope and not read_scope(at.op_name):
             inferred[name] = rule
         if scope or at.op_name:

@@ -32,14 +32,26 @@ products take ``dtype`` operands and accumulate in float32. ``L`` is built
 from differences of the cumulative sums inside the mask, never as a ratio of
 exponentials: ``exp(cum_i) / exp(cum_j)`` is 0 / 0 as soon as a chunk's
 decay underflows, and the masked-out upper triangle (positive differences)
-is never exponentiated. The backward pass is autodiff's through these
-products.
+is never exponentiated.
+
+Two forms compute the chunks, one algorithm and the same casts, chosen by
+``scan_kernel_takes`` from the backend and the shapes alone. On a TPU, at
+shapes on the (8, 128) tile grid (the hybrid model's: chunk 128, 128 state
+rows, 8 heads of 64 a group), a forward and a backward Pallas kernel
+(``ops/pallas/ssd.py``) that keep a chunk's decay matrix, its scores and the
+carried state in VMEM and form them again in the backward pass; there the
+cumulative sum alone is XLA's and autodiff's. Everywhere else, and as the
+second oracle of the tests, ``ssd_chunks_xla``: the batched products and the
+``lax.scan`` below as XLA operations, whose backward pass is autodiff's
+through them (each (chunk x chunk) matrix a head goes to HBM and back).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from alphafold2_tpu.ops.pallas import ssd
 
 
 def causal_conv(x, weight, bias):
@@ -58,6 +70,21 @@ def causal_conv(x, weight, bias):
     return jax.nn.silu(out).astype(x.dtype)
 
 
+def scan_kernel_takes(x_shape, b_shape, chunk: int) -> bool:
+    """Whether the Pallas kernels of ``ops/pallas/ssd.py`` serve a scan of
+    ``x`` (B, T, H, P) with ``b`` (B, T, G, N): on a TPU, when the chunk and
+    the state rows N are multiples of 128, a head's width P is a multiple
+    of 16 and a group's heads H / G are a multiple of 8 (or there is one
+    group), so that every block and every head's slice inside a grid step
+    lies on the (8, 128) tile grid (16 rows a tile in bfloat16). Any other
+    shape, and every backend but the TPU, runs ``ssd_chunks_xla``."""
+    heads, width = x_shape[2], x_shape[3]
+    rep = heads // b_shape[2]
+    return (jax.default_backend() == "tpu"
+            and chunk % 128 == 0 and b_shape[3] % 128 == 0
+            and width % 16 == 0 and (rep % 8 == 0 or rep == heads))
+
+
 def ssd_scan(x, dt, a, b, c, chunk: int, dtype=jnp.float32, state=None):
     """The recurrence above without its ``D x`` term, in chunks.
 
@@ -70,16 +97,27 @@ def ssd_scan(x, dt, a, b, c, chunk: int, dtype=jnp.float32, state=None):
 
     A length off the chunk grid is padded at the end with ``dt = 0`` steps,
     which leave the state alone (decay 1, nothing added), and the output is
-    sliced."""
-    batch, length, heads, width = x.shape
-    groups, n = b.shape[2], b.shape[3]
-    rep = heads // groups
+    sliced. The chunks run through the kernels where ``scan_kernel_takes``
+    the shapes and through ``ssd_chunks_xla`` elsewhere."""
+    length = x.shape[1]
     pad = (-length) % chunk
     if pad:
         x, dt, b, c = (
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (x, dt, b, c))
-    chunks = (length + pad) // chunk
+    form = ssd.ssd_chunks if scan_kernel_takes(x.shape, b.shape, chunk) \
+        else ssd_chunks_xla
+    y, state, chunk_decay = form(x, dt, a, b, c, chunk, dtype, state)
+    return y[:, :length] if pad else y, state, chunk_decay
+
+
+def ssd_chunks_xla(x, dt, a, b, c, chunk: int, dtype=jnp.float32, state=None):
+    """``ssd_scan`` at a length on the chunk grid as batched products and a
+    ``lax.scan`` over the chunks, differentiated by autodiff."""
+    batch, length, heads, width = x.shape
+    groups, n = b.shape[2], b.shape[3]
+    rep = heads // groups
+    chunks = length // chunk
 
     def grouped(t, *tail):  # (B, T, ...) -> (B, chunks, chunk, G, ...)
         return t.reshape(batch, chunks, chunk, groups, *tail)
@@ -125,7 +163,6 @@ def ssd_scan(x, dt, a, b, c, chunk: int, dtype=jnp.float32, state=None):
     carried = jnp.einsum("zkign,zkgrnp->zkgrip", c, entering.astype(dtype),
                          preferred_element_type=jnp.float32)
     y = y + jnp.exp(cum)[..., None] * carried
-    y = jnp.moveaxis(y, 4, 2).reshape(batch, chunks * chunk, heads, width)
-    return (y[:, :length] if pad else y,
+    return (jnp.moveaxis(y, 4, 2).reshape(batch, length, heads, width),
             state.reshape(batch, heads, n, width),
             chunk_decay.reshape(batch, chunks, heads))

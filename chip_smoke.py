@@ -721,20 +721,13 @@ def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
 SSD_SCAN_TOL = 2e-2
 
 
-def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
-                   chunk=128, seed=0, tol=SSD_SCAN_TOL) -> dict:
-    """``ops/ssm.py`` ``ssd_scan`` in bfloat16 at the hybrid model's cell's
-    shape (64 heads of 64 over 8 groups of 128 state rows, 8,192 steps,
-    chunks of 128) under the published initialisation (a uniform on [1, 16],
-    dt = softplus(N(0, 1) + softplus^-1 of a log-uniform [0.001, 0.1])):
-    the output and its gradients towards x, B, C, dt and A against the
-    float32 per-step recurrence (a ``lax.scan`` over the steps, walked in
-    segments under ``jax.checkpoint``), each error the largest absolute
-    difference over the reference's largest entry."""
+def _scan_inputs(heads, width, groups, n, length, seed=0):
+    """(x, B, C, dt, A, a weight of x's shape) of one sequence under the
+    hybrid model's published initialisation (a uniform on [1, 16], dt =
+    softplus(N(0, 1) + softplus^-1 of a log-uniform [0.001, 0.1])); x, B and
+    C are float32 holding bfloat16-rounded values."""
     import jax
     import jax.numpy as jnp
-
-    from alphafold2_tpu.ops import ssm
 
     keys = jax.random.split(jax.random.key(seed), 7)
     x = jax.random.normal(keys[0], (1, length, heads, width))
@@ -746,9 +739,29 @@ def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
     dt = jax.nn.softplus(
         jax.random.normal(keys[5], (1, length, heads))
         + step + jnp.log(-jnp.expm1(-step)))
-    # both sides read the same bfloat16-rounded x, B and C
     x, b, c = (t.astype(jnp.bfloat16).astype(jnp.float32) for t in (x, b, c))
-    weight = jax.random.normal(keys[6], x.shape)
+    return x, b, c, dt, a, jax.random.normal(keys[6], x.shape)
+
+
+def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
+                   chunk=128, seed=0, tol=SSD_SCAN_TOL) -> dict:
+    """``ops/ssm.py`` ``ssd_scan`` in bfloat16 at the hybrid model's cell's
+    shape (64 heads of 64 over 8 groups of 128 state rows, 8,192 steps,
+    chunks of 128; on a TPU that is the Pallas kernels of
+    ``ops/pallas/ssd.py``, and the record's ``implementation`` says which
+    form ran: a fall-back to the XLA form there fails the phase) under the
+    published initialisation (``_scan_inputs``): the output and its
+    gradients towards x, B, C, dt and A against the float32 per-step
+    recurrence (a ``lax.scan`` over the steps, walked in segments under
+    ``jax.checkpoint``), each error the largest absolute difference over the
+    reference's largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.ops import ssm
+
+    x, b, c, dt, a, weight = _scan_inputs(
+        heads, width, groups, n, length, seed)
 
     def recurrence(x, b, c, dt, a):
         rep = heads // groups
@@ -784,6 +797,7 @@ def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
         return float(jnp.max(jnp.abs(got - want))
                      / (jnp.max(jnp.abs(want)) + 1e-30))
 
+    in_kernel = ssm.scan_kernel_takes(x.shape, b.shape, chunk)
     (_, out), grads = both(chunked)(x, b, c, dt, a)
     with jax.default_matmul_precision("highest"):
         (_, out_r), grads_r = both(recurrence)(x, b, c, dt, a)
@@ -791,10 +805,17 @@ def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
             **{f"d{name}": rel_err(g, gr) for name, g, gr in zip(
                 ("x", "B", "C", "dt", "A"), grads, grads_r)}}
     ok = all(e == e and e <= tol for e in errs.values())
-    record = {"phase": "ssd_scan_8k" if length == 8192 else "ssd_scan",
+    at_cell = (heads, width, groups, n, length, chunk) == (
+        64, 64, 8, 128, 8192, 128)
+    record = {"phase": "ssd_scan_8k" if at_cell else "ssd_scan",
               "shape": [1, length, heads, width], "groups": groups,
               "state": n, "chunk": chunk, "tol": tol, "ok": ok,
+              "implementation": "pallas" if in_kernel else "xla",
               **{k: float(f"{v:.3g}") for k, v in errs.items()}}
+    if jax.default_backend() == "tpu" and at_cell and not in_kernel:
+        raise RuntimeError(
+            "the scan fell back to the XLA form at the cell's shape on a "
+            "TPU: " + json.dumps(record))
     if not ok:
         raise RuntimeError(
             "the chunked scan disagrees with the recurrence: "

@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
-"""A/B of the language model's two kernels at the benchmark cell's shapes.
+"""A/B of the language models' kernels at the benchmark cells' shapes.
 
     JAX_PLATFORMS=cpu python scripts/ab_lm_kernels.py --compile-only
                                   # which attention candidates Mosaic takes
     chiprun -- python scripts/ab_lm_kernels.py        # times, on a TPU
     chiprun -- python scripts/ab_lm_kernels.py --kernels attention
+    chiprun -- python scripts/ab_lm_kernels.py --kernels scan
 
 (a) The causal core, 2 x 32 heads x 8,192 x 8,192, forward and forward +
     backward: the stock flash kernel at head 256 (q/k 192 and v 128
@@ -16,6 +17,11 @@
     which an eighth is live: ``jax.lax.ragged_dot`` against the stock
     megablox ``gmm`` at three tilings, forward + backward of the expert
     SwiGLU (gate/up as one product, then down).
+(c) The chunked state-space scan of the hybrid model's cell, 1 x 8,192 steps
+    of 64 heads x 64 over 8 groups of 128 state rows in chunks of 128,
+    bfloat16 products: the XLA form (``ops/ssm.py`` ``ssd_chunks_xla``)
+    beside the Pallas kernels (``ops/pallas/ssd.py``), forward alone and
+    forward + backward towards x, dt, A, B and C.
 
 Each candidate is jitted alone, warmed once, timed best of ``--reps`` with
 ``block_until_ready``. One JSON line a candidate on stdout and in
@@ -212,12 +218,38 @@ def gmm_candidates(reps):
         yield rec
 
 
+def scan_candidates(reps):
+    from alphafold2_tpu.ops import ssm
+    from alphafold2_tpu.ops.pallas import ssd
+
+    heads, width, groups, n, length, chunk = 64, 64, 8, 128, 8192, 128
+    x, b, c, dt, a, weight = chip_smoke._scan_inputs(
+        heads, width, groups, n, length)
+    x, b, c = (t.astype(jnp.bfloat16) for t in (x, b, c))
+    for name, form in (("xla", ssm.ssd_chunks_xla),
+                       ("pallas", ssd.ssd_chunks)):
+        def fwd(x, dt, a, b, c, form=form):
+            return form(x, dt, a, b, c, chunk, jnp.bfloat16)[0]
+
+        def both(*args, fwd=fwd):
+            return jax.grad(lambda *v: (fwd(*v) * weight).sum(),
+                            argnums=(0, 1, 2, 3, 4))(*args)
+
+        rec = {"kernel": "ssd_scan_8k", "impl": name}
+        try:
+            rec["fwd_ms"] = best_ms(jax.jit(fwd), (x, dt, a, b, c), reps)
+            rec["fwd_bwd_ms"] = best_ms(jax.jit(both), (x, dt, a, b, c), reps)
+        except Exception as e:
+            rec["error"] = repr(e)[:300]
+        yield rec
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--out", default="chiprun_out/ab_lm_kernels.jsonl")
-    ap.add_argument("--kernels", choices=("attention", "experts", "all"),
-                    default="all")
+    ap.add_argument("--kernels", default="all",
+                    choices=("attention", "experts", "scan", "all"))
     ap.add_argument("--compile-only", action="store_true",
                     help="compile the attention candidates for one chip of a "
                          "described v5e (JAX_PLATFORMS=cpu, no TPU attached): "
@@ -243,6 +275,8 @@ def main(argv=None) -> int:
                  lambda: splash_candidates(args.reps, sharding)]
     if args.kernels in ("experts", "all"):
         gens.append(lambda: gmm_candidates(args.reps))
+    if args.kernels in ("scan", "all"):
+        gens.append(lambda: scan_candidates(args.reps))
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "a") as out:
         for gen in gens:

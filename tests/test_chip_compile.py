@@ -647,15 +647,40 @@ def test_grouped_core_32_over_2_compiles_for_v5e(one_chip, monkeypatch,
     assert not re.search(r"= bf16\[1,32,8192,128\]\S* broadcast\(", text)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("length", [8192, 8192 - 40])
+def test_state_space_scan_compiles_for_v5e(one_chip, monkeypatch, length,
+                                           direction):
+    """The third language-model cell's scan, 64 heads of 64 over 8 groups of
+    128 state rows in chunks of 128, through ``ssm.ssd_scan`` on the TPU
+    branch: the two kernels of ``ops/pallas/ssd.py`` (a length off the chunk
+    grid padded first), and no decay matrix a head in HBM."""
+    from alphafold2_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def scan(x, b, c, dt, a):
+        return ssm.ssd_scan(x, dt, a, b, c, 128, jnp.bfloat16)[0]
+
+    text = _compile(
+        scan if direction == "fwd" else _grad_of(scan), one_chip,
+        ((1, length, 64, 64), "bfloat16"), ((1, length, 8, 128), "bfloat16"),
+        ((1, length, 8, 128), "bfloat16"), ((1, length, 64), "float32"),
+        ((64,), "float32"))
+    assert "ssd_chunk_fwd" in text
+    assert ("ssd_chunk_bwd" in text) == (direction == "bwd")
+    assert "8,8,128,128]" not in text and " while(" not in text
+
+
 def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted train step of the benchmark's third language-model
     cell (667 M parameters, 1 x 8,192 tokens) for one described chip: the
     splash kernels under the attention layer's core, each once (the forward
     too: the layer's recomputation finds its output and log-sum-exp kept;
     the backward fused), XLA's ragged-product kernels for the held experts,
-    the chunk recurrence's ``while`` body under the scan's scope, no array
-    of length x length anywhere (a state-space layer's largest is the decay
-    matrix, chunks x heads x 128 x 128), and weights + Adam + activations
+    the scan's two kernels under the scan's scope, no array of length x
+    length anywhere and no decay matrix either (chunks x heads x 128 x 128:
+    it stays in the kernels' registers), and weights + Adam + activations
     under 15.0e9 B of the 15.75 GiB the compiler leaves."""
     import os
     import sys
@@ -696,11 +721,20 @@ def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     assert "flash_attention" not in text
     assert "ragged-dot" in text  # the grouped product is a kernel, not dense
     assert "8192,8192]" not in text  # no T x T array, in any layer
+    assert "8,8,128,128]" not in text  # nor a chunk's decay matrix a head
     scopes = instruction_scopes(text)[1].values()
-    # the recurrence over the chunks is a while loop, and its body's
-    # instructions carry the scan's scope: a trace reduced by names finds them
-    looped = [s for s in scopes if "/while/body/" in s]
-    assert looped and all("/ssm/scan/" in s for s in looped)
+    # the scan is the two kernels of ops/pallas/ssd.py under the scan's scope
+    # (a trace reduced by names finds them): a layer's forward twice (the
+    # layer's recomputation keeps nothing of it) and its backward once; no
+    # chunk recurrence is left as a while loop
+    forward = _kernel_scopes(text, "ssd_chunk_fwd")
+    backward = _kernel_scopes(text, "ssd_chunk_bwd")
+    for layer in (0, 2, 4, 7):
+        here = f"layer_{layer}/ssm/scan/"
+        assert sum(here in s for s in forward) == 2, (layer, forward)
+        assert sum(here in s for s in backward) == 1, (layer, backward)
+    assert len(forward) == 8 and len(backward) == 4
+    assert not any("/while/body/" in s for s in scopes)
     for layer in (0, 2, 4, 7):
         for part in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
             assert any(f"layer_{layer}/ssm/{part}/" in s for s in scopes), \

@@ -98,6 +98,9 @@ def test_scan_phase_agrees_with_the_recurrence_at_a_small_size():
     small = dict(heads=4, width=8, groups=2, n=16, length=96, chunk=16)
     rec = chip_smoke.phase_ssd_scan(**small)
     assert rec["ok"] and rec["phase"] == "ssd_scan"
+    # off the TPU (and off the tile grid) the XLA form ran, and the record
+    # says so; on a TPU a fall-back at the cell's shape fails the phase
+    assert rec["implementation"] == "xla"
     assert set(rec) >= {"fwd", "dx", "dB", "dC", "ddt", "dA"}
     assert 0 < max(rec[k] for k in ("fwd", "dx", "dB", "dC", "ddt", "dA")) \
         <= chip_smoke.SSD_SCAN_TOL

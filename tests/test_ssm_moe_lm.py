@@ -230,7 +230,8 @@ def test_the_scans_counters_ride_beside_the_routings(both_sides):
     assert sorted(metrics) == [
         "moe/assignments_here", "moe/dropped", "moe/hist",
         "moe/load_max_over_mean", "moe/rows_computed",
-        "ssm/chunk_decay_mean", "ssm/chunk_decay_min", "ssm/dt_mean"]
+        "ssm/chunk_decay_mean", "ssm/chunk_decay_min", "ssm/dt_mean",
+        "ssm/scan_in_kernel"]
     # every rung's rows hold what the layer was sent (at this size: one rung)
     assert (metrics["moe/rows_computed"]
             >= metrics["moe/assignments_here"]).all()
@@ -240,6 +241,8 @@ def test_the_scans_counters_ride_beside_the_routings(both_sides):
     low, mean = metrics["ssm/chunk_decay_min"], metrics["ssm/chunk_decay_mean"]
     assert (0.0 <= low).all() and (low <= mean).all() and (mean < 1.0).all()
     assert (metrics["ssm/dt_mean"] > 0.0).all()
+    # off the TPU (and off the tile grid) every layer's scan is the XLA form
+    np.testing.assert_array_equal(metrics["ssm/scan_in_kernel"], 0.0)
 
 
 # ------------------------------------- (b) the state-space mixer, whole ---

@@ -5,6 +5,7 @@ dense loop; tiny, on the CPU. The model these serve is tested in
 ``tests/test_ssm_moe_lm.py``.
 """
 
+import functools
 import os
 import sys
 
@@ -18,7 +19,18 @@ sys.path.insert(0, ROOT)
 
 from alphafold2_tpu.models.ssm_moe_lm import relu2  # noqa: E402
 from alphafold2_tpu.ops import moe, ssm  # noqa: E402
+from alphafold2_tpu.ops.pallas import ssd  # noqa: E402
 from benchmark.reference import ssm_lm_model as ref  # noqa: E402
+
+
+@pytest.fixture(params=["xla", "pallas"])
+def scan(request, monkeypatch):
+    """``ssm.ssd_scan`` through each form of its chunks: the XLA products,
+    and the kernels of ``ops/pallas/ssd.py``, interpreted (these shapes are
+    off the predicate's tile grid, which interpretation does not need)."""
+    monkeypatch.setattr(ssm, "scan_kernel_takes",
+                        lambda *shapes: request.param == "pallas")
+    return ssm.ssd_scan
 
 
 def scan_inputs(length, groups, heads=4, width=8, n=16, batch=1, seed=0):
@@ -51,52 +63,65 @@ def output_and_gradients(scan, weight):
     return jax.jit(run)
 
 
+@functools.lru_cache(maxsize=None)
+def reference_readings(length, groups):
+    """What ``test_chunked_scan_is_the_per_step_recurrence`` compares with,
+    once a (length, groups) and not once a case."""
+    args = scan_inputs(length, groups)
+    weight = jax.random.normal(jax.random.key(9), args[0].shape)
+    return output_and_gradients(recurrence, weight)(*args)
+
+
 @pytest.mark.parametrize("groups", [1, 2])
 @pytest.mark.parametrize("chunk", [16, 128])
 @pytest.mark.parametrize("length", [128, 200, 384])
-def test_chunked_scan_is_the_per_step_recurrence(length, chunk, groups):
+def test_chunked_scan_is_the_per_step_recurrence(length, chunk, groups, scan):
     """Lengths on and off the chunk grid, a chunk as long as the sequence,
     heads sharing B and C in one group or two: output, final state and the
-    gradients towards x, dt, A, B and C."""
+    gradients towards x, dt, A, B and C, through either form."""
     args = scan_inputs(length, groups)
     weight = jax.random.normal(jax.random.key(9), args[0].shape)
     y, state, grads = output_and_gradients(
-        lambda *a: ssm.ssd_scan(*a, chunk), weight)(*args)
-    want_y, want_state, want = output_and_gradients(recurrence, weight)(*args)
+        lambda *a: scan(*a, chunk), weight)(*args)
+    want_y, want_state, want = reference_readings(length, groups)
     scale = float(jnp.abs(want_y).max())
     np.testing.assert_allclose(y, want_y, rtol=1e-4, atol=1e-5 * scale)
     np.testing.assert_allclose(state, want_state, rtol=1e-4, atol=1e-4)
-    assert ssm.ssd_scan(*args, chunk)[2].shape == (1, -(-length // chunk), 4)
+    decays = scan(*args, chunk)[2]
+    assert decays.shape == (1, -(-length // chunk), 4)
+    padded = jnp.pad(args[1] * args[2], ((0, 0), (0, -length % chunk), (0, 0)))
+    np.testing.assert_allclose(
+        decays, jnp.exp(padded.reshape(1, -1, chunk, 4).sum(2)), rtol=1e-4)
     for name, got, ref_grad in zip(("x", "dt", "A", "B", "C"), grads, want):
         np.testing.assert_allclose(
             got, ref_grad, rtol=2e-4,
             atol=2e-5 * float(jnp.abs(ref_grad).max()), err_msg=f"d{name}")
 
 
-def test_a_decay_that_underflows_inside_a_chunk_gives_no_nan():
+def test_a_decay_that_underflows_inside_a_chunk_gives_no_nan(scan):
     """dt A = -500 a step: exp of a chunk's sum is 0 in float32 and a ratio
     of exponentials would be 0 / 0. The decay matrix is built from
     differences inside the mask, so the output (each step sees itself) and
     every gradient stay finite, and equal the recurrence's."""
     x, _, _, b, c = scan_inputs(256, 1)
     dt, a = jnp.full((1, 256, 4), 50.0), jnp.full((4,), -10.0)
-    y, state, decays = ssm.ssd_scan(x, dt, a, b, c, 128)
+    y, state, decays = scan(x, dt, a, b, c, 128)
     assert float(decays.max()) == 0.0
     want, _ = jax.jit(recurrence)(x, dt, a, b, c)
     assert bool(jnp.isfinite(y).all())
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-3)
     grads = jax.jit(jax.grad(
-        lambda *args: ssm.ssd_scan(*args, 128)[0].sum(),
+        lambda *args: scan(*args, 128)[0].sum(),
         argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
 
 
-def test_a_later_step_reaches_no_earlier_output():
+def test_a_later_step_reaches_no_earlier_output(scan):
     """Causality at the ops: step 40 of the scan's input moves outputs 40..
     and none before; step 9 of the convolution's moves 9..12 (four taps)."""
     args = scan_inputs(64, 2)
-    y, _, _ = ssm.ssd_scan(*args, 16)
-    y2, _, _ = ssm.ssd_scan(args[0].at[:, 40].add(1.0), *args[1:], 16)
+    y, _, _ = scan(*args, 16)
+    y2, _, _ = scan(args[0].at[:, 40].add(1.0), *args[1:], 16)
     gap = np.abs(np.asarray(y2 - y)).max((0, 2, 3))
     np.testing.assert_array_equal(gap[:40], 0.0)
     assert (gap[40:] > 0).all()
@@ -107,6 +132,58 @@ def test_a_later_step_reaches_no_earlier_output():
     gap = np.abs(np.asarray(conv2 - conv)).max((0, 2))
     assert (gap[:9] == 0).all() and (gap[9:13] > 0).all() \
         and (gap[13:] == 0).all()
+
+
+def test_the_kernels_in_bfloat16_are_the_xla_form_in_bfloat16():
+    """bfloat16 operands, a state entering, two groups: the kernels against
+    the XLA form at the same ``dtype``. The forward casts sit where the XLA
+    form's do, so output and state differ by accumulation order alone; the
+    backward products take their cotangents rounded to bfloat16 in both
+    (the XLA form's score cotangent is bfloat16 by type), in another order."""
+    x, dt, a, b, c = scan_inputs(64, 2, batch=2)
+    x, b, c = (t.astype(jnp.bfloat16) for t in (x, b, c))
+    state = jax.random.normal(jax.random.key(5), (2, 4, 16, 8))
+    weight = jax.random.normal(jax.random.key(9), x.shape)
+
+    def run(chunks):
+        def loss(x, dt, a, b, c, state):
+            y, final, _ = chunks(x, dt, a, b, c, 16, jnp.bfloat16, state)
+            return (y * weight).sum() + final.sum(), (y, final)
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4, 5), has_aux=True))(
+            x, dt, a, b, c, state)
+
+    (_, (y, final)), grads = run(ssd.ssd_chunks)
+    (_, (want_y, want_final)), want = run(ssm.ssd_chunks_xla)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(final, want_final, rtol=1e-5, atol=1e-5)
+    for name, got, ref_grad in zip(
+            ("x", "dt", "A", "B", "C", "state"), grads, want):
+        assert got.dtype == ref_grad.dtype and got.shape == ref_grad.shape
+        got, ref_grad = (np.asarray(g, np.float32) for g in (got, ref_grad))
+        assert np.abs(got - ref_grad).max() <= 2e-2 * np.abs(ref_grad).max(), \
+            f"d{name}"
+
+
+def test_the_kernels_take_tiled_shapes_on_a_tpu_and_nothing_elsewhere(
+        monkeypatch):
+    """The rule of ``scan_kernel_takes``: chunk and state rows multiples of
+    128, a head's width a multiple of 16, a group's heads a multiple of 8 or
+    one group; and no shape at all off the TPU."""
+    cell = ((1, 8192, 64, 64), (1, 8192, 8, 128), 128)
+    assert not ssm.scan_kernel_takes(*cell)  # the tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssm.scan_kernel_takes(*cell)
+    assert ssm.scan_kernel_takes((2, 512, 8, 128), (2, 512, 1, 256), 256)
+    assert ssm.scan_kernel_takes((1, 256, 4, 32), (1, 256, 1, 128), 128)
+    for x_shape, b_shape, chunk in (
+            ((1, 8192, 64, 64), (1, 8192, 8, 128), 64),    # the chunk
+            ((1, 8192, 64, 64), (1, 8192, 8, 64), 128),    # the state rows
+            ((1, 8192, 64, 64), (1, 8192, 16, 128), 128),  # 4 heads a group
+            ((1, 8192, 64, 24), (1, 8192, 8, 128), 128),   # a head's width
+            ((1, 128, 4, 8), (1, 128, 2, 16), 16)):        # this file's sizes
+        assert not ssm.scan_kernel_takes(x_shape, b_shape, chunk)
 
 
 def test_the_convolutions_last_tap_meets_the_current_step():
@@ -125,16 +202,17 @@ def test_the_convolutions_last_tap_meets_the_current_step():
 
 
 @pytest.mark.parametrize("cut", [16, 21])
-def test_a_sequence_split_in_two_with_its_state_carried_is_the_whole(cut):
+def test_a_sequence_split_in_two_with_its_state_carried_is_the_whole(
+        cut, scan):
     """The first half's final state and last three convolution inputs carried
     into the second half give the whole sequence's output, for a cut on and
     off the chunk grid."""
     args = scan_inputs(40, 2)
-    whole, final, _ = ssm.ssd_scan(*args, 16)
+    whole, final, _ = scan(*args, 16)
     first = [t[:, :cut] if t.ndim > 1 else t for t in args]
     second = [t[:, cut:] if t.ndim > 1 else t for t in args]
-    y1, state, _ = ssm.ssd_scan(*first, 16)
-    y2, final2, _ = ssm.ssd_scan(*second, 16, state=state)
+    y1, state, _ = scan(*first, 16)
+    y2, final2, _ = scan(*second, 16, state=state)
     np.testing.assert_allclose(
         jnp.concatenate([y1, y2], 1), whole, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(final2, final, rtol=1e-4, atol=1e-5)

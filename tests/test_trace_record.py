@@ -301,6 +301,36 @@ def test_a_walk_stops_at_its_depth_and_at_its_computation(monkeypatch):
     assert inferred["copy-done.1"] == "user"
 
 
+@pytest.mark.parametrize("steps, found", [(6, "user"), (9, "user"),
+                                          (12, "user"), (13, None)])
+def test_a_prefetch_sliced_twice_finds_its_reader_by_the_far_walk(
+        steps, found):
+    """A conditional's argument moved, sliced, laid out anew and sliced
+    again before a named fusion reads it (nine unnamed steps in the third
+    decoder's expert branches): nothing made it that has a name, the short
+    walk ends after six, the far one after twelve."""
+    chain = ["  %p.1 = f32[8]{0} parameter(0)"]
+    for i in range(steps):  # copy.2 is ``steps`` steps from the fusion
+        chain.append(f"  %copy.{i + 2} = f32[8]{{0}} copy(%{chain_name(i)})")
+    chain.append(
+        f"  ROOT %fusion.99 = f32[8]{{0}} fusion(%{chain_name(steps)}), "
+        f'kind=kLoop, calls=%fused_computation.2, metadata={{op_name="{LM}'
+        '/layer_1/moe/dispatch/gather"}')
+    text = UNNAMED_TEXT[:UNNAMED_TEXT.index("ENTRY")] \
+        + "ENTRY %main.1 (p.1: f32[8]) -> f32[8] {\n" \
+        + "\n".join(chain) + "\n}\n"
+    scopes, inferred = profiler_mod.infer_scopes(
+        profiler_mod.instruction_graph(text)[1])
+    assert inferred.get("copy.2") == found
+    assert scopes.get("copy.2", "") == (
+        f"{LM}/layer_1/moe/dispatch/gather" if found else "")
+    assert inferred[f"copy.{steps + 1}"] == "user"  # one step from it
+
+
+def chain_name(i):
+    return "p.1" if i == 0 else f"copy.{i + 1}"
+
+
 # A trace of that step, as the TPU writes one: the key split's program, then
 # the step's, with the loop thread's spans on a host clock that lags the
 # device's by a millisecond. Own device microseconds of the step: fusion.2
