@@ -21,6 +21,7 @@ class ModelConfig:
     # which (model, loss, batch spec) train() builds: "alphafold2" (the axial
     # trunk, the fields below) | "mla_moe_lm" (the ``lm`` section) |
     # "swa_moe_lm" (the ``swa`` section) | "ssm_moe_lm" (the ``ssm`` section)
+    # | "hybrid_dense_lm" (the ``hybrid`` section)
     arch: str = "alphafold2"
     dim: int = 256  # trunk embedding width (single-repr channels)
     max_seq_len: int = 2048  # positional-embedding table size (max residues)
@@ -133,7 +134,11 @@ class SsmLMConfig:
     ``ssm_moe_lm``. The defaults are the published sizes of a
     Nemotron-H-shaped 30B-A3B model, whole; the share (``experts_held``,
     ``first_expert``, a slice of the vocabulary) and fewer layers are for the
-    caller to set, as in ``LMConfig``."""
+    caller to set, as in ``LMConfig``. The attention layer's softmax scale
+    is ``head_dim ** -0.5`` (``GroupedAttention``'s default: this section
+    has no multiplier) and the head is a table of its own, untied; the
+    dense hybrid's section, ``HybridDenseLMConfig``, is where a scale and a
+    tie come from the configuration."""
 
     vocab_size: int = 131072  # vocabulary rows held here (ids 0..vocab_size-1)
     hidden_size: int = 2688  # residual stream width
@@ -161,6 +166,49 @@ class SsmLMConfig:
     # the share: experts first_expert .. first_expert + experts_held - 1
     experts_held: int = 128  # routed experts this chip holds a layer
     first_expert: int = 0  # id of the first expert held
+    bfloat16: bool = True  # compute dtype (weights stay float32)
+
+
+@dataclass
+class HybridDenseLMConfig:
+    """Decoder-only dense hybrid language model: every layer is a mixer and
+    then a gated MLP, each behind its own RMSNorm and each added to the
+    stream times ``residual_multiplier``; the mixer is a Mamba-2 state-space
+    mixer (``M``) or full causal grouped-query attention without positions
+    (``*``), read from ``layer_pattern`` (models/hybrid_dense_lm.py), read
+    when ``model.arch`` is ``hybrid_dense_lm``. The defaults are the
+    published sizes of a Granite-4.0-H-shaped 3B model
+    (``granitemoehybrid`` with no experts), whole. There is no share of
+    experts to hold: a chip's cut is fewer layers (a pipeline stage) and a
+    slice of the vocabulary, for the caller to set. The softmax scale is
+    ``attention_multiplier`` and not ``head_dim ** -0.5`` (the model hands
+    it to ``GroupedAttention``), and the output head is the embedding table
+    itself (``logits = h E^T / logits_scaling``): there is no switch for
+    either, the family publishes no untied or unscaled member."""
+
+    vocab_size: int = 100352  # vocabulary rows held here (ids 0..vocab_size-1)
+    hidden_size: int = 2048  # residual stream width
+    num_layers: int = 40  # layers run: the pattern's first num_layers entries
+    # one character a layer: M state-space, * attention (layer_types)
+    layer_pattern: str = "MMMMM*MMMM" * 4
+    intermediate_size: int = 8192  # the gated MLP's width, every layer
+    mamba_num_heads: int = 64  # state-space heads
+    mamba_head_dim: int = 64  # width of a state-space head (inner 64 x 64)
+    ssm_groups: int = 1  # groups sharing B and C: one, read by all 64 heads
+    ssm_state_size: int = 128  # state rows a head
+    conv_kernel: int = 4  # taps of the causal depthwise convolution
+    chunk_size: int = 256  # steps a chunk of the chunked scan (ops/ssm.py)
+    time_step_min: float = 0.001  # dt_bias starts at softplus^-1 of a time
+    time_step_max: float = 0.1  # step drawn log-uniform between these two
+    time_step_floor: float = 1e-4  # and floored at this
+    num_heads: int = 32  # attention query heads
+    num_kv_heads: int = 8  # key/value heads: query head h reads h // (32 / 8)
+    head_dim: int = 64  # width of every attention head
+    embedding_multiplier: float = 12.0  # on the embedding row, entering
+    residual_multiplier: float = 0.22  # on every mixer's and MLP's output
+    attention_multiplier: float = 0.015625  # the softmax scale, 1 / 64
+    logits_scaling: float = 8.0  # the logits are divided by this
+    rms_norm_eps: float = 1e-5  # inside every norm's rsqrt
     bfloat16: bool = True  # compute dtype (weights stay float32)
 
 
@@ -303,6 +351,8 @@ class Config:
     lm: LMConfig = field(default_factory=LMConfig)  # model.arch "mla_moe_lm"
     swa: SwaLMConfig = field(default_factory=SwaLMConfig)  # "swa_moe_lm"
     ssm: SsmLMConfig = field(default_factory=SsmLMConfig)  # "ssm_moe_lm"
+    # model.arch "hybrid_dense_lm"
+    hybrid: HybridDenseLMConfig = field(default_factory=HybridDenseLMConfig)
     mesh: MeshConfig = field(default_factory=MeshConfig)  # device mesh axes
     data: DataConfig = field(default_factory=DataConfig)  # dataset + features
     train: TrainConfig = field(default_factory=TrainConfig)  # optimizer loop
@@ -314,8 +364,8 @@ class Config:
     def language_model(self):
         """The section of the language model that ``model.arch`` names: its
         ``vocab_size`` is what ``data.source`` "tokens" draws over."""
-        return {"swa_moe_lm": self.swa, "ssm_moe_lm": self.ssm}.get(
-            self.model.arch, self.lm)
+        return {"swa_moe_lm": self.swa, "ssm_moe_lm": self.ssm,
+                "hybrid_dense_lm": self.hybrid}.get(self.model.arch, self.lm)
 
     @classmethod
     def from_json(cls, s: str) -> "Config":
@@ -325,6 +375,7 @@ class Config:
             lm=LMConfig(**raw.get("lm", {})),
             swa=SwaLMConfig(**raw.get("swa", {})),
             ssm=SsmLMConfig(**raw.get("ssm", {})),
+            hybrid=HybridDenseLMConfig(**raw.get("hybrid", {})),
             mesh=MeshConfig(**raw.get("mesh", {})),
             data=DataConfig(**raw.get("data", {})),
             train=_tuplify(TrainConfig(**raw.get("train", {})), "profile_steps"),

@@ -69,13 +69,14 @@ def relu2(a):
     return jnp.square(jax.nn.relu(a))
 
 
-def layer_kinds(cfg: SsmLMConfig) -> str:
-    """The pattern's first ``num_layers`` characters, one a layer."""
+def layer_kinds(cfg, mixers=MIXERS) -> str:
+    """The pattern's first ``num_layers`` characters, one a layer, each a
+    key of ``mixers``."""
     kinds = cfg.layer_pattern[:cfg.num_layers]
-    if len(kinds) < cfg.num_layers or set(kinds) - set(MIXERS):
+    if len(kinds) < cfg.num_layers or set(kinds) - set(mixers):
         raise ValueError(
             f"layer_pattern {cfg.layer_pattern!r} does not name "
-            f"{cfg.num_layers} layers out of {sorted(MIXERS)}")
+            f"{cfg.num_layers} layers out of {sorted(mixers)}")
     return kinds
 
 

@@ -50,11 +50,18 @@ from alphafold2_tpu.ops import mla, moe
 
 class GroupedAttention(nn.Module):
     """``window`` None: a global layer (full causal, no positions); a
-    number: a window layer (that many keys a query, rotary positions)."""
+    number: a window layer (that many keys a query, rotary positions).
+    ``scale`` is the softmax scale, the caller's to give: None is ``head_dim
+    ** -0.5``, what this model and ``models/ssm_moe_lm.py`` publish; a model
+    whose configuration states one (``models/hybrid_dense_lm.py``:
+    ``attention_multiplier`` 1 / 64 at heads of 64, an eighth of the
+    default) hands it in. ``cfg`` is any section with ``num_heads``,
+    ``num_kv_heads``, ``head_dim`` (and ``rope_theta`` under a window)."""
 
     cfg: SwaLMConfig
     window: Optional[int]
     dtype: Any = jnp.float32
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -62,7 +69,8 @@ class GroupedAttention(nn.Module):
         b, s, _ = x.shape
         heads, groups, width = c.num_heads, c.num_kv_heads, c.head_dim
         # the softmax scale rides on q from its projection on, as in MLA
-        q = ScaledDense(heads * width, width ** -0.5, self.dtype,
+        scale = width ** -0.5 if self.scale is None else self.scale
+        q = ScaledDense(heads * width, scale, self.dtype,
                         name="q_proj")(x).reshape(b, s, heads, width)
         k = _dense(groups * width, self.dtype, "k_proj")(x)
         v = _dense(groups * width, self.dtype, "v_proj")(x)

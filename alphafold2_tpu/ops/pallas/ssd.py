@@ -9,7 +9,11 @@ head of every chunk to HBM (268 MB each in float32 at 8,192 steps of 64
 heads) and reads them back for four batched products; here a grid step
 holds one (batch, group, chunk), builds those matrices a head at a time in
 registers, and carries the group's state (heads x width by state rows,
-float32) in a VMEM scratch along the innermost, sequential chunk axis.
+float32) in a VMEM scratch along the innermost, sequential chunk axis. A
+group of more than eight heads (one group of 64 is the dense hybrid's) is
+walked eight heads a grid step (``heads_a_step``): the grid's second axis is
+then the head blocks, each reading its group's B and C, and the backward
+writes one float32 part of ``dB`` and ``dC`` a head block, summed in XLA.
 
 A grid step's blocks, ``rep`` heads sharing one group's B and C, have time
 (a chunk's steps) as the minor axis, as the XLA form's arrays have and as
@@ -218,12 +222,20 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, cum_t_ref,
         dh0_ref[...] = dh_scr[...]
 
 
-def _specs(chunk, rep, rows, n, chunk_of):
-    """The block of one (batch z, group g, grid step k) in each layout;
-    ``chunk_of(k)`` is the chunk that step visits."""
+def _specs(chunk, rep, rows, n, chunk_of, blocks):
+    """The block of one (batch z, head block g, grid step k) in each layout;
+    ``chunk_of(k)`` is the chunk that step visits. ``shared`` is the block
+    of B or C, which the ``blocks`` head blocks of one group all read."""
     def by_time(size):  # (B, G * size, T)
         return pl.BlockSpec(
             (None, size, chunk), lambda z, g, k: (z, g, chunk_of(k)))
+
+    def shared(size):  # (B, G / blocks * size, T)
+        if blocks == 1:
+            return by_time(size)
+        return pl.BlockSpec(
+            (None, size, chunk),
+            lambda z, g, k: (z, g // blocks, chunk_of(k)))
 
     cum_t = pl.BlockSpec(  # (B, G, T, rep)
         (None, None, chunk, rep), lambda z, g, k: (z, g, chunk_of(k), 0))
@@ -231,7 +243,7 @@ def _specs(chunk, rep, rows, n, chunk_of):
         (None, rows, n), lambda z, g, k: (z, g, 0))
     entering = pl.BlockSpec(  # (B, chunks, G * rows, n)
         (None, None, rows, n), lambda z, g, k: (z, chunk_of(k), g, 0))
-    return by_time, cum_t, state, entering
+    return by_time, shared, cum_t, state, entering
 
 
 _PARAMS = pltpu.CompilerParams(
@@ -239,10 +251,11 @@ _PARAMS = pltpu.CompilerParams(
 
 
 def _sizes(x, b, dt, spec):
-    chunk, rep, width = spec[:3]
+    """(batch, head blocks, chunks, a head block's rows of x, state rows)."""
+    chunk, rep, width, _, _, blocks = spec
     groups = dt.shape[1] // rep
     return (x.shape[0], groups, x.shape[2] // chunk, rep * width,
-            b.shape[1] // groups)
+            b.shape[1] * blocks // groups)
 
 
 def _cum_t(cum, rep):  # (B, H, T) -> (B, G, T, rep)
@@ -252,10 +265,10 @@ def _cum_t(cum, rep):  # (B, H, T) -> (B, G, T, rep)
 
 
 def _forward(x, b, c, dt, cum, state, spec, keep: bool):
-    chunk, rep, width, dtype, interpret = spec
+    chunk, rep, width, dtype, interpret, blocks = spec
     batch, groups, chunks, rows, n = _sizes(x, b, dt, spec)
-    by_time, cum_t, whole, entering = _specs(
-        chunk, rep, rows, n, lambda k: k)
+    by_time, shared, cum_t, whole, entering = _specs(
+        chunk, rep, rows, n, lambda k: k, blocks)
     out_shape = [jax.ShapeDtypeStruct(x.shape, F32),
                  jax.ShapeDtypeStruct(state.shape, F32)]
     out_specs = [by_time(rows), whole]
@@ -266,7 +279,7 @@ def _forward(x, b, c, dt, cum, state, spec, keep: bool):
     return pl.pallas_call(
         functools.partial(_fwd_kernel, width=width, dtype=dtype),
         grid=(batch, groups, chunks),
-        in_specs=[by_time(rows), by_time(n), by_time(n), by_time(rep),
+        in_specs=[by_time(rows), shared(n), shared(n), by_time(rep),
                   by_time(rep), cum_t, whole],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((rows, n), F32)],
@@ -275,21 +288,27 @@ def _forward(x, b, c, dt, cum, state, spec, keep: bool):
 
 
 def _backward(x, b, c, dt, cum, entering, dy, dstate, spec):
-    chunk, rep, width, dtype, interpret = spec
+    chunk, rep, width, dtype, interpret, blocks = spec
     batch, groups, chunks, rows, n = _sizes(x, b, dt, spec)
-    by_time, cum_t, whole, entering_spec = _specs(
-        chunk, rep, rows, n, lambda k: chunks - 1 - k)
+    by_time, shared, cum_t, whole, entering_spec = _specs(
+        chunk, rep, rows, n, lambda k: chunks - 1 - k, blocks)
     cum_t_shape = (batch, groups, x.shape[2], rep)
+    # one head block a group writes dB and dC themselves; several each write
+    # a float32 part, summed below
+    if blocks == 1:
+        db_dc = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (b, c)]
+    else:
+        db_dc = [jax.ShapeDtypeStruct(
+            (batch, groups * n, x.shape[2]), F32)] * 2
     dx, db, dc, ddt, dcum, dcum_t, dentering = pl.pallas_call(
         functools.partial(_bwd_kernel, width=width, dtype=dtype),
         grid=(batch, groups, chunks),
-        in_specs=[by_time(rows), by_time(n), by_time(n), by_time(rep),
+        in_specs=[by_time(rows), shared(n), shared(n), by_time(rep),
                   by_time(rep), cum_t, entering_spec, by_time(rows), whole],
         out_specs=[by_time(rows), by_time(n), by_time(n), by_time(rep),
                    by_time(rep), cum_t, whole],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(b.shape, b.dtype),
-                   jax.ShapeDtypeStruct(c.shape, c.dtype),
+                   *db_dc,
                    jax.ShapeDtypeStruct(dt.shape, F32),
                    jax.ShapeDtypeStruct(cum.shape, F32),
                    jax.ShapeDtypeStruct(cum_t_shape, F32),
@@ -298,7 +317,26 @@ def _backward(x, b, c, dt, cum, entering, dy, dstate, spec):
         compiler_params=_PARAMS, interpret=interpret, name="ssd_chunk_bwd",
     )(x, b, c, dt, cum, _cum_t(cum, rep), entering, dy, dstate)
     dcum = dcum + jnp.swapaxes(dcum_t, 2, 3).reshape(cum.shape)
+    if blocks > 1:
+        db, dc = (
+            t.reshape(batch, groups // blocks, blocks, n, -1).sum(2).reshape(
+                b.shape).astype(b.dtype) for t in (db, dc))
     return dx, db, dc, ddt, dcum, dentering
+
+
+HEADS_A_STEP = 8
+
+
+def heads_a_step(rep: int) -> tuple:
+    """(the heads one grid step holds, the grid steps a group's ``rep``
+    heads are spread over). A group of more than ``HEADS_A_STEP`` heads in
+    whole eights is walked eight heads a grid step: at one group of 64 heads
+    of 64 and a chunk of 256 a step would otherwise hold 4,096 rows of x,
+    y and their cotangents (21 MB of blocks against 16 MB of VMEM) and
+    unroll 64 heads. The blocks of a group read the same B and C."""
+    if rep > HEADS_A_STEP and rep % HEADS_A_STEP == 0:
+        return HEADS_A_STEP, rep // HEADS_A_STEP
+    return rep, 1
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -307,7 +345,8 @@ def _chunks(x, b, c, dt, cum, state, spec):
     ``x`` (B, H * P, T); ``b``, ``c`` (B, G * N, T) in the compute type;
     ``dt`` and ``cum`` (B, H, T) float32; ``state`` (B, H * P, N) float32.
     Returns (y (B, H * P, T) float32, the final state in ``state``'s
-    layout). ``spec`` is (chunk, rep, width, compute type, interpret)."""
+    layout). ``spec`` is (chunk, the heads of a grid step, width, compute
+    type, interpret, the grid steps a group's heads are spread over)."""
     return tuple(_forward(x, b, c, dt, cum, state, spec, keep=False))
 
 
@@ -330,7 +369,8 @@ def ssd_chunks(x, dt, a, b, c, chunk: int, dtype=F32, state=None,
     compiles on a TPU and interprets elsewhere."""
     batch, length, heads, width = x.shape
     groups, n = b.shape[2], b.shape[3]
-    rep, chunks = heads // groups, length // chunk
+    chunks = length // chunk
+    rep, blocks = heads_a_step(heads // groups)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -349,7 +389,7 @@ def ssd_chunks(x, dt, a, b, c, chunk: int, dtype=F32, state=None,
         time_last(x), time_last(b.astype(dtype)), time_last(c.astype(dtype)),
         dt, cum.reshape(batch, heads, length),
         state.reshape(batch, heads * width, n),
-        (chunk, rep, width, jnp.dtype(dtype), bool(interpret)))
+        (chunk, rep, width, jnp.dtype(dtype), bool(interpret), blocks))
     state = jnp.swapaxes(state.reshape(batch, heads, width, n), 2, 3)
     return (jnp.swapaxes(y, 1, 2).reshape(batch, length, heads, width), state,
             jnp.moveaxis(jnp.exp(cum[..., -1]), 1, 2))

@@ -36,8 +36,9 @@ is never exponentiated.
 
 Two forms compute the chunks, one algorithm and the same casts, chosen by
 ``scan_kernel_takes`` from the backend and the shapes alone. On a TPU, at
-shapes on the (8, 128) tile grid (the hybrid model's: chunk 128, 128 state
-rows, 8 heads of 64 a group), a forward and a backward Pallas kernel
+shapes on the (8, 128) tile grid (the hybrid models': chunk 128, 128 state
+rows, 8 heads of 64 a group; or chunk 256 and one group of 64 heads, eight
+heads a grid step), a forward and a backward Pallas kernel
 (``ops/pallas/ssd.py``) that keep a chunk's decay matrix, its scores and the
 carried state in VMEM and form them again in the backward pass; there the
 cumulative sum alone is XLA's and autodiff's. Everywhere else, and as the
@@ -76,7 +77,9 @@ def scan_kernel_takes(x_shape, b_shape, chunk: int) -> bool:
     the state rows N are multiples of 128, a head's width P is a multiple
     of 16 and a group's heads H / G are a multiple of 8 (or there is one
     group), so that every block and every head's slice inside a grid step
-    lies on the (8, 128) tile grid (16 rows a tile in bfloat16). Any other
+    lies on the (8, 128) tile grid (16 rows a tile in bfloat16). A group of
+    more than eight heads in whole eights (the dense hybrid's one group of
+    64) is walked eight heads a grid step (``ssd.heads_a_step``). Any other
     shape, and every backend but the TPU, runs ``ssd_chunks_xla``."""
     heads, width = x_shape[2], x_shape[3]
     rep = heads // b_shape[2]

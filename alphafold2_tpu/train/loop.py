@@ -179,7 +179,8 @@ def build_task(cfg: Config) -> Task:
     architecture's modules are imported only when asked for."""
     if cfg.model.arch == "alphafold2":
         return trunk_task(build_model(cfg))
-    if cfg.model.arch in ("mla_moe_lm", "swa_moe_lm", "ssm_moe_lm"):
+    if cfg.model.arch in ("mla_moe_lm", "swa_moe_lm", "ssm_moe_lm",
+                          "hybrid_dense_lm"):
         from alphafold2_tpu.models import mla_moe_lm as lm
 
         if cfg.model.arch == "mla_moe_lm":
@@ -188,15 +189,19 @@ def build_task(cfg: Config) -> Task:
             from alphafold2_tpu.models.swa_moe_lm import SwaMoeLM
 
             model = SwaMoeLM(cfg.swa)
-        else:
+        elif cfg.model.arch == "ssm_moe_lm":
             from alphafold2_tpu.models.ssm_moe_lm import SsmMoeLM
 
             model = SsmMoeLM(cfg.ssm)
+        else:
+            from alphafold2_tpu.models.hybrid_dense_lm import HybridDenseLM
+
+            model = HybridDenseLM(cfg.hybrid)
         return Task(model, partial(lm.forward, model), lm.loss,
                     lm.step_metrics, partial(lm.init, model), lm.tiny_batch)
     raise ValueError(
         f"unknown model.arch {cfg.model.arch!r}; expected 'alphafold2', "
-        "'mla_moe_lm', 'swa_moe_lm' or 'ssm_moe_lm'")
+        "'mla_moe_lm', 'swa_moe_lm', 'ssm_moe_lm' or 'hybrid_dense_lm'")
 
 
 def build_optimizer(cfg: Config) -> optax.GradientTransformation:

@@ -22,7 +22,9 @@ Default phases, on one chip, at the width of the flagship (bench.py):
    highest matmul precision.
 4. ssd_scan_8k — the chunked state-space scan (``ops/ssm.py``) in bfloat16
    at the hybrid language model's shape, output and five gradients against
-   the float32 per-step recurrence.
+   the float32 per-step recurrence; then ssd_scan_8k_one_group_c256, the
+   same at the dense hybrid's (one group read by all 64 heads, chunks of
+   256).
 
 Every phase prints one JSON object on a line of its own. The LAST line of
 stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
@@ -597,6 +599,7 @@ def kernel_cases(small: bool = False) -> list:
         causal("mla_causal_core_small", 1, 2, 160, 24, 16)
         causal("swa_core_small_global", 1, 6, 160, 16, 16, groups=2)
         causal("swa_core_small_window", 1, 6, 160, 16, 16, groups=2, window=50)
+        causal("gqa_core_small_heads_of_64", 1, 8, 160, 64, 64, groups=2)
         grouped("moe_grouped_matmul_small", 256, 32, 16, 4)
         axial("fused_axial_f32", (2, 2, 32, 16), "float32", 0)
         axial("fused_axial_masked_odd", (1, 2, 40, 16), "float32", 7)
@@ -637,6 +640,10 @@ def kernel_cases(small: bool = False) -> list:
     causal("swa_core_16k_global", 1, 28, 16384, 128, 128, groups=4)
     causal("swa_core_16k_window", 1, 28, 16384, 128, 128, groups=4,
            window=4096)
+    # the dense hybrid's attention layer at its cell's shape: 32 query heads
+    # over 8 key/value heads of 64, half the width of any other cell's, at
+    # 8,192 positions (the fused backward)
+    causal("gqa_core_8k_heads_of_64", 1, 32, 8192, 64, 64, groups=8)
     # the mesh cell's cross-attentions: a chip's blocks are 32,768 x 2,048
     # and 2,048 x 32,768 (only where there is a second chip for the ring)
     if len(jax.devices()) >= 2:
@@ -721,6 +728,13 @@ def phase_kernels(small: bool = False, seed: int = 0, only: str = "") -> dict:
 SSD_SCAN_TOL = 2e-2
 
 
+# (heads, width, groups, state rows, steps, chunk) of the cells' scans: the
+# hybrid expert model's, and the dense hybrid's (every head reads the one
+# group; chunks of 256: my chip run, PR 38, is in PERF.md section 6)
+CELL_SCANS = {(64, 64, 8, 128, 8192, 128): "ssd_scan_8k",
+              (64, 64, 1, 128, 8192, 256): "ssd_scan_8k_one_group_c256"}
+
+
 def _scan_inputs(heads, width, groups, n, length, seed=0):
     """(x, B, C, dt, A, a weight of x's shape) of one sequence under the
     hybrid model's published initialisation (a uniform on [1, 16], dt =
@@ -749,7 +763,9 @@ def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
     shape (64 heads of 64 over 8 groups of 128 state rows, 8,192 steps,
     chunks of 128; on a TPU that is the Pallas kernels of
     ``ops/pallas/ssd.py``, and the record's ``implementation`` says which
-    form ran: a fall-back to the XLA form there fails the phase) under the
+    form ran: a fall-back to the XLA form there fails the phase; ``groups=1,
+    chunk=256`` is the dense hybrid's cell, the other of ``CELL_SCANS``)
+    under the
     published initialisation (``_scan_inputs``): the output and its
     gradients towards x, B, C, dt and A against the float32 per-step
     recurrence (a ``lax.scan`` over the steps, walked in segments under
@@ -805,9 +821,8 @@ def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
             **{f"d{name}": rel_err(g, gr) for name, g, gr in zip(
                 ("x", "B", "C", "dt", "A"), grads, grads_r)}}
     ok = all(e == e and e <= tol for e in errs.values())
-    at_cell = (heads, width, groups, n, length, chunk) == (
-        64, 64, 8, 128, 8192, 128)
-    record = {"phase": "ssd_scan_8k" if at_cell else "ssd_scan",
+    at_cell = CELL_SCANS.get((heads, width, groups, n, length, chunk))
+    record = {"phase": at_cell or "ssd_scan",
               "shape": [1, length, heads, width], "groups": groups,
               "state": n, "chunk": chunk, "tol": tol, "ok": ok,
               "implementation": "pallas" if in_kernel else "xla",
@@ -955,6 +970,7 @@ def main(argv=None) -> int:
             emit(phase_serve())
             emit(phase_kernels())
             emit(phase_ssd_scan())
+            emit(phase_ssd_scan(groups=1, chunk=256))
         emit({"phase": "done", "seconds": round(time.perf_counter() - t0, 1)})
     except Exception as e:  # the boundary: report, then fail
         import traceback

@@ -131,24 +131,30 @@ def test_the_cell_resolves_to_files_of_its_own_kind():
 
 def test_the_manifest_keeps_what_it_had():
     """The four cells and their metrics as before; every list only gained
-    the new cell at its end."""
+    the new cell at its end (and, since, the cells of later PRs after it)."""
+    later = ["train_granite4_h_micro_pp4_seq8k"]
     m = manifest()
-    assert [w["name"] for w in m["workloads"]] == [
+    assert [w["name"] for w in m["workloads"]][:5] == [
         "train_flagship", "train_mesh_dp2sp2", "train_kanana2_ep8_seq8k",
         "train_smallthinker_ep8_seq16k", CELL]
-    assert [c["name"] for c in m["configs"]][-1] == CONFIG
+    assert [c["name"] for c in m["configs"]][4] == CONFIG
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
     for metric in m["end_to_end"] + m["per_layer"]:
         cells = metric.get("workloads", [])
-        assert CELL not in cells[:-1]
+        if CELL in cells:  # nothing after it but what later PRs appended
+            assert cells[cells.index(CELL) + 1:] in ([], later)
     # PR 36 appended two metrics of the record's inferred scopes after them
-    assert [p["name"] for p in m["per_layer"]][-2:] == [
-        "inferred_scope_device_pct.train", "update_device_ms.train_lm"]
-    assert [p["workloads"][-1] for p in m["per_layer"][-2:]] == [CELL, CELL]
-    new = [p["name"] for p in m["per_layer"]][-9:-2]
-    assert all(name.endswith(".train_ssm_lm") for name in new)
-    assert all(p["workloads"] == [CELL] and p["moves"] == "pairs_per_s"
-               for p in m["per_layer"][-9:-2])
+    # (and PR 38 its own cell's after those)
+    names = [p["name"] for p in m["per_layer"]]
+    last = names.index("update_device_ms.train_lm")
+    assert names[last - 1] == "inferred_scope_device_pct.train"
+    assert all(CELL in p["workloads"]
+               for p in m["per_layer"][last - 1:last + 1])
+    new = m["per_layer"][last - 8:last - 1]
+    assert all(p["name"].endswith(".train_ssm_lm") for p in new)
+    assert all(p["workloads"][0] == CELL and p["workloads"][1:] in ([], later)
+               and p["moves"] == "pairs_per_s" for p in new)
+    assert all(p["workloads"] == later for p in m["per_layer"][last + 1:])
     assert m["run_seconds"] == 45
     assert len(json.dumps(m, indent=2)) < 64 * 1024
 

@@ -760,3 +760,141 @@ def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     print(f"compiled step, bytes on the device: {per_device}")
     assert 11e9 < per_device < 15.0e9
+
+
+# --------------------------------------------- the dense hybrid language model ---
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_grouped_core_32_over_8_of_64_compiles_for_v5e(one_chip, monkeypatch,
+                                                       direction):
+    """The fifth model's attention layer: 32 query heads over 8 key/value
+    heads of 64 at 8,192 positions under the causal mask: half the head
+    width any other cell runs. The splash kernel takes it as it is (no head
+    padded to 128: a 64-wide array of the call's shape is in the program and
+    a 128-wide one is not), the fused backward (its partial dq is 0.27 GB);
+    no dense logits, no keys broadcast to the query heads."""
+    import re
+
+    from alphafold2_tpu.ops import mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile(
+        mla.causal_core if direction == "fwd" else _grad_of(mla.causal_core),
+        one_chip, ((1, 32, 8192, 64), "bfloat16"),
+        ((1, 8, 8192, 64), "bfloat16"), ((1, 8, 8192, 64), "bfloat16"))
+    assert "tpu_custom_call" in text and "splash_mha_fwd" in text
+    if direction == "bwd":
+        assert "splash_mha_dkv" in text and "splash_mha_dq" not in text
+    assert "8192,8192]" not in text
+    # (the kernel's own softmax statistics are float32 (1, 32, 8192, 128))
+    assert "bf16[1,32,8192,64]" in text
+    assert "bf16[1,32,8192,128]" not in text
+    assert not re.search(r"= bf16\[1,32,8192,64\]\S* broadcast\(", text)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_one_group_scan_in_chunks_of_256_compiles_for_v5e(
+        one_chip, monkeypatch, direction):
+    """The fifth model's scan, 64 heads of 64 all reading ONE group of 128
+    state rows, in chunks of 256, through ``ssm.ssd_scan`` on the TPU branch:
+    the two kernels of ``ops/pallas/ssd.py`` walking the group eight heads a
+    grid step (whole, a step's blocks pass the chip's VMEM), and no decay
+    matrix a head in HBM."""
+    from alphafold2_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssm.scan_kernel_takes((1, 8192, 64, 64), (1, 8192, 1, 128), 256)
+
+    def scan(x, b, c, dt, a):
+        return ssm.ssd_scan(x, dt, a, b, c, 256, jnp.bfloat16)[0]
+
+    text = _compile(
+        scan if direction == "fwd" else _grad_of(scan), one_chip,
+        ((1, 8192, 64, 64), "bfloat16"), ((1, 8192, 1, 128), "bfloat16"),
+        ((1, 8192, 1, 128), "bfloat16"), ((1, 8192, 64), "float32"),
+        ((64,), "float32"))
+    assert "ssd_chunk_fwd" in text
+    assert ("ssd_chunk_bwd" in text) == (direction == "bwd")
+    assert "64,256,256]" not in text and " while(" not in text
+
+
+def test_hybrid_dense_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole jitted train step of the benchmark's fifth model's cell
+    (772,160,448 parameters, 1 x 8,192 tokens) for one described chip: the
+    splash kernels under the one attention layer's core, each once; the
+    scan's two kernels under each of the nine state-space layers' scan scope
+    (the forward twice: the layer's recomputation keeps nothing of it); every
+    block's scope as the benchmark's readers look for it; no array of length
+    x length and no decay matrix; and weights + gradients + Adam +
+    activations under the 16,911,433,728 B the compiler leaves, with the
+    start NOT on the device (``harness/train_hybrid_dense_lm.py`` keeps it on
+    the host: beside it the sum would pass the chip)."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from alphafold2_tpu.data.pipeline import make_dataset
+    from alphafold2_tpu.observe.profiler import instruction_scopes
+    from alphafold2_tpu.train import loop
+    from benchmark.harness import common, train_hybrid_dense_lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    resolved = common.resolve("train_granite4_h_micro_pp4_seq8k")
+    cfg = train_hybrid_dense_lm.program_config(
+        resolved["config"], resolved["traffic"], 1)
+    task = loop.build_task(cfg)
+    sample = next(iter(make_dataset(
+        cfg.data, vocab_size=cfg.language_model().vocab_size)))
+    assert sample["tokens"].shape == (1, 8192)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    state = jax.eval_shape(lambda: loop.tiny_init_state(cfg, task, sample))
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 772_160_448
+    rng = jax.eval_shape(lambda: jax.random.key(1))
+    compiled = loop.make_train_step(task, None, numerics_mode="norms").lower(
+        shapes(state), shapes({k: jnp.asarray(v) for k, v in sample.items()}),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"):
+        scopes = _kernel_scopes(text, kernel)
+        assert len(scopes) == 1 and "layer_5/attn_global/core" in scopes[0]
+    assert not _kernel_scopes(text, "splash_mha_dq_no_residuals")
+    assert "ragged-dot" not in text and " conditional(" not in text
+    # no T x T logits a head (tokens x MLP width is 8,192 x 8,192 too)
+    assert ",8192,8192]" not in text
+    assert "64,256,256]" not in text  # nor a chunk's decay matrix a head
+    forward = _kernel_scopes(text, "ssd_chunk_fwd")
+    backward = _kernel_scopes(text, "ssd_chunk_bwd")
+    state_space = [i for i in range(10) if i != 5]
+    for layer in state_space:
+        here = f"layer_{layer}/ssm/scan/"
+        assert sum(here in s for s in forward) == 2, (layer, forward)
+        assert sum(here in s for s in backward) == 1, (layer, backward)
+    assert len(forward) == 18 and len(backward) == 9
+    scopes = instruction_scopes(text)[1].values()
+    assert not any("/while/body/" in s for s in scopes)
+    for layer in state_space:
+        for part in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
+            assert any(f"layer_{layer}/ssm/{part}/" in s for s in scopes), \
+                (layer, part)
+    for layer in range(10):
+        for part in ("mixer_norm", "ffn_norm", "dense_ffn"):
+            assert any(f"layer_{layer}/{part}/" in s for s in scopes), \
+                (layer, part)
+    # as the benchmark's reader finds them (names side by side, wrappers off)
+    from benchmark.readers.scope_paths_device_ms import holds, names
+    for part in ("embed", "final_norm", "head", "loss", "optimizer"):
+        assert any(holds(names(s), part) for s in scopes), part
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"compiled step, bytes on the device: {per_device} (temporaries "
+          f"{ma.temp_size_in_bytes})")
+    assert 12_354_567_168 < per_device < 16_911_433_728

@@ -71,7 +71,7 @@ def test_kernel_phase_agrees_with_the_references(monkeypatch):
     monkeypatch.setattr(mla, "causal_kernel_takes", lambda n: n >= 128)
     rec = chip_smoke.phase_kernels(small=True)
     names = [c["case"] for c in rec["cases"]]
-    assert len(names) == 12 and all(c["ok"] for c in rec["cases"])
+    assert len(names) == 13 and all(c["ok"] for c in rec["cases"])
     for kernel in ("fused_axial", "tied_row", "block_sparse"):
         assert any(n.startswith(kernel) for n in names)
         assert any(n.startswith(kernel) and "masked" in n for n in names)
@@ -79,11 +79,13 @@ def test_kernel_phase_agrees_with_the_references(monkeypatch):
     assert {"mla_causal_core_small", "moe_grouped_matmul_small"} <= set(names)
     # the grouped-query core under both of its masks
     assert {"swa_core_small_global", "swa_core_small_window"} <= set(names)
-    # the three cores under a remat that keeps their named results and under
+    # and at the dense hybrid's head width of 64, four query heads a key head
+    assert "gqa_core_small_heads_of_64" in names
+    # the four cores under a remat that keeps their named results and under
     # one that runs the forward kernel again: the same numbers
     kept = [c["kept_vs_recomputed"] for c in rec["cases"]
             if "kept_vs_recomputed" in c]
-    assert kept == [0.0, 0.0, 0.0]
+    assert kept == [0.0, 0.0, 0.0, 0.0]
     # the ring over two devices (jnp blocks here), one case masked
     assert {"ring_flash_small", "ring_flash_small_masked"} <= set(names)
     # float32 in interpret mode: far inside the chip's tolerance
@@ -106,6 +108,12 @@ def test_scan_phase_agrees_with_the_recurrence_at_a_small_size():
         <= chip_smoke.SSD_SCAN_TOL
     with pytest.raises(RuntimeError, match="disagrees with the recurrence"):
         chip_smoke.phase_ssd_scan(**small, tol=1e-9)
+    # the two cells' shapes are named, and nothing else is
+    assert sorted(chip_smoke.CELL_SCANS.values()) == [
+        "ssd_scan_8k", "ssd_scan_8k_one_group_c256"]
+    one = chip_smoke.phase_ssd_scan(
+        heads=16, width=8, groups=1, n=16, length=96, chunk=32)
+    assert one["ok"] and one["phase"] == "ssd_scan" and one["groups"] == 1
 
 
 def test_kernel_phase_takes_a_filter_by_name():
@@ -196,7 +204,8 @@ def test_success_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert json.loads(lines[-1]) == {"ok": True, "device": device}
     assert sorted(device) == ["count", "kind", "platform"]
     assert calls == ["phase_train", "phase_serve", "phase_kernels",
-                     "phase_ssd_scan"]
+                     "phase_ssd_scan",
+                     ("phase_ssd_scan", {"groups": 1, "chunk": 256})]
     for ln in lines:  # one JSON object per earlier line
         assert isinstance(json.loads(ln), dict)
 

@@ -134,15 +134,40 @@ def test_a_later_step_reaches_no_earlier_output(scan):
         and (gap[13:] == 0).all()
 
 
-def test_the_kernels_in_bfloat16_are_the_xla_form_in_bfloat16():
-    """bfloat16 operands, a state entering, two groups: the kernels against
+def test_one_group_of_64_heads_in_chunks_of_256(scan):
+    """The dense hybrid's scan: every one of 64 heads reads the one group's
+    B and C, whose gradients sum over all 64, in chunks of 256 (two of
+    them, so a state is carried). The kernels walk the group eight heads a
+    grid step and XLA sums the eight parts of dB and dC
+    (``ssd.heads_a_step``): output, final state and the five gradients
+    against the per-step recurrence, through either form."""
+    assert ssd.heads_a_step(64) == (8, 8) and ssd.heads_a_step(8) == (8, 1)
+    assert ssd.heads_a_step(4) == (4, 1) and ssd.heads_a_step(12) == (12, 1)
+    args = scan_inputs(512, 1, heads=64)
+    weight = jax.random.normal(jax.random.key(9), args[0].shape)
+    y, state, grads = output_and_gradients(
+        lambda *a: scan(*a, 256), weight)(*args)
+    want_y, want_state, want = output_and_gradients(recurrence, weight)(*args)
+    scale = float(jnp.abs(want_y).max())
+    np.testing.assert_allclose(y, want_y, rtol=1e-4, atol=1e-5 * scale)
+    np.testing.assert_allclose(state, want_state, rtol=1e-4, atol=1e-4)
+    for name, got, ref_grad in zip(("x", "dt", "A", "B", "C"), grads, want):
+        np.testing.assert_allclose(
+            got, ref_grad, rtol=2e-4,
+            atol=2e-5 * float(jnp.abs(ref_grad).max()), err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("heads,groups", [(4, 2), (16, 1)])
+def test_the_kernels_in_bfloat16_are_the_xla_form_in_bfloat16(heads, groups):
+    """bfloat16 operands, a state entering, two groups (or one group walked
+    in two head blocks): the kernels against
     the XLA form at the same ``dtype``. The forward casts sit where the XLA
     form's do, so output and state differ by accumulation order alone; the
     backward products take their cotangents rounded to bfloat16 in both
     (the XLA form's score cotangent is bfloat16 by type), in another order."""
-    x, dt, a, b, c = scan_inputs(64, 2, batch=2)
+    x, dt, a, b, c = scan_inputs(64, groups, heads=heads, batch=2)
     x, b, c = (t.astype(jnp.bfloat16) for t in (x, b, c))
-    state = jax.random.normal(jax.random.key(5), (2, 4, 16, 8))
+    state = jax.random.normal(jax.random.key(5), (2, heads, 16, 8))
     weight = jax.random.normal(jax.random.key(9), x.shape)
 
     def run(chunks):
