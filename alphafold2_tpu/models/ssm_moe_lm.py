@@ -117,15 +117,17 @@ class CausalConv(nn.Module):
     taps), uniform +-1/sqrt(taps) as a depthwise ``Conv1d``'s, and ``bias``."""
 
     taps: int
+    channels: int
+    start: int = 0
 
     @nn.compact
     def __call__(self, x):
         bound = self.taps ** -0.5
         kernel = self.param("kernel", uniform_between(-bound, bound),
-                            (x.shape[-1], self.taps))
+                            (self.channels, self.taps))
         bias = self.param("bias", uniform_between(-bound, bound),
-                          (x.shape[-1],))
-        return ssm.causal_conv(x, kernel, bias)
+                          (self.channels,))
+        return ssm.causal_conv(x, kernel, bias, self.start)
 
 
 class GatedGroupRMSNorm(nn.Module):
@@ -151,8 +153,9 @@ class Mamba2Mixer(nn.Module):
     decay of a whole chunk, ``exp(sum_chunk dt A)``, over heads and chunks
     (how little of a state survives one chunk: where even the mean is 0 the
     carried term is dead and the recurrence over chunks does no work), the
-    mean time step, and ``scan_in_kernel``: 1.0 where the scan ran through
-    the Pallas kernels, 0.0 where it fell back to the XLA form)."""
+    mean time step, and ``scan_in_kernel`` and ``conv_in_kernel``: 1.0
+    where the scan, or the convolution, ran through its Pallas kernels, 0.0
+    where it fell back to the XLA form)."""
 
     cfg: SsmLMConfig
     dtype: Any = jnp.float32
@@ -166,9 +169,12 @@ class Mamba2Mixer(nn.Module):
         inner = heads * width
         proj = _dense(2 * inner + 2 * groups * n + heads, self.dtype,
                       "in_proj")(u)
-        z, conv_in, dt = jnp.split(
-            proj, [inner, 2 * inner + 2 * groups * n], axis=-1)
-        conv_out = CausalConv(c.conv_kernel, name="conv")(conv_in)
+        conv_channels = inner + 2 * groups * n
+        z, dt = proj[..., :inner], proj[..., inner + conv_channels:]
+        # the convolution reads its channels of the projection where they
+        # lie: a kernel takes no sliced copy of them
+        conv_out = CausalConv(c.conv_kernel, conv_channels, inner,
+                              name="conv")(proj)
         x, b_in, c_in = jnp.split(
             conv_out, [inner, inner + groups * n], axis=-1)
         a_log = self.param("A_log", a_log_init, (heads,))
@@ -185,7 +191,9 @@ class Mamba2Mixer(nn.Module):
                         "chunk_decay_mean": chunk_decay.mean(),
                         "dt_mean": dt.mean(),
                         "scan_in_kernel": jnp.float32(ssm.scan_kernel_takes(
-                            x.shape, b_in.shape, c.chunk_size))}
+                            x.shape, b_in.shape, c.chunk_size)),
+                        "conv_in_kernel": jnp.float32(ssm.conv_kernel_takes(
+                            conv_out.shape, c.conv_kernel, inner))}
         normed = GatedGroupRMSNorm(
             groups, c.rms_norm_eps, self.dtype, name="gate_norm")(
             y.reshape(b, s, inner), z)

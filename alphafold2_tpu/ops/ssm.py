@@ -45,6 +45,12 @@ cumulative sum alone is XLA's and autodiff's. Everywhere else, and as the
 second oracle of the tests, ``ssd_chunks_xla``: the batched products and the
 ``lax.scan`` below as XLA operations, whose backward pass is autodiff's
 through them (each (chunk x chunk) matrix a head goes to HBM and back).
+
+The convolution has two forms in the same way, chosen by
+``conv_kernel_takes``: on a TPU, with the channels in whole blocks of 32
+rows and the length on the lane grid, a forward and a backward Pallas kernel
+(``ops/pallas/conv.py``) that read ``x`` once a pass and shift it on the
+chip; everywhere else, and as the tests' oracle, ``causal_conv_xla``.
 """
 
 from __future__ import annotations
@@ -52,15 +58,44 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from alphafold2_tpu.ops.pallas import ssd
+from alphafold2_tpu.ops.pallas import conv, ssd
 
 
-def causal_conv(x, weight, bias):
+def conv_kernel_takes(shape, taps: int, start: int = 0) -> bool:
+    """Whether the Pallas kernels of ``ops/pallas/conv.py`` serve a
+    convolution of ``x`` (B, T, C) with ``taps`` taps (of its channels from
+    ``start`` on): on a TPU, when the channels and ``start`` are multiples
+    of ``conv.ROWS`` (a grid step's rows), the length a multiple of 128
+    (whole lanes) and no longer than a block holds (``conv.BLOCK_ELEMENTS``:
+    a grid step holds the whole sequence), and the taps at most 128 (a shift
+    within one lane tile). Any other shape, and every backend but the TPU,
+    runs ``causal_conv_xla``."""
+    _, length, channels = shape
+    rows = conv.ROWS
+    return (jax.default_backend() == "tpu"
+            and channels % rows == 0 and start % rows == 0
+            and length % 128 == 0 and rows * length <= conv.BLOCK_ELEMENTS
+            and taps <= 128)
+
+
+def causal_conv(x, weight, bias, start: int = 0):
     """Depthwise causal convolution over time, then SiLU: ``silu(bias +
     sum_j weight[:, j] * x[t - (K - 1) + j])``, zeros before the sequence's
-    start (``weight[:, K - 1]`` meets the current step). ``x`` (B, T, C),
-    ``weight`` (C, K), ``bias`` (C,). K shifted multiply-adds in float32,
-    ``x``'s type out."""
+    start (``weight[:, K - 1]`` meets the current step), over the channels
+    ``start`` .. ``start + C`` of ``x`` (B, T, C'). ``weight`` (C, K),
+    ``bias`` (C,); (B, T, C) in ``x``'s type out. Through the kernels, which
+    read those channels where they lie, where ``conv_kernel_takes`` the
+    shape; elsewhere through ``causal_conv_xla`` on the slice."""
+    channels, taps = weight.shape
+    shape = x.shape[:2] + (channels,)
+    if conv_kernel_takes(shape, taps, start):
+        return conv.causal_conv(x, weight, bias, start)
+    return causal_conv_xla(x[..., start:start + channels], weight, bias)
+
+
+def causal_conv_xla(x, weight, bias):
+    """``causal_conv`` as XLA operations: K shifted multiply-adds in float32
+    over a padded copy, differentiated by autodiff."""
     taps = weight.shape[1]
     length = x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))

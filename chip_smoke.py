@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """First light on the attached TPU: drive the main path once, check it, say so.
 
-    python chip_smoke.py                # one chip: train, serve, kernels, scan
+    python chip_smoke.py                # one chip: train, serve, kernels, scan,
+                                        # convolution
     python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh, and
                                         # the ring's blocks against references
 
@@ -25,6 +26,9 @@ Default phases, on one chip, at the width of the flagship (bench.py):
    the float32 per-step recurrence; then ssd_scan_8k_one_group_c256, the
    same at the dense hybrid's (one group read by all 64 heads, chunks of
    256).
+5. causal_conv_8k — the state-space layers' causal depthwise convolution
+   (``ops/ssm.py``) on bfloat16 inputs at both state-space cells' channel
+   counts, output and three gradients against the float32 XLA form.
 
 Every phase prints one JSON object on a line of its own. The LAST line of
 stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
@@ -838,6 +842,79 @@ def phase_ssd_scan(heads=64, width=64, groups=8, n=128, length=8192,
     return record
 
 
+# ------------------------------------------------ the causal convolution ---
+
+# The output is cast to bfloat16 (2**-9 of an entry) and dx likewise; dw and
+# db sum 8,192 steps of float32 products of a bfloat16-rounded cotangent.
+# Set before the first chip run at five times bfloat16's rounding of the
+# largest entry. On the chip the kernels read 0.0013-0.0041 (PERF.md section
+# 6); on the CPU the output with its taps reversed reads 1.01 against the
+# reference, shifted one step further 1.07, without its bias 0.17.
+CONV_TOL = 1e-2
+
+# the conv channels (x, B and C) of the two state-space cells at 8,192 steps
+# and 4 taps: the hybrid expert model's and the dense hybrid's
+CELL_CONVS = (6144, 4352)
+
+
+def phase_causal_conv(channels=CELL_CONVS, length=8192, taps=4, seed=0,
+                      tol=CONV_TOL) -> dict:
+    """``ops/ssm.py`` ``causal_conv`` on bfloat16 inputs at the state-space
+    cells' shapes (on a TPU the Pallas kernels of ``ops/pallas/conv.py``; a
+    fall-back to the XLA form there fails the phase) under the published
+    initialisation (taps and bias uniform on +-1/sqrt(taps)): the output and
+    its gradients towards x, the taps and the bias against
+    ``causal_conv_xla`` in float32 on the same bfloat16-rounded inputs, each
+    error the largest absolute difference over the reference's largest
+    entry. One case a channel count."""
+    import jax
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.ops import ssm
+
+    def both(fn, weight):
+        return jax.jit(jax.value_and_grad(
+            lambda *args: (jnp.sum(fn(*args).astype(jnp.float32) * weight),
+                           fn(*args)),
+            argnums=(0, 1, 2), has_aux=True))
+
+    def rel_err(got, want):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(got - want))
+                     / (jnp.max(jnp.abs(want)) + 1e-30))
+
+    cases, failed = [], []
+    for i, width in enumerate(channels):
+        keys = jax.random.split(jax.random.key(seed + i), 4)
+        bound = taps ** -0.5
+        x = jax.random.normal(keys[0], (1, length, width), jnp.bfloat16)
+        w = jax.random.uniform(keys[1], (width, taps), jnp.float32,
+                               -bound, bound)
+        b = jax.random.uniform(keys[2], (width,), jnp.float32, -bound, bound)
+        weight = jax.random.normal(keys[3], x.shape)
+        (_, out), grads = both(ssm.causal_conv, weight)(x, w, b)
+        (_, out_r), grads_r = both(ssm.causal_conv_xla, weight)(
+            x.astype(jnp.float32), w, b)
+        errs = {"fwd": rel_err(out, out_r),
+                **{f"d{name}": rel_err(g, gr) for name, g, gr in zip(
+                    ("x", "w", "b"), grads, grads_r)}}
+        in_kernel = ssm.conv_kernel_takes(x.shape, taps)
+        ok = all(e == e and e <= tol for e in errs.values())
+        case = {"shape": [1, length, width], "taps": taps, "ok": ok,
+                "implementation": "pallas" if in_kernel else "xla",
+                **{k: float(f"{v:.3g}") for k, v in errs.items()}}
+        cases.append(case)
+        if not ok or (jax.default_backend() == "tpu" and not in_kernel):
+            failed.append(case)
+    record = {"phase": "causal_conv_8k" if length == 8192 else "causal_conv",
+              "tol": tol, "cases": cases}
+    if failed:
+        raise RuntimeError(
+            "the convolution disagrees with its float32 reference or fell "
+            "back to the XLA form on a TPU: " + json.dumps(record))
+    return record
+
+
 # ------------------------------------------------------------- four chips ---
 
 
@@ -971,6 +1048,7 @@ def main(argv=None) -> int:
             emit(phase_kernels())
             emit(phase_ssd_scan())
             emit(phase_ssd_scan(groups=1, chunk=256))
+            emit(phase_causal_conv())
         emit({"phase": "done", "seconds": round(time.perf_counter() - t0, 1)})
     except Exception as e:  # the boundary: report, then fail
         import traceback

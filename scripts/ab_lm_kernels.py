@@ -6,6 +6,7 @@
     chiprun -- python scripts/ab_lm_kernels.py        # times, on a TPU
     chiprun -- python scripts/ab_lm_kernels.py --kernels attention
     chiprun -- python scripts/ab_lm_kernels.py --kernels scan
+    python scripts/ab_lm_kernels.py --kernels conv    # on a TPU
 
 (a) The causal core, 2 x 32 heads x 8,192 x 8,192, forward and forward +
     backward: the stock flash kernel at head 256 (q/k 192 and v 128
@@ -22,6 +23,10 @@
     bfloat16 products: the XLA form (``ops/ssm.py`` ``ssd_chunks_xla``)
     beside the Pallas kernels (``ops/pallas/ssd.py``), forward alone and
     forward + backward towards x, dt, A, B and C.
+(d) The causal depthwise convolution of the two state-space cells, 1 x
+    8,192 steps of 6,144 and of 4,352 bfloat16 channels, 4 taps: the XLA
+    form (``ops/ssm.py`` ``causal_conv_xla``) beside the Pallas kernels
+    (``ops/pallas/conv.py``), forward alone and forward + backward.
 
 Each candidate is jitted alone, warmed once, timed best of ``--reps`` with
 ``block_until_ready``. One JSON line a candidate on stdout and in
@@ -244,12 +249,48 @@ def scan_candidates(reps):
         yield rec
 
 
+def conv_candidates(reps):
+    """The state-space layers' causal depthwise convolution at the two
+    cells' shapes, bfloat16 x of 8,192 steps and 4 taps: the XLA form beside
+    the kernels, forward and forward + backward towards x, the taps and the
+    bias. x and the output are handed over time-minor, (B, C, T), as the
+    layer lays them out, so neither form pays a transpose the step does
+    not."""
+    from alphafold2_tpu.ops import ssm
+    from alphafold2_tpu.ops.pallas import conv
+
+    length, taps = 8192, 4
+    for channels in chip_smoke.CELL_CONVS:
+        keys = jax.random.split(jax.random.key(channels), 4)
+        x = jax.random.normal(keys[0], (1, channels, length), jnp.bfloat16)
+        w = jax.random.uniform(keys[1], (channels, taps), jnp.float32, -.5, .5)
+        b = jax.random.uniform(keys[2], (channels,), jnp.float32, -.5, .5)
+        weight = jax.random.normal(keys[3], x.shape, jnp.bfloat16)
+        for name, by_time in (("xla", ssm.causal_conv_xla),
+                              ("pallas", conv.causal_conv)):
+            def form(x, w, b, by_time=by_time):
+                return jnp.swapaxes(by_time(jnp.swapaxes(x, 1, 2), w, b), 1, 2)
+
+            def both(x, w, b, form=form):  # the output and its pull-back
+                y, pull = jax.vjp(form, x, w, b)
+                return y, pull(weight)
+
+            rec = {"kernel": "causal_conv_8k", "impl": name,
+                   "channels": channels}
+            try:
+                rec["fwd_ms"] = best_ms(jax.jit(form), (x, w, b), reps)
+                rec["fwd_bwd_ms"] = best_ms(jax.jit(both), (x, w, b), reps)
+            except Exception as e:
+                rec["error"] = repr(e)[:300]
+            yield rec
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--out", default="chiprun_out/ab_lm_kernels.jsonl")
     ap.add_argument("--kernels", default="all",
-                    choices=("attention", "experts", "scan", "all"))
+                    choices=("attention", "experts", "scan", "conv", "all"))
     ap.add_argument("--compile-only", action="store_true",
                     help="compile the attention candidates for one chip of a "
                          "described v5e (JAX_PLATFORMS=cpu, no TPU attached): "
@@ -277,6 +318,8 @@ def main(argv=None) -> int:
         gens.append(lambda: gmm_candidates(args.reps))
     if args.kernels in ("scan", "all"):
         gens.append(lambda: scan_candidates(args.reps))
+    if args.kernels in ("conv", "all"):
+        gens.append(lambda: conv_candidates(args.reps))
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "a") as out:
         for gen in gens:

@@ -672,6 +672,27 @@ def test_state_space_scan_compiles_for_v5e(one_chip, monkeypatch, length,
     assert "8,8,128,128]" not in text and " while(" not in text
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("channels", [6144, 4352])
+def test_causal_conv_compiles_for_v5e(one_chip, monkeypatch, channels,
+                                      direction):
+    """The two state-space cells' convolutions, 8,192 bfloat16 steps of
+    6,144 and of 4,352 channels, 4 taps, through ``ssm.causal_conv`` on the
+    TPU branch: the two kernels of ``ops/pallas/conv.py`` (a block of 32
+    rows and the whole sequence within the scoped VMEM), and no padded
+    copy of x."""
+    from alphafold2_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile(
+        ssm.causal_conv if direction == "fwd" else _grad_of(ssm.causal_conv),
+        one_chip, ((1, 8192, channels), "bfloat16"),
+        ((channels, 4), "float32"), ((channels,), "float32"))
+    assert "causal_conv_fwd" in text
+    assert ("causal_conv_bwd" in text) == (direction == "bwd")
+    assert f",8195,{channels}]" not in text
+
+
 def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted train step of the benchmark's third language-model
     cell (667 M parameters, 1 x 8,192 tokens) for one described chip: the
@@ -734,6 +755,14 @@ def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         assert sum(here in s for s in forward) == 2, (layer, forward)
         assert sum(here in s for s in backward) == 1, (layer, backward)
     assert len(forward) == 8 and len(backward) == 4
+    # the convolution likewise: the two kernels of ops/pallas/conv.py
+    conv_forward = _kernel_scopes(text, "causal_conv_fwd")
+    conv_backward = _kernel_scopes(text, "causal_conv_bwd")
+    for layer in (0, 2, 4, 7):
+        here = f"layer_{layer}/ssm/conv/"
+        assert sum(here in s for s in conv_forward) == 2, layer
+        assert sum(here in s for s in conv_backward) == 1, layer
+    assert len(conv_forward) == 8 and len(conv_backward) == 4
     assert not any("/while/body/" in s for s in scopes)
     for layer in (0, 2, 4, 7):
         for part in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
@@ -878,6 +907,13 @@ def test_hybrid_dense_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
         assert sum(here in s for s in forward) == 2, (layer, forward)
         assert sum(here in s for s in backward) == 1, (layer, backward)
     assert len(forward) == 18 and len(backward) == 9
+    conv_forward = _kernel_scopes(text, "causal_conv_fwd")
+    conv_backward = _kernel_scopes(text, "causal_conv_bwd")
+    for layer in state_space:
+        here = f"layer_{layer}/ssm/conv/"
+        assert sum(here in s for s in conv_forward) == 2, layer
+        assert sum(here in s for s in conv_backward) == 1, layer
+    assert len(conv_forward) == 18 and len(conv_backward) == 9
     scopes = instruction_scopes(text)[1].values()
     assert not any("/while/body/" in s for s in scopes)
     for layer in state_space:
@@ -897,4 +933,11 @@ def test_hybrid_dense_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     print(f"compiled step, bytes on the device: {per_device} (temporaries "
           f"{ma.temp_size_in_bytes})")
-    assert 12_354_567_168 < per_device < 16_911_433_728
+    # 12,195,462,144 B: the state, weights and both Adam moments (12 B a
+    # parameter, 9.27 GB) and 2.93 GB of temporaries. The gradients are not
+    # 4 B a parameter among them: the buffer assignment holds each product's
+    # weight gradient in bfloat16 (the cotangent of the weight's bf16 copy),
+    # made float32 only inside that leaf's update fusion, so 1.62 GB of
+    # gradients are live where the backward pass ends. The floor sits 95 MB
+    # (0.8%) under the reading
+    assert 12_100_000_000 < per_device < 16_911_433_728

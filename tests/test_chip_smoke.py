@@ -116,6 +116,29 @@ def test_scan_phase_agrees_with_the_recurrence_at_a_small_size():
     assert one["ok"] and one["phase"] == "ssd_scan" and one["groups"] == 1
 
 
+def test_conv_phase_agrees_with_the_float32_form_at_a_small_size(
+        monkeypatch):
+    """The convolution's phase, small: through the XLA form off the TPU
+    (the record says so), and through the kernels interpreted where the
+    rule is steered to take them; every error of the bfloat16 path under
+    the chip's tolerance, and a tolerance nothing meets fails the phase."""
+    from alphafold2_tpu.ops import ssm
+
+    small = dict(channels=(32, 96), length=256)
+    rec = chip_smoke.phase_causal_conv(**small)
+    assert rec["phase"] == "causal_conv"
+    assert [c["implementation"] for c in rec["cases"]] == ["xla", "xla"]
+    monkeypatch.setattr(ssm, "conv_kernel_takes", lambda *shape: True)
+    rec = chip_smoke.phase_causal_conv(**small)
+    for case in rec["cases"]:
+        assert case["ok"] and case["implementation"] == "pallas"
+        assert 0 < max(case[k] for k in ("fwd", "dx", "dw", "db")) \
+            <= chip_smoke.CONV_TOL
+    with pytest.raises(RuntimeError, match="disagrees with its float32"):
+        chip_smoke.phase_causal_conv(**small, tol=1e-9)
+    assert chip_smoke.CELL_CONVS == (6144, 4352)
+
+
 def test_kernel_phase_takes_a_filter_by_name():
     rec = chip_smoke.phase_kernels(small=True, only="moe_grouped")
     assert [c["case"] for c in rec["cases"]] == ["moe_grouped_matmul_small"]
@@ -187,7 +210,7 @@ def _fake_chip(monkeypatch, count=1, **phases):
     monkeypatch.setattr("alphafold2_tpu.enable_compile_cache", lambda: None)
     calls = []
     for name in ("phase_train", "phase_serve", "phase_kernels",
-                 "phase_ssd_scan", "phase_mesh"):
+                 "phase_ssd_scan", "phase_causal_conv", "phase_mesh"):
         def phase(name=name, **kwargs):
             calls.append((name, kwargs) if kwargs else name)
             if name in phases:
@@ -205,7 +228,8 @@ def test_success_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert sorted(device) == ["count", "kind", "platform"]
     assert calls == ["phase_train", "phase_serve", "phase_kernels",
                      "phase_ssd_scan",
-                     ("phase_ssd_scan", {"groups": 1, "chunk": 256})]
+                     ("phase_ssd_scan", {"groups": 1, "chunk": 256}),
+                     "phase_causal_conv"]
     for ln in lines:  # one JSON object per earlier line
         assert isinstance(json.loads(ln), dict)
 

@@ -167,11 +167,14 @@ def test_the_steps_counters_have_no_router_and_a_row_a_state_space_layer(
     assert out_p["moe"] == {}
     metrics = lm.step_metrics(out_p)
     assert sorted(metrics) == [
-        "ssm/chunk_decay_mean", "ssm/chunk_decay_min", "ssm/dt_mean",
-        "ssm/scan_in_kernel", "stream/rms_in", "stream/rms_out"]
-    for name in ("ssm/chunk_decay_min", "ssm/dt_mean", "ssm/scan_in_kernel"):
+        "ssm/chunk_decay_mean", "ssm/chunk_decay_min", "ssm/conv_in_kernel",
+        "ssm/dt_mean", "ssm/scan_in_kernel", "stream/rms_in",
+        "stream/rms_out"]
+    for name in ("ssm/chunk_decay_min", "ssm/dt_mean", "ssm/scan_in_kernel",
+                 "ssm/conv_in_kernel"):
         assert metrics[name].shape == (9,)
     assert float(metrics["ssm/scan_in_kernel"].max()) == 0.0  # the CPU
+    assert float(metrics["ssm/conv_in_kernel"].max()) == 0.0
     # the entering stream is 12 x a row of length 1 (root mean square 12 /
     # sqrt(hidden)); the 0.22 on each of twenty adds holds the leaving one
     rel = 1e-5 if dtype == "float32" else 1e-2

@@ -19,7 +19,7 @@ sys.path.insert(0, ROOT)
 
 from alphafold2_tpu.models.ssm_moe_lm import relu2  # noqa: E402
 from alphafold2_tpu.ops import moe, ssm  # noqa: E402
-from alphafold2_tpu.ops.pallas import ssd  # noqa: E402
+from alphafold2_tpu.ops.pallas import conv, ssd  # noqa: E402
 from benchmark.reference import ssm_lm_model as ref  # noqa: E402
 
 
@@ -247,6 +247,132 @@ def test_a_sequence_split_in_two_with_its_state_carried_is_the_whole(
     conv = ssm.causal_conv(u, w, bias)
     tail = ssm.causal_conv(u[:, cut - 3:], w, bias)[:, 3:]
     np.testing.assert_allclose(tail, conv[:, cut:], rtol=1e-5, atol=1e-6)
+
+
+def conv_inputs(batch, length, channels, dtype=jnp.float32, taps=4, seed=0):
+    """(x, taps, bias, a weight of x's shape) under the layer's
+    initialisation: taps and bias uniform on +-1/sqrt(taps)."""
+    keys = jax.random.split(jax.random.key(seed), 4)
+    bound = taps ** -0.5
+    x = jax.random.normal(keys[0], (batch, length, channels)).astype(dtype)
+    w = jax.random.uniform(keys[1], (channels, taps), minval=-bound,
+                           maxval=bound)
+    b = jax.random.uniform(keys[2], (channels,), minval=-bound, maxval=bound)
+    return x, w, b, jax.random.normal(keys[3], x.shape)
+
+
+def conv_and_gradients(form, weight):
+    """One jitted call: (output, gradients of a weighted sum of it towards
+    x, the taps and the bias)."""
+    def loss(x, w, b):
+        return (form(x, w, b).astype(jnp.float32) * weight).sum()
+
+    return jax.jit(lambda *args: (
+        form(*args), jax.grad(loss, argnums=(0, 1, 2))(*args)))
+
+
+@pytest.mark.parametrize("batch,length,channels,dtype", [
+    (1, 256, 32, "float32"), (2, 512, 64, "float32"),
+    (1, 1024, 128, "bfloat16"), (2, 256, 96, "bfloat16")])
+def test_the_conv_kernels_are_the_xla_form(batch, length, channels, dtype):
+    """The kernels of ``ops/pallas/conv.py``, interpreted, against
+    ``causal_conv_xla`` and autodiff through it, at shapes on the tile grid.
+    The pre-activation is summed in the same order in float32 and cast
+    once, so the outputs agree to bfloat16's last bit; dx differs by the
+    order of SiLU's derivative's products (float32, then one rounding to
+    x's type: a unit in its last place); dw and db sum the same float32
+    terms over batch and time in another order."""
+    x, w, b, weight = conv_inputs(batch, length, channels, jnp.dtype(dtype))
+    y, grads = conv_and_gradients(conv.causal_conv, weight)(x, w, b)
+    want_y, want = conv_and_gradients(ssm.causal_conv_xla, weight)(x, w, b)
+    assert y.dtype == x.dtype and y.shape == x.shape
+    ulp = 2.0 ** -7 if dtype == "bfloat16" else 1e-6
+    got_y, want_y = (np.asarray(t, np.float32) for t in (y, want_y))
+    np.testing.assert_allclose(got_y, want_y, rtol=ulp, atol=1e-6)
+    for name, got, ref_grad in zip(("x", "w", "b"), grads, want):
+        assert got.dtype == ref_grad.dtype and got.shape == ref_grad.shape
+        got, ref_grad = (np.asarray(g, np.float32) for g in (got, ref_grad))
+        tol = ulp if name == "x" else 1e-5
+        assert np.abs(got - ref_grad).max() <= tol * np.abs(ref_grad).max(), \
+            f"d{name}"
+
+
+@pytest.mark.parametrize("start", [0, 32])
+def test_the_conv_kernels_read_their_channels_where_they_lie(start):
+    """The layer's projection holds the convolved channels among others: the
+    kernels read channels ``start`` .. ``start + C`` of the whole array in
+    place (``ssm.causal_conv``'s ``start``), and the gradient towards the
+    other channels is zero. Against the XLA form on the slice."""
+    x, w, b, _ = conv_inputs(2, 256, 80, jnp.bfloat16)
+    w, b = w[:32], b[:32]
+    weight = jax.random.normal(jax.random.key(7), (2, 256, 32))
+    y, grads = conv_and_gradients(
+        lambda x, w, b: conv.causal_conv(x, w, b, start), weight)(x, w, b)
+    want_y, want = conv_and_gradients(
+        lambda x, w, b: ssm.causal_conv_xla(x[..., start:start + 32], w, b),
+        weight)(x, w, b)
+    assert y.shape == (2, 256, 32)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(want_y, np.float32), rtol=2.0 ** -7)
+    dx = np.asarray(grads[0], np.float32)
+    assert (dx[..., :start] == 0).all() and (dx[..., start + 32:] == 0).all()
+    for got, ref_grad in zip(grads, want):
+        got, ref_grad = (np.asarray(g, np.float32) for g in (got, ref_grad))
+        assert np.abs(got - ref_grad).max() <= 2.0 ** -7 * np.abs(
+            ref_grad).max()
+
+
+def test_in_the_conv_kernels_a_step_reaches_no_earlier_output_and_no_later_dx():
+    """Causality through the kernels: step 300 of x moves outputs 300..303
+    (four taps) and none before or after; it moves dx at 297..303 only (the
+    steps whose outputs read 300, and those 300's output reads)."""
+    x, w, b, weight = conv_inputs(1, 512, 32)
+    run = conv_and_gradients(conv.causal_conv, weight)
+    y, (dx, _, _) = run(x, w, b)
+    y2, (dx2, _, _) = run(x.at[:, 300].add(1.0), w, b)
+    gap = np.abs(np.asarray(y2 - y)).max((0, 2))
+    assert (gap[:300] == 0).all() and (gap[300:304] > 0).all() \
+        and (gap[304:] == 0).all()
+    gap = np.abs(np.asarray(dx2 - dx)).max((0, 2))
+    assert (gap[:297] == 0).all() and (gap[297:304] > 0).all() \
+        and (gap[304:] == 0).all()
+
+
+def test_the_conv_kernels_see_zeros_before_the_start_and_after_the_end():
+    """A shift is a roll along the block's lanes with the wrapped steps
+    masked: x large in the LAST three steps reaches none of the first three
+    outputs (each is silu(bias) exactly), and a cotangent on the FIRST three
+    outputs alone reaches no dx of the last three."""
+    x = jnp.zeros((1, 256, 32)).at[:, -3:].set(100.0)
+    _, w, b, _ = conv_inputs(1, 256, 32)
+    y = conv.causal_conv(x, w, b)
+    np.testing.assert_array_equal(y[0, :3], jnp.broadcast_to(
+        jax.nn.silu(b), (3, 32)))
+    weight = jnp.zeros(x.shape).at[:, :3].set(1.0)
+    x = jax.random.normal(jax.random.key(4), x.shape)
+    _, (dx, _, _) = conv_and_gradients(conv.causal_conv, weight)(x, w, b)
+    assert bool(jnp.all(dx[0, -3:] == 0)) and bool(jnp.all(dx[0, :3] != 0))
+
+
+def test_the_conv_kernels_take_tiled_shapes_on_a_tpu_and_nothing_elsewhere(
+        monkeypatch):
+    """The rule of ``conv_kernel_takes``: channels and their first channel
+    in the array multiples of a block's 32 rows, the length a multiple of
+    128 and whole in a block, at most 128 taps; and no shape at all off the
+    TPU."""
+    cells = ((1, 8192, 6144), (1, 8192, 4352))
+    assert not any(ssm.conv_kernel_takes(s, 4, 4096) for s in cells)  # CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert all(ssm.conv_kernel_takes(s, 4, 4096) for s in cells)
+    assert ssm.conv_kernel_takes((2, 256, 96), 4)
+    assert ssm.conv_kernel_takes((1, 8192, 32), 128, 64)
+    for shape, taps, start in (((1, 8192, 6160), 4, 0),    # channels off 32
+                               ((1, 8192, 6144), 4, 4112),  # start off 32
+                               ((1, 8000, 6144), 4, 0),    # length off 128
+                               ((1, 16384, 6144), 4, 0),   # a block's length
+                               ((1, 8192, 6144), 129, 0),  # taps
+                               ((1, 20, 6), 4, 0)):        # this file's
+        assert not ssm.conv_kernel_takes(shape, taps, start), (shape, start)
 
 
 def test_ungated_experts_run_two_products_against_a_dense_loop():
