@@ -21,7 +21,7 @@ class ModelConfig:
     # which (model, loss, batch spec) train() builds: "alphafold2" (the axial
     # trunk, the fields below) | "mla_moe_lm" (the ``lm`` section) |
     # "swa_moe_lm" (the ``swa`` section) | "ssm_moe_lm" (the ``ssm`` section)
-    # | "hybrid_dense_lm" (the ``hybrid`` section)
+    # | "hybrid_dense_lm" (the ``hybrid`` section) | "dsa_moe_lm" (``dsa``)
     arch: str = "alphafold2"
     dim: int = 256  # trunk embedding width (single-repr channels)
     max_seq_len: int = 2048  # positional-embedding table size (max residues)
@@ -213,6 +213,41 @@ class HybridDenseLMConfig:
 
 
 @dataclass
+class DsaLMConfig:
+    """Decoder-only language model whose every layer attends, for each
+    query, the ``index_topk`` earlier keys a learned indexer scores highest
+    (DeepSeek-style sparse attention), over grouped-query heads with a
+    per-head RMSNorm on q and k, followed by softmax-routed SwiGLU experts
+    (models/dsa_moe_lm.py), read when ``model.arch`` is ``dsa_moe_lm``. The
+    defaults are the published sizes of a Keye-VL-2.0-shaped 30B-A3B
+    language model, whole; the share (``experts_held``, ``first_expert``, a
+    slice of the vocabulary) and fewer layers are for the caller to set, as
+    in ``LMConfig``."""
+
+    vocab_size: int = 151936  # vocabulary rows held here (ids 0..vocab_size-1)
+    hidden_size: int = 2048  # residual stream width
+    num_layers: int = 48  # blocks, every one sparse attention + experts
+    num_heads: int = 32  # query heads
+    num_kv_heads: int = 4  # key/value heads: query head h reads h // (32 / 4)
+    head_dim: int = 128  # width of every attention head
+    index_num_heads: int = 16  # the indexer's query heads
+    index_head_dim: int = 64  # width of the indexer's heads, its key's too
+    index_topk: int = 2048  # keys a query attends (all of them below that)
+    index_chunk: int = 512  # queries a block of the selection
+    index_loss_weight: float = 1.0  # lambda on the indexer's KL, summed
+    # over the layers, beside the next-token loss
+    moe_intermediate_size: int = 768  # one expert's SwiGLU width
+    n_routed_experts: int = 128  # the router's width, held or not
+    num_experts_per_tok: int = 8  # experts a token is routed to
+    rope_theta: float = 1e7  # rotary base
+    rms_norm_eps: float = 1e-6  # inside every RMSNorm's rsqrt
+    # the share: experts first_expert .. first_expert + experts_held - 1
+    experts_held: int = 128  # routed experts this chip holds a layer
+    first_expert: int = 0  # id of the first expert held
+    bfloat16: bool = True  # compute dtype (weights stay float32)
+
+
+@dataclass
 class MeshConfig:
     data_parallel: int = 1  # dp axis size; -1 = fill with all devices
     seq_parallel: int = 1  # sp axis size (pair-map row sharding)
@@ -353,6 +388,7 @@ class Config:
     ssm: SsmLMConfig = field(default_factory=SsmLMConfig)  # "ssm_moe_lm"
     # model.arch "hybrid_dense_lm"
     hybrid: HybridDenseLMConfig = field(default_factory=HybridDenseLMConfig)
+    dsa: DsaLMConfig = field(default_factory=DsaLMConfig)  # "dsa_moe_lm"
     mesh: MeshConfig = field(default_factory=MeshConfig)  # device mesh axes
     data: DataConfig = field(default_factory=DataConfig)  # dataset + features
     train: TrainConfig = field(default_factory=TrainConfig)  # optimizer loop
@@ -365,7 +401,8 @@ class Config:
         """The section of the language model that ``model.arch`` names: its
         ``vocab_size`` is what ``data.source`` "tokens" draws over."""
         return {"swa_moe_lm": self.swa, "ssm_moe_lm": self.ssm,
-                "hybrid_dense_lm": self.hybrid}.get(self.model.arch, self.lm)
+                "hybrid_dense_lm": self.hybrid, "dsa_moe_lm": self.dsa}.get(
+                    self.model.arch, self.lm)
 
     @classmethod
     def from_json(cls, s: str) -> "Config":
@@ -376,6 +413,7 @@ class Config:
             swa=SwaLMConfig(**raw.get("swa", {})),
             ssm=SsmLMConfig(**raw.get("ssm", {})),
             hybrid=HybridDenseLMConfig(**raw.get("hybrid", {})),
+            dsa=DsaLMConfig(**raw.get("dsa", {})),
             mesh=MeshConfig(**raw.get("mesh", {})),
             data=DataConfig(**raw.get("data", {})),
             train=_tuplify(TrainConfig(**raw.get("train", {})), "profile_steps"),

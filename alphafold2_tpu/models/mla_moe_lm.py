@@ -207,15 +207,15 @@ class MlaMoeLM(nn.Module):
                 c, i < c.first_k_dense, dtype, name=f"layer_{i}"))
 
 
-def remat_layer(block):
+def remat_layer(block, names=(mla.CORE_RESIDUALS,)):
     """``block`` under ``nn.remat``: the backward pass recomputes a layer
     from its input (at 16,384 tokens one layer's activations are 2.6 GB)
-    and keeps across that only what the causal core's kernel names, its
-    output and log-sum-exp (0.14 GB a layer), so the recomputation runs
-    every operation of the layer but the attention kernel."""
+    and keeps across that only what ``names`` name: by default what the
+    causal core's kernel names, its output and log-sum-exp (0.14 GB a
+    layer), so the recomputation runs every operation of the layer but the
+    attention kernel."""
     return nn.remat(
-        block, policy=jax.checkpoint_policies.save_only_these_names(
-            mla.CORE_RESIDUALS))
+        block, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def decoder_stack(tokens, cfg, layer):

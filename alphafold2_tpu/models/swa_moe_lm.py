@@ -91,11 +91,13 @@ class GroupedAttention(nn.Module):
 
 class RoutedExperts(nn.Module):
     """``sum_e w_e Expert_e(y)`` over the experts held here, the experts and
-    weights chosen from ``route_from``. Returns (output, the layer's routing
-    counters)."""
+    weights chosen from ``route_from``; ``activation`` on the gate (ReLU:
+    ReGLU experts, this model's; SiLU: SwiGLU, ``models/dsa_moe_lm.py``'s).
+    Returns (output, the layer's routing counters)."""
 
     cfg: SwaLMConfig
     dtype: Any = jnp.float32
+    activation: Any = jax.nn.relu
 
     @nn.compact
     def __call__(self, y, route_from):
@@ -112,7 +114,7 @@ class RoutedExperts(nn.Module):
         w_down = self.param("w_down", fan_in_normal(1), (held, width, d))
         out, plan = moe.held_experts_sum(
             y.reshape(b * s, d), experts, weights, w_gate, w_up, w_down,
-            c.first_expert, c.n_routed_experts, self.dtype, jax.nn.relu)
+            c.first_expert, c.n_routed_experts, self.dtype, self.activation)
         return out.reshape(b, s, d), moe.load_counters(plan)
 
 

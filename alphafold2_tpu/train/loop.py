@@ -179,6 +179,15 @@ def build_task(cfg: Config) -> Task:
     architecture's modules are imported only when asked for."""
     if cfg.model.arch == "alphafold2":
         return trunk_task(build_model(cfg))
+    if cfg.model.arch == "dsa_moe_lm":
+        from alphafold2_tpu.models import dsa_moe_lm as dsa_lm
+        from alphafold2_tpu.models import mla_moe_lm as lm
+
+        model = dsa_lm.DsaMoeLM(cfg.dsa)
+        return Task(model, partial(lm.forward, model),
+                    partial(dsa_lm.loss, weight=cfg.dsa.index_loss_weight),
+                    lm.step_metrics, partial(lm.init, model),
+                    dsa_lm.tiny_batch)
     if cfg.model.arch in ("mla_moe_lm", "swa_moe_lm", "ssm_moe_lm",
                           "hybrid_dense_lm"):
         from alphafold2_tpu.models import mla_moe_lm as lm
@@ -201,7 +210,8 @@ def build_task(cfg: Config) -> Task:
                     lm.step_metrics, partial(lm.init, model), lm.tiny_batch)
     raise ValueError(
         f"unknown model.arch {cfg.model.arch!r}; expected 'alphafold2', "
-        "'mla_moe_lm', 'swa_moe_lm', 'ssm_moe_lm' or 'hybrid_dense_lm'")
+        "'mla_moe_lm', 'swa_moe_lm', 'ssm_moe_lm', 'hybrid_dense_lm' or "
+        "'dsa_moe_lm'")
 
 
 def build_optimizer(cfg: Config) -> optax.GradientTransformation:

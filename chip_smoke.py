@@ -2,7 +2,7 @@
 """First light on the attached TPU: drive the main path once, check it, say so.
 
     python chip_smoke.py                # one chip: train, serve, kernels, scan,
-                                        # convolution
+                                        # convolution, sparse core
     python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh, and
                                         # the ring's blocks against references
 
@@ -29,6 +29,10 @@ Default phases, on one chip, at the width of the flagship (bench.py):
 5. causal_conv_8k — the state-space layers' causal depthwise convolution
    (``ops/ssm.py``) on bfloat16 inputs at both state-space cells' channel
    counts, output and three gradients against the float32 XLA form.
+6. sparse_core_16k — the learned-sparse-attention core (``ops/dsa.py``) at
+   the fifth language model's shape, every query reading the 2,048 keys an
+   indexer of random weights picks: output, log-sum-exp and three
+   gradients in bfloat16 against a float32 form in blocks of queries.
 
 Every phase prints one JSON object on a line of its own. The LAST line of
 stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
@@ -915,6 +919,99 @@ def phase_causal_conv(channels=CELL_CONVS, length=8192, taps=4, seed=0,
     return record
 
 
+SPARSE_CORE_TOL = 2e-2
+
+
+def phase_sparse_core(heads=32, groups=4, width=128, length=16384, topk=2048,
+                      seed=0, tol=SPARSE_CORE_TOL) -> dict:
+    """``ops/dsa.py`` ``sparse_core`` on bfloat16 q, k and v at the sixth
+    language model's shape (on a TPU the Pallas kernels of
+    ``ops/pallas/dsa_core.py``; a fall-back to the dense form there fails
+    the phase), each query reading the ``topk`` keys that ``selection``
+    picks from an indexer of random weights: the output, the log-sum-exp
+    and the gradients towards q, k and v against the same mathematics in
+    float32 at the highest matmul precision, 256 queries at a time (a
+    whole 16,384 x 16,384 float32 score matrix a head would not fit), each
+    error the largest absolute difference over the reference's largest
+    entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.ops import dsa
+
+    keys = jax.random.split(jax.random.key(seed), 8)
+    q = (jax.random.normal(keys[0], (1, heads, length, width))
+         * width ** -0.5).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (1, groups, length, width)).astype(
+        jnp.bfloat16) for kk in keys[1:3])
+    weight = jax.random.normal(keys[3], q.shape)
+    packed, _ = jax.jit(lambda a, b, c: dsa.selection(a, b, c, topk, 512))(
+        jax.random.normal(keys[4], (1, length, 16, 64), jnp.bfloat16),
+        jax.random.normal(keys[5], (1, length, 64), jnp.bfloat16),
+        jax.random.normal(keys[6], (1, length, 16)))
+
+    def blocked(q, k, v):
+        """The core in float32, a block of 256 queries at a time."""
+        rep = heads // groups
+        k32, v32 = (jnp.repeat(t.astype(jnp.float32), rep, 1) for t in (k, v))
+
+        @jax.checkpoint
+        def one(args):
+            qb, words = args
+            keep = dsa.unpack(words)[:, None]
+            logits = jnp.einsum("bhqd,bhkd->bhqk", qb.astype(jnp.float32),
+                                k32, precision="highest")
+            logits = jnp.where(keep, logits, -jnp.inf)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            out = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(logits - lse[..., None]),
+                             v32, precision="highest")
+            return out, lse
+
+        qs = q.reshape(1, heads, length // 256, 256, width).transpose(
+            2, 0, 1, 3, 4)
+        words = packed.reshape(1, length // 256, 8, length).transpose(
+            1, 0, 2, 3)
+        out, lse = jax.lax.map(one, (qs, words))
+        return (out.transpose(1, 2, 0, 3, 4).reshape(q.shape),
+                lse.transpose(1, 2, 0, 3).reshape(1, heads, length))
+
+    def kernel(q, k, v):
+        out, lse, _ = dsa.sparse_core(q, k, v, packed)
+        return out, lse
+
+    def both(fn):
+        def weighted(*a):
+            out, lse = fn(*a)
+            return jnp.sum(out.astype(jnp.float32) * weight), (out, lse)
+
+        return jax.jit(jax.value_and_grad(weighted, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    def rel_err(got, want):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(got - want))
+                     / (jnp.max(jnp.abs(want)) + 1e-30))
+
+    (_, (out, lse)), grads = both(kernel)(q, k, v)
+    (_, (out_r, lse_r)), grads_r = both(blocked)(q, k, v)
+    errs = {"fwd": rel_err(out, out_r), "lse": rel_err(lse, lse_r),
+            **{f"d{name}": rel_err(g, gr) for name, g, gr in zip(
+                ("q", "k", "v"), grads, grads_r)}}
+    in_kernel = dsa.core_kernel_takes(length, heads, groups)
+    ok = all(e == e and e <= tol for e in errs.values())
+    record = {"phase": "sparse_core_16k" if length == 16384
+              else "sparse_core", "shape": [1, heads, length, width],
+              "groups": groups, "topk": topk, "tol": tol, "ok": ok,
+              "implementation": "pallas" if in_kernel else "xla",
+              "tiles_visited_pct": float(dsa.tiles_visited_pct(packed)),
+              **{k_: float(f"{v_:.3g}") for k_, v_ in errs.items()}}
+    if not ok or (jax.default_backend() == "tpu" and not in_kernel):
+        raise RuntimeError(
+            "the sparse core disagrees with its float32 reference or fell "
+            "back to the dense form on a TPU: " + json.dumps(record))
+    return record
+
+
 # ------------------------------------------------------------- four chips ---
 
 
@@ -1049,6 +1146,7 @@ def main(argv=None) -> int:
             emit(phase_ssd_scan())
             emit(phase_ssd_scan(groups=1, chunk=256))
             emit(phase_causal_conv())
+            emit(phase_sparse_core())
         emit({"phase": "done", "seconds": round(time.perf_counter() - t0, 1)})
     except Exception as e:  # the boundary: report, then fail
         import traceback

@@ -94,6 +94,12 @@ def test_the_cell_resolves_to_files_of_its_own_kind():
             os.path.join(BENCH, "readers", spec["reader"] + ".py"))
 
 
+# the cells later PRs appended, in their order
+LATER_CELLS = ["train_nemotron3_nano_ep16_seq8k",
+               "train_granite4_h_micro_pp4_seq8k",
+               "train_keye_vl2_ep8_seq16k"]
+
+
 def test_the_manifest_keeps_what_it_had():
     """The three cells and their metrics as before; every list only gained
     the new cell at its end (and, since, the cells of later PRs after it)."""
@@ -106,10 +112,8 @@ def test_the_manifest_keeps_what_it_had():
     for metric in m["end_to_end"] + m["per_layer"]:
         cells = metric.get("workloads", [])
         if CELL in cells:  # nothing after it but what later PRs appended
-            assert cells[cells.index(CELL) + 1:] in (
-                [], ["train_nemotron3_nano_ep16_seq8k"],
-                ["train_nemotron3_nano_ep16_seq8k",
-                 "train_granite4_h_micro_pp4_seq8k"])
+            after = cells[cells.index(CELL) + 1:]
+            assert after == [c for c in LATER_CELLS if c in after]
     assert m["run_seconds"] == 45
 
 

@@ -693,6 +693,50 @@ def test_causal_conv_compiles_for_v5e(one_chip, monkeypatch, channels,
     assert f",8195,{channels}]" not in text
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_sparse_core_compiles_for_v5e(one_chip, monkeypatch, direction):
+    """The fifth language-model cell's sparse core, 32 query heads over 4
+    key/value heads of 128 at 16,384 positions, every query's selection
+    bit-packed, through ``dsa.sparse_core`` on the TPU branch: the three
+    kernels of ``ops/pallas/dsa_core.py`` (a group's eight heads a grid
+    step within the scoped VMEM), no key or value broadcast to the query
+    heads and no length x length array."""
+    import re
+
+    from alphafold2_tpu.ops import dsa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def core(q, k, v, packed):
+        out, _, in_kernel = dsa.sparse_core(q, k, v, packed)
+        assert in_kernel
+        return out
+
+    text = _compile(
+        core if direction == "fwd" else _grad_of(core), one_chip,
+        ((1, 32, 16384, 128), "bfloat16"), ((1, 4, 16384, 128), "bfloat16"),
+        ((1, 4, 16384, 128), "bfloat16"), ((1, 512, 16384), "int32"))
+    assert "dsa_core_fwd" in text
+    assert ("dsa_core_dq" in text) == ("dsa_core_dkv" in text) \
+        == (direction == "bwd")
+    assert "16384,16384]" not in text
+    assert not re.search(r"= bf16\[1,32,16384,128\]\S* broadcast\(", text)
+
+
+def test_selection_compiles_for_v5e(one_chip):
+    """The same cell's selection of one layer: the indexer's scores of 512
+    queries at a time against the 16,384 keys, the exact top 2,048, the
+    packing; no length x length array of scores."""
+    from alphafold2_tpu.ops import dsa
+
+    text = _compile(
+        lambda q_i, k_i, w: dsa.selection(q_i, k_i, w, 2048, 512),
+        one_chip, ((1, 16384, 16, 64), "bfloat16"),
+        ((1, 16384, 64), "bfloat16"), ((1, 16384, 16), "float32"))
+    assert "s32[1,512,16384]" in text
+    assert "16384,16384]" not in text and "approx" not in text.lower()
+
+
 def test_ssm_lm_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted train step of the benchmark's third language-model
     cell (667 M parameters, 1 x 8,192 tokens) for one described chip: the

@@ -139,6 +139,26 @@ def test_conv_phase_agrees_with_the_float32_form_at_a_small_size(
     assert chip_smoke.CELL_CONVS == (6144, 4352)
 
 
+def test_sparse_core_phase_agrees_with_the_float32_form_at_a_small_size(
+        monkeypatch):
+    """The sparse core's phase, small: the dense form off the TPU (the
+    record says so), and the kernels interpreted where the rule is steered
+    to take them; every error under the chip's tolerance, and a tolerance
+    nothing meets fails the phase."""
+    from alphafold2_tpu.ops import dsa
+
+    small = dict(heads=8, groups=2, width=64, length=512, topk=64)
+    rec = chip_smoke.phase_sparse_core(**small)
+    assert rec["phase"] == "sparse_core" and rec["implementation"] == "xla"
+    monkeypatch.setattr(dsa, "core_kernel_takes", lambda *shape: True)
+    rec = chip_smoke.phase_sparse_core(**small)
+    assert rec["ok"] and rec["implementation"] == "pallas"
+    assert 0 < max(rec[k] for k in ("fwd", "lse", "dq", "dk", "dv")) \
+        <= chip_smoke.SPARSE_CORE_TOL
+    with pytest.raises(RuntimeError, match="disagrees with its float32"):
+        chip_smoke.phase_sparse_core(**small, tol=1e-9)
+
+
 def test_kernel_phase_takes_a_filter_by_name():
     rec = chip_smoke.phase_kernels(small=True, only="moe_grouped")
     assert [c["case"] for c in rec["cases"]] == ["moe_grouped_matmul_small"]
@@ -210,7 +230,8 @@ def _fake_chip(monkeypatch, count=1, **phases):
     monkeypatch.setattr("alphafold2_tpu.enable_compile_cache", lambda: None)
     calls = []
     for name in ("phase_train", "phase_serve", "phase_kernels",
-                 "phase_ssd_scan", "phase_causal_conv", "phase_mesh"):
+                 "phase_ssd_scan", "phase_causal_conv", "phase_sparse_core",
+                 "phase_mesh"):
         def phase(name=name, **kwargs):
             calls.append((name, kwargs) if kwargs else name)
             if name in phases:
@@ -229,7 +250,7 @@ def test_success_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert calls == ["phase_train", "phase_serve", "phase_kernels",
                      "phase_ssd_scan",
                      ("phase_ssd_scan", {"groups": 1, "chunk": 256}),
-                     "phase_causal_conv"]
+                     "phase_causal_conv", "phase_sparse_core"]
     for ln in lines:  # one JSON object per earlier line
         assert isinstance(json.loads(ln), dict)
 
