@@ -44,7 +44,7 @@ scores,select}``, ``layer_N/attn/indexer_loss``, ``layer_N/moe/{router,
 dispatch,experts,combine}``, ``embed``, ``final_norm``, ``head``, ``loss``).
 A layer's recomputation in the backward pass keeps the packed selection and
 the core's output and log-sum-exp (``remat_layer``'s names and
-``ops/dsa.py`` ``SELECTION``): no top-k runs twice.
+``ops/dsa.py`` ``SELECTION``): no selection is made twice.
 """
 
 from __future__ import annotations
@@ -114,8 +114,8 @@ def rotary_first_half(x, positions, theta: float):
 class Indexer(nn.Module):
     """The indexer's projections and the selection they make. ``u`` is the
     stopped stream. Returns (qI (B, S, J, Di), kI (B, S, Di), w (B, S, J)
-    float32, the packed selection, selected keys summed over the
-    queries)."""
+    float32, the packed selection, selected keys summed over the queries,
+    whether the selection's threshold came from the Pallas kernel)."""
 
     cfg: DsaLMConfig
     dtype: Any = jnp.float32
@@ -137,17 +137,18 @@ class Indexer(nn.Module):
             q_i = rotary_first_half(q_i, positions, c.rope_theta)
             k_i = rotary_first_half(k_i[:, :, None], positions,
                                     c.rope_theta)[:, :, 0]
-        packed, selected = dsa.selection(q_i, k_i, w, c.index_topk,
-                                         c.index_chunk)
-        return q_i, k_i, w, packed, selected
+        packed, selected, in_kernel = dsa.selection(
+            q_i, k_i, w, c.index_topk, c.index_chunk)
+        return q_i, k_i, w, packed, selected, in_kernel
 
 
 class SparseAttention(nn.Module):
     """Returns (the attention's output, the layer's counters of the sparse
     attention: ``keys_selected_mean``, ``indexer_kl`` (the indexer's loss,
-    which carries its gradient), ``core_in_kernel``, ``tiles_visited_pct``,
-    ``selection_sample`` (``ops/dsa.py`` ``selection_sample``: the words of
-    the packed selection that the benchmark sets beside its reference's))."""
+    which carries its gradient), ``core_in_kernel``, ``select_in_kernel``,
+    ``tiles_visited_pct``, ``selection_sample`` (``ops/dsa.py``
+    ``selection_sample``: the words of the packed selection that the
+    benchmark sets beside its reference's))."""
 
     cfg: DsaLMConfig
     dtype: Any = jnp.float32
@@ -175,7 +176,7 @@ class SparseAttention(nn.Module):
             positions = jnp.arange(s)
             q = mla.rotary_half_split(q, positions, c.rope_theta)
             k = mla.rotary_half_split(k, positions, c.rope_theta)
-        q_i, k_i, w, packed, selected = Indexer(
+        q_i, k_i, w, packed, selected, select_in_kernel = Indexer(
             c, self.dtype, name="indexer")(jax.lax.stop_gradient(u))
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         with jax.named_scope("core"):
@@ -187,6 +188,7 @@ class SparseAttention(nn.Module):
             "keys_selected_mean": selected.astype(jnp.float32) / (b * s),
             "indexer_kl": kl,
             "core_in_kernel": jnp.float32(in_kernel),
+            "select_in_kernel": jnp.float32(select_in_kernel),
             "tiles_visited_pct": dsa.tiles_visited_pct(packed),
             "selection_sample": dsa.selection_sample(packed),
         }

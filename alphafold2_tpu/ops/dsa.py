@@ -9,8 +9,10 @@ one key head and ``w`` (B, S, J) its per-head weights:
   (:func:`index_scores`), float32;
 - selection ``S_t``: the ``topk`` largest ``I[t, s]``, ties to the lower
   ``s`` as ``lax.top_k`` breaks them; every ``s <= t`` while ``t < topk``
-  (:func:`select`). Exact: a top-k that is approximate picks other keys, and
-  is another model;
+  (:func:`select`): the keys at or above the ``topk``-th largest score, on a
+  TPU found by the Pallas bisection of ``ops/pallas/dsa_select.py``,
+  elsewhere read from ``lax.top_k``. Exact: a top-k that is approximate
+  picks other keys, and is another model;
 - the core ``softmax_{s in S_t}(q_h,t . k_s) v_s`` (:func:`sparse_core`):
   on a TPU the Pallas kernels of ``ops/pallas/dsa_core.py``, elsewhere the
   dense masked form :func:`sparse_core_xla`;
@@ -39,7 +41,7 @@ from jax.ad_checkpoint import checkpoint_name
 import jax.numpy as jnp
 import numpy as np
 
-from alphafold2_tpu.ops.pallas import dsa_core
+from alphafold2_tpu.ops.pallas import dsa_core, dsa_select
 
 F32 = jnp.float32
 SELECTION = "dsa_selection"  # the checkpoint name of the packed mask
@@ -59,6 +61,16 @@ def core_kernel_takes(length: int, heads: int, groups: int) -> bool:
             and (rep % 8 == 0 or groups == 1))
 
 
+def select_kernel_takes(shape) -> bool:
+    """Whether the Pallas threshold serves a (B, C, S) block of scores: on a
+    TPU, with S on the lanes' 128, C in whole grid steps of rows, and a
+    grid step's rows within ``dsa_select.TILE_BYTES`` of VMEM."""
+    _, c, s = shape
+    return (jax.default_backend() == "tpu" and s % dsa_select.LANES == 0
+            and c % dsa_select.ROWS == 0
+            and dsa_select.ROWS * s * 4 <= dsa_select.TILE_BYTES)
+
+
 def index_scores(q_i, k_i, w, start):
     """The indexer's scores of a block of queries against every key:
     ``q_i`` (B, C, J, Di), ``k_i`` (B, S, Di), ``w`` (B, C, J) float32; the
@@ -72,26 +84,26 @@ def index_scores(q_i, k_i, w, start):
     return jnp.where(causal, scores, -jnp.inf)
 
 
-def _total_order(x):
-    """float32 -> int32 keys in the order ``lax.top_k`` sorts by: the
-    float's order, with -0.0 below +0.0."""
-    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
-    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-
-
 def select(scores, topk: int):
     """(B, C, S) scores (``-inf`` off the causal part) -> (B, C, S) bool:
     the ``topk`` largest a row, ties to the lower key; all the causal keys
-    of a row that has no more than ``topk``. The value of the ``topk``-th
-    largest comes from ``lax.top_k``, the keys from comparisons with it, so
-    no scatter is made."""
+    of a row that has no more than ``topk``. The order key of the
+    ``topk``-th largest comes from ``ops/pallas/dsa_select.py`` where
+    :func:`select_kernel_takes`, from ``lax.top_k`` elsewhere; the keys
+    from comparisons with it, so no scatter is made."""
     if topk >= scores.shape[-1]:
         return scores > -jnp.inf
-    kth = jax.lax.top_k(scores, topk)[0][..., -1:]
     # compared as ``lax.top_k`` orders them, -0.0 below +0.0
-    key, kth_key = _total_order(scores), _total_order(kth)
+    key = dsa_select.order_key(scores)
+    if select_kernel_takes(scores.shape):
+        kth_key = dsa_select.kth_key(
+            scores, topk, interpret=jax.default_backend() != "tpu")[..., None]
+    else:
+        kth_key = dsa_select.order_key(
+            jax.lax.top_k(scores, topk)[0][..., -1:])
     above = key > kth_key
-    tied = (key == kth_key) & (kth > -jnp.inf)
+    tied = (key == kth_key) & (
+        kth_key > dsa_select.order_key(jnp.float32(-jnp.inf)))
     room = topk - above.sum(-1, keepdims=True)
 
     def by_index(tied):  # the lowest-index ties that fit
@@ -139,11 +151,12 @@ def _unblocks(t):
 
 def selection(q_i, k_i, w, topk: int, chunk: int):
     """The selection of every query, bit-packed: (packed (B, S / 32, S)
-    int32, selected keys summed over the queries). ``chunk`` queries a
-    block (a multiple of 256 dividing S; the whole length where S is
-    shorter). No gradient: the inputs are stopped."""
+    int32, selected keys summed over the queries, whether the threshold
+    came from the Pallas kernel). ``chunk`` queries a block (a multiple of
+    256 dividing S; the whole length where S is shorter). No gradient: the
+    inputs are stopped."""
     q_i, k_i, w = jax.lax.stop_gradient((q_i, k_i, w))
-    s = q_i.shape[1]
+    b, s = q_i.shape[:2]
     chunk = min(chunk, s)
 
     def one(args):
@@ -158,7 +171,8 @@ def selection(q_i, k_i, w, topk: int, chunk: int):
         one, (_blocks(q_i, chunk), _blocks(w, chunk),
               jnp.arange(0, s, chunk)))
     packed = checkpoint_name(_unblocks(packed), SELECTION)
-    return packed, counts.sum()
+    return (packed, counts.sum(),
+            topk < s and select_kernel_takes((b, chunk, s)))
 
 
 def tiles_visited_pct(packed):

@@ -2,7 +2,7 @@
 """First light on the attached TPU: drive the main path once, check it, say so.
 
     python chip_smoke.py                # one chip: train, serve, kernels, scan,
-                                        # convolution, sparse core
+                                        # convolution, sparse core, selection
     python chip_smoke.py --four-chips   # four chips: the (dp, sp) mesh, and
                                         # the ring's blocks against references
 
@@ -33,6 +33,10 @@ Default phases, on one chip, at the width of the flagship (bench.py):
    the fifth language model's shape, every query reading the 2,048 keys an
    indexer of random weights picks: output, log-sum-exp and three
    gradients in bfloat16 against a float32 form in blocks of queries.
+7. select_16k — the same model's selection of 2,048 of 16,384 keys for a
+   block of 512 queries (``ops/dsa.py`` ``select``): the packed selection
+   through the Pallas threshold against the ``lax.top_k`` form, bit for
+   bit, and both forms' times.
 
 Every phase prints one JSON object on a line of its own. The LAST line of
 stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and
@@ -945,7 +949,7 @@ def phase_sparse_core(heads=32, groups=4, width=128, length=16384, topk=2048,
     k, v = (jax.random.normal(kk, (1, groups, length, width)).astype(
         jnp.bfloat16) for kk in keys[1:3])
     weight = jax.random.normal(keys[3], q.shape)
-    packed, _ = jax.jit(lambda a, b, c: dsa.selection(a, b, c, topk, 512))(
+    packed = jax.jit(lambda a, b, c: dsa.selection(a, b, c, topk, 512)[0])(
         jax.random.normal(keys[4], (1, length, 16, 64), jnp.bfloat16),
         jax.random.normal(keys[5], (1, length, 64), jnp.bfloat16),
         jax.random.normal(keys[6], (1, length, 16)))
@@ -1009,6 +1013,68 @@ def phase_sparse_core(heads=32, groups=4, width=128, length=16384, topk=2048,
         raise RuntimeError(
             "the sparse core disagrees with its float32 reference or fell "
             "back to the dense form on a TPU: " + json.dumps(record))
+    return record
+
+
+def phase_select(length=16384, topk=2048, chunk=512, heads=16, width=64,
+                 seed=0, reps=5) -> dict:
+    """``ops/dsa.py`` ``select`` at the fifth language model's block: the
+    scores of ``chunk`` queries against ``length`` keys from an indexer of
+    random weights (``heads`` heads of ``width``), for the block whose rows
+    straddle ``topk`` causal keys (the first half of its rows keep every
+    causal key) and for the last block. The packed selection through the
+    Pallas threshold (``ops/pallas/dsa_select.py``; a fall-back to
+    ``lax.top_k`` on a TPU fails the phase) must equal the ``lax.top_k``
+    form's bit for bit; both forms' ms a block, best of ``reps``, on the
+    host's clock."""
+    import jax
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.ops import dsa
+
+    keys = jax.random.split(jax.random.key(seed), 3)
+    q_i = jax.random.normal(keys[0], (1, chunk, heads, width))
+    k_i = jax.random.normal(keys[1], (1, length, width))
+    w = jax.random.normal(keys[2], (1, chunk, heads)) * (heads * width) ** -.5
+    starts = (max(topk - chunk // 2, 0), length - chunk)
+    scores = [jax.jit(dsa.index_scores, static_argnums=3)(q_i, k_i, w, start)
+              for start in starts]
+    in_kernel = dsa.select_kernel_takes(scores[0].shape)
+
+    def best_ms(fn, x):
+        jax.block_until_ready(fn(x))
+        times = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(x))
+            times.append(time.perf_counter() - t0)
+        return 1e3 * min(times)
+
+    kernel_form = jax.jit(lambda s: dsa.pack(dsa.select(s, topk)))
+    packed = [kernel_form(s) for s in scores]
+    kernel_ms = best_ms(kernel_form, scores[-1])
+    takes = dsa.select_kernel_takes
+    dsa.select_kernel_takes = lambda shape: False  # the lax.top_k form
+    try:
+        top_k_form = jax.jit(lambda s: dsa.pack(dsa.select(s, topk)))
+        want = [top_k_form(s) for s in scores]
+        top_k_ms = best_ms(top_k_form, scores[-1])
+    finally:
+        dsa.select_kernel_takes = takes
+    equal = all(bool(jnp.array_equal(a, b)) for a, b in zip(packed, want))
+    record = {"phase": "select_16k" if length == 16384 else "select",
+              "shape": [1, chunk, length], "topk": topk,
+              "block_starts": list(starts), "equal": equal,
+              "implementation": "pallas" if in_kernel else "top_k",
+              "keys_selected": [int(jnp.sum(jax.lax.population_count(p)))
+                                for p in packed],
+              "ms": {"pallas" if in_kernel else "top_k": round(kernel_ms, 3),
+                     "top_k": round(top_k_ms, 3)}}
+    if not equal or (jax.default_backend() == "tpu" and not in_kernel):
+        raise RuntimeError(
+            "the selection through the Pallas threshold differs from the "
+            "lax.top_k form or fell back to it on a TPU: "
+            + json.dumps(record))
     return record
 
 
@@ -1147,6 +1213,7 @@ def main(argv=None) -> int:
             emit(phase_ssd_scan(groups=1, chunk=256))
             emit(phase_causal_conv())
             emit(phase_sparse_core())
+            emit(phase_select())
         emit({"phase": "done", "seconds": round(time.perf_counter() - t0, 1)})
     except Exception as e:  # the boundary: report, then fail
         import traceback

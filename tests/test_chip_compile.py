@@ -723,17 +723,23 @@ def test_sparse_core_compiles_for_v5e(one_chip, monkeypatch, direction):
     assert not re.search(r"= bf16\[1,32,16384,128\]\S* broadcast\(", text)
 
 
-def test_selection_compiles_for_v5e(one_chip):
-    """The same cell's selection of one layer: the indexer's scores of 512
-    queries at a time against the 16,384 keys, the exact top 2,048, the
-    packing; no length x length array of scores."""
+def test_selection_compiles_for_v5e(one_chip, monkeypatch):
+    """The same cell's selection of one layer on the TPU branch: the
+    indexer's scores of 512 queries at a time against the 16,384 keys, the
+    exact top 2,048 through the Pallas threshold of
+    ``ops/pallas/dsa_select.py`` (32 rows of 16,384 keys a grid step within
+    the scoped VMEM) and no sort of the scores, the packing; no length x
+    length array of scores."""
     from alphafold2_tpu.ops import dsa
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = _compile(
-        lambda q_i, k_i, w: dsa.selection(q_i, k_i, w, 2048, 512),
+        lambda q_i, k_i, w: dsa.selection(q_i, k_i, w, 2048, 512)[:2],
         one_chip, ((1, 16384, 16, 64), "bfloat16"),
         ((1, 16384, 64), "bfloat16"), ((1, 16384, 16), "float32"))
-    assert "s32[1,512,16384]" in text
+    assert "s32[1,512,16384]" in text and "dsa_select_kth" in text
+    assert not [line for line in text.split("\n")
+                if " sort(" in line and "[1,512,16384]" in line]
     assert "16384,16384]" not in text and "approx" not in text.lower()
 
 

@@ -159,6 +159,27 @@ def test_sparse_core_phase_agrees_with_the_float32_form_at_a_small_size(
         chip_smoke.phase_sparse_core(**small, tol=1e-9)
 
 
+def test_select_phase_agrees_with_the_top_k_form_at_a_small_size(
+        monkeypatch):
+    """The selection's phase, small: the ``lax.top_k`` form alone off the
+    TPU (the record says so), and the Pallas threshold interpreted where
+    the rule is steered to take it, bit for bit the ``lax.top_k`` form's on
+    both blocks (the first straddles ``topk`` causal keys)."""
+    from alphafold2_tpu.ops import dsa
+
+    small = dict(length=512, topk=64, chunk=256, heads=4, width=16, reps=1)
+    rec = chip_smoke.phase_select(**small)
+    assert rec["phase"] == "select" and rec["implementation"] == "top_k"
+    monkeypatch.setattr(dsa, "select_kernel_takes", lambda shape: True)
+    rec = chip_smoke.phase_select(**small)
+    assert rec["equal"] and rec["implementation"] == "pallas"
+    assert rec["block_starts"] == [0, 256]
+    # 1 + 2 + .. + 64 keys for the first block's first 64 rows, 64 a row
+    # for the rest
+    assert rec["keys_selected"] == [64 * 65 // 2 + 192 * 64, 256 * 64]
+    assert sorted(rec["ms"]) == ["pallas", "top_k"]
+
+
 def test_kernel_phase_takes_a_filter_by_name():
     rec = chip_smoke.phase_kernels(small=True, only="moe_grouped")
     assert [c["case"] for c in rec["cases"]] == ["moe_grouped_matmul_small"]
@@ -231,7 +252,7 @@ def _fake_chip(monkeypatch, count=1, **phases):
     calls = []
     for name in ("phase_train", "phase_serve", "phase_kernels",
                  "phase_ssd_scan", "phase_causal_conv", "phase_sparse_core",
-                 "phase_mesh"):
+                 "phase_select", "phase_mesh"):
         def phase(name=name, **kwargs):
             calls.append((name, kwargs) if kwargs else name)
             if name in phases:
@@ -250,7 +271,8 @@ def test_success_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert calls == ["phase_train", "phase_serve", "phase_kernels",
                      "phase_ssd_scan",
                      ("phase_ssd_scan", {"groups": 1, "chunk": 256}),
-                     "phase_causal_conv", "phase_sparse_core"]
+                     "phase_causal_conv", "phase_sparse_core",
+                     "phase_select"]
     for ln in lines:  # one JSON object per earlier line
         assert isinstance(json.loads(ln), dict)
 

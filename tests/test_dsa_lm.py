@@ -27,7 +27,7 @@ from alphafold2_tpu.config import (  # noqa: E402
 )
 from alphafold2_tpu.models import dsa_moe_lm as dsa_lm  # noqa: E402
 from alphafold2_tpu.ops import dsa, mla  # noqa: E402
-from alphafold2_tpu.ops.pallas import dsa_core  # noqa: E402
+from alphafold2_tpu.ops.pallas import dsa_core, dsa_select  # noqa: E402
 from benchmark.reference import dsa_lm_model as ref  # noqa: E402
 
 # hidden 64, 4 query heads over 2 key/value heads of 32, an indexer of 4
@@ -164,6 +164,7 @@ def test_routing_counts_and_counters(both_sides):
     want = (topk * (topk + 1) / 2 + (SEQ - topk) * topk) / SEQ
     np.testing.assert_allclose(dsa_counters["keys_selected_mean"], want)
     np.testing.assert_array_equal(dsa_counters["core_in_kernel"], 0.0)
+    np.testing.assert_array_equal(dsa_counters["select_in_kernel"], 0.0)
     assert dsa_counters["tiles_visited_pct"].shape == (2,)
 
 
@@ -196,25 +197,88 @@ def test_the_loss_weight_scales_the_indexers_gradient_alone(params):
 # -------------------------------------------------- (c) the selection ---
 
 
-@pytest.mark.parametrize("ties", [False, True])
-def test_select_keeps_the_keys_top_k_names(ties):
+def _select_scores(case):
+    """(2, 32, 256) scores of 32 queries at positions 0, 8, .., 248 against
+    256 keys (``-inf`` past each), and the ``topk`` to pick: random normal;
+    rounded to halves (ties at the boundary); drawn from {1, +0.0, -0.0,
+    -1} (the signed zeros straddle the boundary); every score negative;
+    ``topk`` over most rows' causal keys (the k-th is ``-inf``); ``topk``
+    1; ``topk`` one short of the keys."""
+    rng = np.random.default_rng(7)
+    scores = rng.standard_normal((2, 32, 256)).astype(np.float32)
+    topk = {"short_rows": 200, "topk_1": 1, "topk_s_minus_1": 255}.get(case, 16)
+    if case == "ties":
+        scores = np.round(scores * 2) / 2
+    elif case == "signed_zeros":
+        scores = rng.choice(np.array([1.0, 0.0, -0.0, -1.0], np.float32),
+                            p=[0.03, 0.05, 0.05, 0.87], size=scores.shape)
+    elif case == "all_negative":
+        scores = -np.abs(scores) - 1.0
+    t = 8 * np.arange(32)
+    causal = t[:, None] >= np.arange(256)[None, :]
+    return jnp.asarray(np.where(causal, scores, -np.inf)), topk, t
+
+
+@pytest.fixture(params=["kernel", "top_k"])
+def form(request, monkeypatch):
+    """``select`` with its threshold from the Pallas kernel (interpreted
+    here) or from ``lax.top_k``."""
+    monkeypatch.setattr(dsa, "select_kernel_takes",
+                        lambda shape: request.param == "kernel")
+    return request.param
+
+
+@pytest.mark.parametrize("case", ["distinct", "ties", "signed_zeros",
+                                  "all_negative", "short_rows", "topk_1",
+                                  "topk_s_minus_1"])
+def test_select_keeps_the_keys_top_k_names(form, case):
     """The threshold and the lower-key rule pick exactly ``lax.top_k``'s
     indices, causal keys only, every causal key of a short row; with ties
-    at the boundary too (scores rounded to a few values)."""
-    s, topk = 96, 16
-    scores = jax.random.normal(jax.random.key(7), (2, s, s))
-    if ties:
-        scores = jnp.round(scores * 2) / 2
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    scores = jnp.where(causal, scores, -jnp.inf)
-    keep = dsa.select(scores, topk)
+    at the boundary, -0.0 and +0.0 on either side of it, negative rows,
+    ``topk`` 1 and one short of the keys; the threshold from the kernel or
+    from ``lax.top_k`` alike."""
+    scores, topk, t = _select_scores(case)
+    keep = jax.jit(lambda s: dsa.select(s, topk))(scores)  # traced anew
     _, idx = jax.lax.top_k(scores, topk)
-    want = jnp.zeros((2, s, s), bool).at[
-        jnp.arange(2)[:, None, None], jnp.arange(s)[None, :, None],
+    causal = scores > -jnp.inf
+    want = jnp.zeros(scores.shape, bool).at[
+        jnp.arange(2)[:, None, None], jnp.arange(32)[None, :, None],
         idx].set(True) & causal
     np.testing.assert_array_equal(keep, want)
     np.testing.assert_array_equal(keep.sum(-1), jnp.broadcast_to(
-        jnp.minimum(jnp.arange(s) + 1, topk), (2, s)))
+        jnp.minimum(t + 1, topk), (2, 32)))
+
+
+def test_kth_key_is_the_key_top_k_ends_at():
+    """The kernel alone, interpreted: on rows of random 32-bit patterns
+    read as float32 (NaNs made 1.0), so that every bit of the keys is
+    decided, the key of ``lax.top_k``'s last value, for ``topk`` low, in
+    the middle and high."""
+    rng = np.random.default_rng(3)
+    bits = rng.integers(-2 ** 31, 2 ** 31, (1, 32, 512)).astype(np.int32)
+    x = bits.view(np.float32)
+    x = jnp.asarray(np.where(np.isnan(x), np.float32(1.0), x))
+    for topk in (3, 256, 509):
+        got = jax.jit(dsa_select.kth_key, static_argnums=(1, 2))(
+            x, topk, True)
+        np.testing.assert_array_equal(got, dsa_select.order_key(
+            jax.lax.top_k(x, topk)[0][..., -1]))
+
+
+def test_selection_says_where_its_threshold_came_from(monkeypatch):
+    """``selection``'s flag reads the rule, and the packed selection is the
+    same bits through the kernel as through ``lax.top_k``."""
+    keys = jax.random.split(jax.random.key(4), 3)
+    args = (jax.random.normal(keys[0], (1, 256, 2, 8)),
+            jax.random.normal(keys[1], (1, 256, 8)),
+            jax.random.normal(keys[2], (1, 256, 2)))
+    made = {}
+    for takes in (False, True):
+        monkeypatch.setattr(dsa, "select_kernel_takes", lambda shape: takes)
+        made[takes] = dsa.selection(*args, 24, 256)
+        assert made[takes][2] is takes
+    np.testing.assert_array_equal(made[True][0], made[False][0])
+    assert int(made[True][1]) == int(made[False][1])
 
 
 def test_pack_lays_queries_out_as_the_kernels_read_them():
